@@ -58,7 +58,7 @@ Six rule families (see ANALYSIS.md for the full contract):
   also reach an unminimized-DFA source (raw ``DFA(...)`` construction,
   ``compile_dfa(minimize=False)``) — an un-reduced table silently
   closes the assoc gate and shrinks the stride budget
-  (analysis.shrink; PERF.md "shrink").
+  (analysis.shrink; DEVICE_PLANE.md "shrink").
 - **launch graph / transfer budget** (`device-multi-launch-chain`,
   `device-undonated-buffer`, `device-host-roundtrip`,
   `device-sync-in-staging-loop`, `stage-redundant-copy`): the

@@ -1,6 +1,6 @@
 """Batch-exactness dataflow rules for ``FilterPlugin.process_batch``.
 
-The batched fast path (PERF.md) carries delicate contracts the type
+The batched fast path (DEVICE_PLANE.md) carries delicate contracts the type
 system cannot see: the engine treats ``return None`` / any raise from
 ``process_batch`` as a *decline* and re-runs the chain per-record from
 the declining filter onward (``engine._ingest_raw`` + the decoded-tail

@@ -1,7 +1,7 @@
 """grep-unminimized-dfa rule.
 
-fbtpu-shrink (PERF.md "shrink") moves the whole kernel-table economy —
-assoc eligibility, stride depth, native table cache footprint, mesh
+fbtpu-shrink (DEVICE_PLANE.md "shrink") moves the whole kernel-table
+economy — assoc eligibility, stride depth, native table cache footprint, mesh
 replication size — onto one invariant: every ``DFA`` that reaches
 ``GrepProgram`` / ``GrepTables`` / ``GrepFilterTables`` passed through
 the compile-path reduction pass (``regex.dfa.compile_dfa``: Hopcroft

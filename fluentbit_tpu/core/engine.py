@@ -265,7 +265,7 @@ class Engine:
             "fluentbit", "device", "reattach_total",
             "Late/re-attach generations (the mesh lane swapped in "
             "live after earlier refusals)")
-        # fbtpu-shrink (PERF.md "shrink"): compile-path DFA reduction
+        # fbtpu-shrink (DEVICE_PLANE.md "shrink"): compile-path DFA reduction
         # outcomes plus the approximate first-pass mask's runtime
         # economics — an approx mask that admits nearly everything is
         # pure overhead, and these counters (not a mystery-slow ingest
@@ -1051,7 +1051,7 @@ class Engine:
                 self.m_memrb_dropped_bytes.inc(
                     c.size, (ins.display_name,))
 
-        # ---- raw fast path (VERDICT r1: no decode-per-append) ----
+        # ---- raw fast path (no decode-per-append) ----
         # When nothing on the chain needs decoded events — no
         # processors, no stream-processor task, and every matching
         # filter can operate on raw chunk bytes (grep's native
@@ -1059,8 +1059,8 @@ class Engine:
         # scanner and appended as raw spans. When additionally every
         # matching filter is stateless (thread_safe_raw), the chain runs
         # under the INPUT's lock only, so independent inputs ingest in
-        # parallel (VERDICT r2 #4: the global lock stops serializing
-        # independent tags; reference threaded inputs + per-input chunk
+        # parallel (the global lock stops serializing independent
+        # tags; reference threaded inputs + per-input chunk
         # maps, src/flb_input_thread.c:225).
         matching = [f for f in self.filters if f.route.matches(tag)]
         # flux-backed tasks don't need decoded events — their hidden

@@ -115,13 +115,11 @@ def build_sharded_counts(mesh, n_pad: int):
     ``lax.psum`` over the mesh axis. Factored out of the dispatch
     wrapper so the fbtpu-speccheck static==dynamic crosscheck can
     ``lower()`` the exact shipped program on the simulated mesh."""
-    from jax import lax
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
 
-    from ..ops.device import shard_map_fn
     from ..ops.mesh import rule_spec
 
-    shard_map = shard_map_fn()
     axis = mesh.axis_names[0]
 
     def step(s, v):
@@ -250,12 +248,11 @@ def build_fused_absorb(mesh, n_pad: int, n_fields: int, hll_p: int,
         donate_idx = tuple(range(2 + 2 * n_fields, 2 + 3 * n_fields))
     if mesh is None:
         return jax.jit(step, donate_argnums=donate_idx)
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from ..ops.device import shard_map_fn
     from ..ops.mesh import rule_spec
 
-    shard_map = shard_map_fn()
     in_specs = [rule_spec("flux-fused", axis, "seg"),
                 rule_spec("flux-fused", axis, "valid")]
     for _ in range(n_fields):

@@ -434,8 +434,8 @@ class GrepTables:
         The tables are read-only so sharing is CORRECT — but with
         several inputs ingesting concurrently every walker hammers the
         same physical arrays, and on small hosts the shared hot lines
-        serialize in the cache hierarchy (BENCH_r05: inputs4 at 0.92×
-        of inputs1). Each ingest thread matching through its own copy
+        serialize in the cache hierarchy (an earlier CPU-host run:
+        inputs4 at 0.92× of inputs1). Each ingest thread matching through its own copy
         keeps the walk NUMA/cache-local; the copy is a few hundred KB,
         made once per (thread, filter)."""
         new = self.__class__.__new__(self.__class__)
@@ -700,8 +700,8 @@ def stage_field(
     (a caller-known record count) skips the counting pre-pass.
 
     The returned arrays are views of a per-thread arena reused across
-    calls (the VERDICT-r4 staging-ceiling fix: a fresh zeroed [B, L]
-    matrix per chunk was pure memset bandwidth) — consume or copy them
+    calls (the staging-ceiling fix of an earlier round: a fresh zeroed
+    [B, L] matrix per chunk was pure memset bandwidth) — consume or copy them
     before this thread's next stage_field call. Bytes past lengths[i]
     in a row are NOT zeroed; consumers mask by length (both DFA kernels
     do). Extraction fans out across the native worker pool

@@ -29,7 +29,8 @@ DFA (fluentbit_tpu.regex.dfa) runs over a ``[B, L] uint8`` batch as a
 - Kernel selection: ``kernel="auto"`` (default) picks scan vs assoc per
   program shape at trace time — the sequential scan on host-CPU backends
   (where the log2-depth compose tree's S× extra work is pure overhead:
-  BENCH_r05 measured it 300× slower there), the parallel-in-time assoc
+  an earlier CPU-host run measured it 300× slower there), the
+  parallel-in-time assoc
   kernel on real accelerators when the state count is small enough for
   the extra parallel work to ride otherwise-idle vector lanes.
 
@@ -205,8 +206,8 @@ class GrepProgram:
         """Scan-vs-assoc per program shape, decided at trace time (the
         attached platform is known by then). The scan kernel's Lk
         serialized gathers are cheap on a host CPU where the assoc
-        tree's S× parallel work is pure overhead (BENCH_r05: 300×
-        slower there); assoc pays off only when idle vector lanes
+        tree's S× parallel work is pure overhead (an earlier CPU-host
+        run: 300× slower there); assoc pays off only when idle vector lanes
         absorb that work — a real accelerator and a small state count."""
         if self.kernel != "auto":
             return self.kernel
@@ -517,11 +518,8 @@ class GrepProgram:
         counts[R])`` with ``B`` divisible by the mesh size; ``counts`` is
         the global (all-device) per-rule match total.
         """
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        from .device import shard_map_fn
-
-        shard_map = shard_map_fn()
 
         if self._jit is None:
             from . import device
@@ -621,14 +619,17 @@ class GrepProgram:
             return "rules"
         return "batch"
 
-    def _mesh_handle(self, mesh, donate: str = "auto",
-                     with_counts: bool = True):
-        """Build (and cache per mesh structure) the explicitly
-        partitioned matcher: a ``shard_map`` program under ``jax.jit``
-        with declarative PartitionSpecs from the partition-rules layer,
-        tables device_put once with their shardings, and staged input
-        buffers donated where (and only where) they can alias an
-        output.
+    def _mesh_program(self, mesh, donate: str = "auto",
+                      with_counts: bool = True):
+        """Build the explicitly partitioned matcher for ``mesh`` WITHOUT
+        placing anything on a device: a ``shard_map`` program under
+        ``jax.jit`` with declarative PartitionSpecs from the
+        partition-rules layer, and staged input buffers donated where
+        (and only where) they can alias an output. Returns ``(fn,
+        table shardings, batch sharding, lengths sharding, variant,
+        donate_idx)`` — what :meth:`_mesh_handle` places and caches,
+        and what tests/test_tpu_compile.py lowers for a described
+        4-chip mesh.
 
         ``with_counts=False`` compiles the engine-dispatch variant
         WITHOUT the per-rule match totals: the counts are an O(R·B)
@@ -636,24 +637,12 @@ class GrepProgram:
         sync point per segment launch — and the filter path never
         reads them. Only match_mesh/bench/metrics consumers pay for
         counts."""
+        from jax import shard_map
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
-        from . import device
-        from .device import shard_map_fn
         from .mesh import (aliasable_donations, match_partition_rules,
-                           mesh_key, partition_rules)
-
-        if self._jit is None:
-            if not device.wait(60.0):
-                raise RuntimeError(
-                    f"device backend not attached: {device.status()}"
-                )
-            self._materialize()
-        key = (mesh_key(mesh), donate, with_counts)
-        h = self._mesh_cache.get(key)
-        if h is not None:
-            return h
+                           partition_rules)
 
         axis = mesh.axis_names[0]
         variant = self.mesh_variant(mesh)
@@ -690,7 +679,6 @@ class GrepProgram:
                 counts = lax.psum(counts, axis_name=axis)
             return mask.astype(jnp.int32), counts
 
-        shard_map = shard_map_fn()
         out_specs = (spec_mask, spec_counts) if with_counts else spec_mask
         sm = shard_map(step, mesh=mesh,
                        in_specs=(tspecs, spec_b, spec_l),
@@ -732,6 +720,28 @@ class GrepProgram:
 
         fn = jax.jit(sm, in_shardings=(tsh, sh_b, sh_l),
                      out_shardings=out_sh, donate_argnums=donate_idx)
+        return fn, tsh, sh_b, sh_l, variant, donate_idx
+
+    def _mesh_handle(self, mesh, donate: str = "auto",
+                     with_counts: bool = True):
+        """The :meth:`_mesh_program` of ``mesh``, with the tables
+        device_put once under their shardings — built once and cached
+        per mesh structure."""
+        from . import device
+        from .mesh import mesh_key
+
+        if self._jit is None:
+            if not device.wait(60.0):
+                raise RuntimeError(
+                    f"device backend not attached: {device.status()}"
+                )
+            self._materialize()
+        key = (mesh_key(mesh), donate, with_counts)
+        h = self._mesh_cache.get(key)
+        if h is not None:
+            return h
+        fn, tsh, sh_b, sh_l, variant, donate_idx = self._mesh_program(
+            mesh, donate, with_counts)
         tables_dev = jax.device_put(self._tbl, tsh)
         h = _MeshHandle(fn, tables_dev, sh_b, sh_l, variant,
                         int(mesh.devices.size), donate_idx, with_counts)

@@ -426,12 +426,11 @@ def build_sharded_hll(hll: HyperLogLog, mesh):
     lax.pmax (union of HLLs). Factored out of the dispatch wrapper so
     the fbtpu-speccheck static==dynamic crosscheck can ``lower()`` the
     exact shipped program on the simulated mesh."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from .device import shard_map_fn
     from .mesh import rule_spec
 
-    shard_map = shard_map_fn()
     axis = mesh.axis_names[0]
     regs_spec = rule_spec("flux-hll", axis, "registers")
 
@@ -489,12 +488,11 @@ def build_sharded_cms(cms: CountMin, mesh):
     declarative ``flux-cms`` partition rule). Factored out of the
     dispatch wrapper for the fbtpu-speccheck lowering crosscheck, like
     :func:`build_sharded_hll`."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from .device import shard_map_fn
     from .mesh import rule_spec
 
-    shard_map = shard_map_fn()
     axis = mesh.axis_names[0]
     table_spec = rule_spec("flux-cms", axis, "table")
 

@@ -45,8 +45,9 @@ class ShardedTimings:
     """Per-thread timing shards for the raw path's hot-loop accounting.
 
     The previous shared dict + lock serialized every ingest worker on
-    one mutex several times per chunk (the BENCH_r05 multi-input
-    regression's lock half); adds now go to an uncontended thread-local
+    one mutex several times per chunk (the lock half of a multi-input
+    regression an earlier CPU-host run showed); adds now go to an
+    uncontended thread-local
     shard and reads sum across shards. The mapping interface
     (iteration / item get / item set) keeps bench.py's reset-and-read
     usage working: item reads return the cross-shard sum, item writes
@@ -54,7 +55,14 @@ class ShardedTimings:
     and store the value into every shard — meaningful for zero only.
     """
 
-    _KEYS = ("extract_s", "kernel_s", "compact_s", "records")
+    #: ``records`` counts every record the raw path served (native
+    #: matcher or device); the last three count the DEVICE lane only —
+    #: records whose segment was launched through the grep DeviceLane,
+    #: the overflow rows among them (longer than ``tpu_max_record_len``,
+    #: resolved on the CPU after the launch), and the staged bytes
+    #: handed to the launch (the [R, Bp, L] plane + lengths)
+    _KEYS = ("extract_s", "kernel_s", "compact_s", "records",
+             "device_records", "overflow_rows", "h2d_bytes")
 
     def __init__(self):
         import threading
@@ -201,7 +209,7 @@ class GrepFilter(FilterPlugin):
         ConfigMapEntry("tpu_max_record_len", "int", default=512,
                        desc="field byte length staged on device; longer "
                             "values resolve on the CPU fallback"),
-        # fbtpu-shrink approximate mode (PERF.md "shrink"): run an
+        # fbtpu-shrink approximate mode (DEVICE_PLANE.md "shrink"): run an
         # over-approximated (smaller) DFA as a first-pass mask on the
         # raw path and re-check admitted records exactly — output
         # stays byte-identical; only the hot table shrinks
@@ -240,8 +248,8 @@ class GrepFilter(FilterPlugin):
         # program_for is numpy-only (cheap); the backend transfer waits
         # on the attach controller so a slow/hung platform init never
         # blocks plugin init or ingest — records run the bit-exact CPU
-        # path until the device is up (VERDICT r2: CLI was un-killable
-        # for minutes inside eager jax init).
+        # path until the device is up (an earlier round's CLI was
+        # un-killable for minutes inside eager jax init).
         import threading
 
         self._program = None
@@ -938,6 +946,7 @@ class GrepFilter(FilterPlugin):
             batch, lengths, cnt = item
             lens_parts.append(lengths[:, :cnt])
             cnts.append(cnt)
+            tm.add("h2d_bytes", batch.nbytes + lengths.nbytes)
             if mesh is not None:
                 # sharded launch through the device fault domain: the
                 # launch closure re-stages (fresh device_put + donation)
@@ -987,6 +996,8 @@ class GrepFilter(FilterPlugin):
         lengths = np.concatenate(lens_parts, axis=1)
         # overflow rows (-2): decode just those records on the CPU
         overflow_rows = np.unique(np.nonzero(lengths == -2)[1])
+        tm.add("device_records", n)
+        tm.add("overflow_rows", len(overflow_rows))
         if len(overflow_rows):
             from ..codec.events import decode_events
 

@@ -224,9 +224,9 @@ class LogToMetricsFilter(FilterPlugin):
         """fluentbit_grep_shrink_* compile-outcome counters for the
         selector-rule DFAs — compiled through the same reducer as
         filter_grep's (FlbRegex → compile_dfa), so their savings land
-        in the same dashboard family, labelled by plugin (PERF.md
-        "shrink"); table bytes are accounted in the fbtpu-xray budget
-        report (ANALYSIS.md "fbtpu-xray")."""
+        in the same dashboard family, labelled by plugin
+        (DEVICE_PLANE.md "shrink"); table bytes are accounted in the
+        fbtpu-xray budget report (ANALYSIS.md "fbtpu-xray")."""
         if engine is None or getattr(engine, "m_shrink_states", None) \
                 is None:
             return

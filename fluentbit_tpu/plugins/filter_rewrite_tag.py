@@ -148,7 +148,7 @@ class RewriteTagFilter(FilterPlugin):
         rewrite_tag match matrices — the rule DFAs compile through the
         same reducer as filter_grep's (FlbRegex → compile_dfa), so
         their savings land in the same dashboard family, labelled by
-        plugin (PERF.md "shrink"); the table-bytes side is accounted in
+        plugin (DEVICE_PLANE.md "shrink"); the table-bytes side is accounted in
         the fbtpu-xray budget report (ANALYSIS.md "fbtpu-xray")."""
         if engine is None or getattr(engine, "m_shrink_states", None) \
                 is None:

@@ -23,6 +23,7 @@
 #include <cstdlib>
 #include <cstring>
 #include <mutex>
+#include <new>
 #include <thread>
 #include <vector>
 
@@ -706,10 +707,13 @@ struct WorkPool {
     }
 };
 
-// deliberately leaked: detached workers may be parked in cv_work.wait
-// at process exit, and destroying a condvar/mutex with waiters is UB —
-// a static instance's destructor would run exactly then
-WorkPool &g_pool = *new WorkPool;
+// deliberately never destroyed: detached workers may be parked in
+// cv_work.wait at process exit, and destroying a condvar/mutex with
+// waiters is UB — a static instance's destructor would run exactly
+// then. Placement-new into static storage: no destructor is ever
+// registered, and no heap allocation can fail before main()
+alignas(WorkPool) unsigned char g_pool_storage[sizeof(WorkPool)];
+WorkPool &g_pool = *new (g_pool_storage) WorkPool;
 
 // FBTPU_DFA_THREADS: unset → all cores (capped 16); 0 or negative →
 // threading disabled (1). The ONE parser for every threaded path.

@@ -201,9 +201,18 @@ def test_lane_fallback_bit_exact_after_post_donation_failure():
 def test_donation_consumed_buffer_would_raise_without_restage():
     """The hazard the lane's re-stage protocol avoids, demonstrated
     directly: after one dispatch the donated lengths device buffer is
-    deleted; re-launching against the SAME buffers raises instead of
-    silently reading verdict bytes. (The launch closures re-device_put
-    from host arrays on every attempt, so they never hit this.)"""
+    deleted, and reading it raises instead of silently returning
+    verdict bytes. (The launch closures re-device_put from host arrays
+    on every attempt, so they never hit this.)
+
+    The proof stops at the buffer: it does NOT launch the program a
+    second time against the consumed buffer. On jax 0.9.0 the CPU
+    client rejects that launch per partition ("Buffer has been deleted
+    or donated ... partition 2") after the other partitions were
+    enqueued; those never complete, and every later multi-device
+    launch in the process blocks behind them in ``np.asarray`` — which
+    is what wedged test_lane_device_lost_shrinks_then_regrows and the
+    tier-1 run behind it."""
     mesh = _mesh_or_skip()
     prog = program_for(PATTERNS, 96)
     batch, lengths = _staged()
@@ -219,8 +228,9 @@ def test_donation_consumed_buffer_would_raise_without_restage():
     ld = jax.device_put(np.ascontiguousarray(lengths), h.sh_l)
     np.asarray(h.fn(h.tables, bd, ld))
     assert ld.is_deleted(), "donation must consume the staged buffer"
-    with pytest.raises(Exception):
-        np.asarray(h.fn(h.tables, bd, ld))
+    assert not bd.is_deleted(), "the u8 batch has no aliasable output"
+    with pytest.raises(RuntimeError, match="deleted"):
+        np.asarray(ld)
 
 
 def test_lane_deadline_soft_kills_hung_launch():

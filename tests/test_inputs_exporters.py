@@ -56,7 +56,7 @@ def test_node_exporter_extended_collectors(tmp_path):
         "cpu  10 0 20 300 0 0 0 0\ncpu0 10 0 20 300 0 0 0 0\n"
         "intr 12345 1 2 3\nctxt 99999\nbtime 1700000000\n"
         "processes 4321\nprocs_running 3\nprocs_blocked 1\n")
-    (proc / "sys/fs/file-nr").write_text("1234\t0\t808348\n")
+    (proc / "sys/fs/file-nr").write_text("1234\t0\t809348\n")
     (proc / "uptime").write_text("5000.5 9000.0\n")
     cf = sys_ / "devices/system/cpu/cpu0/cpufreq"
     cf.mkdir(parents=True)
@@ -110,7 +110,7 @@ def test_node_exporter_extended_collectors(tmp_path):
     assert by_name["node_forks_total"]["values"][0]["value"] == 4321
     assert by_name["node_procs_running"]["values"][0]["value"] == 3
     assert by_name["node_filefd_allocated"]["values"][0]["value"] == 1234
-    assert by_name["node_filefd_maximum"]["values"][0]["value"] == 808348
+    assert by_name["node_filefd_maximum"]["values"][0]["value"] == 809348
     freq = by_name["node_cpu_scaling_frequency_hertz"]
     assert freq["values"][0]["value"] == 2200000 * 1000
     temp = by_name["node_hwmon_temp_celsius"]

@@ -1,0 +1,256 @@
+"""The kernels of the main path, compiled for the chip — without one.
+
+The TPU compiler is installed in the sandbox and compiles for a chip
+that is *described*, not attached (guide: on-chip-measurement §2). These
+tests lower the programs ``chip_smoke.py`` runs, at the shapes it runs
+them, for a described ``v5e:2x2``: what the chip's compiler would refuse
+(tiling, memory, partitioning) fails here at no chip time.
+
+Rules this file keeps (each one has cost a whole tier-1 run somewhere):
+
+- ONE file: only the xdist worker that is handed it loads libtpu.
+- The topology is described inside a module-scoped fixture that skips
+  when it cannot be described — never at import, never in conftest,
+  never in a ``skipif``/``parametrize`` argument, never ``autouse``.
+- Everything compiles in the test's own process (a child could not load
+  the library its parent holds).
+- ``device.platform()`` still answers "cpu" here, so the selection
+  points would pick their CPU branches: the kernels are lowered with
+  shapes directly instead of steering the selection through an option.
+- The persistent compile cache is off around the compiles: a TPU
+  executable written from here cannot be read back without a chip, and
+  the next run would warn about every entry.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding  # noqa: E402
+from jax.sharding import PartitionSpec as P  # noqa: E402
+
+from fluentbit_tpu.flux import kernels  # noqa: E402
+from fluentbit_tpu.ops.grep import GrepProgram  # noqa: E402
+from fluentbit_tpu.ops.sketch import (CountMin, HyperLogLog,  # noqa: E402
+                                      build_sharded_cms, build_sharded_hll)
+from fluentbit_tpu.regex.dfa import compile_dfa  # noqa: E402
+
+#: chip_smoke.py's small Exclude rule (S=10, k=5); the apache2 rule is
+#: read from conf/baseline1-grep.conf, so this compiles what ships
+SMALL = r"curl/8\.5"
+
+
+def _apache2():
+    from fluentbit_tpu.config_format import load_config_file
+
+    conf = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "conf", "baseline1-grep.conf")
+    rule = next(s.get("regex") for s in load_config_file(conf).sections
+                if s.name == "filter")
+    return rule.split(None, 1)[1]
+
+
+APACHE2 = _apache2()
+
+SEGMENT = 4096      # filter_grep's segment (a bucket_size rung)
+PUSH = 16384        # chip_smoke's records per append
+FIELD_LEN = 256     # log_to_metrics / flux staged width
+HLL_P = 14
+CMS_SHAPE = (4, 16384)
+GROUPS_PAD = 8      # flux's padded segment table for 4 tenants
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "skip"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    assert len(topo.devices) == 4
+    return lambda axis: Mesh(np.asarray(topo.devices), (axis,))
+
+
+def sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _program(pattern, kernel):
+    return GrepProgram([compile_dfa(pattern)], 512, kernel=kernel)
+
+
+def _table_shapes(prog, sharding):
+    return {k: sds(v.shape, v.dtype, sharding)
+            for k, v in prog._np.items() if v is not None}
+
+
+# -- one chip: the grep children, per (Bp, L) bucket the smoke produces --
+
+@pytest.mark.parametrize("length", [256, 512])
+@pytest.mark.parametrize("pattern,kernel,impl,max_states", [
+    (APACHE2, "scan", "_match_impl", 690),
+    (SMALL, "assoc", "_match_assoc_impl", 10),
+    (SMALL, "scan", "_match_impl", 10),
+], ids=["apache2-scan", "small-assoc", "small-scan"])
+def test_grep_child_compiles_for_one_chip(one_chip, pattern, kernel, impl,
+                                          max_states, length):
+    prog = _program(pattern, kernel)
+    assert prog.max_states == max_states
+    compiled = jax.jit(getattr(prog, impl)).lower(
+        _table_shapes(prog, one_chip),
+        sds((1, SEGMENT, length), jnp.uint8, one_chip),
+        sds((1, SEGMENT), jnp.int32, one_chip)).compile()
+    out = compiled.output_shardings
+    assert out.device_set == {one_chip._device}
+    # well inside one chip's 16 GB next to everything else it holds
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_kernel_selection_rule_matches_what_was_compiled():
+    """The accelerator arm of ``_resolve_kernel`` — which no CPU test
+    process ever takes — picks exactly the (pattern, kernel) pairs
+    compiled above: assoc at S <= 64, scan beyond."""
+    from fluentbit_tpu.ops import device
+
+    was = device._platform
+    device._platform = "tpu"
+    try:
+        assert _program(APACHE2, "auto")._resolve_kernel() == "scan"
+        assert _program(SMALL, "auto")._resolve_kernel() == "assoc"
+    finally:
+        device._platform = was
+    assert _program(SMALL, "auto")._resolve_kernel() == "scan"  # cpu
+
+
+# -- one chip: the sketches ---------------------------------------------
+
+def test_hll_update_compiles_for_one_chip(one_chip):
+    hll = HyperLogLog(p=HLL_P)
+    compiled = jax.jit(hll._update_impl).lower(
+        sds((hll.m,), jnp.int32, one_chip),
+        sds((PUSH, FIELD_LEN), jnp.uint8, one_chip),
+        sds((PUSH,), jnp.int32, one_chip)).compile()
+    assert compiled.output_shardings.device_set == {one_chip._device}
+
+
+def test_cms_update_compiles_for_one_chip(one_chip):
+    cms = CountMin(*CMS_SHAPE)
+    compiled = jax.jit(cms._update_impl).lower(
+        sds(CMS_SHAPE, cms._dtype, one_chip),
+        sds((PUSH, FIELD_LEN), jnp.uint8, one_chip),
+        sds((PUSH,), jnp.int32, one_chip),
+        sds((PUSH,), jnp.int32, one_chip)).compile()
+    assert compiled.output_shardings.device_set == {one_chip._device}
+
+
+def _fused_args(cms, place):
+    """The fused absorb's flat argument list for one distinct column
+    plus the count-min top-k, each placed by ``place(name)``."""
+    m = 1 << HLL_P
+    return [
+        sds((PUSH,), jnp.int32, place("seg")),
+        sds((PUSH,), jnp.int32, place("valid")),
+        sds((PUSH, FIELD_LEN), jnp.uint8, place("batch")),
+        sds((PUSH,), jnp.int32, place("lengths")),
+        sds((GROUPS_PAD, m), jnp.int32, place("registers")),
+        sds(CMS_SHAPE, cms._dtype, place("table")),
+        sds((PUSH, FIELD_LEN), jnp.uint8, place("comp")),
+        sds((PUSH,), jnp.int32, place("comp_len")),
+    ]
+
+
+def test_donating_fused_absorb_compiles_for_one_chip(one_chip):
+    """``donate = plat not in (None, "cpu")``: the program a chip runs
+    and no CPU test has ever built. The register stack must alias."""
+    cms = CountMin(*CMS_SHAPE)
+    fn = kernels.build_fused_absorb(None, GROUPS_PAD, 1, HLL_P, cms,
+                                    donate=True)
+    compiled = fn.lower(*_fused_args(cms, lambda _n: one_chip)).compile()
+    stack_bytes = GROUPS_PAD * (1 << HLL_P) * 4
+    assert compiled.memory_analysis().alias_size_in_bytes == stack_bytes
+
+
+# -- four chips: the mesh programs and their collectives ------------------
+
+@pytest.mark.parametrize("with_counts,collective", [(True, True),
+                                                    (False, False)],
+                         ids=["counts-psum", "engine-countsfree"])
+def test_grep_mesh_program_compiles_for_four_chips(mesh4, with_counts,
+                                                   collective):
+    mesh = mesh4("batch")
+    prog = _program(APACHE2, "scan")
+    prog._materialize()  # tables on the test's CPU backend: names+shapes
+    fn, tsh, sh_b, sh_l, variant, donate_idx = prog._mesh_program(
+        mesh, "auto", with_counts)
+    assert variant == "batch" and donate_idx == (2,)
+    tables = {k: sds(v.shape, v.dtype, tsh[k])
+              for k, v in prog._tbl.items()}
+    compiled = fn.lower(tables, sds((1, SEGMENT, 512), jnp.uint8, sh_b),
+                        sds((1, SEGMENT), jnp.int32, sh_l)).compile()
+    # the engine variant must stay free of the per-segment sync point
+    assert ("all-reduce" in compiled.as_text()) is collective
+    # the donated lengths shard aliases the i32 verdict shard
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == SEGMENT // 4 * 4
+
+
+@pytest.mark.parametrize("sketch", ["hll-pmax", "cms-psum"])
+def test_sharded_sketch_compiles_for_four_chips(mesh4, sketch):
+    mesh = mesh4("flux")
+    rep = NamedSharding(mesh, P())
+    rows = NamedSharding(mesh, P("flux"))
+    plane = NamedSharding(mesh, P("flux", None))
+    if sketch == "hll-pmax":
+        hll = HyperLogLog(p=HLL_P)
+        lowered = build_sharded_hll(hll, mesh).lower(
+            sds((hll.m,), jnp.int32, rep),
+            sds((PUSH, FIELD_LEN), jnp.uint8, plane),
+            sds((PUSH,), jnp.int32, rows))
+    else:
+        cms = CountMin(*CMS_SHAPE)
+        lowered = build_sharded_cms(cms, mesh).lower(
+            sds(CMS_SHAPE, cms._dtype, rep),
+            sds((PUSH, FIELD_LEN), jnp.uint8, plane),
+            sds((PUSH,), jnp.int32, rows),
+            sds((PUSH,), jnp.int32, rows))
+    assert "all-reduce" in lowered.compile().as_text()
+
+
+def test_donating_fused_absorb_compiles_for_four_chips(mesh4):
+    """The flux merge ``chip_smoke.py --chips 4`` runs: counts psum,
+    register stack pmax, count-min psum — one program, stack donated."""
+    mesh = mesh4("flux")
+    cms = CountMin(*CMS_SHAPE)
+    fn = kernels.build_fused_absorb(mesh, GROUPS_PAD, 1, HLL_P, cms,
+                                    donate=True)
+    specs = {"seg": P("flux"), "valid": P("flux"), "lengths": P("flux"),
+             "comp_len": P("flux"), "batch": P("flux", None),
+             "comp": P("flux", None), "registers": P(), "table": P()}
+    compiled = fn.lower(*_fused_args(
+        cms, lambda n: NamedSharding(mesh, specs[n]))).compile()
+    assert compiled.as_text().count("all-reduce") >= 3
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == GROUPS_PAD * (1 << HLL_P) * 4
