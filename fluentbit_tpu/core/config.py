@@ -266,6 +266,9 @@ class ServiceConfig:
     tpu_enable: bool = True
     tpu_batch_records: int = 8192
     tpu_max_record_len: int = 512
+    # jax.profiler.start_server on this port once the device is
+    # attached (0 = off): captures show the fbtpu: spans (core/spans.py)
+    profiler_port: int = 0
 
     extra: Dict[str, Any] = field(default_factory=dict)
 
@@ -310,6 +313,7 @@ class ServiceConfig:
         "tpu.enable": ("tpu_enable", parse_bool),
         "tpu.batch_records": ("tpu_batch_records", int),
         "tpu.max_record_len": ("tpu_max_record_len", int),
+        "profiler_port": ("profiler_port", int),
     }
 
     def set(self, key: str, value: Any) -> None:

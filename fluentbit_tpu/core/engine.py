@@ -42,6 +42,7 @@ from .plugin import (
     registry as default_registry,
 )
 from .scheduler import backoff_full_jitter
+from .spans import span, spanned
 
 log = logging.getLogger("flb.engine")
 
@@ -261,6 +262,12 @@ class Engine:
             "fluentbit", "device", "mesh_devices",
             "Devices in the lane's current mesh (shrinks on loss, "
             "regrows on breaker re-close)", ("lane",))
+        self.m_device_lane_seconds = m.gauge(
+            "fluentbit", "device", "lane_seconds",
+            "Seconds of a lane's launches since start, by phase: spawn "
+            "(begin to worker running), run (launch closure on the "
+            "worker), blocked (finish waiting on the worker)",
+            ("lane", "phase"))
         self.m_device_reattach = m.counter(
             "fluentbit", "device", "reattach_total",
             "Late/re-attach generations (the mesh lane swapped in "
@@ -647,12 +654,36 @@ class Engine:
         from ..ops import fault as _fault
 
         _fault.add_listener(self._on_device_event)
+        if self.service.profiler_port:
+            threading.Thread(target=self._serve_profiler, daemon=True,
+                             name="flb-profiler").start()
         self._stopping = False
         self._stop_event.clear()
         self._thread = threading.Thread(target=self._run, name="flb-engine", daemon=True)
         self._thread.start()
         if not self._started.wait(timeout=10):
             raise RuntimeError("engine failed to start")
+
+    def _serve_profiler(self) -> None:
+        """``[SERVICE] profiler_port``: once the device is attached,
+        open jax's profiler server, so that an ``xprof``/TensorBoard
+        capture shows the ``fbtpu:`` spans (core/spans.py) over the
+        device's operations on one timeline. One server per process;
+        it waits for the attach as long as the sketch and flux launch
+        paths do, and a failure here never stops the engine."""
+        from ..ops import device
+
+        if not device.wait(max(60.0, device.default_wait())):
+            log.warning("profiler_port: device attach did not complete; "
+                        "no profiler server")
+            return
+        try:
+            import jax
+
+            jax.profiler.start_server(self.service.profiler_port)
+        except Exception:
+            log.exception("profiler_port %d: profiler server failed to "
+                          "start", self.service.profiler_port)
 
     def _ensure_worker_pool(self, out: OutputInstance) -> None:
         """Build the output's worker pool when configured (start() and
@@ -976,6 +1007,7 @@ class Engine:
     # ingest path (reference: flb_input_log_append → input_chunk_append_raw)
     # ------------------------------------------------------------------
 
+    @spanned("engine.append")
     def input_log_append(self, ins: InputInstance, tag: Optional[str],
                          data: bytes, n_records: Optional[int] = None) -> int:
         """Append encoded log events; runs processors then the filter chain
@@ -1358,11 +1390,14 @@ class Engine:
                         # decline costs nothing extra since the tail
                         # is bit-exact with the decode path)
                         committed = True
-                    got = plugin.process_batch(chunk)
+                    with span("filter." + plugin.name):
+                        got = plugin.process_batch(chunk)
                 if got is None and getattr(
                         plugin, "can_filter_raw", None) is not None \
                         and plugin.can_filter_raw():
-                    got = plugin.filter_raw(data, tag, self, n_records=n)
+                    with span("filter." + plugin.name):
+                        got = plugin.filter_raw(data, tag, self,
+                                                n_records=n)
             except Exception:
                 log.exception("filter %s raw path failed", f.display_name)
                 got = None
@@ -1592,7 +1627,9 @@ class Engine:
             before = len(events)
             t0 = time.perf_counter_ns() if trace_ctx is not None else 0
             try:
-                result, new_events = f.plugin.filter(events, tag, self)
+                with span("filter." + f.plugin.name):
+                    result, new_events = f.plugin.filter(events, tag,
+                                                         self)
             except Exception:
                 log.exception("filter %s failed", f.display_name)
                 continue
@@ -1621,16 +1658,19 @@ class Engine:
     # dispatch + flush (reference: flb_engine_flush → flb_engine_dispatch)
     # ------------------------------------------------------------------
 
+    @spanned("engine.flush")
     def flush_all(self) -> None:
         """Drain ready chunks into tasks and start per-route flushes."""
         if self.started_at:
             self.m_uptime.set(time.time() - self.started_at)
         # guard watchdog rides this (the housekeeping timer): heartbeat,
         # flush-deadline scan, occupancy gauges, shed/readmit — never a
-        # per-record cost (core/guard.py); qos queue gauges ride the
-        # same timer
+        # per-record cost (core/guard.py); qos queue gauges and the
+        # device lanes' seconds ride the same timer (the registry has
+        # no collect-time callback)
         self.guard.housekeeping()
         self.qos.update_gauges()
+        self._update_lane_gauges()
         self.qos.resume_paused(self.inputs)
         self._reap_retired_outputs()
         with self._ingest_lock:
@@ -1728,6 +1768,18 @@ class Engine:
                     with self._ingest_lock:
                         self._backlog.extend(leftovers)
                 break
+
+    def _update_lane_gauges(self) -> None:
+        """``fluentbit_device_lane_seconds{lane,phase}``: where each
+        device lane's launches spent their time, summed since start —
+        spawn (begin → worker running), run (the launch closure on the
+        worker), blocked (finish waiting on the worker)."""
+        from ..ops import fault as _fault
+
+        for lane, st in _fault.snapshot().items():
+            for phase in ("spawn", "run", "blocked"):
+                self.m_device_lane_seconds.set(st[phase + "_s"],
+                                               (lane, phase))
 
     def _reap_retired_outputs(self) -> None:
         """Free hot-reload-removed outputs once their in-flight
@@ -2038,8 +2090,10 @@ class Engine:
                                                    chunk.tag, rec,
                                                    chunk))
                         else:
-                            result = await out.plugin.flush(
-                                data, chunk.tag, self)
+                            with span("output.flush",
+                                      out=out.display_name):
+                                result = await out.plugin.flush(
+                                    data, chunk.tag, self)
                     except asyncio.CancelledError:
                         raise
                     except Exception:
@@ -2080,7 +2134,8 @@ class Engine:
             _guard.CANCEL_EVENT.set(rec.cancel_event)
         FLUSH_CHUNK.set(chunk)
         try:
-            return await plugin.flush(data, tag, self)
+            with span("output.flush", out=plugin.name):
+                return await plugin.flush(data, tag, self)
         finally:
             if rec is not None:
                 rec.worker_done = True

@@ -47,7 +47,7 @@ def _pad_segments(n_seg: int) -> int:
     Host-only: n_seg is always a Python int computed BEFORE tracing (it
     becomes the jit-static output shape), never a tracer."""
     n = 8
-    while n < n_seg:  # fbtpu-lint: allow(jax-retrace) host-side shape prep
+    while n < n_seg:
         n *= 2
     return n
 
@@ -91,9 +91,10 @@ def segment_counts(seg: np.ndarray, valid: np.ndarray,
     n_pad = _pad_segments(n_seg)
     fn = _jit_cache.get(n_pad)
     if fn is None:
-        fn = _jit_cache[n_pad] = jax.jit(
-            lambda s, v: _counts_impl(s, v, n_pad)
-        )
+        def flux_counts(s, v):
+            return _counts_impl(s, v, n_pad)
+
+        fn = _jit_cache[n_pad] = jax.jit(flux_counts)
     got = np.asarray(fn(jnp.asarray(seg.astype(np.int32)),
                         jnp.asarray(valid.astype(np.int32))))
     return got[:n_seg]
@@ -126,6 +127,7 @@ def build_sharded_counts(mesh, n_pad: int):
         local = _counts_impl(s, v, n_pad)
         return lax.psum(local, axis_name=axis)
 
+    step.__name__ = "flux_counts_mesh"
     return jax.jit(shard_map(
         step, mesh=mesh,
         in_specs=(rule_spec("flux-counts", axis, "seg"),
@@ -214,32 +216,38 @@ def build_fused_absorb(mesh, n_pad: int, n_fields: int, hll_p: int,
     axis = mesh.axis_names[0] if mesh is not None else None
 
     def step(seg, valid, *rest):
-        counts = _counts_impl(seg, valid, n_pad)
-        if axis is not None:
-            counts = lax.psum(counts, axis_name=axis)
+        with jax.named_scope("flux.counts"):
+            counts = _counts_impl(seg, valid, n_pad)
+            if axis is not None:
+                counts = lax.psum(counts, axis_name=axis)
         outs = [counts]
         for f in range(n_fields):
             b, ln = rest[2 * f], rest[2 * f + 1]
             regs = rest[2 * n_fields + f]
-            idx, rank = hll_index_rank(b, ln, hll_p)
-            # 2-D scatter-max into the per-group register stack: row =
-            # the row's segment id, column = the hash's register index.
-            # Invalid rows carry rank 0 (a no-op under max), so pad
-            # rows may scatter anywhere.
-            local = regs.at[seg, idx].max(rank)
-            outs.append(lax.pmax(local, axis_name=axis)
-                        if axis is not None else local)
+            with jax.named_scope("flux.hll"):
+                idx, rank = hll_index_rank(b, ln, hll_p)
+                # 2-D scatter-max into the per-group register stack:
+                # row = the row's segment id, column = the hash's
+                # register index. Invalid rows carry rank 0 (a no-op
+                # under max), so pad rows may scatter anywhere.
+                local = regs.at[seg, idx].max(rank)
+                outs.append(lax.pmax(local, axis_name=axis)
+                            if axis is not None else local)
         if cms is not None:
             table, comp, comp_len = rest[3 * n_fields:]
-            w = jnp.ones_like(comp_len)  # flux absorbs are weight-1
-            # + 0*sum: ties the accumulator to the sharded batch so the
-            # fori_loop carry's varying annotation stays consistent
-            zero = jnp.zeros_like(table) + (
-                0 * comp_len.sum()).astype(table.dtype)
-            local = cms._update_impl(zero, comp, comp_len, w)
-            outs.append(table + (lax.psum(local, axis_name=axis)
-                                 if axis is not None else local))
+            with jax.named_scope("flux.cms"):
+                w = jnp.ones_like(comp_len)  # flux absorbs are weight-1
+                # + 0*sum: ties the accumulator to the sharded batch so
+                # the fori_loop carry's varying annotation stays
+                # consistent
+                zero = jnp.zeros_like(table) + (
+                    0 * comp_len.sum()).astype(table.dtype)
+                local = cms._update_impl(zero, comp, comp_len, w)
+                outs.append(table + (lax.psum(local, axis_name=axis)
+                                     if axis is not None else local))
         return tuple(outs)
+
+    step.__name__ = "flux_absorb" if mesh is None else "flux_absorb_mesh"
 
     donate_idx: tuple = ()
     if donate:

@@ -38,6 +38,7 @@ import numpy as np
 from ..codec.events import encode_event, now_event_time
 from ..core.config import ConfigMapEntry
 from ..core.plugin import FilterPlugin, FilterResult, registry
+from ..core.spans import ShardedTimings
 from .exporter import FluxExporter
 from .state import FluxSpec, FluxState, WindowSpec
 
@@ -113,6 +114,10 @@ class FluxFilter(FilterPlugin):
             ))
             if self.snapshot_path:
                 self.state.load(self.snapshot_path)
+        # seconds of absorb_batch/absorb_events outside the device
+        # launch (the lane's stats hold the launch's own seconds)
+        self.raw_timings = ShardedTimings(("absorb_s",))
+        self.state.timings = self.raw_timings
         metrics = engine.metrics if engine is not None else None
         if metrics is None:
             from ..core.metrics import MetricsRegistry

@@ -51,6 +51,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import failpoints as _fp
+from ..core.spans import span
 from ..ops.batch import assemble, bucket_size
 from ..ops.sketch import CountMin, HyperLogLog
 from . import kernels
@@ -284,6 +285,11 @@ class FluxState:
         self.late_records_total = 0
         self.window_emits_total = 0
         self.batches_total = 0
+        #: the owning plugin's ``raw_timings`` (``absorb_s``: host work
+        #: of an absorb outside the lane's guarded launch); None for a
+        #: state nobody times
+        self.timings = None
+        self._launch_s = 0.0  # lane.run seconds of the absorb under way
 
     # ------------------------------------------------------------ absorb
 
@@ -309,7 +315,9 @@ class FluxState:
         self.batches_total += 1
         if n <= 0:
             return 0
+        t0 = time.perf_counter()
         self._absorb_rows(self._groups, n, strcols, numcols)
+        self._note_absorb(t0)
         self.records_total += n
         return n
 
@@ -320,6 +328,7 @@ class FluxState:
         n = len(events)
         if n == 0:
             return 0
+        t0 = time.perf_counter()
         # the decode-side coercion: non-dict bodies become empty maps
         # (all columns missing, row still counts) — parity with both
         # the codec's _to_event and the native stagers' non-map rows
@@ -340,8 +349,17 @@ class FluxState:
         else:
             self._absorb_rows(self._groups, n, strcols, numcols)
             absorbed = n
+        self._note_absorb(t0)
         self.records_total += absorbed
         return absorbed
+
+    def _note_absorb(self, t0: float) -> None:
+        """One absorb's host seconds onto the plugin's ``raw_timings``
+        (engine thread, under the ingest lock like all of this)."""
+        launch, self._launch_s = self._launch_s, 0.0
+        if self.timings is not None:
+            self.timings.add("absorb_s",
+                             time.perf_counter() - t0 - launch)
 
     def _str_column(self, bodies: List[dict], field: str):
         vals: List[Optional[bytes]] = []
@@ -555,21 +573,23 @@ class FluxState:
             if _fp.ACTIVE:
                 _fp.fire("flux.device_update")
             m = lane.current_mesh(axis="flux") if mesh_on else None
-            if m is not None:
-                got = kernels.sharded_fused_absorb(
-                    m, seg32, valid, fcols, regs0, comp, comp_len,
-                    table0, hll_p=spec.hll_p, cms=self.cms,
-                    n_seg=n_groups)
-            else:  # mesh shrunk below 2 devices (or none): plain jit
-                got = kernels.fused_absorb(
-                    seg32, valid, fcols, regs0, comp, comp_len,
-                    table0, hll_p=spec.hll_p, cms=self.cms,
-                    n_seg=n_groups)
+            with span("flux.dispatch"):
+                if m is not None:
+                    got = kernels.sharded_fused_absorb(
+                        m, seg32, valid, fcols, regs0, comp, comp_len,
+                        table0, hll_p=spec.hll_p, cms=self.cms,
+                        n_seg=n_groups)
+                else:  # mesh shrunk below 2 devices (or none): plain jit
+                    got = kernels.fused_absorb(
+                        seg32, valid, fcols, regs0, comp, comp_len,
+                        table0, hll_p=spec.hll_p, cms=self.cms,
+                        n_seg=n_groups)
             counts, regs_out, table_out = got
-            return (_wait(counts),
-                    tuple(_wait(r) for r in regs_out),
-                    _wait(table_out) if table_out is not None
-                    else None)
+            with span("flux.force"):
+                return (_wait(counts),
+                        tuple(_wait(r) for r in regs_out),
+                        _wait(table_out) if table_out is not None
+                        else None)
 
         def fallback():
             # device path failed: re-materialize EVERY sketch from its
@@ -590,7 +610,9 @@ class FluxState:
                 self.cms.host_update(comp, comp_len)
             return counts, None, None
 
+        t0 = time.perf_counter()
         counts, regs_out, table_out = lane.run(launch, fallback)
+        self._launch_s += time.perf_counter() - t0
         if regs_out is not None:
             for fi, f in enumerate(fields):
                 for gid, g in enumerate(groups):

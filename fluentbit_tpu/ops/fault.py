@@ -66,9 +66,11 @@ from __future__ import annotations
 import logging
 import os
 import threading
+import time
 from typing import Callable, Dict, List, Optional
 
 from ..core.lockorder import make_lock
+from ..core.spans import bind, current_ids, span
 
 log = logging.getLogger("flb.device.fault")
 
@@ -174,16 +176,20 @@ class _Flight:
     """One in-flight watched launch (the lane's begin/finish handle)."""
 
     __slots__ = ("launch", "fallback", "denied", "deadline", "done",
-                 "box", "thread")
+                 "box", "t_begin", "ids")
 
-    def __init__(self, launch, fallback, denied: bool, deadline: float):
+    def __init__(self, launch, fallback, denied: bool, deadline: float,
+                 t_begin: float = 0.0):
         self.launch = launch
         self.fallback = fallback
         self.denied = denied
         self.deadline = deadline
         self.done = threading.Event()
         self.box: dict = {}
-        self.thread: Optional[threading.Thread] = None
+        self.t_begin = t_begin  # begin() entered: spawn_s counts from it
+        # the caller's span ids (chunk, seg) while a profiler session
+        # runs: the worker's spans carry them across the thread hop
+        self.ids: dict = current_ids() or {}
 
 
 class DeviceLane:
@@ -221,6 +227,11 @@ class DeviceLane:
             "launches": 0, "ok": 0, "failures": 0, "timeouts": 0,
             "fallback_segments": 0, "short_circuits": 0,
             "device_lost": 0, "breaker_trips": 0, "abandoned": 0,
+            # seconds, summed over launches: begin() entered → the
+            # worker running (thread create, start, wait to be
+            # scheduled); the launch closure on the worker; finish()
+            # blocked on the worker
+            "spawn_s": 0.0, "run_s": 0.0, "blocked_s": 0.0,
         }
         self._lost = 0           # devices shrunk out of the mesh
         self._ok_since_shrink = 0  # healthy launches on the shrunk mesh
@@ -313,6 +324,7 @@ class DeviceLane:
         spec exercises exactly the re-stage-on-retry hazard."""
         from .. import failpoints as _fp
 
+        t_run = time.perf_counter()
         try:
             if _fp.ACTIVE:
                 _fp.fire("device.launch_hang")
@@ -320,13 +332,18 @@ class DeviceLane:
                     _fp.fire("mesh.device_lost")
                 except _fp.FailpointError as e:
                     raise DeviceLostError(str(e)) from None
-            out = flight.launch()
+            with bind(lane=self.name, **flight.ids), span("lane.launch"):
+                out = flight.launch()
             if _fp.ACTIVE:
                 _fp.fire("device.dispatch")
             flight.box["result"] = out
         except BaseException as e:  # noqa: BLE001 - resolves to fallback
             flight.box["error"] = e
         finally:
+            t_done = time.perf_counter()
+            with self._lock:
+                self._stats["spawn_s"] += t_run - flight.t_begin
+                self._stats["run_s"] += t_done - t_run
             flight.done.set()
 
     def begin(self, launch, fallback,
@@ -337,6 +354,7 @@ class DeviceLane:
         wedged execution, and what keeps staging overlap alive when the
         caller pipelines begin/finish. ``fallback`` is the bit-exact
         host twin, called at ``finish`` time only."""
+        t_begin = time.perf_counter()
         with self._lock:
             self._stats["launches"] += 1
         if not self.breaker.allow():
@@ -346,12 +364,11 @@ class DeviceLane:
             return _Flight(launch, fallback, denied=True, deadline=0.0)
         fl = _Flight(launch, fallback, denied=False,
                      deadline=self.deadline if deadline is None
-                     else deadline)
-        t = threading.Thread(target=self._watched, args=(fl,),
+                     else deadline, t_begin=t_begin)
+        with span("lane.begin", lane=self.name):
+            threading.Thread(target=self._watched, args=(fl,),
                              daemon=True,
-                             name=f"flb-lane-{self.name}")
-        fl.thread = t
-        t.start()
+                             name=f"flb-lane-{self.name}").start()
         return fl
 
     def finish(self, flight: _Flight):
@@ -361,7 +378,12 @@ class DeviceLane:
         returns — a soft-killed worker's late result is discarded."""
         if flight.denied:
             return self._fall_back(flight, record=False)
-        if not flight.done.wait(flight.deadline):
+        t_wait = time.perf_counter()
+        with bind(lane=self.name, **flight.ids), span("lane.wait"):
+            done = flight.done.wait(flight.deadline)
+        with self._lock:
+            self._stats["blocked_s"] += time.perf_counter() - t_wait
+        if not done:
             # wedged launch: abandon the worker (daemon thread; its
             # eventual result lands in a box nobody reads) and serve
             # the segment on the host twin

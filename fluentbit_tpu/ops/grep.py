@@ -259,6 +259,13 @@ class GrepProgram:
             "kernel_resolved": kernel_resolved,
         }
 
+    def program_name(self, suffix: str = "") -> str:
+        """The jitted function's name — ``jit_<name>`` is the module
+        name a profiler trace shows on ``XLA Modules`` (known once the
+        kernel is resolved)."""
+        return (f"grep_{self.kernel_resolved}_S{self.max_states}"
+                f"_k{self.k}{suffix}")
+
     def _merge_rule_axis(self, parts):
         """Reassemble per-child rule rows into the caller's order."""
         return jnp.concatenate(list(parts), axis=0)[self._inv_perm]
@@ -286,6 +293,7 @@ class GrepProgram:
             def impl(batch, lengths):
                 return kern(tbl, batch, lengths)
 
+            impl.__name__ = self.program_name()
             self._impl = impl
             self._jit = jax.jit(impl)
             self._np = None  # tables now live on device; free host copy
@@ -395,7 +403,8 @@ class GrepProgram:
     def _match_impl(self, t: dict, batch: "jnp.ndarray",
                     lengths: "jnp.ndarray"):
         R, B, L = batch.shape
-        comb = self._super_symbols(t, batch, lengths)
+        with jax.named_scope("grep.symbols"):
+            comb = self._super_symbols(t, batch, lengths)
         comb_t = jnp.moveaxis(comb, 2, 0)  # [Lk, R, B]
 
         # + 0*lengths: ties the carry to the (possibly mesh-sharded) batch
@@ -408,7 +417,8 @@ class GrepProgram:
             ns = jnp.take_along_axis(t["trans_flat"], idx, axis=1)
             return ns, None
 
-        final, _ = lax.scan(step, state0, comb_t)
+        with jax.named_scope("grep.scan"):
+            final, _ = lax.scan(step, state0, comb_t)
         return (final == ACC) & (lengths >= 0)
 
     def _match_assoc_impl(self, t: dict, batch: "jnp.ndarray",
@@ -428,7 +438,8 @@ class GrepProgram:
         R, B, L = batch.shape
         m = self.segment
         S = self.max_states
-        comb = self._super_symbols(t, batch, lengths)  # [R, B, Lk]
+        with jax.named_scope("grep.symbols"):
+            comb = self._super_symbols(t, batch, lengths)  # [R, B, Lk]
         Lk = comb.shape[2]
         G = -(-Lk // m)
         # pad the segment grid to a power of two with all-EOL segments
@@ -452,24 +463,25 @@ class GrepProgram:
         def gather_rule(tf, idx):
             return tf[idx]
 
-        states = jnp.arange(S, dtype=jnp.int32)
-        idx0 = (states[None, None, None, :]
-                * t["Ck"][:, None, None, None] + comb[..., 0:1])
-        F = jax.vmap(gather_rule)(t["trans_flat"], idx0)  # [R,B,G2,S]
-
         def seg_step(F, c_j):  # c_j: [R, B, G2]
             idx = F * t["Ck"][:, None, None, None] + c_j[..., None]
             return jax.vmap(gather_rule)(t["trans_flat"], idx), None
 
-        if m > 1:
-            comb_j = jnp.moveaxis(comb[..., 1:], 3, 0)  # [m-1, R, B, G2]
-            F, _ = lax.scan(seg_step, F, comb_j)
-        g = G2
-        while g > 1:  # static tree: g halves each round
-            f_half = F[:, :, 0::2]
-            g_half = F[:, :, 1::2]
-            F = jnp.take_along_axis(g_half, f_half, axis=3)
-            g //= 2
+        with jax.named_scope("grep.assoc_segments"):
+            states = jnp.arange(S, dtype=jnp.int32)
+            idx0 = (states[None, None, None, :]
+                    * t["Ck"][:, None, None, None] + comb[..., 0:1])
+            F = jax.vmap(gather_rule)(t["trans_flat"], idx0)  # [R,B,G2,S]
+            if m > 1:
+                comb_j = jnp.moveaxis(comb[..., 1:], 3, 0)  # [m-1,R,B,G2]
+                F, _ = lax.scan(seg_step, F, comb_j)
+        with jax.named_scope("grep.assoc_tree"):
+            g = G2
+            while g > 1:  # static tree: g halves each round
+                f_half = F[:, :, 0::2]
+                g_half = F[:, :, 1::2]
+                F = jnp.take_along_axis(g_half, f_half, axis=3)
+                g //= 2
         final_fn = F[:, :, 0, :]  # [R, B, S]: whole-line function
         start_idx = jnp.broadcast_to(t["starts"][:, None, None], (R, B, 1))
         final = jnp.take_along_axis(final_fn, start_idx, axis=2)[..., 0]
@@ -537,6 +549,7 @@ class GrepProgram:
             )
             return mask, counts
 
+        step.__name__ = self.program_name("_sharded")
         return jax.jit(
             shard_map(
                 step,
@@ -679,6 +692,7 @@ class GrepProgram:
                 counts = lax.psum(counts, axis_name=axis)
             return mask.astype(jnp.int32), counts
 
+        step.__name__ = self.program_name("_mesh")
         out_specs = (spec_mask, spec_counts) if with_counts else spec_mask
         sm = shard_map(step, mesh=mesh,
                        in_specs=(tspecs, spec_b, spec_l),

@@ -124,7 +124,10 @@ class HyperLogLog:
                 if not wait:
                     device.attach_async()
                 return None
-            self._update = jax.jit(self._update_impl)
+            def hll_update(registers, batch, lengths):
+                return self._update_impl(registers, batch, lengths)
+
+            self._update = jax.jit(hll_update)
         return self._update
 
     def _ensure_device(self, wait: bool = False) -> bool:
@@ -247,7 +250,10 @@ class CountMin:
                 if not wait:
                     device.attach_async()
                 return None
-            self._update = jax.jit(self._update_impl)
+            def cms_update(table, batch, lengths, weights):
+                return self._update_impl(table, batch, lengths, weights)
+
+            self._update = jax.jit(cms_update)
         return self._update
 
     def _ensure_device(self, wait: bool = False) -> bool:
@@ -438,6 +444,7 @@ def build_sharded_hll(hll: HyperLogLog, mesh):
         local = hll._update_impl(regs, b, ln)
         return lax.pmax(local, axis_name=axis)
 
+    step.__name__ = "hll_update_mesh"
     return jax.jit(shard_map(
         step, mesh=mesh,
         in_specs=(regs_spec, P(axis, None), P(axis)),
@@ -503,6 +510,7 @@ def build_sharded_cms(cms: CountMin, mesh):
         local = cms._update_impl(zero, b, ln, w)
         return table + lax.psum(local, axis_name=axis)
 
+    step.__name__ = "cms_update_mesh"
     return jax.jit(shard_map(
         step, mesh=mesh,
         in_specs=(table_spec, P(axis, None), P(axis), P(axis)),

@@ -27,6 +27,7 @@ import numpy as np
 from ..core.config import ConfigMapEntry
 from ..core.plugin import FilterPlugin, FilterResult, registry
 from ..core.record_accessor import RecordAccessor
+from ..core.spans import ShardedTimings, bind, span
 from ..regex import FlbRegex
 
 log = logging.getLogger("flb")
@@ -41,64 +42,15 @@ class _RawDecline(Exception):
     serve this chunk — unwind and decline to the decode path."""
 
 
-class ShardedTimings:
-    """Per-thread timing shards for the raw path's hot-loop accounting.
-
-    The previous shared dict + lock serialized every ingest worker on
-    one mutex several times per chunk (the lock half of a multi-input
-    regression an earlier CPU-host run showed); adds now go to an
-    uncontended thread-local
-    shard and reads sum across shards. The mapping interface
-    (iteration / item get / item set) keeps bench.py's reset-and-read
-    usage working: item reads return the cross-shard sum, item writes
-    are the RESET hook (bench zeroes between warmup and measurement)
-    and store the value into every shard — meaningful for zero only.
-    """
-
-    #: ``records`` counts every record the raw path served (native
-    #: matcher or device); the last three count the DEVICE lane only —
-    #: records whose segment was launched through the grep DeviceLane,
-    #: the overflow rows among them (longer than ``tpu_max_record_len``,
-    #: resolved on the CPU after the launch), and the staged bytes
-    #: handed to the launch (the [R, Bp, L] plane + lengths)
-    _KEYS = ("extract_s", "kernel_s", "compact_s", "records",
-             "device_records", "overflow_rows", "h2d_bytes")
-
-    def __init__(self):
-        import threading
-
-        self._tls = threading.local()
-        self._shards: list = []
-        self._reg_lock = threading.Lock()  # shard registration (cold)
-
-    def _shard(self) -> dict:
-        d = getattr(self._tls, "d", None)
-        if d is None:
-            d = {k: 0 for k in self._KEYS}
-            with self._reg_lock:
-                self._shards.append(d)
-            self._tls.d = d
-        return d
-
-    def add(self, key: str, value) -> None:
-        self._shard()[key] += value
-
-    def __iter__(self):
-        return iter(self._KEYS)
-
-    def __contains__(self, key) -> bool:
-        return key in self._KEYS
-
-    def __getitem__(self, key):
-        with self._reg_lock:
-            shards = list(self._shards)
-        return sum(d[key] for d in shards)
-
-    def __setitem__(self, key, value) -> None:
-        with self._reg_lock:
-            shards = list(self._shards)
-        for d in shards:
-            d[key] = value
+#: ``raw_timings`` keys. ``records`` counts every record the raw path
+#: served (native matcher or device); ``device_records``,
+#: ``overflow_rows`` and ``h2d_bytes`` count the DEVICE lane only —
+#: records whose segment was launched through the grep DeviceLane, the
+#: overflow rows among them (longer than ``tpu_max_record_len``,
+#: resolved on the CPU after the launch), and the staged bytes handed
+#: to the launch (the [R, Bp, L] plane + lengths)
+_TIMING_KEYS = ("extract_s", "kernel_s", "compact_s", "records",
+                "device_records", "overflow_rows", "h2d_bytes")
 
 
 def _len_bucket(n: int, cap: int) -> int:
@@ -259,7 +211,7 @@ class GrepFilter(FilterPlugin):
         self._mesh_resolved = False
         self._mesh_on = False
         self._mesh_gen = None
-        self.raw_timings = ShardedTimings()
+        self.raw_timings = ShardedTimings(_TIMING_KEYS)
         # per-worker copies of the read-only native tables (multi-input
         # scaling: no cross-thread sharing of the hot arrays)
         self._tls_tables = threading.local()
@@ -642,13 +594,13 @@ class GrepFilter(FilterPlugin):
                     return (n, data)
                 if n_keep == 0:
                     return (0, b"")
-                t0 = _time.perf_counter()
-                # by design: this compact sits on the host-native
-                # approx branch (no device launch reachable when
-                # use_native holds) — no verdict crossed PCIe here
-                # fbtpu-lint: allow(device-host-roundtrip)
-                compacted = native.compact(data, offsets[: n + 1], keep)
-                tm.add("compact_s", _time.perf_counter() - t0)
+                with tm.timed("compact_s", "grep.compact"):
+                    # by design: this compact sits on the host-native
+                    # approx branch (no device launch reachable when
+                    # use_native holds) — no verdict crossed PCIe here
+                    # fbtpu-lint: allow(device-host-roundtrip)
+                    compacted = native.compact(data, offsets[: n + 1],
+                                               keep)
                 if compacted is not None:
                     return (n_keep, compacted)
                 parts = [
@@ -697,9 +649,8 @@ class GrepFilter(FilterPlugin):
             return (n, data)
         if n_keep == 0:
             return (0, b"")
-        t0 = _time.perf_counter()
-        compacted = native.compact(data, offsets[: n + 1], keep)
-        tm.add("compact_s", _time.perf_counter() - t0)
+        with tm.timed("compact_s", "grep.compact"):
+            compacted = native.compact(data, offsets[: n + 1], keep)
         if compacted is not None:
             return (n_keep, compacted)
         parts = [
@@ -862,10 +813,10 @@ class GrepFilter(FilterPlugin):
         n_dev = mesh.devices.size if mesh is not None else 1
 
         def stages():
-            for s, e in bounds:
+            def stage(s, e):
                 t0 = _time.perf_counter()
                 cnt = e - s
-                span = data if offs_box[0] is None \
+                part = data if offs_box[0] is None \
                     else data[offs_box[0][s]: offs_box[0][e]]
                 if mesh is not None:
                     # mesh staging: ONE jit-stable width (the sharded
@@ -886,7 +837,7 @@ class GrepFilter(FilterPlugin):
                         offs = np.empty(cnt + 1, dtype=np.int64) \
                             if want_offs else None
                         count = native.stage_field_into(
-                            span, key, batch[r0], lengths[r0],
+                            part, key, batch[r0], lengths[r0],
                             n_hint=cnt, offsets_out=offs)
                         if count is None or count != cnt:
                             raise _RawDecline
@@ -896,8 +847,7 @@ class GrepFilter(FilterPlugin):
                             batch[r, :cnt] = batch[r0, :cnt]
                             lengths[r, :cnt] = lengths[r0, :cnt]
                     extract_s[0] += _time.perf_counter() - t0
-                    yield batch, lengths, cnt
-                    continue
+                    return batch, lengths, cnt
                 staged = {}
                 max_staged = 1
                 for key in by_key:
@@ -911,7 +861,7 @@ class GrepFilter(FilterPlugin):
                     wide = np.empty((cnt, Lmax), dtype=np.uint8)
                     wlen = np.full((cnt,), -1, dtype=np.int32)
                     count = native.stage_field_into(
-                        span, key, wide, wlen, n_hint=cnt,
+                        part, key, wide, wlen, n_hint=cnt,
                         offsets_out=offs)
                     if count is None or count != cnt:
                         raise _RawDecline
@@ -938,12 +888,25 @@ class GrepFilter(FilterPlugin):
                         batch[r, :cnt] = b[:cnt, :L]
                         lengths[r, :cnt] = ln[:cnt]
                 extract_s[0] += _time.perf_counter() - t0
-                yield batch, lengths, cnt
+                return batch, lengths, cnt
+
+            for si, (s, e) in enumerate(bounds):
+                with span("grep.stage", seg=si):
+                    item = stage(s, e)
+                yield item + (si,)
 
         lane = self._lane()
 
+        def forced(b, ln):
+            # enqueue + argument copy-in, then the wait for the
+            # execution and the copy-out
+            with span("grep.dispatch"):
+                out = self._program.dispatch(b, ln)
+            with span("grep.force"):
+                return np.asarray(out)
+
         def dispatch(item):
-            batch, lengths, cnt = item
+            batch, lengths, cnt, si = item
             lens_parts.append(lengths[:, :cnt])
             cnts.append(cnt)
             tm.add("h2d_bytes", batch.nbytes + lengths.nbytes)
@@ -963,18 +926,21 @@ class GrepFilter(FilterPlugin):
                     m = lane.current_mesh()
                     if m is None:
                         # mesh shrunk below 2 devices: serve unsharded
-                        return np.asarray(self._program.dispatch(b, ln))
-                    m_i32, _, _b2, _bp = self._program.dispatch_mesh(
-                        m, b, ln, with_counts=False)
-                    return np.asarray(m_i32).astype(bool)
+                        return forced(b, ln)
+                    with span("grep.dispatch"):
+                        m_i32, _, _b2, _bp = self._program.dispatch_mesh(
+                            m, b, ln, with_counts=False)
+                    with span("grep.force"):
+                        return np.asarray(m_i32).astype(bool)
             else:
                 def launch(b=batch, ln=lengths):
-                    return np.asarray(self._program.dispatch(b, ln))
+                    return forced(b, ln)
 
             def fallback(b=batch, ln=lengths, c=cnt):
                 return self._host_mask(b, ln, c)
 
-            return lane.begin(launch, fallback)
+            with bind(seg=si):
+                return lane.begin(launch, fallback)
 
         def collect(pending):
             # nothing is committed until here: the segment's verdict is
@@ -1001,10 +967,11 @@ class GrepFilter(FilterPlugin):
         if len(overflow_rows):
             from ..codec.events import decode_events
 
-            for b_idx in overflow_rows:
-                span = bytes(data[offsets[b_idx]: offsets[b_idx + 1]])
-                ev = decode_events(span)[0]
-                for r, rule in enumerate(self.rules):
-                    if lengths[r, b_idx] == -2:
-                        mask[r, b_idx] = rule.match(ev.body)
+            with span("grep.overflow", rows=len(overflow_rows)):
+                for b_idx in overflow_rows:
+                    rec = bytes(data[offsets[b_idx]: offsets[b_idx + 1]])
+                    ev = decode_events(rec)[0]
+                    for r, rule in enumerate(self.rules):
+                        if lengths[r, b_idx] == -2:
+                            mask[r, b_idx] = rule.match(ev.body)
         return mask, offsets, n

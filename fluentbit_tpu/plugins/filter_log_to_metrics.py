@@ -32,6 +32,7 @@ from ..core.config import ConfigMapEntry
 from ..core.metrics import MetricsRegistry
 from ..core.plugin import FilterPlugin, FilterResult, registry
 from ..core.record_accessor import RecordAccessor
+from ..core.spans import ShardedTimings, span
 from .filter_grep import legacy_keep, parse_grep_rules
 
 log = logging.getLogger("flb")
@@ -130,6 +131,16 @@ class LogToMetricsFilter(FilterPlugin):
             self.value_field if str(self.value_field or "").startswith("$")
             else "$" + (self.value_field or "value")
         ) if self.value_field else None
+
+        # where an append's time goes, in seconds (engine thread only):
+        # picking records and their values (per record, in Python),
+        # staging the batch, the sketch/metric update, reading the
+        # sketch back (a device→host copy and the candidate sort). Each
+        # key is a counter because a per-layer metric of the benchmark
+        # reads it over the whole window; what has no metric (the
+        # snapshot's emit) is a span only
+        self.raw_timings = ShardedTimings(
+            ("select_s", "stage_s", "update_s", "query_s"))
 
         # the cmt context emitted through the pipeline
         self.cmt = MetricsRegistry()
@@ -287,12 +298,16 @@ class LogToMetricsFilter(FilterPlugin):
         from .. import native
         from .filter_grep import legacy_keep_mask
 
+        tm = self.raw_timings
         data = chunk.as_bytes()
-        got = native.grep_match(data, self._batch_tables, n_hint=chunk.n)
-        if got is None:
-            return None
-        mask, _offsets, n = got
-        count = int(legacy_keep_mask(self.rules, mask).sum()) if n else 0
+        with tm.timed("select_s", "l2m.select"):
+            got = native.grep_match(data, self._batch_tables,
+                                    n_hint=chunk.n)
+            if got is None:
+                return None
+            mask, _offsets, n = got
+            count = int(legacy_keep_mask(self.rules, mask).sum()) \
+                if n else 0
         if count:
             # one batched inc == n per-record incs on the same (static)
             # label set; the snapshot emits once per append, exactly
@@ -319,27 +334,19 @@ class LogToMetricsFilter(FilterPlugin):
     # -- the filter --
 
     def filter(self, events: list, tag: str, engine) -> tuple:
-        selected = [
-            ev for ev in events
-            if isinstance(ev.body, dict) and self._selected(ev.body)
-        ]
-        if self.mode == "counter":
-            for ev in selected:
-                self.metric.inc(1, self._labels(ev.body))
-        elif self.mode == "gauge":
-            for ev in selected:
-                v = self._value(ev.body)
-                if v is not None:
-                    self.metric.set(v, self._labels(ev.body))
-        elif self.mode == "histogram":
-            for ev in selected:
-                v = self._value(ev.body)
-                if v is not None:
-                    self.metric.observe(v, self._labels(ev.body))
-        elif self.mode == "cardinality":
+        tm = self.raw_timings
+        with tm.timed("select_s", "l2m.select"):
+            selected = [
+                ev for ev in events
+                if isinstance(ev.body, dict) and self._selected(ev.body)
+            ]
+        if self.mode == "cardinality":
             self._update_hll(selected)
-        else:
+        elif self.mode == "frequency":
             self._update_cms(selected)
+        else:
+            with tm.timed("update_s", "l2m.update"):
+                self._update_metric(selected)
 
         if selected:
             self._dirty = True
@@ -351,13 +358,29 @@ class LogToMetricsFilter(FilterPlugin):
             return (FilterResult.MODIFIED, [])
         return (FilterResult.NOTOUCH, events)
 
+    def _update_metric(self, selected: list) -> None:
+        if self.mode == "counter":
+            for ev in selected:
+                self.metric.inc(1, self._labels(ev.body))
+        elif self.mode == "gauge":
+            for ev in selected:
+                v = self._value(ev.body)
+                if v is not None:
+                    self.metric.set(v, self._labels(ev.body))
+        else:  # histogram
+            for ev in selected:
+                v = self._value(ev.body)
+                if v is not None:
+                    self.metric.observe(v, self._labels(ev.body))
+
     def _emit_snapshot(self) -> None:
-        payload = packb(self.cmt.to_msgpack_obj())
-        self.emitter.add_event(
-            self.tag, payload, EVENT_TYPE_METRICS,
-            n_records=len(list(self.cmt.metrics())),
-        )
-        self._dirty = False
+        with span("l2m.emit"):
+            payload = packb(self.cmt.to_msgpack_obj())
+            self.emitter.add_event(
+                self.tag, payload, EVENT_TYPE_METRICS,
+                n_records=len(list(self.cmt.metrics())),
+            )
+            self._dirty = False
 
     # -- sketch modes --
 
@@ -368,27 +391,39 @@ class LogToMetricsFilter(FilterPlugin):
                         bucket_size(len(values),
                                     max_len=self.tpu_max_record_len))
 
+    def _values(self, selected: list) -> List[bytes]:
+        with self.raw_timings.timed("select_s", "l2m.select"):
+            vals = [self._value_bytes(ev.body) for ev in selected]
+            return [v for v in vals if v is not None]
+
     def _update_hll(self, selected: list) -> None:
-        vals = [self._value_bytes(ev.body) for ev in selected]
-        vals = [v for v in vals if v is not None]
+        tm = self.raw_timings
+        vals = self._values(selected)
         if not vals:
             return
-        b = self._staged(vals)
-        self.hll.update(b.batch, b.lengths)
-        for i in b.overflow:  # oversized values resolve on CPU
-            self.hll.add_cpu(vals[i])
-        labels = self._labels(selected[0].body) if self.label_keys else ()
-        self.metric.set(self.hll.estimate(), labels)
+        with tm.timed("stage_s", "l2m.stage"):
+            b = self._staged(vals)
+        with tm.timed("update_s", "l2m.update"):
+            self.hll.update(b.batch, b.lengths)
+            for i in b.overflow:  # oversized values resolve on CPU
+                self.hll.add_cpu(vals[i])
+        with tm.timed("query_s", "l2m.query"):
+            labels = self._labels(selected[0].body) \
+                if self.label_keys else ()
+            # the estimate reads the registers back: device→host copy
+            self.metric.set(self.hll.estimate(), labels)
 
     def _update_cms(self, selected: list) -> None:
-        vals = [self._value_bytes(ev.body) for ev in selected]
-        vals = [v for v in vals if v is not None]
+        tm = self.raw_timings
+        vals = self._values(selected)
         if not vals:
             return
-        b = self._staged(vals)
-        self.cms.update(b.batch, b.lengths)
-        for i in b.overflow:  # oversized values resolve on CPU
-            self.cms.add_cpu(vals[i])
+        with tm.timed("stage_s", "l2m.stage"):
+            b = self._staged(vals)
+        with tm.timed("update_s", "l2m.update"):
+            self.cms.update(b.batch, b.lengths)
+            for i in b.overflow:  # oversized values resolve on CPU
+                self.cms.add_cpu(vals[i])
         for v in vals:
             # delete-and-reinsert refreshes recency (dict preserves
             # insertion order; plain reassignment would not move the key)
@@ -399,16 +434,19 @@ class LogToMetricsFilter(FilterPlugin):
             drop = len(self._freq_candidates) - 4096
             for k in list(self._freq_candidates)[:drop]:
                 del self._freq_candidates[k]
-        base = self._labels(selected[0].body) if self.label_keys else ()
-        # one device→host table copy for the whole candidate set
-        ests = self.cms.query_many(list(self._freq_candidates))
-        top = sorted(
-            zip(ests, self._freq_candidates), reverse=True,
-        )[: self.frequency_top_k]
-        # the gauge reports the CURRENT top-k only: stale series from
-        # values that dropped out must not linger in the exposition
-        self.metric.clear()
-        for est, v in top:
-            self.metric.set(
-                est, base + (v.decode("utf-8", "replace"),)
-            )
+        with tm.timed("query_s", "l2m.query"):
+            base = self._labels(selected[0].body) \
+                if self.label_keys else ()
+            # one device→host table copy for the whole candidate set
+            ests = self.cms.query_many(list(self._freq_candidates))
+            top = sorted(
+                zip(ests, self._freq_candidates), reverse=True,
+            )[: self.frequency_top_k]
+            # the gauge reports the CURRENT top-k only: stale series
+            # from values that dropped out must not linger in the
+            # exposition
+            self.metric.clear()
+            for est, v in top:
+                self.metric.set(
+                    est, base + (v.decode("utf-8", "replace"),)
+                )

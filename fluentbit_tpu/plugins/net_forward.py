@@ -73,6 +73,7 @@ from ..core.plugin import FLUSH_CHUNK, FlushResult, InputPlugin, \
     OutputPlugin, registry
 from ..core.relay import DedupLedger, ForwardSpool, stable_chunk_id
 from ..core.scheduler import backoff_full_jitter
+from ..core.spans import bind, span
 from ..core.upstream import close_quietly
 from .. import failpoints as _fp
 
@@ -83,6 +84,8 @@ log = logging.getLogger("flb.forward")
 #: becomes a metric label / quota bucket key
 _TENANT_MAX_LEN = 128
 _PRIORITY_MAX = 7
+
+_NO_MSG = object()  # the Unpacker holds no complete message
 
 
 def _entries_to_events(entries) -> tuple:
@@ -203,11 +206,24 @@ class ForwardInput(InputPlugin):
         u = Unpacker()
         authed = not self.shared_key
         while True:
-            data = await reader.read(65536)
+            with span("forward.read") as sp:
+                data = await reader.read(65536)
+                sp.set_metadata(bytes=len(data))
             if not data:
                 return
-            u.feed(data)
-            for msg in u:
+            fed = False
+            while True:
+                # one span per attempt to take a message off the
+                # Unpacker: an incomplete frame is re-walked from its
+                # start at every read (done=0), which the count shows
+                with span("forward.unpack") as sp:
+                    if not fed:
+                        u.feed(data)
+                        fed = True
+                    msg = next(u, _NO_MSG)
+                    sp.set_metadata(done=int(msg is not _NO_MSG))
+                if msg is _NO_MSG:
+                    break
                 if not isinstance(msg, (list, tuple)) or not msg:
                     continue
                 if not authed:
@@ -241,58 +257,67 @@ class ForwardInput(InputPlugin):
             return
         if self.tag_prefix:
             tag = f"{self.tag_prefix}.{tag}"
-        option = None
-        if isinstance(msg[1], (bytes, memoryview)):
-            # PackedForward / CompressedPackedForward
-            option = msg[2] if len(msg) > 2 and isinstance(msg[2], dict) else None
-            blob = bytes(msg[1])
-            if option and option.get("compressed") == "gzip":
-                blob = gzip.decompress(blob)
-            entries = list(Unpacker(blob))
-            buf, n = _entries_to_events(entries)
-        elif isinstance(msg[1], (list, tuple)):
-            # Forward mode
-            option = msg[2] if len(msg) > 2 and isinstance(msg[2], dict) else None
-            buf, n = _entries_to_events(msg[1])
+        body = msg[1]
+        packed = isinstance(body, (bytes, memoryview))
+        if packed or isinstance(body, (list, tuple)):
+            # PackedForward / CompressedPackedForward, or Forward mode
+            opt_at = 2
         else:
             # Message mode [tag, time, record, option?]
             if len(msg) < 3 or not isinstance(msg[2], dict):
                 return
-            option = msg[3] if len(msg) > 3 and isinstance(msg[3], dict) else None
-            buf, n = _entries_to_events([[msg[1], msg[2]]])
+            opt_at = 3
+        option = msg[opt_at] if len(msg) > opt_at \
+            and isinstance(msg[opt_at], dict) else None
         ack_ref = option.get("chunk") if option else None
         cid = self._chunk_key(ack_ref)
-        if n:
-            if cid is not None and self._ledger is not None \
-                    and self._ledger.seen(cid):
-                # redelivery inside the retry window: lost ack,
-                # ambiguous-ack resend, or post-crash replay — acked,
-                # absorbed zero times
-                self._m_dedup.inc(1, (self.instance.display_name,))
-            else:
-                tenant, priority = _wire_stamp(option)
-                absorbed = await self._absorb(engine, tag, buf, n,
-                                              tenant, priority, cid)
-                if not absorbed:
-                    # backpressure: NO ack — the peer's ack timeout
-                    # turns into RETRY+backoff, pausing the stream;
-                    # the resend dedups if a later pass absorbed it
-                    self.n_withheld_acks += 1
-                    self._m_withheld.inc(
-                        1, (self.instance.display_name,))
-                    return
-        if ack_ref is not None:
-            if _fp.ACTIVE:
-                try:
-                    # absorb recorded, ack not yet written: the classic
-                    # lost-ack window — the edge resends, the ledger
-                    # dedups (connection stays up: a dropped ack is not
-                    # a dropped link)
-                    _fp.fire("forward.ack_drop")
-                except _fp.FailpointError:
-                    return
-            writer.write(packb({"ack": ack_ref}))
-            await writer.drain()
+        # the chunk id is taken before the re-encode so that every span
+        # of this frame from here to the ack carries it
+        with bind(chunk=cid):
+            with span("forward.reencode"):
+                if packed:
+                    blob = bytes(body)
+                    if option and option.get("compressed") == "gzip":
+                        blob = gzip.decompress(blob)
+                    entries = list(Unpacker(blob))
+                elif opt_at == 2:
+                    entries = body
+                else:
+                    entries = [[msg[1], msg[2]]]
+                buf, n = _entries_to_events(entries)
+            if n:
+                if cid is not None and self._ledger is not None \
+                        and self._ledger.seen(cid):
+                    # redelivery inside the retry window: lost ack,
+                    # ambiguous-ack resend, or post-crash replay —
+                    # acked, absorbed zero times
+                    self._m_dedup.inc(1, (self.instance.display_name,))
+                else:
+                    tenant, priority = _wire_stamp(option)
+                    with span("forward.absorb"):
+                        absorbed = await self._absorb(
+                            engine, tag, buf, n, tenant, priority, cid)
+                    if not absorbed:
+                        # backpressure: NO ack — the peer's ack timeout
+                        # turns into RETRY+backoff, pausing the stream;
+                        # the resend dedups if a later pass absorbed it
+                        self.n_withheld_acks += 1
+                        self._m_withheld.inc(
+                            1, (self.instance.display_name,))
+                        return
+            if ack_ref is not None:
+                if _fp.ACTIVE:
+                    try:
+                        # absorb recorded, ack not yet written: the
+                        # classic lost-ack window — the edge resends,
+                        # the ledger dedups (connection stays up: a
+                        # dropped ack is not a dropped link)
+                        _fp.fire("forward.ack_drop")
+                    except _fp.FailpointError:
+                        return
+                with span("forward.ack"):
+                    writer.write(packb({"ack": ack_ref}))
+                    await writer.drain()
 
     @staticmethod
     def _chunk_key(ack_ref) -> Optional[str]:

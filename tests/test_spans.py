@@ -1,0 +1,752 @@
+"""Spans and counters inside the program (core/spans.py): ``span()`` on
+the profiler's own buffer and clock, lane and plugin counters where the
+work happens, named device programs.
+
+A forward frame goes socket → ``in_forward`` → grep → ``lib`` output
+under a CPU ``jax.profiler`` session. On a CPU backend ``filter_raw``
+takes the native twin, so the device lane is forced the way
+``test_launchgraph.py::test_static_matches_dynamic_grep_chain`` does it
+(``FBTPU_MESH=1``, ``tpu_batch_records 1``, skipped without a mesh).
+"""
+
+import glob
+import os
+import re
+import socket
+import subprocess
+import sys
+import threading
+import time
+
+import pytest
+
+import fluentbit_tpu as flb
+from fluentbit_tpu.codec.msgpack import Unpacker, packb
+from fluentbit_tpu.core import spans
+from fluentbit_tpu.ops import fault
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+APACHE2 = (r'^(?<host>[^ ]*) [^ ]* (?<user>[^ ]*) \[(?<time>[^\]]*)\] '
+           r'"(?<method>\S+)(?: +(?<path>[^ ]*) +\S*)?" (?<code>[^ ]*) '
+           r'(?<size>[^ ]*)(?: "(?<referer>[^\"]*)" "(?<agent>.*)")?$')
+OK_LINE = ('10.0.0.1 - frank [10/Oct/2000:13:55:36 -0700] '
+           '"GET /a HTTP/1.1" 200 23 "http://r" "curl"')
+N_LINES = 96
+CHUNK = "frame-0001"
+#: ``log_to_metrics``' ``raw_timings``: seconds by phase of an append
+L2M_KEYS = ("select_s", "stage_s", "update_s", "query_s")
+
+
+def wait_for(cond, timeout=20.0, interval=0.01):
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        v = cond()
+        if v:
+            return v
+        time.sleep(interval)
+    raise TimeoutError("condition not met")
+
+
+def frame(chunk=CHUNK, n=N_LINES) -> bytes:
+    """One Forward-mode frame; every fourth line fails the regex."""
+    entries = [[1700000000 + i,
+                {"log": OK_LINE if i % 4 else f"kernel: oom {i}"}]
+               for i in range(n)]
+    return packb(["app", entries, {"chunk": chunk}])
+
+
+class Aggregator:
+    """forward input → grep (device lane forced) → lib output."""
+
+    def __init__(self):
+        self.ctx = flb.create(flush="50ms", grace="1")
+        self.ctx.input("forward", listen="127.0.0.1", port="0")
+        self.ctx.filter("grep", match="*", regex=f"log {APACHE2}",
+                        tpu_batch_records="1")
+        self.got = []
+        self.ctx.output("lib", match="*",
+                        callback=lambda d, _t: self.got.append(bytes(d)))
+        self.engine = self.ctx.engine
+        self.grep = self.engine.filters[0].plugin
+        self.ctx.start()
+        self.port = wait_for(
+            lambda: self.engine.inputs[0].plugin.bound_port)
+
+    def send(self, data: bytes, chunk=CHUNK, piece=0) -> None:
+        """Send one frame (in ``piece``-byte writes when given) and wait
+        for its ack."""
+        with socket.create_connection(("127.0.0.1", self.port)) as s:
+            s.settimeout(60)
+            if piece:
+                for i in range(0, len(data), piece):
+                    s.sendall(data[i:i + piece])
+                    time.sleep(0.002)
+            else:
+                s.sendall(data)
+            u = Unpacker()
+            while True:
+                u.feed(s.recv(4096))
+                for msg in u:
+                    assert msg == {"ack": chunk}
+                    return
+
+    def output(self, n_bytes_least=1) -> bytes:
+        self.ctx.flush_now()
+        wait_for(lambda: sum(map(len, self.got)) >= n_bytes_least)
+        return b"".join(self.got)
+
+    def stop(self) -> None:
+        self.ctx.stop()
+
+
+@pytest.fixture(scope="module")
+def mesh_env():
+    jax = pytest.importorskip("jax")
+    if len(jax.devices()) < 2:
+        pytest.skip("need a multi-device mesh")
+    saved = {k: os.environ.get(k)
+             for k in ("FBTPU_MESH", "FBTPU_SEGMENT_RECORDS")}
+    os.environ["FBTPU_MESH"] = "1"
+    os.environ["FBTPU_SEGMENT_RECORDS"] = "32"
+    yield jax
+    for k, v in saved.items():
+        if v is None:
+            os.environ.pop(k, None)
+        else:
+            os.environ[k] = v
+
+
+def read_events(trace_dir: str) -> list:
+    """Every ``fbtpu:`` event of the trace: dicts with name, start, end,
+    stats and the thread line it lies on."""
+    from jax.profiler import ProfileData
+
+    path = glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb"))[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for li, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith(spans.PREFIX):
+                    out.append({
+                        "name": e.name[len(spans.PREFIX):],
+                        "start": e.start_ns,
+                        "end": e.start_ns + e.duration_ns,
+                        "stats": dict(e.stats),
+                        "line": (plane.name, li)})
+    return out
+
+
+@pytest.fixture(scope="module")
+def runs(mesh_env, tmp_path_factory):
+    """The same frame three times through one aggregator: untraced (it
+    also compiles the program), under a profiler session, untraced
+    again. → per-run output bytes, the session's events, and what
+    ``span()`` returned outside the session."""
+    jax = mesh_env
+    agg = Aggregator()
+    try:
+        seen = {}
+        # each send has a chunk id of its own: a redelivered id is acked
+        # from the dedup ledger and absorbed zero times
+        agg.send(frame("frame-0000"), "frame-0000")
+        seen["cold"] = agg.output()
+        agg.got.clear()
+
+        trace_dir = str(tmp_path_factory.mktemp("trace"))
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        try:
+            seen["enabled_in_session"] = spans.enabled()
+            # 997-byte writes: the frame arrives over several reads
+            agg.send(frame(), piece=997)
+            seen["traced"] = agg.output()
+        finally:
+            jax.profiler.stop_trace()
+        agg.got.clear()
+        seen["events"] = read_events(trace_dir)
+
+        seen["span_off"] = spans.span("forward.read", bytes=1)
+        seen["bind_off"] = spans.bind(chunk="x")
+        seen["ids_off"] = spans.current_ids()
+        agg.send(frame("frame-0002"), "frame-0002")
+        seen["untraced"] = agg.output()
+        seen["mesh_on"] = agg.grep._mesh is not None
+    finally:
+        agg.stop()
+    return seen
+
+
+def by_name(events, name):
+    return [e for e in events if e["name"] == name]
+
+
+def inside(inner, outer) -> bool:
+    return outer["start"] <= inner["start"] and inner["end"] <= outer["end"]
+
+
+# ------------------------------------------------------ span(), alone
+
+
+def test_spans_module_does_not_import_jax():
+    code = (
+        "import importlib.util, sys\n"
+        f"spec = importlib.util.spec_from_file_location('spans_alone', "
+        f"{os.path.join(REPO, 'fluentbit_tpu', 'core', 'spans.py')!r})\n"
+        "m = importlib.util.module_from_spec(spec)\n"
+        "sys.modules['spans_alone'] = m\n"
+        "spec.loader.exec_module(m)\n"
+        "assert 'jax' not in sys.modules\n"
+        "assert m.span('forward.read', bytes=3) is m.NOOP\n"
+        "assert m.bind(chunk='c') is m.NOOP\n"
+        "assert m.current_ids() is None and not m.enabled()\n"
+        "with m.span('x') as sp:\n"
+        "    sp.set_metadata(bytes=1)\n"
+        "assert 'jax' not in sys.modules\n"
+        "print('ok')\n")
+    proc = subprocess.run([sys.executable, "-c", code], timeout=60,
+                          capture_output=True, text=True)
+    assert proc.returncode == 0 and proc.stdout.strip() == "ok", \
+        proc.stdout + proc.stderr
+
+
+def test_span_is_the_shared_noop_without_a_session():
+    pytest.importorskip("jax")
+    assert not spans.enabled()
+    assert spans.span("engine.append") is spans.NOOP
+    assert spans.span("lane.launch", chunk="c", seg=1) is spans.NOOP
+    assert spans.bind(chunk="c") is spans.NOOP
+    assert spans.current_ids() is None
+
+
+def test_spanned_keeps_the_name_and_the_result():
+    @spans.spanned("engine.append")
+    def input_log_append(a, b=2):
+        """doc"""
+        return a + b
+
+    assert input_log_append.__name__ == "input_log_append"
+    assert input_log_append.__doc__ == "doc"
+    assert input_log_append(1, b=3) == 4
+
+
+def test_bind_nests_and_restores_under_a_session(tmp_path):
+    jax = pytest.importorskip("jax")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert spans.enabled() and spans.current_ids() is None
+        with spans.bind(chunk="c1", skipped=None):
+            assert spans.current_ids() == {"chunk": "c1"}
+            with spans.bind(seg=2):
+                assert spans.current_ids() == {"chunk": "c1", "seg": 2}
+                seen = {}
+                t = threading.Thread(
+                    target=lambda: seen.update(ids=spans.current_ids()))
+                t.start()
+                t.join(10)
+                assert seen == {"ids": None}  # a new thread binds anew
+            assert spans.current_ids() == {"chunk": "c1"}
+        assert spans.current_ids() is None
+        assert spans.span("x", a=1) is not spans.NOOP
+    finally:
+        jax.profiler.stop_trace()
+    assert spans.current_ids() is None
+
+
+def test_sharded_timings_sum_reset_and_timed():
+    tm = spans.ShardedTimings(("a_s", "n"))
+    assert list(tm) == ["a_s", "n"] and "n" in tm and "x" not in tm
+    tm.add("n", 2)
+    t = threading.Thread(target=tm.add, args=("n", 3))
+    t.start()
+    t.join(10)
+    assert tm["n"] == 5 and len(tm._shards) == 2
+    with tm.timed("a_s", "grep.compact"):
+        time.sleep(0.01)
+    assert 0.009 < tm["a_s"] < 5.0
+    tm["a_s"] = 0
+    assert tm["a_s"] == 0 and tm["n"] == 5
+
+
+# ------------------------------------------ one frame, traced and not
+
+
+SPANS_OF_A_FRAME = ("forward.read", "forward.unpack", "forward.reencode",
+                    "forward.absorb", "engine.append", "filter.grep",
+                    "grep.stage", "lane.begin", "lane.launch",
+                    "grep.dispatch", "grep.force", "lane.wait",
+                    "grep.compact", "forward.ack")
+
+
+@pytest.mark.mesh
+@pytest.mark.parametrize("name", SPANS_OF_A_FRAME)
+def test_traced_frame_has_span(runs, name):
+    assert runs["mesh_on"] and runs["enabled_in_session"]
+    assert by_name(runs["events"], name), \
+        sorted({e["name"] for e in runs["events"]})
+
+
+@pytest.mark.mesh
+def test_every_span_from_reencode_on_carries_the_chunk(runs):
+    events = runs["events"]
+    start = by_name(events, "forward.reencode")[0]["start"]
+    end = by_name(events, "forward.ack")[0]["end"]
+    # (the flush timer's spans may fall in between: they are no frame's)
+    after = [e for e in events if start <= e["start"] and e["end"] <= end
+             and e["name"] in SPANS_OF_A_FRAME[2:]]
+    assert len(after) >= 12
+    assert {e["stats"].get("chunk") for e in after} == {CHUNK}
+    # the lane worker's spans are on other threads and carry it too
+    engine_line = by_name(events, "forward.read")[0]["line"]
+    worker = [e for e in after if e["line"] != engine_line]
+    assert {e["name"] for e in worker} == {"lane.launch", "grep.dispatch",
+                                           "grep.force"}
+    # before the frame is whole nobody knows its chunk
+    for e in by_name(events, "forward.read") \
+            + by_name(events, "forward.unpack"):
+        assert "chunk" not in e["stats"]
+
+
+@pytest.mark.mesh
+def test_segment_spans_carry_seg_across_the_thread_hop(runs):
+    events = runs["events"]
+    n_seg = N_LINES // 32
+    for name in ("grep.stage", "lane.begin", "lane.launch", "lane.wait",
+                 "grep.dispatch", "grep.force"):
+        assert sorted(e["stats"]["seg"] for e in by_name(events, name)) \
+            == list(range(n_seg)), name
+    for launch in by_name(events, "lane.launch"):
+        for name in ("grep.dispatch", "grep.force"):
+            inner = [e for e in by_name(events, name)
+                     if e["stats"]["seg"] == launch["stats"]["seg"]]
+            assert len(inner) == 1 and inside(inner[0], launch)
+            assert inner[0]["line"] == launch["line"]
+
+
+@pytest.mark.mesh
+def test_lane_spans_name_their_lane(runs):
+    """``lane.force_ms`` divides by the launches of the grep lane, not
+    by every lane's: the lane's spans, the worker's too, say whose."""
+    for name in ("lane.begin", "lane.launch", "lane.wait",
+                 "grep.dispatch", "grep.force"):
+        assert {e["stats"].get("lane")
+                for e in by_name(runs["events"], name)} == {"grep"}, name
+    for name in ("grep.stage", "filter.grep", "engine.append"):
+        assert all("lane" not in e["stats"]
+                   for e in by_name(runs["events"], name)), name
+
+
+@pytest.mark.mesh
+def test_flush_spans_belong_to_no_frame(runs):
+    """``bind`` is a ContextVar, and a callback or a task scheduled
+    from inside a bound frame would copy the frame's ids: the engine
+    schedules its flushes from its own housekeeping task, so that they
+    carry no frame's ``chunk``."""
+    flushes = by_name(runs["events"], "engine.flush") \
+        + by_name(runs["events"], "output.flush")
+    assert {e["name"] for e in flushes} == {"engine.flush",
+                                            "output.flush"}
+    for e in flushes:
+        assert not {"chunk", "seg", "lane"} & set(e["stats"]), e
+
+
+@pytest.mark.mesh
+def test_spans_lie_inside_one_another_as_the_table_says(runs):
+    events = runs["events"]
+
+    def one(name):
+        got = by_name(events, name)
+        assert len(got) == 1, (name, len(got))
+        return got[0]
+
+    absorb, append = one("forward.absorb"), one("engine.append")
+    grep, ack = one("filter.grep"), one("forward.ack")
+    reencode = one("forward.reencode")
+    assert inside(append, absorb) and inside(grep, append)
+    for name in ("grep.stage", "lane.begin", "lane.wait", "grep.compact"):
+        for e in by_name(events, name):
+            assert inside(e, grep), name
+            assert e["line"] == grep["line"]
+    assert reencode["end"] <= absorb["start"]
+    assert absorb["end"] <= ack["start"]
+    # the frame came in pieces: failed attempts (done=0), then the one
+    # that took the message (done=1), all before the re-encode
+    unpack = by_name(events, "forward.unpack")
+    done = [e for e in unpack if e["stats"]["done"] == 1]
+    failed = [e for e in unpack if e["stats"]["done"] == 0
+              and e["end"] <= reencode["start"]]
+    assert len(done) == 1 and len(failed) >= 2
+    assert done[0]["end"] <= reencode["start"]
+    reads = by_name(events, "forward.read")
+    assert sum(e["stats"]["bytes"] for e in reads) == len(frame())
+
+
+@pytest.mark.mesh
+def test_untraced_frame_records_nothing(runs):
+    assert runs["span_off"] is spans.NOOP
+    assert runs["bind_off"] is spans.NOOP
+    assert runs["ids_off"] is None
+    # every event of the session belongs to the one traced frame
+    chunks = {e["stats"].get("chunk") for e in runs["events"]}
+    assert chunks == {CHUNK, None}
+    assert len(by_name(runs["events"], "forward.absorb")) == 1
+
+
+@pytest.mark.mesh
+def test_output_bytes_equal_with_and_without_a_session(runs):
+    assert runs["traced"] and runs["traced"] == runs["untraced"]
+    assert runs["traced"] == runs["cold"]
+    from fluentbit_tpu.codec.events import decode_events
+
+    assert len(decode_events(runs["traced"])) == N_LINES - N_LINES // 4
+
+
+# ------------------------------------------------------ lane counters
+
+
+def test_lane_stats_seconds_grow_over_a_launch():
+    lane = fault.DeviceLane("spans-test")
+    before = lane.stats()
+    for key in ("spawn_s", "run_s", "blocked_s"):
+        assert before[key] == 0.0
+
+    def launch():
+        time.sleep(0.02)
+        return 7
+
+    assert lane.run(launch, lambda: -1) == 7
+    st = lane.stats()
+    assert st["launches"] == st["ok"] == 1
+    assert st["run_s"] >= 0.019 and st["spawn_s"] > 0.0
+    assert st["blocked_s"] > 0.0
+    assert st["blocked_s"] <= st["spawn_s"] + st["run_s"] + 0.005
+    # begin/finish apart: the caller blocks for less than the launch ran
+    fl = lane.begin(launch, lambda: -1)
+    time.sleep(0.03)
+    assert lane.finish(fl) == 7
+    st2 = lane.stats()
+    assert st2["run_s"] - st["run_s"] >= 0.019
+    assert st2["blocked_s"] - st["blocked_s"] < 0.019
+
+
+def test_lane_seconds_on_health_and_prometheus():
+    ctx = flb.create(flush="50ms", grace="1")
+    ctx.input("dummy", tag="t", dummy='{"log":"x"}', rate="1")
+    ctx.output("null", match="*")
+    lane = fault.lane("grep")
+    lane.run(lambda: time.sleep(0.005), lambda: None)
+    ctx.start()
+    try:
+        ctx.flush_now()
+        text = wait_for(lambda: (
+            lambda t: t if 'phase="run"' in t else None)(
+                ctx.engine.metrics.to_prometheus()))
+    finally:
+        ctx.stop()
+    for phase in ("spawn", "run", "blocked"):
+        assert re.search(
+            r'fluentbit_device_lane_seconds\{lane="grep",phase="%s"\} '
+            r'[0-9.e+-]+' % phase, text), phase
+    lanes = fault.health_block()["lanes"]["grep"]
+    assert lanes["run_s"] >= 0.005 and "spawn_s" in lanes \
+        and "blocked_s" in lanes
+
+
+@pytest.mark.mesh
+def test_lane_workers_make_no_timing_shards(mesh_env):
+    """The trap: ShardedTimings keeps one shard per thread that adds,
+    and the lane starts a thread per launch."""
+    from fluentbit_tpu.codec.events import encode_event
+    from fluentbit_tpu.core.engine import Engine
+
+    saved = os.environ["FBTPU_SEGMENT_RECORDS"]
+    os.environ["FBTPU_SEGMENT_RECORDS"] = "8"
+    try:
+        e = Engine()
+        f = e.filter("grep")
+        f.set("regex", f"log {APACHE2}")
+        f.set("tpu_batch_records", "1")
+        ins = e.input("dummy")
+        for x in e.inputs + e.filters:
+            x.configure()
+            x.plugin.init(x, e)
+        chunk = b"".join(
+            encode_event({"log": OK_LINE if i % 4 else f"oom {i}"},
+                         float(i)) for i in range(1600))
+        before = fault.lane("grep").stats()
+        assert e.input_log_append(ins, "t", chunk) == 1200
+        after = fault.lane("grep").stats()
+    finally:
+        os.environ["FBTPU_SEGMENT_RECORDS"] = saved
+    plugin = e.filters[0].plugin
+    assert plugin._mesh is not None
+    assert after["launches"] - before["launches"] == 200
+    assert after["run_s"] > before["run_s"]
+    tm = plugin.raw_timings
+    assert len(tm._shards) == 1  # the one ingest thread
+    assert tm["device_records"] == 1600 and tm["kernel_s"] > 0
+    # the seven keys the benchmark and the smoke read, and no other
+    assert set(tm) == {"extract_s", "kernel_s", "compact_s", "records",
+                       "device_records", "overflow_rows", "h2d_bytes"}
+
+
+# ------------------------------------------------- plugin raw_timings
+
+
+def test_log_to_metrics_and_flux_have_raw_timings():
+    from fluentbit_tpu.codec.events import encode_event
+    from fluentbit_tpu.core.engine import Engine
+
+    e = Engine()
+    for mode, field in (("cardinality", "user"), ("frequency", "path")):
+        f = e.filter("log_to_metrics")
+        for k, v in {"metric_mode": mode, "value_field": field,
+                     "metric_name": f"m_{mode}", "tag": "metrics",
+                     "metric_description": "d"}.items():
+            f.set(k, v)
+    f = e.filter("flux")
+    for k, v in {"group_by": "tenant", "distinct_field": "user",
+                 "export_interval_sec": "0"}.items():
+        f.set(k, v)
+    ins = e.input("dummy")
+    for x in e.inputs + e.filters:
+        x.configure()
+        x.plugin.init(x, e)
+    raw = b"".join(
+        encode_event({"tenant": "a", "user": f"u{i % 13}",
+                      "path": f"/p{i % 7}"}, float(i)) for i in range(64))
+    assert e.input_log_append(ins, "t", raw) == 64
+    card, freq, flux = (f.plugin for f in e.filters)
+    for plugin in (card, freq):
+        tm = plugin.raw_timings
+        assert set(tm) == set(L2M_KEYS)
+        for key in L2M_KEYS:
+            assert tm[key] > 0, (plugin.mode, key)
+    tm = flux.raw_timings
+    assert tm is flux.state.timings
+    assert set(tm) == {"absorb_s"} and tm["absorb_s"] > 0
+    assert len(tm._shards) == 1
+
+
+@pytest.mark.parametrize("key", L2M_KEYS + ("absorb_s", "compact_s"))
+def test_every_timing_key_this_pr_adds_feeds_a_metric(key):
+    """An always-on counter that nothing reads is only a cost: each
+    seconds key of ``log_to_metrics`` and ``flux`` (and grep's
+    ``compact_s``, read by nothing before) is the numerator of one
+    data-only per-layer metric of the benchmark."""
+    import json
+
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        declared = {m["name"] for m in json.load(f)["per_layer"]}
+    read = {}
+    for path in glob.glob(os.path.join(REPO, "benchmark",
+                                       "layer_metrics", "*.json")):
+        with open(path) as f:
+            spec = json.load(f)
+        if spec["reader"] == "counters:ratio":
+            read[spec["args"]["num"]] = os.path.basename(path)[:-5]
+    plugin = {"absorb_s": "flux", "compact_s": "grep"}.get(
+        key, "log_to_metrics")
+    counter = f"filter.{plugin}.{key}"
+    assert read.get(counter) in declared, counter
+
+
+# --------------------------------------- the benchmark's outside hooks
+
+
+def test_outside_wrappers_of_the_benchmark_are_still_called(monkeypatch):
+    """``benchmark/run.py::install_spans`` subclasses the Unpacker,
+    rebinds ``_entries_to_events`` and setattr's wrappers on the engine
+    and the plugins: each name must still be looked up at call time."""
+    from fluentbit_tpu.plugins import net_forward
+
+    calls = {}
+
+    def counted(fn, key):
+        def call(*a, **kw):
+            calls[key] = calls.get(key, 0) + 1
+            return fn(*a, **kw)
+        return call
+
+    class TimedUnpacker(net_forward.Unpacker):
+        feed = counted(net_forward.Unpacker.feed, "feed")
+        __next__ = counted(net_forward.Unpacker.__next__, "next")
+
+    monkeypatch.setattr(net_forward, "Unpacker", TimedUnpacker)
+    monkeypatch.setattr(
+        net_forward, "_entries_to_events",
+        counted(net_forward._entries_to_events, "reencode"))
+    ctx = flb.create(flush="50ms", grace="1")
+    ctx.input("forward", listen="127.0.0.1", port="0")
+    ctx.filter("grep", match="*", regex="log GET")
+    got = []
+    ctx.output("lib", match="*", callback=lambda d, _t: got.append(d))
+    engine = ctx.engine
+    for attr in ("input_log_append", "flush_all"):
+        setattr(engine, attr, counted(getattr(engine, attr), attr))
+    plugin = engine.filters[0].plugin
+    for attr in ("filter_raw", "filter"):
+        setattr(plugin, attr, counted(getattr(plugin, attr), "grep"))
+    ctx.start()
+    try:
+        port = wait_for(lambda: engine.inputs[0].plugin.bound_port)
+        with socket.create_connection(("127.0.0.1", port)) as s:
+            s.settimeout(30)
+            s.sendall(frame(n=8))
+            assert s.recv(4096)
+        ctx.flush_now()
+        wait_for(lambda: got)
+    finally:
+        ctx.stop()
+    assert calls["feed"] >= 1 and calls["next"] >= 2
+    assert calls["reencode"] == 1 and calls["input_log_append"] == 1
+    assert calls["grep"] >= 1 and calls["flush_all"] >= 1
+
+
+# ------------------------------------------------ named device programs
+
+
+@pytest.mark.parametrize("kernel", ["scan", "assoc"])
+def test_jitted_grep_program_has_a_stable_module_name(kernel):
+    pytest.importorskip("jax")
+    import numpy as np
+
+    from fluentbit_tpu.ops import device
+    from fluentbit_tpu.ops.grep import GrepProgram
+    from fluentbit_tpu.regex.dfa import compile_dfa
+
+    assert device.wait(120)
+    prog = GrepProgram([compile_dfa(r"curl/8\.5")], max_len=64,
+                       kernel=kernel)
+    batch = np.zeros((1, 8, 64), dtype=np.uint8)
+    lengths = np.full((1, 8), -1, dtype=np.int32)
+    prog.match(batch, lengths)
+    text = prog._jit.lower(batch, lengths).as_text()
+    m = re.search(r"module @(\w+)", text)
+    assert re.fullmatch(r"jit_grep_(scan|assoc)_S\d+_k\d+", m.group(1))
+    assert m.group(1) == f"jit_{prog.program_name()}"
+    assert prog.program_name().startswith(f"grep_{kernel}_S")
+    assert "grep.symbols" in prog._jit.lower(batch, lengths).as_text(
+        debug_info=True)
+
+
+def test_sketch_and_flux_programs_are_named():
+    jax = pytest.importorskip("jax")
+    import numpy as np
+
+    from fluentbit_tpu.flux import kernels
+    from fluentbit_tpu.ops import device
+    from fluentbit_tpu.ops.sketch import CountMin, HyperLogLog
+
+    assert device.wait(120)
+    batch = np.zeros((8, 16), dtype=np.uint8)
+    lengths = np.full((8,), 3, dtype=np.int32)
+
+    def module(fn, *args):
+        return re.search(r"module @(\w+)",
+                         fn.lower(*args).as_text()).group(1)
+
+    hll, cms = HyperLogLog(p=4), CountMin(depth=2, width=16)
+    assert module(hll._device_jit(wait=True), hll.registers, batch,
+                  lengths) == "jit_hll_update"
+    assert module(cms._device_jit(wait=True), cms.table, batch, lengths,
+                  np.ones((8,), np.int32)) == "jit_cms_update"
+    fn = kernels.build_fused_absorb(None, 8, 1, 4)
+    seg = np.zeros((8,), np.int32)
+    regs = np.zeros((8, 16), np.int32)
+    assert module(fn, seg, seg, batch, lengths, regs) == "jit_flux_absorb"
+    del jax
+
+
+def test_profiler_port_is_a_service_key_off_by_default():
+    from fluentbit_tpu.core.config import ServiceConfig
+
+    svc = ServiceConfig()
+    assert svc.profiler_port == 0
+    svc.set("Profiler_Port", "9012")
+    assert svc.profiler_port == 9012 and "profiler_port" not in svc.extra
+
+
+def run_with_profiler_port(monkeypatch, port, start_server,
+                           attached=True):
+    """An engine started with ``profiler_port``: → what
+    ``jax.profiler.start_server`` was called with (and whether the
+    device was attached by then), and whether ``_serve_profiler`` ran
+    to its end without raising."""
+    import jax
+
+    from fluentbit_tpu.core.engine import Engine
+    from fluentbit_tpu.ops import device
+
+    calls, ended = [], threading.Event()
+    serve = Engine._serve_profiler
+
+    def fake(p):
+        calls.append((p, device.ready()))
+        return start_server(p)
+
+    def watched(engine):
+        assert threading.current_thread().name == "flb-profiler"
+        serve(engine)  # a raise here leaves ``ended`` unset
+        ended.set()
+
+    monkeypatch.setattr(jax.profiler, "start_server", fake)
+    monkeypatch.setattr(Engine, "_serve_profiler", watched)
+    if not attached:
+        monkeypatch.setattr(device, "wait", lambda _timeout=None: False)
+    ctx = flb.create(flush="50ms", grace="1")
+    if port:
+        ctx.service_set(profiler_port=str(port))
+    ctx.input("dummy", tag="t", dummy='{"log":"x"}', rate="1")
+    ctx.output("null", match="*")
+    ctx.start()
+    try:
+        served = ended.wait(60 if port else 0.2)
+        assert ctx.engine._thread.is_alive()  # the engine serves on
+    finally:
+        ctx.stop()
+    return calls, served
+
+
+def free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def test_profiler_port_starts_the_server_once_after_the_attach(
+        monkeypatch):
+    port = free_port()
+    calls, served = run_with_profiler_port(monkeypatch, port,
+                                           lambda _p: None)
+    assert served and calls == [(port, True)]  # once, device attached
+
+
+def test_profiler_port_off_starts_nothing(monkeypatch):
+    calls, served = run_with_profiler_port(monkeypatch, 0,
+                                           lambda _p: None)
+    assert not served and calls == []
+
+
+def test_profiler_port_failure_is_logged_and_does_not_raise(
+        monkeypatch, caplog):
+    def refuse(_p):
+        raise RuntimeError("address already in use")
+
+    with caplog.at_level("ERROR", logger="flb.engine"):
+        calls, served = run_with_profiler_port(monkeypatch, free_port(),
+                                               refuse)
+    assert served and len(calls) == 1
+    assert any("profiler server failed to start" in r.getMessage()
+               for r in caplog.records)
+
+
+def test_profiler_port_without_a_device_starts_no_server(monkeypatch,
+                                                         caplog):
+    with caplog.at_level("WARNING", logger="flb.engine"):
+        calls, served = run_with_profiler_port(
+            monkeypatch, free_port(), lambda _p: None, attached=False)
+    assert served and calls == []
+    assert any("no profiler server" in r.getMessage()
+               for r in caplog.records)
