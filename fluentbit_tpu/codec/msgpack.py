@@ -8,7 +8,8 @@ Reference parity: lib/msgpack-c in the reference tree; EventTime semantics per
 plugins/out_forward/forward.c (Fluentd forward protocol) and
 src/flb_time.c (flb_time_append_to_msgpack).
 
-A C++ accelerated codec (native/msgpack.cpp) can shadow these entry points;
+The C extension (native/fbtpu_codec.c, loaded by ``_native_codec``) serves
+iteration over an ``Unpacker`` where it reproduces this module bit for bit;
 the pure-Python version is the semantic reference and the fallback.
 """
 
@@ -16,6 +17,8 @@ from __future__ import annotations
 
 import struct
 from typing import Any, Callable, Iterator, List, Tuple
+
+from . import _native_codec
 
 __all__ = [
     "packb",
@@ -278,12 +281,21 @@ class Unpacker:
 
     ``tell()`` reports the byte offset of the next object, which the chunk
     layer uses to slice raw per-record msgpack regions out of a chunk.
+
+    Iteration asks the C extension first, when it is loaded and the
+    ``ext_hook`` is the default: it finds the message's end without
+    building an object, so a message that is not whole yet costs one
+    span walk and not a decode thrown away, and decodes a whole one
+    once. ``native`` says whether the extension served the last
+    ``next()``; what it cannot reproduce bit for bit it hands back
+    (``FallbackError``) and the Python walk below decides.
     """
 
     def __init__(self, buf: bytes = b"", ext_hook: Callable[[int, bytes], Any] = _default_ext_hook):
         self._buf = memoryview(bytes(buf)) if not isinstance(buf, (bytes, memoryview)) else memoryview(buf)
         self._pos = 0
         self._ext_hook = ext_hook
+        self.native = False
 
     def feed(self, data: bytes) -> None:
         remaining = bytes(self._buf[self._pos:]) + bytes(data)
@@ -297,6 +309,20 @@ class Unpacker:
         return self
 
     def __next__(self) -> Any:
+        self.native = False
+        mod = _native_codec.load() \
+            if self._ext_hook is _default_ext_hook else None
+        if mod is not None:
+            try:
+                got = mod.unpack_from(self._buf, self._pos)
+            except mod.FallbackError:
+                pass  # ExtType, 0xC1, hostile nesting: Python decides
+            else:
+                self.native = True
+                if got is None:
+                    raise StopIteration
+                obj, self._pos = got
+                return obj
         if self._pos >= len(self._buf):
             raise StopIteration
         start = self._pos

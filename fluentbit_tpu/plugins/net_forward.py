@@ -214,14 +214,18 @@ class ForwardInput(InputPlugin):
             fed = False
             while True:
                 # one span per attempt to take a message off the
-                # Unpacker: an incomplete frame is re-walked from its
-                # start at every read (done=0), which the count shows
+                # Unpacker. A frame comes in several reads; until it is
+                # whole an attempt (done=0) costs the C codec one walk
+                # over its spans (native=1), or the Python walk a
+                # decode thrown away where the extension is not loaded
+                # or handed the bytes back (native=0)
                 with span("forward.unpack") as sp:
                     if not fed:
                         u.feed(data)
                         fed = True
                     msg = next(u, _NO_MSG)
-                    sp.set_metadata(done=int(msg is not _NO_MSG))
+                    sp.set_metadata(done=int(msg is not _NO_MSG),
+                                    native=int(u.native))
                 if msg is _NO_MSG:
                     break
                 if not isinstance(msg, (list, tuple)) or not msg:

@@ -563,26 +563,33 @@ fail:
 
 /* ---- span-level msgpack walking (no PyObject) ---- */
 
-static const uint8_t *mp_skip_span(const uint8_t *p, const uint8_t *end,
-                                   int depth);
+/* mp_walk: end of the one object at p, or NULL. A NULL with *bad
+ * left alone means the buffer ended inside the object (more bytes may
+ * complete it); *bad = 1 means no bytes ever will: 0xC1, or nesting
+ * past MAX_DEPTH. */
+static const uint8_t *mp_walk(const uint8_t *p, const uint8_t *end,
+                              int depth, int *bad);
 
 static const uint8_t *mp_skip_n(const uint8_t *p, const uint8_t *end,
-                                long long n, int depth) {
+                                long long n, int depth, int *bad) {
     for (long long i = 0; i < n; i++) {
-        p = mp_skip_span(p, end, depth);
+        p = mp_walk(p, end, depth, bad);
         if (!p) return NULL;
     }
     return p;
 }
 
-static const uint8_t *mp_skip_span(const uint8_t *p, const uint8_t *end,
-                                   int depth) {
-    if (depth > MAX_DEPTH || p >= end) return NULL;
+static const uint8_t *mp_walk(const uint8_t *p, const uint8_t *end,
+                              int depth, int *bad) {
+    if (depth > MAX_DEPTH) { *bad = 1; return NULL; }
+    if (p >= end) return NULL;
     uint8_t b = *p++;
     long long n;
     if (b < 0x80 || b >= 0xE0) return p;              /* fixint */
-    if (b <= 0x8F) return mp_skip_n(p, end, 2LL * (b & 0x0F), depth + 1);
-    if (b <= 0x9F) return mp_skip_n(p, end, b & 0x0F, depth + 1);
+    if (b <= 0x8F)
+        return mp_skip_n(p, end, 2LL * (b & 0x0F), depth + 1, bad);
+    if (b <= 0x9F)
+        return mp_skip_n(p, end, b & 0x0F, depth + 1, bad);
     if (b <= 0xBF) { n = b & 0x1F; return (end - p >= n) ? p + n : NULL; }
     switch (b) {
     case 0xC0: case 0xC2: case 0xC3: return p;
@@ -622,23 +629,30 @@ static const uint8_t *mp_skip_span(const uint8_t *p, const uint8_t *end,
     case 0xDC:
         if (end - p < 2) return NULL;
         n = ((long long)p[0] << 8) | p[1];
-        return mp_skip_n(p + 2, end, n, depth + 1);
+        return mp_skip_n(p + 2, end, n, depth + 1, bad);
     case 0xDD:
         if (end - p < 4) return NULL;
         n = ((long long)p[0] << 24) | ((long long)p[1] << 16)
           | ((long long)p[2] << 8) | p[3];
-        return mp_skip_n(p + 4, end, n, depth + 1);
+        return mp_skip_n(p + 4, end, n, depth + 1, bad);
     case 0xDE:
         if (end - p < 2) return NULL;
         n = ((long long)p[0] << 8) | p[1];
-        return mp_skip_n(p + 2, end, 2 * n, depth + 1);
+        return mp_skip_n(p + 2, end, 2 * n, depth + 1, bad);
     case 0xDF:
         if (end - p < 4) return NULL;
         n = ((long long)p[0] << 24) | ((long long)p[1] << 16)
           | ((long long)p[2] << 8) | p[3];
-        return mp_skip_n(p + 4, end, 2 * n, depth + 1);
-    default: return NULL;                              /* 0xC1 */
+        return mp_skip_n(p + 4, end, 2 * n, depth + 1, bad);
+    default: *bad = 1; return NULL;                    /* 0xC1 */
     }
+}
+
+/* the walk where torn and malformed are one answer */
+static const uint8_t *mp_skip_span(const uint8_t *p, const uint8_t *end,
+                                   int depth) {
+    int bad = 0;
+    return mp_walk(p, end, depth, &bad);
 }
 
 /* str header reader: NULL when the object at p is not a str */
@@ -1410,6 +1424,55 @@ static PyObject *py_parser_json_batch(PyObject *self, PyObject *args) {
     return res;
 }
 
+/* unpack_from(buf, pos) — the streaming Unpacker's fast path
+ * (codec/msgpack.Unpacker.__next__). The span walk comes first and
+ * builds nothing: a message the buffer does not hold whole yet costs
+ * one pass over its bytes and returns None, where the Python walk
+ * built and threw away every object up to the tear, once per read.
+ * A whole message is decoded once, over exactly its span, so no
+ * array32/map32 header can make PyList_New ask for more entries than
+ * the message has bytes. What decode_obj cannot reproduce bit for bit
+ * (non-EventTime ext), and what no further byte can complete (0xC1,
+ * nesting at decode_obj's bound), raises FallbackError: the caller
+ * runs the Python walk, which decodes or raises as it always did. */
+static PyObject *py_unpack_from(PyObject *self, PyObject *args) {
+    Py_buffer view;
+    Py_ssize_t pos;
+    if (!PyArg_ParseTuple(args, "y*n", &view, &pos)) return NULL;
+    if (pos < 0 || pos > view.len) {
+        PyBuffer_Release(&view);
+        PyErr_SetString(PyExc_ValueError, "unpack_from: pos out of range");
+        return NULL;
+    }
+    const uint8_t *base = (const uint8_t *)view.buf;
+    const uint8_t *end = base + view.len;
+    int bad = 0;
+    /* depth 1: the walk then refuses exactly the nesting decode_obj
+     * would (walk depth d + 1 > MAX_DEPTH <=> decode depth d >= MAX) */
+    const uint8_t *msg_end = mp_walk(base + pos, end, 1, &bad);
+    if (!msg_end) {
+        PyBuffer_Release(&view);
+        if (bad) {
+            PyErr_SetString(g_fallback, "malformed or too deeply nested");
+            return NULL;
+        }
+        Py_RETURN_NONE;
+    }
+    rd r = {base + pos, msg_end, 0};
+    PyObject *obj = decode_obj(&r);
+    if (obj ? r.p != msg_end : PyErr_ExceptionMatches(g_truncated)) {
+        /* the walk and the decoder are twins; should they ever part,
+         * the Python walk decides and nothing is mis-positioned */
+        Py_CLEAR(obj);
+        PyErr_Clear();
+        PyErr_SetString(g_fallback, "span walk and decode disagree");
+    }
+    Py_ssize_t at = msg_end - base;
+    PyBuffer_Release(&view);
+    if (!obj) return NULL;
+    return Py_BuildValue("(Nn)", obj, at);
+}
+
 static PyObject *py_init(PyObject *self, PyObject *args) {
     PyObject *logevent, *eventtime;
     if (!PyArg_ParseTuple(args, "OO", &logevent, &eventtime)) return NULL;
@@ -1429,6 +1492,10 @@ static PyMethodDef methods[] = {
      "parser_json_batch(buf, key) → (out, n_records, n_parsed): "
      "whole-chunk JSON field transcode (filter_parser fast path); "
      "raises FallbackError when the per-record path must run"},
+    {"unpack_from", py_unpack_from, METH_VARARGS,
+     "unpack_from(buf, pos) → (obj, end) for the one msgpack object "
+     "at pos, None while the buffer does not hold it whole; raises "
+     "FallbackError when the Python walk must decide"},
     {"_init", py_init, METH_VARARGS,
      "register the LogEvent and EventTime classes"},
     {NULL, NULL, 0, NULL},

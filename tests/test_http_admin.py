@@ -182,7 +182,16 @@ def test_cli_sighup_ignored_without_hot_reload(tmp_path):
         env=env, cwd=REPO, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
     )
     try:
-        time.sleep(2.5)  # give it time to start
+        # the handlers are in place once it says so (a fixed sleep lost
+        # the race with the imports on a loaded machine: the default
+        # action of SIGHUP kills a process that has none yet)
+        os.set_blocking(p.stderr.fileno(), False)
+        said, deadline = b"", time.time() + 60
+        while b" started (pid" not in said and p.poll() is None \
+                and time.time() < deadline:
+            said += p.stderr.read() or b""
+            time.sleep(0.05)
+        assert b" started (pid" in said, said
         p.send_signal(signal.SIGHUP)
         time.sleep(1.0)
         assert p.poll() is None, "process died on SIGHUP"

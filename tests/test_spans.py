@@ -383,6 +383,20 @@ def test_spans_lie_inside_one_another_as_the_table_says(runs):
 
 
 @pytest.mark.mesh
+def test_unpack_spans_say_whether_the_extension_served_them(runs):
+    """``native=1`` on every attempt, the failed ones too, where the C
+    codec is loaded: a benchmark run that reads 0 measured the Python
+    fallback."""
+    from fluentbit_tpu.codec import _native_codec
+
+    want = int(_native_codec.load() is not None)
+    unpack = by_name(runs["events"], "forward.unpack")
+    assert len(unpack) >= 3
+    assert {e["stats"]["native"] for e in unpack} == {want}
+    assert {e["stats"]["done"] for e in unpack} == {0, 1}
+
+
+@pytest.mark.mesh
 def test_untraced_frame_records_nothing(runs):
     assert runs["span_off"] is spans.NOOP
     assert runs["bind_off"] is spans.NOOP
