@@ -495,14 +495,14 @@ def grep_launch_probe(prog) -> None:
         return round(sorted(times)[len(times) // 2], 3)
 
     for child in prog._children or [prog]:
-        R = len(child.dfas)
+        K = child.n_planes  # the distinct staged planes its rules read
         for L in (256, 512):
-            batch = np.full((R, SEGMENT, L), ord("a"), np.uint8)
-            lengths = np.full((R, SEGMENT), L // 2, np.int32)
+            batch = np.full((K, SEGMENT, L), ord("a"), np.uint8)
+            lengths = np.full((K, SEGMENT), L // 2, np.int32)
             dev = [jax.device_put(batch), jax.device_put(lengths)]
             say(stage="grep:launch_probe", kernel=child.kernel_resolved,
                 max_states=child.max_states, k=child.k,
-                shape=[R, SEGMENT, L],
+                rules=len(child.dfas), shape=[K, SEGMENT, L],
                 h2d_ms=median_ms(lambda: [
                     jax.device_put(a).block_until_ready()
                     for a in (batch, lengths)]),

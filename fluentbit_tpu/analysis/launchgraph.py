@@ -87,8 +87,11 @@ SCOPES = ("fluentbit_tpu/plugins/", "fluentbit_tpu/flux/")
 #: the worker — the counters the parity test reads).
 LANE_LAUNCH = frozenset({"run", "begin"})
 
-#: Helpers that wrap their own lane launch (flux/kernels.py).
-GUARDED_LAUNCH_FNS = frozenset({"guarded_segment_counts"})
+#: Helpers that wrap their own lane launch, known by name where the
+#: caller imports them from another module (flux/kernels.py;
+#: filter_grep's staged launch, which filter_rewrite_tag shares). In
+#: their own module the walker inlines them instead.
+GUARDED_LAUNCH_FNS = frozenset({"guarded_segment_counts", "staged_match"})
 
 #: Raw jit/pjit/shard_map dispatch terminals, by launch kind.
 KIND_BY_NAME = {
@@ -96,6 +99,7 @@ KIND_BY_NAME = {
     "match_sharded": "grep-mesh",
     "sharded_segment_counts": "flux-segment-counts",
     "guarded_segment_counts": "flux-segment-counts",
+    "staged_match": "grep-mesh",
     "sharded_hll_registers": "flux-hll", "sharded_hll_update": "flux-hll",
     "device_registers": "flux-hll",
     "sharded_cms_table": "flux-cms", "sharded_cms_update": "flux-cms",
@@ -142,6 +146,8 @@ _SEVERITY = {
 #: fusion PR keeps them device-resident across segments).
 TRANSFER_SHAPES: Dict[str, Dict[str, List[Tuple[str, str, str, bool]]]] = {
     "grep-mesh": {
+        # sized at one staged plane a rule (every rule on its own key):
+        # the upper bound — rules that share a key share its plane
         "h2d": [("batch", "R*Bp*L", "uint8", False),
                 ("lengths", "4*R*Bp", "int32", True)],
         "d2h": [("mask", "4*R*Bp", "int32", False)],
@@ -493,7 +499,7 @@ class _EntryWalk:
             kind, _ = _closure_kind(defs)
             self._site(call, kind, f"lane.{t}", lane=True, ctx=ctx)
             return 1
-        if t in GUARDED_LAUNCH_FNS:
+        if t in GUARDED_LAUNCH_FNS and self._callee(call, ctx) is None:
             self._site(call, KIND_BY_NAME[t], t, lane=True, ctx=ctx)
             return 1
         if t in DISPATCH_NAMES:

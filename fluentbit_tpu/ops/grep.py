@@ -7,11 +7,17 @@ DFA (fluentbit_tpu.regex.dfa) runs over a ``[B, L] uint8`` batch as a
 
     state[b] = trans[state[b], class(byte[b, t])]        t = 0..L
 
-- Multi-rule: R DFAs run in one kernel over ``[R, B, L]`` (each grep rule
-  may address a different record field, hence per-rule batches). All R
-  transition tables are fused into ONE flat gather per scan step
-  (``trans_flat[R, max_flat]`` + per-rule radix), so the step cost does
-  not grow a kernel launch per rule.
+- Multi-rule: R DFAs run in one kernel. The caller stages each DISTINCT
+  record field once — the planes ``[K, B, L]`` — and the program's
+  static rule→plane index (``plane_of``) gathers every rule's plane on
+  the device, so eight rules on one key cost one plane of host→device
+  bytes, not eight. All R transition tables are fused into ONE flat
+  gather per scan step (``trans_flat[R, max_flat]`` + per-rule radix),
+  so the step cost does not grow a kernel launch per rule.
+- First match: ``dispatch(..., first_match=True)`` reduces the merged
+  ``[R, B]`` mask on the device to one i32 a record — the first rule,
+  in the caller's rule order, whose DFA accepts, or -1 — so a router
+  (rewrite_tag) copies out B integers instead of R×B verdicts.
 - k-byte super-steps: transition tables are pre-composed to ``C^k``
   columns (T2[s, c1*C+c2] = T[T[s,c1],c2]), cutting sequential scan steps
   by k at the cost of a larger (still VMEM-resident) table. k is chosen
@@ -92,15 +98,29 @@ def compose_table(trans: np.ndarray, k: int) -> np.ndarray:
 class GrepProgram:
     """R compiled DFAs fused into one device program.
 
-    Produces ``match(batch_u8[R,B,L], lengths[R,B]) -> bool[R,B]``.
+    Produces ``match(planes_u8[K,B,L], lengths[K,B]) -> bool[R,B]``:
+    ``planes`` holds each distinct staged field once and ``plane_of[r]``
+    (static, default ``range(R)``: one plane a rule) names the plane
+    rule ``r`` reads.
     """
 
     def __init__(self, dfas: Sequence[DFA], max_len: int = 512,
-                 kernel: Optional[str] = None, segment: int = 32):
+                 kernel: Optional[str] = None, segment: int = 32,
+                 plane_of: Optional[Sequence[int]] = None):
         if not HAVE_JAX:
             raise RuntimeError("jax is unavailable")
         self.dfas = list(dfas)
         self.max_len = max_len
+        if plane_of is None:
+            plane_of = range(len(self.dfas))
+        self.plane_of = tuple(int(p) for p in plane_of)
+        if len(self.plane_of) != len(self.dfas) or (
+                self.plane_of and min(self.plane_of) < 0):
+            raise ValueError(f"plane_of {self.plane_of!r} does not name "
+                             f"a plane for each of {len(self.dfas)} rules")
+        #: planes the caller stages (children take the parent's planes
+        #: whole and gather their own rules' from them)
+        self.n_planes = max(self.plane_of, default=-1) + 1
         # kernel variant: "scan" = sequential lax.scan of table gathers
         # (Lk serialized steps, minimal FLOPs); "assoc" = parallel-in-
         # time function composition (segments scanned as transition
@@ -144,13 +164,18 @@ class GrepProgram:
             ]
             self._children = [
                 GrepProgram([self.dfas[int(i)] for i in idxs], max_len,
-                            kernel=self.kernel, segment=segment)
+                            kernel=self.kernel, segment=segment,
+                            plane_of=[self.plane_of[int(i)]
+                                      for i in idxs])
                 for idxs in self._child_idxs
             ]
+            for c in self._children:
+                c.n_planes = self.n_planes
             perm = np.concatenate(self._child_idxs)
             self._inv_perm = np.argsort(perm)
             self.k = distinct_ks[0]
             self.max_states = max(d.n_states for d in self.dfas)
+            self._merge_jit = None
             self._np = None
             self._jit = None
             self._mat_lock = threading.Lock()
@@ -267,8 +292,27 @@ class GrepProgram:
                 f"_k{self.k}{suffix}")
 
     def _merge_rule_axis(self, parts):
-        """Reassemble per-child rule rows into the caller's order."""
-        return jnp.concatenate(list(parts), axis=0)[self._inv_perm]
+        """Reassemble per-child rule rows into the caller's order (one
+        small jitted program, ``jit_grep_merge``)."""
+        fn = self._merge_jit
+        if fn is None:
+            inv = self._inv_perm
+
+            def grep_merge(*rows):
+                return jnp.concatenate(rows, axis=0)[inv]
+
+            fn = self._merge_jit = jax.jit(grep_merge)
+        return fn(*parts)
+
+    def _gather_planes(self, planes, lengths):
+        """Each rule's plane and lengths from the distinct staged ones,
+        on the device: ``[K, B, L]``, ``[K, B]`` → ``[R, B, L]``,
+        ``[R, B]`` through the static ``plane_of`` (no-op for the
+        one-plane-a-rule layout)."""
+        if self.plane_of == tuple(range(self.n_planes)):
+            return planes, lengths
+        return (jnp.stack([planes[p] for p in self.plane_of]),
+                jnp.stack([lengths[p] for p in self.plane_of]))
 
     def _materialize(self) -> None:
         """Transfer tables to the attached backend + build the jit.
@@ -290,8 +334,8 @@ class GrepProgram:
                     else self._match_impl)
             tbl = self._tbl
 
-            def impl(batch, lengths):
-                return kern(tbl, batch, lengths)
+            def impl(planes, lengths):
+                return kern(tbl, *self._gather_planes(planes, lengths))
 
             impl.__name__ = self.program_name()
             self._impl = impl
@@ -489,19 +533,7 @@ class GrepProgram:
         # to the batch, mirroring _match_impl's state0 trick
         return (final + 0 * lengths == ACC) & (lengths >= 0)
 
-    def dispatch(self, batch: np.ndarray, lengths: np.ndarray):
-        """Launch the kernel WITHOUT forcing the result (jax dispatch
-        is asynchronous) — the launch half of the double-buffered
-        staging pipeline (core.chunk_batch.double_buffered): the caller
-        stages the next segment while this one's kernel is in flight,
-        then forces with np.asarray one segment behind."""
-        if self._children is not None:
-            # per-k child programs: every child launches (async) before
-            # the merge touches any result, so the k-groups overlap the
-            # same way double-buffered segments do
-            parts = [c.dispatch(batch[idx], lengths[idx])
-                     for c, idx in zip(self._children, self._child_idxs)]
-            return self._merge_rule_axis(parts)
+    def _ensure_materialized(self) -> None:
         if self._jit is None:
             from . import device
 
@@ -510,12 +542,37 @@ class GrepProgram:
                     f"device backend not attached: {device.status()}"
                 )
             self._materialize()
-        return self._jit(jnp.asarray(batch), jnp.asarray(lengths))
 
-    def match(self, batch: np.ndarray, lengths: np.ndarray) -> np.ndarray:
-        """Run the kernel; returns bool [R, B] (numpy). Blocks up to the
-        attach-wait deadline if the backend isn't up yet."""
-        return np.asarray(self.dispatch(batch, lengths))
+    def dispatch(self, planes: np.ndarray, lengths: np.ndarray,
+                 first_match: bool = False):
+        """Launch the kernel WITHOUT forcing the result (jax dispatch
+        is asynchronous) — the launch half of the double-buffered
+        staging pipeline (core.chunk_batch.double_buffered): the caller
+        stages the next segment while this one's kernel is in flight,
+        then forces with np.asarray one segment behind.
+
+        ``planes[K, B, L]`` / ``lengths[K, B]`` are the distinct staged
+        fields; they cross to the device ONCE, whatever the number of
+        rules or per-k children that read them. → ``mask[R, B]`` bool,
+        or with ``first_match`` the ``[B]`` i32 first-match vector."""
+        planes, lengths = jnp.asarray(planes), jnp.asarray(lengths)
+        if self._children is not None:
+            # per-k child programs: every child launches (async) before
+            # the merge touches any result, so the k-groups overlap the
+            # same way double-buffered segments do
+            mask = self._merge_rule_axis(
+                [c.dispatch(planes, lengths) for c in self._children])
+        else:
+            self._ensure_materialized()
+            mask = self._jit(planes, lengths)
+        return first_match_of(mask) if first_match else mask
+
+    def match(self, planes: np.ndarray, lengths: np.ndarray,
+              first_match: bool = False) -> np.ndarray:
+        """Run the kernel; returns bool [R, B] (numpy), or the i32 [B]
+        first-match vector. Blocks up to the attach-wait deadline if
+        the backend isn't up yet."""
+        return np.asarray(self.dispatch(planes, lengths, first_match))
 
     # -- multi-device (SPMD over a 1-D device mesh) --
 
@@ -526,24 +583,17 @@ class GrepProgram:
         global per-rule match counts reduce with ``lax.psum`` over ICI
         (the metrics-reduction contract of BASELINE/SURVEY §2.4).
 
-        Returns ``fn(batch[R, B, L], lengths[R, B]) -> (mask[R, B],
+        Returns ``fn(planes[K, B, L], lengths[K, B]) -> (mask[R, B],
         counts[R])`` with ``B`` divisible by the mesh size; ``counts`` is
         the global (all-device) per-rule match total.
         """
         from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
-        if self._jit is None:
-            from . import device
+        self._ensure_materialized()
 
-            if not device.wait(60.0):
-                raise RuntimeError(
-                    f"device backend not attached: {device.status()}"
-                )
-            self._materialize()
-
-        def step(batch, lengths):
-            mask = self._impl(batch, lengths)
+        def step(planes, lengths):
+            mask = self._impl(planes, lengths)
             counts = lax.psum(
                 jnp.sum(mask.astype(jnp.int32), axis=1), axis_name=axis
             )
@@ -560,29 +610,24 @@ class GrepProgram:
         )
 
     def match_sharded(self, mesh, batch: np.ndarray, lengths: np.ndarray):
-        """Pad B up to the mesh size and run the SPMD matcher; returns
-        (mask[R, B] numpy, counts[R] numpy, matcher-padded batch size)."""
+        """Pad B up to the mesh size and run the SPMD matcher over the
+        staged planes ``batch[K, B, L]``; returns (mask[R, B] numpy,
+        counts[R] numpy, matcher-padded batch size)."""
         from .mesh import mesh_key, pad_to_devices
 
         if self._children is not None:
             masks, counts, bp = [], [], 0
-            for c, idx in zip(self._children, self._child_idxs):
-                m, ct, bp = c.match_sharded(mesh, batch[idx], lengths[idx])
+            for c in self._children:
+                m, ct, bp = c.match_sharded(mesh, batch, lengths)
                 masks.append(m)
                 counts.append(ct)
             inv = self._inv_perm
             return (np.concatenate(masks, axis=0)[inv],
                     np.concatenate(counts, axis=0)[inv], bp)
 
-        R, B, L = batch.shape
+        B = batch.shape[1]
         Bp = pad_to_devices(B, mesh.devices.size)
-        if Bp != B:
-            batch = np.concatenate(
-                [batch, np.zeros((R, Bp - B, L), dtype=batch.dtype)], axis=1
-            )
-            lengths = np.concatenate(
-                [lengths, np.full((R, Bp - B), -1, dtype=lengths.dtype)], axis=1
-            )
+        batch, lengths = _pad_rows(batch, lengths, Bp)
         key = mesh_key(mesh)
         fn = self._sharded_cache.get(key)
         if fn is None:
@@ -594,7 +639,7 @@ class GrepProgram:
     # -- explicitly partitioned pjit program (the fbtpu-mesh plane) --
 
     def mesh_variant(self, mesh) -> str:
-        """Which axis of the [R, B, L] program shards across the mesh.
+        """Which axis of the program shards across the mesh.
 
         ``"batch"`` (default): B splits across devices, the transition/
         pair-class tables replicate — right whenever the tables are
@@ -666,19 +711,28 @@ class GrepProgram:
         # evaluates statically; only the staged-input/output specs are
         # per-variant here
         if variant == "rules":
+            # each device matches its own rules: it is handed those
+            # rules' planes only, [R, B, L] sharded on the rule axis
+            # (dispatch_mesh expands the staged planes on the host)
             table_rules = partition_rules("grep-rules", axis)
             spec_b, spec_l = P(axis, None, None), P(axis, None)
             spec_mask, spec_counts = P(axis, None), P(axis)
+            n_in = R
         else:
+            # the staged planes [K, Bp, L] shard on the batch axis and
+            # every device gathers its rules' planes from its own shard
             table_rules = partition_rules("grep-batch", axis)
             spec_b, spec_l = P(None, axis, None), P(None, axis)
             spec_mask, spec_counts = P(None, axis), P()
+            n_in = self.n_planes
         tspecs = match_partition_rules(table_rules, self._tbl)
 
         kern = (self._match_assoc_impl
                 if self.kernel_resolved == "assoc" else self._match_impl)
 
         def step(t, batch, lengths):
+            if variant == "batch":
+                batch, lengths = self._gather_planes(batch, lengths)
             mask = kern(t, batch, lengths)
             # i32 mask (not bool): exactly matches the donated lengths
             # buffer's sharded aval, so XLA aliases the verdict into
@@ -711,8 +765,9 @@ class GrepProgram:
         # a copy (plus a warning) for anything else, which the mesh
         # bench must never report as donated. Shapes vary per call, so
         # the donate set is computed from dtypes on a canonical shape:
-        # lengths i32 [R, B] ↔ mask i32 [R, B] always aliases; batch
-        # u8 [R, B, L] never has an aliasable output.
+        # lengths i32 [K, B] ↔ mask i32 [R, B] aliases when the planes
+        # are one a rule (K == R); batch u8 never has an aliasable
+        # output.
         Bc = mesh.devices.size * 8  # canonical shape for the aval match
         Lc = self.max_len
         donate_idx: tuple = ()
@@ -723,8 +778,8 @@ class GrepProgram:
             cand = aliasable_donations(
                 mesh,
                 in_specs=[
-                    ((R, Bc, Lc), np.uint8, spec_b, True),
-                    ((R, Bc), np.int32, spec_l, True),
+                    ((n_in, Bc, Lc), np.uint8, spec_b, True),
+                    ((n_in, Bc), np.int32, spec_l, True),
                 ],
                 out_specs=outs,
             )
@@ -741,15 +796,9 @@ class GrepProgram:
         """The :meth:`_mesh_program` of ``mesh``, with the tables
         device_put once under their shardings — built once and cached
         per mesh structure."""
-        from . import device
         from .mesh import mesh_key
 
-        if self._jit is None:
-            if not device.wait(60.0):
-                raise RuntimeError(
-                    f"device backend not attached: {device.status()}"
-                )
-            self._materialize()
+        self._ensure_materialized()
         key = (mesh_key(mesh), donate, with_counts)
         h = self._mesh_cache.get(key)
         if h is not None:
@@ -763,15 +812,19 @@ class GrepProgram:
         return h
 
     def dispatch_mesh(self, mesh, batch: np.ndarray, lengths: np.ndarray,
-                      donate: str = "auto", with_counts: bool = True):
-        """Launch the partitioned matcher WITHOUT forcing (the mesh half
-        of the double-buffered pipeline). Pads B up to the mesh size
+                      donate: str = "auto", with_counts: bool = True,
+                      first_match: bool = False):
+        """Launch the partitioned matcher over the staged planes
+        ``batch[K, B, L]`` WITHOUT forcing (the mesh half of the
+        double-buffered pipeline). Pads B up to the mesh size
         (batch variant; the rules variant shards R and takes B as-is),
         transfers the staged buffers with their input shardings — each
         device receives only its own shard — and returns
         ``(mask_i32 dev[R, Bp], counts dev | None, B, Bp)``
         (``with_counts=False`` skips the per-rule totals and their
-        cross-device psum — the engine filter path never reads them).
+        cross-device psum — the engine filter path never reads them;
+        ``first_match`` returns the ``[Bp]`` i32 first-match vector in
+        the mask's place).
         The staged device buffers are CONSUMED when donation is on:
         re-reading them after dispatch raises instead of silently
         aliasing the verdict bytes."""
@@ -779,14 +832,16 @@ class GrepProgram:
 
         if self._children is not None:
             # per-k children: launch them all first (async), then merge
-            # on the rule axis. Children may pad B differently (the
+            # on the rule axis. Each child takes the host planes whole
+            # and places them itself (a donated buffer cannot be shared
+            # between programs). Children may pad B differently (the
             # rules variant is gated off, but keep the contract local):
             # each part is sliced back to B lazily before the concat.
             B = batch.shape[1]
             parts, count_parts, bps = [], [], []
-            for c, idx in zip(self._children, self._child_idxs):
+            for c in self._children:
                 m, ct, _b, bp = c.dispatch_mesh(
-                    mesh, batch[idx], lengths[idx], donate, with_counts)
+                    mesh, batch, lengths, donate, with_counts)
                 parts.append(m)
                 count_parts.append(ct)
                 bps.append(bp)
@@ -804,18 +859,19 @@ class GrepProgram:
             mask = self._merge_rule_axis(parts)
             counts = (self._merge_rule_axis(count_parts)
                       if with_counts else None)
+            if first_match:
+                mask = first_match_of(mask)
             return mask, counts, B, Bp
 
         h = self._mesh_handle(mesh, donate, with_counts)
-        R, B, L = batch.shape
-        Bp = pad_to_devices(B, h.n_devices) if h.variant == "batch" else B
-        if Bp != B:
-            batch = np.concatenate(
-                [batch, np.zeros((R, Bp - B, L), dtype=batch.dtype)],
-                axis=1)
-            lengths = np.concatenate(
-                [lengths, np.full((R, Bp - B), -1, dtype=lengths.dtype)],
-                axis=1)
+        B = batch.shape[1]
+        if h.variant == "batch":
+            Bp = pad_to_devices(B, h.n_devices)
+            batch, lengths = _pad_rows(batch, lengths, Bp)
+        else:
+            Bp = B
+            idx = list(self.plane_of)
+            batch, lengths = batch[idx], lengths[idx]
         bd = jax.device_put(np.ascontiguousarray(batch, dtype=np.uint8),
                             h.sh_b)
         ld = jax.device_put(np.ascontiguousarray(lengths, dtype=np.int32),
@@ -824,11 +880,14 @@ class GrepProgram:
             mask_i32, counts = h.fn(h.tables, bd, ld)
         else:
             mask_i32, counts = h.fn(h.tables, bd, ld), None
+        if first_match:
+            mask_i32 = first_match_of(mask_i32)
         return mask_i32, counts, B, Bp
 
     def match_mesh(self, mesh, batch: np.ndarray, lengths: np.ndarray,
                    donate: str = "auto"):
-        """Run the partitioned matcher and force: returns
+        """Run the partitioned matcher over the staged planes
+        ``batch[K, B, L]`` and force: returns
         ``(mask[R, B] bool numpy, counts[R] numpy, Bp)`` — bit-exact
         with :meth:`match` and the CPU chain (tier-1 ``mesh`` tests)."""
         mask_i32, counts, B, Bp = self.dispatch_mesh(
@@ -853,8 +912,9 @@ class GrepProgram:
         h = self._mesh_handle(mesh, donate)
         R = len(self.dfas)
         Bp = pad_to_devices(B, h.n_devices) if h.variant == "batch" else B
-        batch = np.zeros((R, Bp, self.max_len), dtype=np.uint8)
-        lengths = np.full((R, Bp), -1, dtype=np.int32)
+        n_in = self.n_planes if h.variant == "batch" else R
+        batch = np.zeros((n_in, Bp, self.max_len), dtype=np.uint8)
+        lengths = np.full((n_in, Bp), -1, dtype=np.int32)
         bd = jax.device_put(batch, h.sh_b)
         ld = jax.device_put(lengths, h.sh_l)
         lowered = h.fn.lower(h.tables, bd, ld)
@@ -890,19 +950,52 @@ class _MeshHandle:
         self.with_counts = with_counts
 
 
+def _pad_rows(planes: np.ndarray, lengths: np.ndarray, Bp: int):
+    """Pad the batch axis of staged planes up to ``Bp`` rows (zero
+    bytes, length -1: a missing value, no rule accepts it)."""
+    K, B, L = planes.shape
+    if Bp == B:
+        return planes, lengths
+    return (np.concatenate(
+                [planes, np.zeros((K, Bp - B, L), dtype=planes.dtype)],
+                axis=1),
+            np.concatenate(
+                [lengths, np.full((K, Bp - B), -1, dtype=lengths.dtype)],
+                axis=1))
+
+
+def grep_first_match(mask):
+    """``mask[R, B]`` (bool or i32) → ``[B]`` i32: the first rule in row
+    order that accepts the record, or -1 — the reduction of a
+    first-match-wins rule list."""
+    hit = mask != 0
+    first = jnp.argmax(hit, axis=0).astype(jnp.int32)
+    return jnp.where(hit.any(axis=0), first, jnp.int32(-1))
+
+
+#: the same on the device, after the children are merged: one small
+#: jitted program (``jit_grep_first_match``)
+first_match_of = jax.jit(grep_first_match) if HAVE_JAX else None
+
+
 @functools.lru_cache(maxsize=64)
 def _cached_program(patterns: Tuple[str, ...], max_len: int,
-                    minimize: bool) -> "GrepProgram":
+                    minimize: bool,
+                    plane_of: Optional[Tuple[int, ...]]) -> "GrepProgram":
     from ..regex.dfa import compile_dfa
 
     return GrepProgram([compile_dfa(p, minimize=minimize)
-                        for p in patterns], max_len)
+                        for p in patterns], max_len, plane_of=plane_of)
 
 
-def program_for(patterns: Sequence[str], max_len: int = 512) -> "GrepProgram":
-    """Compiled-program cache keyed by the pattern tuple (and the
-    FBTPU_DFA_MIN toggle — the bench's minimization-off differential
-    must never be served a cached minimized program, or vice versa)."""
+def program_for(patterns: Sequence[str], max_len: int = 512,
+                plane_of: Optional[Sequence[int]] = None) -> "GrepProgram":
+    """Compiled-program cache keyed by the pattern tuple, the rule→plane
+    index (and the FBTPU_DFA_MIN toggle — the bench's minimization-off
+    differential must never be served a cached minimized program, or
+    vice versa)."""
     from ..regex.dfa import minimize_enabled
 
-    return _cached_program(tuple(patterns), max_len, minimize_enabled())
+    return _cached_program(
+        tuple(patterns), max_len, minimize_enabled(),
+        None if plane_of is None else tuple(int(p) for p in plane_of))

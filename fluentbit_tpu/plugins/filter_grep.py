@@ -9,8 +9,9 @@ of the *last examined* rule, matching the reference exactly).
 
 Execution: when every rule pattern compiles to a DFA (and ``tpu.enable``
 is on, jax present), matching runs vectorized on device via
-fluentbit_tpu.ops.grep — field values are staged into a ``[R, B, L]``
-batch, the fused DFA kernel produces the per-rule match matrix, and the
+fluentbit_tpu.ops.grep — each distinct field the rules read is staged
+once into the planes ``[K, B, L]``, the fused DFA kernel gathers every
+rule's plane from them and produces the per-rule match matrix, and the
 legacy/AND/OR verdict is applied as vector ops on the mask. Records whose
 field overflows ``tpu_max_record_len`` (or batches smaller than
 ``tpu_batch_records``) resolve on the CPU path with identical semantics.
@@ -48,7 +49,7 @@ class _RawDecline(Exception):
 #: records whose segment was launched through the grep DeviceLane, the
 #: overflow rows among them (longer than ``tpu_max_record_len``,
 #: resolved on the CPU after the launch), and the staged bytes handed
-#: to the launch (the [R, Bp, L] plane + lengths)
+#: to the launch (the distinct [K, Bp, L] planes + their lengths)
 _TIMING_KEYS = ("extract_s", "kernel_s", "compact_s", "records",
                 "device_records", "overflow_rows", "h2d_bytes")
 
@@ -140,6 +141,318 @@ def parse_grep_rules(properties) -> List[Rule]:
     return rules
 
 
+def plane_index(rules) -> Tuple[list, Tuple[int, ...]]:
+    """The distinct record fields a rule list reads, in first-seen
+    order (as the first rule's accessor), and each rule's place among
+    them — the rule→plane index a ``GrepProgram`` is built with: a
+    field is staged once, however many rules read it."""
+    accessors, plane_of, seen = [], [], {}
+    for rule in rules:
+        key = (rule.ra.head, tuple(rule.ra.parts))
+        if key not in seen:
+            seen[key] = len(accessors)
+            accessors.append(rule.ra)
+        plane_of.append(seen[key])
+    return accessors, tuple(plane_of)
+
+
+def rule_matches(rule, body) -> bool:
+    """One rule against one decoded record on the host (string values
+    only, flb_ra_key.c:418) — what decides an overflow row."""
+    val = _to_text(rule.ra.get(body)) if isinstance(body, dict) else None
+    return val is not None and bool(rule.regex.match(val))
+
+
+def first_of_mask(mask: np.ndarray) -> np.ndarray:
+    """Host twin of ``ops.grep.first_match_of``: ``mask[R, B]`` → the
+    first accepting rule a record, or -1."""
+    return np.where(mask.any(axis=0), mask.argmax(axis=0),
+                    -1).astype(np.int32)
+
+
+def host_mask(rules, plane_of, planes: np.ndarray, lengths: np.ndarray,
+              cnt: int) -> np.ndarray:
+    """Bit-exact host twin of the kernel verdict over a staged segment
+    — the DeviceLane fallback. Rows with length < 0 (missing -1,
+    overflow -2) stay False, exactly like the kernel; the caller's
+    overflow decode then fixes -2 rows the same way it does after a
+    device launch."""
+    mask = np.zeros((len(rules), cnt), dtype=bool)
+    for r, rule in enumerate(rules):
+        ln = lengths[plane_of[r]]
+        row = planes[plane_of[r]]
+        rx = rule.regex
+        for i in range(cnt):
+            li = int(ln[i])
+            if li >= 0:
+                mask[r, i] = rx.match(bytes(row[i, :li]).decode(
+                    "utf-8", "surrogateescape"))
+    return mask
+
+
+def decoded_match(rules, program, lane, events: list,
+                  max_len: int) -> np.ndarray:
+    """The decoded path's launch: stage each distinct field of
+    ``events`` once (``ops.batch.assemble``), run the fused DFA kernel
+    through ``lane`` (bit-exact host fallback), resolve overflow rows
+    on the CPU. Returns mask[R, B] bool."""
+    from ..ops.batch import assemble, bucket_size
+
+    B = len(events)
+    # rules addressing the same field share one extraction, one staged
+    # plane and one host→device copy (the staging loop is the hot-path
+    # bottleneck)
+    accessors, plane_of = plane_index(rules)
+    Bp = bucket_size(B, max_len=max_len)
+    values: list = []
+    staged: list = []
+    for ra in accessors:
+        vals: List[Optional[bytes]] = []
+        for ev in events:
+            v = _to_text(ra.get(ev.body)) \
+                if isinstance(ev.body, dict) else None
+            vals.append(v.encode("utf-8") if v is not None else None)
+        values.append(vals)
+        staged.append(assemble(vals, max_len, Bp))
+    batch = np.stack([b.batch for b in staged])
+    lengths = np.stack([b.lengths for b in staged])
+    mask = lane.run(
+        lambda: np.asarray(program.match(batch, lengths)),
+        lambda: host_mask(rules, plane_of, batch, lengths,
+                          batch.shape[1]),
+    )
+    mask = np.array(mask[:, :B])
+    for r, rule in enumerate(rules):
+        k = plane_of[r]
+        for i in staged[k].overflow:
+            mask[r, i] = rule.regex.match(values[k][i])
+    return mask
+
+
+def staged_match(rules, program, lane, tm, data, n_records, *,
+                 max_len: int, min_records: int, mesh=None,
+                 first_match: bool = False):
+    """Device matching straight off chunk bytes, with double-buffered
+    staging — the one staged launch ``filter_grep`` and
+    ``filter_rewrite_tag`` share.
+
+    The chunk's records split into fixed-size segments; each DISTINCT
+    key the rules read is staged once a segment
+    (native.stage_field_into over the segment's byte span) into the
+    planes ``[K, Bp, L]`` the program gathers its rules' inputs from;
+    staging of segment N+1 runs while segment N's kernel is in flight
+    (jax async dispatch — core.chunk_batch.double_buffered), and each
+    verdict is forced one segment behind. Every launch goes through
+    ``lane`` (``begin``/``finish``: breaker, deadline, the bit-exact
+    host fallback); rows longer than ``max_len`` (-2) are decided on
+    the host after the launch.
+
+    With ``mesh`` set, each segment launches through the explicitly
+    partitioned pjit matcher instead: the batch axis is padded to the
+    mesh size and sharded across devices at ONE jit-stable width, and
+    the staged lengths are donated to the kernel where they can alias
+    its output.
+
+    ``tm`` (the plugin's ``raw_timings``) takes ``extract_s``,
+    ``kernel_s`` (wall less extraction), ``h2d_bytes`` (the planes and
+    their lengths), ``device_records`` and ``overflow_rows``.
+    Returns ``(verdict, offsets[n+1], n)`` — ``mask[R, n]`` bool, or
+    with ``first_match`` the ``[n]`` i32 first-match vector — or None
+    to decline (fewer than ``min_records`` records, or bytes the
+    staging walk cannot serve); nothing is counted on a decline."""
+    import os as _os
+    import time as _time
+
+    from .. import native
+    from ..core.chunk_batch import double_buffered, segment_bounds
+    from ..ops.batch import bucket_size
+
+    if not isinstance(data, bytes):
+        data = bytes(data)
+    # default matches a bucket_size rung exactly: a full segment
+    # stages with ZERO pad rows (8192 would round up to the 16384
+    # bucket and double every segment's staging + kernel work)
+    seg = int(_os.environ.get("FBTPU_SEGMENT_RECORDS", "4096"))
+    n = n_records
+    offsets = None
+    if n is None or n > seg:
+        # segmentation (or an unknown count) needs the boundary
+        # table up front; single-segment chunks with a known count
+        # skip this walk and take the offsets the first
+        # stage_field call discovers anyway
+        offsets = native.scan_offsets(data)
+        if offsets is None:
+            return None
+        n = len(offsets) - 1
+    if n < min_records:
+        return None  # small batches: decline BEFORE staging/kernel
+    accessors, plane_of = plane_index(rules)
+    keys = [ra.head.encode("utf-8") for ra in accessors]
+    K = len(keys)
+    bounds = segment_bounds(n, seg)
+    multi = len(bounds) > 1
+    extract_s = [0.0]
+    lens_parts: list = []
+    cnts: list = []
+    offs_box = [offsets]  # filled by staging when not pre-scanned
+
+    n_dev = mesh.devices.size if mesh is not None else 1
+
+    def stages():
+        def stage_key(part, key, wide, wlen, cnt):
+            # single-segment chunks take the boundary table straight
+            # from the staging walk (it computes one anyway, the same
+            # whichever key discovers it) — never re-scan
+            want_offs = offs_box[0] is None
+            offs = np.empty(cnt + 1, dtype=np.int64) \
+                if want_offs else None
+            count = native.stage_field_into(
+                part, key, wide, wlen, n_hint=cnt, offsets_out=offs)
+            if count is None or count != cnt:
+                raise _RawDecline
+            if want_offs:
+                offs_box[0] = offs
+
+        def stage(s, e):
+            t0 = _time.perf_counter()
+            cnt = e - s
+            part = data if offs_box[0] is None \
+                else data[offs_box[0][s]: offs_box[0][e]]
+            if mesh is not None:
+                # mesh staging: ONE jit-stable width (the sharded
+                # program wants one compiled shape, not per-chunk
+                # L buckets) and extraction lands straight in the
+                # [K, Bp, L] transfer matrix — no arena copy, the
+                # native pool splits the walk across cores
+                Bp = bucket_size(seg if multi else cnt,
+                                 max_len=max_len, multiple_of=n_dev)
+                batch = np.empty((K, Bp, max_len), dtype=np.uint8)
+                lengths = np.full((K, Bp), -1, dtype=np.int32)
+                for k in range(K):
+                    stage_key(part, keys[k], batch[k], lengths[k], cnt)
+                extract_s[0] += _time.perf_counter() - t0
+                return batch, lengths, cnt
+            staged = []
+            max_staged = 1
+            for k in range(K):
+                # stage straight into a caller-owned [cnt, max_len]
+                # matrix: no arena round-trip, ONE copy per key (the
+                # L-bucketed slice into the segment planes below)
+                wide = np.empty((cnt, max_len), dtype=np.uint8)
+                wlen = np.full((cnt,), -1, dtype=np.int32)
+                stage_key(part, keys[k], wide, wlen, cnt)
+                staged.append((wide, wlen))
+                mx = int(wlen[:cnt].max()) if cnt else 0
+                max_staged = max(max_staged, mx)
+            # scan-length bucketing: the DFA scan is sequential in
+            # L, so clamp to the longest staged value (rounded to a
+            # small bucket set for jit shape stability)
+            L = _len_bucket(max_staged, max_len)
+            # segment-uniform batch shape: one compile covers every
+            # full segment of the chunk stream
+            Bp = bucket_size(seg if multi else cnt, max_len=L)
+            batch = np.zeros((K, Bp, L), dtype=np.uint8)
+            lengths = np.full((K, Bp), -1, dtype=np.int32)
+            for k, (b, ln) in enumerate(staged):
+                batch[k, :cnt] = b[:cnt, :L]
+                lengths[k, :cnt] = ln[:cnt]
+            extract_s[0] += _time.perf_counter() - t0
+            return batch, lengths, cnt
+
+        for si, (s, e) in enumerate(bounds):
+            with span("grep.stage", seg=si):
+                item = stage(s, e)
+            yield item + (si,)
+
+    def forced(b, ln):
+        # enqueue + argument copy-in, then the wait for the
+        # execution and the copy-out
+        with span("grep.dispatch"):
+            out = program.dispatch(b, ln, first_match=first_match)
+        with span("grep.force"):
+            return np.asarray(out)
+
+    def dispatch(item):
+        batch, lengths, cnt, si = item
+        lens_parts.append(lengths[:, :cnt])
+        cnts.append(cnt)
+        tm.add("h2d_bytes", batch.nbytes + lengths.nbytes)
+        if mesh is not None:
+            # sharded launch through the device fault domain: the
+            # launch closure re-stages (fresh device_put + donation)
+            # on EVERY attempt — after a failed launch the donated
+            # lengths buffer is consumed (deleted aval), so a retry
+            # or fallback must read the host arrays, never the
+            # device buffers. The counts-free variant skips the
+            # per-segment psum the filter verdict never reads.
+            # Forcing inside the launch keeps the deadline armed
+            # over the whole execution AND preserves the staging
+            # overlap (the worker forces while the caller stages
+            # the next segment).
+            def launch(b=batch, ln=lengths):
+                m = lane.current_mesh()
+                if m is None:
+                    # mesh shrunk below 2 devices: serve unsharded
+                    return forced(b, ln)
+                with span("grep.dispatch"):
+                    out, _, _b2, _bp = program.dispatch_mesh(
+                        m, b, ln, with_counts=False,
+                        first_match=first_match)
+                with span("grep.force"):
+                    out = np.asarray(out)
+                    return out if first_match else out.astype(bool)
+        else:
+            def launch(b=batch, ln=lengths):
+                return forced(b, ln)
+
+        def fallback(b=batch, ln=lengths, c=cnt):
+            mask = host_mask(rules, plane_of, b, ln, c)
+            return first_of_mask(mask) if first_match else mask
+
+        with bind(seg=si):
+            return lane.begin(launch, fallback)
+
+    def collect(pending):
+        # nothing is committed until here: the segment's verdict is
+        # the device result OR the bit-exact host fallback, exactly
+        # one of the two (fbtpu-armor)
+        return lane.finish(pending)
+
+    t_all = _time.perf_counter()
+    try:
+        verdicts = double_buffered(stages(), dispatch, collect)
+    except _RawDecline:
+        return None
+    wall = _time.perf_counter() - t_all
+    tm.add("extract_s", extract_s[0])
+    tm.add("kernel_s", max(wall - extract_s[0], 0.0))
+    offsets = offs_box[0]
+    verdict = np.concatenate(
+        [np.asarray(v)[..., :c] for v, c in zip(verdicts, cnts)], axis=-1)
+    lengths = np.concatenate(lens_parts, axis=1)
+    # overflow rows (-2): decode just those records on the CPU
+    overflow_rows = np.unique(np.nonzero(lengths == -2)[1])
+    tm.add("device_records", n)
+    tm.add("overflow_rows", len(overflow_rows))
+    if len(overflow_rows):
+        from ..codec.events import decode_events
+
+        with span("grep.overflow", rows=len(overflow_rows)):
+            for b_idx in overflow_rows:
+                rec = bytes(data[offsets[b_idx]: offsets[b_idx + 1]])
+                body = decode_events(rec)[0].body
+                if first_match:
+                    # the per-record rule walk, break on first match
+                    verdict[b_idx] = next(
+                        (r for r, rule in enumerate(rules)
+                         if rule_matches(rule, body)), -1)
+                    continue
+                for r, rule in enumerate(rules):
+                    if lengths[plane_of[r], b_idx] == -2:
+                        verdict[r, b_idx] = rule_matches(rule, body)
+    return verdict, offsets, n
+
+
 @registry.register
 class GrepFilter(FilterPlugin):
     name = "grep"
@@ -221,8 +534,9 @@ class GrepFilter(FilterPlugin):
                 from ..ops.grep import program_for
 
                 self._program = program_for(
-                    tuple(r.pattern for r in self.rules), self.tpu_max_record_len
-                )
+                    tuple(r.pattern for r in self.rules),
+                    self.tpu_max_record_len,
+                    plane_of=plane_index(self.rules)[1])
                 device.wait()  # bounded (FBTPU_ATTACH_WAIT_S, default 2s)
                 self._program.try_ready()
             except Exception:
@@ -381,64 +695,10 @@ class GrepFilter(FilterPlugin):
             ln = self._lane_obj = fault.lane("grep")
         return ln
 
-    def _host_mask(self, batch: np.ndarray, lengths: np.ndarray,
-                   cnt: int) -> np.ndarray:
-        """Bit-exact host twin of the kernel verdict over a staged
-        segment — the DeviceLane fallback. Rows with length < 0
-        (missing -1, overflow -2) stay False, exactly like the kernel;
-        the caller's overflow decode then fixes -2 rows the same way it
-        does after a device launch."""
-        R = len(self.rules)
-        mask = np.zeros((R, cnt), dtype=bool)
-        for r, rule in enumerate(self.rules):
-            ln = lengths[r]
-            row = batch[r]
-            rx = rule.regex
-            for i in range(cnt):
-                li = int(ln[i])
-                if li >= 0:
-                    mask[r, i] = rx.match(bytes(row[i, :li]).decode(
-                        "utf-8", "surrogateescape"))
-        return mask
-
     def _match_matrix_device(self, events: list) -> np.ndarray:
-        """Stage field values, run the fused DFA kernel, resolve overflow
-        rows on CPU. Returns mask[R, B] bool."""
-        from ..ops.batch import assemble, bucket_size
-
-        B = len(events)
-        R = len(self.rules)
-        # rules addressing the same field share one extraction + staging
-        # pass (the staging loop is the hot-path bottleneck)
-        by_path: dict = {}
-        for r, rule in enumerate(self.rules):
-            by_path.setdefault(rule.ra.pattern, (rule.ra, []))[1].append(r)
-        L = self.tpu_max_record_len
-        Bp = bucket_size(B, max_len=L)
-        values: List[Optional[List[Optional[bytes]]]] = [None] * R
-        batches = [None] * R
-        for ra, idxs in by_path.values():
-            vals: List[Optional[bytes]] = []
-            for ev in events:
-                v = _to_text(ra.get(ev.body))
-                vals.append(v.encode("utf-8") if v is not None else None)
-            staged = assemble(vals, L, Bp)
-            for r in idxs:
-                values[r] = vals
-                batches[r] = staged
-        batch = np.stack([b.batch for b in batches])
-        lengths = np.stack([b.lengths for b in batches])
-        lane = self._lane()
-        mask = lane.run(
-            lambda: np.asarray(self._program.match(batch, lengths)),
-            lambda: self._host_mask(batch, lengths, batch.shape[1]),
-        )
-        mask = np.array(mask[:, :B])
-        for r, brec in enumerate(batches):
-            rule = self.rules[r]
-            for i in brec.overflow:
-                mask[r, i] = rule.regex.match(values[r][i])
-        return mask
+        """mask[R, B]: rule r's regex matches record b's field value."""
+        return decoded_match(self.rules, self._program, self._lane(),
+                             events, self.tpu_max_record_len)
 
     def filter(self, events: list, tag: str, engine) -> tuple:
         if (
@@ -751,227 +1011,9 @@ class GrepFilter(FilterPlugin):
         return t
 
     def _jax_match_raw(self, data, n_records, mesh=None):
-        """Device-kernel raw matching with double-buffered staging.
-
-        The chunk's records split into fixed-size segments; host
-        msgpack extraction (native.stage_field over the segment's byte
-        span) of segment N+1 runs while segment N's kernel is in
-        flight (jax async dispatch — core.chunk_batch.double_buffered),
-        and each mask is forced one segment behind. On a real
-        accelerator the staging walk hides behind the DFA scan; single-
-        segment chunks degrade to the stage-then-match order.
-
-        With ``mesh`` set, each segment launches through the
-        explicitly partitioned pjit matcher instead: the batch axis is
-        padded to the mesh size and sharded across devices, extraction
-        stages STRAIGHT into the [R, Bp, L] transfer matrix
-        (native.stage_field_into — the walk fans out across cores
-        behind FBTPU_STAGE_THREADS, so per-device shards extract in
-        parallel), and the staged buffers are donated to the kernel.
-        The next segment's extraction overlaps the in-flight sharded
-        launch exactly as on one device.
-        Returns (mask[R, n], offsets[n+1], n) or None to decline."""
-        import os as _os
-        import time as _time
-
-        from .. import native
-        from ..core.chunk_batch import double_buffered, segment_bounds
-        from ..ops.batch import bucket_size
-
-        tm = self.raw_timings
-        if not isinstance(data, bytes):
-            data = bytes(data)
-        # default matches a bucket_size rung exactly: a full segment
-        # stages with ZERO pad rows (8192 would round up to the 16384
-        # bucket and double every segment's staging + kernel work)
-        seg = int(_os.environ.get("FBTPU_SEGMENT_RECORDS", "4096"))
-        n = n_records
-        offsets = None
-        if n is None or n > seg:
-            # segmentation (or an unknown count) needs the boundary
-            # table up front; single-segment chunks with a known count
-            # skip this walk and take the offsets the first
-            # stage_field call discovers anyway
-            offsets = native.scan_offsets(data)
-            if offsets is None:
-                return None
-            n = len(offsets) - 1
-        if n < self.tpu_batch_records:
-            return None  # small batches: decline BEFORE staging/kernel
-        by_key: dict = {}
-        for r, rule in enumerate(self.rules):
-            by_key.setdefault(rule.ra.head.encode("utf-8"), []).append(r)
-        R = len(self.rules)
-        Lmax = self.tpu_max_record_len
-        bounds = segment_bounds(n, seg)
-        multi = len(bounds) > 1
-        extract_s = [0.0]
-        lens_parts: list = []
-        cnts: list = []
-        offs_box = [offsets]  # filled by staging when not pre-scanned
-
-        n_dev = mesh.devices.size if mesh is not None else 1
-
-        def stages():
-            def stage(s, e):
-                t0 = _time.perf_counter()
-                cnt = e - s
-                part = data if offs_box[0] is None \
-                    else data[offs_box[0][s]: offs_box[0][e]]
-                if mesh is not None:
-                    # mesh staging: ONE jit-stable width (the sharded
-                    # program wants one compiled shape, not per-chunk
-                    # L buckets) and extraction lands straight in the
-                    # [R, Bp, L] transfer matrix — no arena copy, the
-                    # native pool splits the walk across cores
-                    Bp = bucket_size(seg if multi else cnt,
-                                     max_len=Lmax, multiple_of=n_dev)
-                    batch = np.empty((R, Bp, Lmax), dtype=np.uint8)
-                    lengths = np.full((R, Bp), -1, dtype=np.int32)
-                    for key, idxs in by_key.items():
-                        r0 = idxs[0]
-                        # single-segment chunks take the boundary
-                        # table straight from the staging walk (it
-                        # computes one anyway) — never re-scan
-                        want_offs = offs_box[0] is None
-                        offs = np.empty(cnt + 1, dtype=np.int64) \
-                            if want_offs else None
-                        count = native.stage_field_into(
-                            part, key, batch[r0], lengths[r0],
-                            n_hint=cnt, offsets_out=offs)
-                        if count is None or count != cnt:
-                            raise _RawDecline
-                        if want_offs:
-                            offs_box[0] = offs
-                        for r in idxs[1:]:
-                            batch[r, :cnt] = batch[r0, :cnt]
-                            lengths[r, :cnt] = lengths[r0, :cnt]
-                    extract_s[0] += _time.perf_counter() - t0
-                    return batch, lengths, cnt
-                staged = {}
-                max_staged = 1
-                for key in by_key:
-                    # stage straight into a caller-owned [cnt, Lmax]
-                    # matrix: no arena round-trip, so multi-key rule
-                    # sets keep ONE copy per key (the L-bucketed slice
-                    # into the segment batch below) instead of two
-                    want_offs = offs_box[0] is None
-                    offs = np.empty(cnt + 1, dtype=np.int64) \
-                        if want_offs else None
-                    wide = np.empty((cnt, Lmax), dtype=np.uint8)
-                    wlen = np.full((cnt,), -1, dtype=np.int32)
-                    count = native.stage_field_into(
-                        part, key, wide, wlen, n_hint=cnt,
-                        offsets_out=offs)
-                    if count is None or count != cnt:
-                        raise _RawDecline
-                    if want_offs:
-                        # single-segment: the staging walk's boundary
-                        # table serves overflow decode + compaction
-                        # (same values whichever key discovered them)
-                        offs_box[0] = offs
-                    staged[key] = (wide, wlen)
-                    mx = int(wlen[:cnt].max()) if cnt else 0
-                    max_staged = max(max_staged, mx)
-                # scan-length bucketing: the DFA scan is sequential in
-                # L, so clamp to the longest staged value (rounded to a
-                # small bucket set for jit shape stability)
-                L = _len_bucket(max_staged, Lmax)
-                # segment-uniform batch shape: one compile covers every
-                # full segment of the chunk stream
-                Bp = bucket_size(seg if multi else cnt, max_len=L)
-                batch = np.zeros((R, Bp, L), dtype=np.uint8)
-                lengths = np.full((R, Bp), -1, dtype=np.int32)
-                for key, idxs in by_key.items():
-                    b, ln = staged[key]
-                    for r in idxs:
-                        batch[r, :cnt] = b[:cnt, :L]
-                        lengths[r, :cnt] = ln[:cnt]
-                extract_s[0] += _time.perf_counter() - t0
-                return batch, lengths, cnt
-
-            for si, (s, e) in enumerate(bounds):
-                with span("grep.stage", seg=si):
-                    item = stage(s, e)
-                yield item + (si,)
-
-        lane = self._lane()
-
-        def forced(b, ln):
-            # enqueue + argument copy-in, then the wait for the
-            # execution and the copy-out
-            with span("grep.dispatch"):
-                out = self._program.dispatch(b, ln)
-            with span("grep.force"):
-                return np.asarray(out)
-
-        def dispatch(item):
-            batch, lengths, cnt, si = item
-            lens_parts.append(lengths[:, :cnt])
-            cnts.append(cnt)
-            tm.add("h2d_bytes", batch.nbytes + lengths.nbytes)
-            if mesh is not None:
-                # sharded launch through the device fault domain: the
-                # launch closure re-stages (fresh device_put + donation)
-                # on EVERY attempt — after a failed launch the donated
-                # lengths buffer is consumed (deleted aval), so a retry
-                # or fallback must read the host arrays, never the
-                # device buffers. The counts-free variant skips the
-                # per-segment psum the filter verdict never reads.
-                # Forcing inside the launch keeps the deadline armed
-                # over the whole execution AND preserves the staging
-                # overlap (the worker forces while the caller stages
-                # the next segment).
-                def launch(b=batch, ln=lengths):
-                    m = lane.current_mesh()
-                    if m is None:
-                        # mesh shrunk below 2 devices: serve unsharded
-                        return forced(b, ln)
-                    with span("grep.dispatch"):
-                        m_i32, _, _b2, _bp = self._program.dispatch_mesh(
-                            m, b, ln, with_counts=False)
-                    with span("grep.force"):
-                        return np.asarray(m_i32).astype(bool)
-            else:
-                def launch(b=batch, ln=lengths):
-                    return forced(b, ln)
-
-            def fallback(b=batch, ln=lengths, c=cnt):
-                return self._host_mask(b, ln, c)
-
-            with bind(seg=si):
-                return lane.begin(launch, fallback)
-
-        def collect(pending):
-            # nothing is committed until here: the segment's verdict is
-            # the device result OR the bit-exact host fallback, exactly
-            # one of the two (fbtpu-armor)
-            return lane.finish(pending)
-
-        t_all = _time.perf_counter()
-        try:
-            masks = double_buffered(stages(), dispatch, collect)
-        except _RawDecline:
-            return None
-        wall = _time.perf_counter() - t_all
-        tm.add("extract_s", extract_s[0])
-        tm.add("kernel_s", max(wall - extract_s[0], 0.0))
-        offsets = offs_box[0]
-        mask = np.concatenate(
-            [np.asarray(m)[:, :c] for m, c in zip(masks, cnts)], axis=1)
-        lengths = np.concatenate(lens_parts, axis=1)
-        # overflow rows (-2): decode just those records on the CPU
-        overflow_rows = np.unique(np.nonzero(lengths == -2)[1])
-        tm.add("device_records", n)
-        tm.add("overflow_rows", len(overflow_rows))
-        if len(overflow_rows):
-            from ..codec.events import decode_events
-
-            with span("grep.overflow", rows=len(overflow_rows)):
-                for b_idx in overflow_rows:
-                    rec = bytes(data[offsets[b_idx]: offsets[b_idx + 1]])
-                    ev = decode_events(rec)[0]
-                    for r, rule in enumerate(self.rules):
-                        if lengths[r, b_idx] == -2:
-                            mask[r, b_idx] = rule.match(ev.body)
-        return mask, offsets, n
+        """Device-kernel raw matching (``staged_match``): returns
+        (mask[R, n], offsets[n+1], n) or None to decline."""
+        return staged_match(
+            self.rules, self._program, self._lane(), self.raw_timings,
+            data, n_records, max_len=self.tpu_max_record_len,
+            min_records=self.tpu_batch_records, mesh=mesh)

@@ -10,13 +10,18 @@ alias ``emitter_for_<name>``, :245-260) and re-enters the full pipeline;
 the original is kept or dropped per the rule's keep flag (:375).
 
 Device path: when every rule regex compiles to a DFA and the append is
-large, the per-rule match matrix runs vectorized on device
-(fluentbit_tpu.ops.grep); capture extraction + tag composition run on
-the CPU only for the first matching rule of each matched record.
+large, the rules run vectorized on device (fluentbit_tpu.ops.grep);
+capture extraction + tag composition run on the CPU only for the first
+matching rule of each matched record.
 
 Batched fast path (``process_batch``): on the engine's raw ingest path
-the per-rule match matrix comes from the native one-pass DFA straight
-off chunk bytes — no Python decode at all. Records whose winning rule
+the verdict — the first matching rule of each record — comes straight
+off chunk bytes, no Python decode at all: from the device once a
+non-CPU backend is attached (``filter_grep.staged_match``: each
+distinct key staged once, launched through the ``grep`` DeviceLane, the
+first-match reduction on the device, overflow rows and the lane's
+fallback decided by the host rule walk), from the native one-pass DFA
+while the device attaches and on a CPU backend. Records whose winning rule
 has a tag-static template (no ``$0..$9`` captures, no record fields)
 group into per-tag span gathers (native compact) and re-emit in one
 emitter append per tag; only records whose template needs captures or
@@ -28,6 +33,7 @@ at chunk granularity.
 from __future__ import annotations
 
 import logging
+import time
 from typing import List, Optional
 
 import numpy as np
@@ -36,7 +42,10 @@ from ..codec.events import reencode_event
 from ..core.config import ConfigMapEntry, parse_bool
 from ..core.plugin import FilterPlugin, FilterResult, registry
 from ..core.record_accessor import RecordAccessor, Template
+from ..core.spans import ShardedTimings, span
 from ..regex import FlbRegex
+from .filter_grep import (decoded_match, first_of_mask, plane_index,
+                          staged_match)
 
 
 log = logging.getLogger("flb")
@@ -48,6 +57,19 @@ def _to_text(v) -> Optional[str]:
     if isinstance(v, str):
         return v
     return None
+
+
+#: ``raw_timings`` keys of the batched path. ``extract_s``,
+#: ``kernel_s``, ``h2d_bytes``, ``device_records`` and ``overflow_rows``
+#: are the staged launch's (``filter_grep.staged_match``: the device
+#: lane only); ``records`` counts every record ``process_batch``
+#: served; ``emit_s`` is the time from the verdict to the last emitter
+#: append (grouping, ``native.compact``, ``add_record`` and the
+#: pipeline re-entry under it), ``emits`` the emitter appends and
+#: ``emit_backpressure`` those the emitter refused (originals kept)
+_TIMING_KEYS = ("extract_s", "kernel_s", "h2d_bytes", "device_records",
+                "overflow_rows", "records", "emit_s", "emits",
+                "emit_backpressure")
 
 
 class RewriteRule:
@@ -111,7 +133,7 @@ class RewriteTagFilter(FilterPlugin):
                 self._program = program_for(
                     tuple(r.regex.pattern for r in self.rules),
                     self.tpu_max_record_len,
-                )
+                    plane_of=plane_index(self.rules)[1])
                 device.wait()  # bounded; CPU path serves until attached
                 self._program.try_ready()
             except Exception:
@@ -122,6 +144,7 @@ class RewriteTagFilter(FilterPlugin):
         # (simple top-level keys only); rules with tag-static templates
         # render once per chunk, the rest decode per matched record
         self._batch_tables = None
+        self.raw_timings = ShardedTimings(_TIMING_KEYS)
         self._batch_static = [r.template.static_for_tag
                               for r in self.rules]
         if self.emitter is not None and all(
@@ -169,58 +192,23 @@ class RewriteTagFilter(FilterPlugin):
 
     # -- matching --
 
-    def _values_matrix(self, events: list) -> List[List[Optional[str]]]:
-        vals: List[List[Optional[str]]] = []
-        for rule in self.rules:
-            ra = rule.ra
-            vals.append([
-                _to_text(ra.get(ev.body)) if isinstance(ev.body, dict) else None
-                for ev in events
-            ])
-        return vals
-
-    def _device_match_matrix(self, values) -> np.ndarray:
-        """mask[R, B]: rule r's regex matches record b's field value."""
-        from ..ops.batch import assemble, bucket_size
-
-        R = len(self.rules)
-        B = len(values[0])
-        Bp = bucket_size(B, max_len=self.tpu_max_record_len)
-        staged = [
-            assemble(
-                [v.encode("utf-8") if v is not None else None
-                 for v in values[r]],
-                self.tpu_max_record_len, Bp,
-            )
-            for r in range(R)
-        ]
-        batch = np.stack([s.batch for s in staged])
-        lengths = np.stack([s.lengths for s in staged])
-
-        def host_twin():
-            # bit-exact host fallback (fbtpu-armor DeviceLane): the
-            # same per-row regex the overflow fix-up below applies
-            out = np.zeros((R, B), dtype=bool)
-            for r in range(R):
-                rx = self.rules[r].regex
-                for i, v in enumerate(values[r]):
-                    if v is not None:
-                        out[r, i] = rx.match(v)
-            return out
-
+    def _lane(self):
+        """The DFA plane's fault domain: every launch of this plugin
+        goes through the process-global "grep" DeviceLane."""
         from ..ops import fault
 
-        lane = fault.lane("grep")  # the DFA plane's fault domain
-        mask = lane.run(
-            lambda: np.asarray(self._program.match(batch, lengths)),
-            host_twin,
-        )
-        mask = np.array(mask[:, :B])
-        for r, s in enumerate(staged):
-            rx = self.rules[r].regex
-            for i in s.overflow:
-                mask[r, i] = rx.match(values[r][i])
-        return mask
+        return fault.lane("grep")
+
+    def _device_serves(self) -> bool:
+        """The platform gate (same as filter_grep's): a non-CPU backend
+        is attached and the program is on it. On a jax CPU backend the
+        staging + launch costs far more than the host scan it
+        replaces."""
+        from ..ops import device
+
+        return (self._program is not None
+                and device.platform() not in (None, "cpu")
+                and self._program.try_ready())
 
     def _first_match_cpu(self, body):
         """Per-record rule scan, break on first match (process_record)."""
@@ -266,17 +254,46 @@ class RewriteTagFilter(FilterPlugin):
             return (n, chunk.data, n)
         tag = chunk.tag
         data = chunk.as_bytes()
-        got = native.grep_match(data, self._batch_tables, n_hint=chunk.n)
-        if got is None:
-            return None
-        mask, offsets, n = got
+        tm = self.raw_timings
+        # first matching rule per record (process_record's break), or -1
+        with span("rewrite.stage"):
+            got = None
+            if self._device_serves():
+                got = staged_match(
+                    self.rules, self._program, self._lane(), tm, data,
+                    chunk.n, max_len=self.tpu_max_record_len,
+                    min_records=self.tpu_batch_records, first_match=True)
+            if got is None:
+                # the host twin: while the device attaches, on a CPU
+                # backend, and for what the staged launch declines
+                got = native.grep_match(data, self._batch_tables,
+                                        n_hint=chunk.n)
+                if got is None:
+                    return None
+                got = (first_of_mask(got[0]), got[1], got[2])
+        first, offsets, n = got
+        tm.add("records", n)
         if n == 0:
             return (0, data, 0)
-        any_match = mask.any(axis=0)
-        if not any_match.any():
+        if not (first >= 0).any():
             return (n, data, n)
-        # first matching rule per record (process_record's break)
-        first = np.where(any_match, mask.argmax(axis=0), -1)
+        t0 = time.perf_counter()
+        try:
+            return self._emit_batch(chunk, data, first,
+                                    offsets[: n + 1], n)
+        finally:
+            tm.add("emit_s", time.perf_counter() - t0)
+
+    def _emit_batch(self, chunk, data, first, offsets, n):
+        """Group the matched records by rendered tag in first-seen
+        order, gather each group's spans, re-emit it in one emitter
+        append, drop what ``keep false`` says — → ``process_batch``'s
+        result."""
+        from .. import native
+        from ..codec.events import decode_events
+
+        tag = chunk.tag
+        tm = self.raw_timings
         keep = np.ones(n, dtype=bool)
         # new_tag → {"mask": members, "drop": non-keep members,
         #            "first": first contributing record index}
@@ -313,15 +330,16 @@ class RewriteTagFilter(FilterPlugin):
         # records whose winning rule needs captures or record fields:
         # decode just those spans and run the per-record rule walk
         for b in need_record:
-            span = bytes(data[offsets[b]: offsets[b + 1]])
+            rec = bytes(data[offsets[b]: offsets[b + 1]])
             try:
-                ev = decode_events(span)[0]
+                ev = decode_events(rec)[0]
             except (ValueError, IndexError):
                 return None
             rule = captures = None
-            for r, rl in enumerate(self.rules):
-                if not mask[r, b]:
-                    continue
+            # the verdict names the first rule whose DFA accepts: the
+            # walk starts there (a capture the regex then refuses
+            # moves on, as the per-record path does)
+            for rl in self.rules[int(first[b]):]:
                 v = _to_text(rl.ra.get(ev.body)) \
                     if isinstance(ev.body, dict) else None
                 if v is None:
@@ -344,27 +362,31 @@ class RewriteTagFilter(FilterPlugin):
                                    key=lambda kv: kv[1]["first"]):
             m = ent["mask"]
             count = int(m.sum())
-            payload = native.compact(data, offsets, m)
-            if payload is None:
-                payload = b"".join(
-                    data[offsets[i]: offsets[i + 1]]
-                    for i in np.nonzero(m)[0]
-                )
-            try:
-                rc = self.emitter.add_record(new_tag, payload, count)
-            except Exception:
-                # earlier groups are already committed: letting this
-                # raise would decline the batch and the decoded-tail
-                # rerun would re-emit them a second time — degrade a
-                # failed group to the backpressure outcome instead
-                # (originals kept; fbtpu-lint batch-commit-replay)
-                log.exception("rewrite_tag emitter append failed for "
-                              "tag %r; originals kept", new_tag)
-                rc = -1
+            with span("rewrite.emit", tag=new_tag, rows=count):
+                payload = native.compact(data, offsets, m)
+                if payload is None:
+                    payload = b"".join(
+                        data[offsets[i]: offsets[i + 1]]
+                        for i in np.nonzero(m)[0]
+                    )
+                try:
+                    rc = self.emitter.add_record(new_tag, payload, count)
+                except Exception:
+                    # earlier groups are already committed: letting
+                    # this raise would decline the batch and the
+                    # decoded-tail rerun would re-emit them a second
+                    # time — degrade a failed group to the backpressure
+                    # outcome instead (originals kept; fbtpu-lint
+                    # batch-commit-replay)
+                    log.exception("rewrite_tag emitter append failed "
+                                  "for tag %r; originals kept", new_tag)
+                    rc = -1
+            tm.add("emits", 1)
             if rc < 0:
                 # backpressure: keep the originals (reference keeps the
                 # record when in_emitter refuses it) — drop flags for
                 # this group are simply never applied
+                tm.add("emit_backpressure", 1)
                 continue
             emitted += count
             keep &= ~ent["drop"]
@@ -396,20 +418,14 @@ class RewriteTagFilter(FilterPlugin):
             is self.emitter.instance
         ):
             return (FilterResult.NOTOUCH, events)
-        from ..ops import device
-
         # platform gate FIRST (same as filter_grep): on a CPU jax
         # backend the batch assemble + kernel launch per chunk costs
         # far more than the host regex scan it replaces
-        use_device = (
-            self._program is not None
-            and len(events) >= self.tpu_batch_records
-            and device.platform() not in (None, "cpu")
-            and self._program.try_ready()
-        )
+        use_device = (len(events) >= self.tpu_batch_records
+                      and self._device_serves())
         if use_device:
-            values = self._values_matrix(events)
-            mask = self._device_match_matrix(values)
+            mask = decoded_match(self.rules, self._program, self._lane(),
+                                 events, self.tpu_max_record_len)
         keep = [True] * len(events)
         # emits BATCH per rendered tag: one emitter append per (tag)
         # group instead of one full pipeline re-entry per record
@@ -419,14 +435,12 @@ class RewriteTagFilter(FilterPlugin):
         for b, ev in enumerate(events):
             if use_device:
                 rule = captures = None
-                for r in range(len(self.rules)):
-                    if mask[r, b]:
-                        captures = self.rules[r].regex.search_captures(
-                            values[r][b]
-                        )
-                        if captures is not None:
-                            rule = self.rules[r]
-                            break
+                for r in np.nonzero(mask[:, b])[0]:
+                    captures = self.rules[r].regex.search_captures(
+                        _to_text(self.rules[r].ra.get(ev.body)))
+                    if captures is not None:
+                        rule = self.rules[r]
+                        break
             else:
                 rule, captures = self._first_match_cpu(ev.body)
             if rule is None:

@@ -434,10 +434,24 @@ def test_shipped_flux_chain(graph):
     assert not ch["sites"][0]["in_loop"]
 
 
+def test_shipped_rewrite_tag_chain(graph):
+    # rewrite_tag launches through the staged helper it shares with
+    # filter_grep (known to the walker by name across the module
+    # boundary): one lane-guarded launch a segment, and the two
+    # compacts (per tag, survivors) that re-walk the chunk with the
+    # verdict — recorded debt in launch_budget.json
+    ch = _chain(graph, "filter_rewrite_tag.py::RewriteTagFilter"
+                       ".process_batch")
+    assert ch["launches_per_segment"] == 1
+    assert ch["sync_hits"] == []
+    (site,) = ch["sites"]
+    assert site["what"] == "staged_match" and site["lane"] is True
+    assert site["kind"] == "grep-mesh"
+    assert ch["scatter_passes"] == 2
+
+
 def test_shipped_host_only_entries(graph):
     for suffix in ("filter_parser.py::ParserFilter.process_batch",
-                   "filter_rewrite_tag.py::RewriteTagFilter"
-                   ".process_batch",
                    "flux/plugin.py::FluxFilter.process_batch",
                    "filter_log_to_metrics.py::LogToMetricsFilter"
                    ".process_batch"):
@@ -711,9 +725,42 @@ def test_static_matches_dynamic_flux_chain(graph, monkeypatch):
 
 
 @pytest.mark.mesh
+def test_static_matches_dynamic_rewrite_tag_chain(graph, monkeypatch):
+    # the platform gate forced open (a CPU backend serves on the native
+    # twin): one launch a staged segment, as the analyzer says
+    pytest.importorskip("jax")
+    from fluentbit_tpu.codec.events import encode_event
+    from fluentbit_tpu.core.engine import Engine
+    from fluentbit_tpu.ops import device
+
+    static = _chain(graph, "RewriteTagFilter.process_batch")[
+        "launches_per_segment"]
+    assert device.wait(120)
+    monkeypatch.setattr(device, "platform", lambda: "tpu")
+    monkeypatch.setenv("FBTPU_SEGMENT_RECORDS", "128")
+    n, seg = 700, 128
+    e = Engine()
+    rt = e.filter("rewrite_tag")
+    rt.set("rule", "$log ^alpha routed.alpha false")
+    rt.set("rule", "$log ERROR routed.error false")
+    ins = e.input("dummy")
+    for x in e.inputs + e.filters:
+        x.configure()
+        x.plugin.init(x, e)
+    raw = b"".join(
+        encode_event({"log": f"ERROR {i}" if i % 2 else f"alpha {i}"},
+                     float(i))
+        for i in range(n))
+    before = _lane_launches("grep")
+    e.input_log_append(ins, "t", raw)
+    assert e.filters[0].plugin.raw_timings["device_records"] == n
+    assert _lane_launches("grep") - before == -(-n // seg) * static
+
+
+@pytest.mark.mesh
 def test_static_matches_dynamic_host_only_chains():
-    # parser-regex and rewrite_tag: the analyzer says ZERO device
-    # launches — no lane anywhere may tick while they process a batch
+    # parser-regex, and rewrite_tag on a CPU backend (the native twin
+    # serves): no lane anywhere may tick while they process a batch
     pytest.importorskip("jax")
     from fluentbit_tpu.codec.events import encode_event
     from fluentbit_tpu.core.engine import Engine

@@ -416,6 +416,118 @@ def test_output_bytes_equal_with_and_without_a_session(runs):
     assert len(decode_events(runs["traced"])) == N_LINES - N_LINES // 4
 
 
+# ----------------------------------- a rewrite_tag frame, traced
+
+
+REWRITE_RULES = ("$log kernel: sys.kernel false",
+                 "$log ERROR app.error false",
+                 r"$log cron\[\d+\] sys.cron false")
+
+
+@pytest.fixture(scope="module")
+def rewrite_events(mesh_env, tmp_path_factory):
+    """One 96-line frame through forward → rewrite_tag (the platform
+    gate forced open, three 32-line segments) → emitter → ``lib``,
+    once to compile and once under a profiler session. → the traced
+    frame's events, the records by tag and the plugin's timings."""
+    from fluentbit_tpu.codec.events import decode_events
+    from fluentbit_tpu.ops import device
+
+    jax = mesh_env
+    assert device.wait(120)
+    saved = device.platform
+    device.platform = lambda: "tpu"
+    ctx = flb.create(flush="50ms", grace="1")
+    try:
+        ctx.input("forward", listen="127.0.0.1", port="0")
+        f = ctx.filter("rewrite_tag", match="app", tpu_batch_records="1")
+        for rule in REWRITE_RULES:
+            ctx.set(f, rule=rule)
+        got = {}
+        ctx.output("lib", match="*", callback=lambda d, t: got.setdefault(
+            t, []).extend(decode_events(bytes(d))))
+        ctx.start()
+        port = wait_for(lambda: ctx.engine.inputs[0].plugin.bound_port)
+        lines = ["kernel: oom", "app ERROR x", "cron[7]: job", "plain"]
+        entries = [[1700000000 + i, {"log": f"{lines[i % 4]} {i}"}]
+                   for i in range(N_LINES)]
+
+        def send(chunk):
+            with socket.create_connection(("127.0.0.1", port)) as s:
+                s.settimeout(120)
+                s.sendall(packb(["app", entries, {"chunk": chunk}]))
+                u = Unpacker()
+                while True:
+                    u.feed(s.recv(4096))
+                    for msg in u:
+                        assert msg == {"ack": chunk}
+                        return
+
+        send("rw-0000")  # compiles the programs
+        trace_dir = str(tmp_path_factory.mktemp("rewrite_trace"))
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        try:
+            send("rw-0001")
+        finally:
+            jax.profiler.stop_trace()
+        ctx.flush_now()
+        wait_for(lambda: sum(map(len, got.values())) == 2 * N_LINES)
+        plugin = ctx.engine.filters[0].plugin
+        timings = {k: plugin.raw_timings[k] for k in plugin.raw_timings}
+    finally:
+        ctx.stop()
+        device.platform = saved
+    events = [e for e in read_events(trace_dir)
+              if e["stats"].get("chunk") == "rw-0001"]
+    return {"events": events, "got": got, "timings": timings}
+
+
+@pytest.mark.mesh
+@pytest.mark.parametrize("name", ["filter.rewrite_tag", "rewrite.stage",
+                                  "rewrite.emit", "grep.stage",
+                                  "lane.begin", "lane.launch",
+                                  "grep.dispatch", "grep.force",
+                                  "lane.wait"])
+def test_traced_rewrite_frame_has_span_with_its_chunk(rewrite_events, name):
+    assert by_name(rewrite_events["events"], name), \
+        sorted({e["name"] for e in rewrite_events["events"]})
+
+
+@pytest.mark.mesh
+def test_rewrite_spans_carry_seg_lane_tag_and_rows(rewrite_events):
+    events = rewrite_events["events"]
+    n_seg = N_LINES // 32
+    assert rewrite_events["timings"]["device_records"] == 2 * N_LINES
+    for name in ("grep.stage", "lane.begin", "lane.launch", "lane.wait",
+                 "grep.dispatch", "grep.force"):
+        assert sorted(e["stats"]["seg"] for e in by_name(events, name)) \
+            == list(range(n_seg)), name
+    for name in ("lane.begin", "lane.launch", "lane.wait",
+                 "grep.dispatch", "grep.force"):
+        assert {e["stats"].get("lane")
+                for e in by_name(events, name)} == {"grep"}, name
+    (stage,) = by_name(events, "rewrite.stage")
+    (outer,) = by_name(events, "filter.rewrite_tag")
+    assert inside(stage, outer) and "lane" not in stage["stats"]
+    for name in ("grep.stage", "lane.begin", "lane.wait"):
+        assert all(inside(e, stage) for e in by_name(events, name)), name
+    emits = by_name(events, "rewrite.emit")
+    assert [(e["stats"]["tag"], e["stats"]["rows"]) for e in emits] == [
+        ("sys.kernel", N_LINES // 4), ("app.error", N_LINES // 4),
+        ("sys.cron", N_LINES // 4)]
+    for e in emits:
+        assert inside(e, outer) and stage["end"] <= e["start"]
+    # the re-entry under the emitter's append is inside the emit span
+    appends = by_name(events, "engine.append")
+    assert len(appends) == 1 + len(emits)
+    got = rewrite_events["got"]
+    assert {t: len(v) for t, v in got.items()} == {
+        "sys.kernel": N_LINES // 2, "app.error": N_LINES // 2,
+        "sys.cron": N_LINES // 2, "app": N_LINES // 2}
+
+
 # ------------------------------------------------------ lane counters
 
 
@@ -566,6 +678,44 @@ def test_every_timing_key_this_pr_adds_feeds_a_metric(key):
     assert read.get(counter) in declared, counter
 
 
+#: ``rewrite_tag``'s ``raw_timings`` (the staged launch's keys through
+#: the helper it shares with grep, and its own three)
+REWRITE_KEYS = ("extract_s", "kernel_s", "h2d_bytes", "device_records",
+                "overflow_rows", "records", "emit_s", "emits",
+                "emit_backpressure")
+
+
+@pytest.mark.parametrize("key", REWRITE_KEYS)
+def test_every_rewrite_timing_key_feeds_a_metric_or_a_check(key):
+    """The same rule for ``rewrite_tag``'s keys: each is the numerator
+    of a declared data-only metric, or read by a named check of the
+    configuration's plain reference."""
+    import json
+
+    from fluentbit_tpu.plugins.filter_rewrite_tag import _TIMING_KEYS
+
+    assert set(_TIMING_KEYS) == set(REWRITE_KEYS)
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        declared = {m["name"] for m in json.load(f)["per_layer"]}
+    numerators = set()
+    for path in glob.glob(os.path.join(REPO, "benchmark",
+                                       "layer_metrics", "*.json")):
+        with open(path) as f:
+            spec = json.load(f)
+        if spec["reader"] == "counters:ratio" \
+                and os.path.basename(path)[:-5] in declared:
+            numerators.add(spec["args"]["num"])
+    with open(os.path.join(REPO, "benchmark", "reference",
+                           "rewrite-syslog.py")) as f:
+        reference = f.read()
+    counter = f"filter.rewrite_tag.{key}"
+    # the reference reads the plugin's counters as ``pre + "<key>"``
+    # inside the named verdicts of its ``checks``
+    checked = 'pre = "filter.rewrite_tag."' in reference \
+        and f'c.get(pre + "{key}"' in reference
+    assert counter in numerators or checked, counter
+
+
 # --------------------------------------- the benchmark's outside hooks
 
 
@@ -643,6 +793,42 @@ def test_jitted_grep_program_has_a_stable_module_name(kernel):
     assert prog.program_name().startswith(f"grep_{kernel}_S")
     assert "grep.symbols" in prog._jit.lower(batch, lengths).as_text(
         debug_info=True)
+
+
+def test_rewrite_tag_programs_have_stable_module_names():
+    """Config 3's eight rules: three per-stride assoc children, the
+    merge and the first-match reduction, each a named module."""
+    pytest.importorskip("jax")
+    import numpy as np
+
+    from fluentbit_tpu.ops import device
+    from fluentbit_tpu.ops import grep as ops_grep
+    from fluentbit_tpu.regex.dfa import compile_dfa
+
+    assert device.wait(120)
+    patterns = ("sshd", "kernel:", r"systemd\[1\]", "ERROR", "WARN",
+                "nginx", r"cron\[\d+\]", ".*OOM.*")
+    prog = ops_grep.GrepProgram([compile_dfa(p) for p in patterns],
+                                max_len=64, kernel="assoc",
+                                plane_of=(0,) * len(patterns))
+    planes = np.zeros((1, 8, 64), dtype=np.uint8)
+    lengths = np.full((1, 8), -1, dtype=np.int32)
+    first = prog.match(planes, lengths, first_match=True)
+    assert first.tolist() == [-1] * 8
+
+    def module(fn, *args):
+        return re.search(r"module @(\w+)",
+                         fn.lower(*args).as_text()).group(1)
+
+    names = [module(c._jit, planes, lengths) for c in prog._children]
+    assert names == [f"jit_{c.program_name()}" for c in prog._children]
+    assert [re.fullmatch(r"jit_grep_assoc_S\d+_k(\d)", n).group(1)
+            for n in names] == ["4", "5", "6"]
+    masks = [np.zeros((len(c.dfas), 8), dtype=bool)
+             for c in prog._children]
+    assert module(prog._merge_jit, *masks) == "jit_grep_merge"
+    assert module(ops_grep.first_match_of,
+                  np.zeros((8, 8), dtype=bool)) == "jit_grep_first_match"
 
 
 def test_sketch_and_flux_programs_are_named():

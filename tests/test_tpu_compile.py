@@ -129,6 +129,46 @@ def test_grep_child_compiles_for_one_chip(one_chip, pattern, kernel, impl,
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+#: BASELINE config 3's eight rewrite_tag rules (conf/baseline3-rewrite
+#: .conf): all S <= 64, so all assoc on the chip, in three stride groups
+CONFIG3 = ("sshd", "kernel:", r"systemd\[1\]", "ERROR", "WARN", "nginx",
+           r"cron\[\d+\]", ".*OOM.*")
+
+
+@pytest.mark.parametrize("length", [256, 512])
+@pytest.mark.parametrize("k,n_rules", [(6, 5), (5, 2), (4, 1)],
+                         ids=["k6x5", "k5x2", "k4x1"])
+def test_config3_assoc_child_compiles_for_one_chip(one_chip, k, n_rules,
+                                                   length):
+    """rewrite-syslog's program: each per-stride child takes the ONE
+    staged plane ``[1, B, L]`` and gathers its rules' inputs from it
+    (k=6 children had never been compiled for the chip)."""
+    prog = GrepProgram([compile_dfa(p) for p in CONFIG3], 512,
+                       kernel="assoc", plane_of=(0,) * len(CONFIG3))
+    child = next(c for c in prog._children if c.k == k)
+    assert len(child.dfas) == n_rules and child.n_planes == 1
+    assert child.plane_of == (0,) * n_rules
+
+    def step(tables, planes, lengths):
+        return child._match_assoc_impl(
+            tables, *child._gather_planes(planes, lengths))
+
+    compiled = jax.jit(step).lower(
+        _table_shapes(child, one_chip),
+        sds((1, SEGMENT, length), jnp.uint8, one_chip),
+        sds((1, SEGMENT), jnp.int32, one_chip)).compile()
+    assert compiled.output_shardings.device_set == {one_chip._device}
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_first_match_reduction_compiles_for_one_chip(one_chip):
+    from fluentbit_tpu.ops.grep import grep_first_match
+
+    compiled = jax.jit(grep_first_match).lower(
+        sds((len(CONFIG3), SEGMENT), jnp.bool_, one_chip)).compile()
+    assert compiled.output_shardings.device_set == {one_chip._device}
+
+
 def test_kernel_selection_rule_matches_what_was_compiled():
     """The accelerator arm of ``_resolve_kernel`` — which no CPU test
     process ever takes — picks exactly the (pattern, kernel) pairs
