@@ -409,7 +409,8 @@ def grep_phase(dev: dict, n_records: int, mesh_sample: bool) -> None:
         say(stage="grep:decision", max_states=d["max_states"], k=d["k"],
             kernel_resolved=d["kernel_resolved"],
             rules=[{"pattern": r["pattern"][:40], "s": r["s"], "c": r["c"],
-                    "k": r["k"]} for r in d["rules"]])
+                    "k": r["k"], "class_runs": r["class_runs"]}
+                   for r in d["rules"]])
     tm = plugin.raw_timings
     launches = expected_launches(n_records)
     declines = total(ctx.engine.m_filter_batch_decline)

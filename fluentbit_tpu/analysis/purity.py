@@ -325,14 +325,13 @@ class JaxPurityRules(Rule):
                       where: str, out: List[Finding],
                       dict_params: Set[str] = frozenset()) -> None:
         # pytree-structure membership is static at trace time: a kernel
-        # taking its table pytree as a DICT param branches on
-        # `"pair_maps" in t` to pick a sub-kernel — that is pytree
+        # taking its table pytree as a DICT param may branch on
+        # `"<leaf>" in t` to pick a sub-kernel — that is pytree
         # STRUCTURE (fixed per jit cache entry), not tracer data, so it
-        # can never boolify a tracer (the partitioned mesh plane's
-        # table-pytree idiom, ops/grep.py _super_symbols). Only params
-        # the function also string-subscripts qualify: `"GET" in batch`
-        # over a traced ARRAY param still iterates the tracer and must
-        # keep firing.
+        # can never boolify a tracer. The idiom is allowed; no kernel
+        # in the tree uses it today. Only params the function also
+        # string-subscripts qualify: `"GET" in batch` over a traced
+        # ARRAY param still iterates the tracer and must keep firing.
         test = node.test
         if isinstance(test, ast.Compare) and len(test.ops) == 1 \
                 and isinstance(test.ops[0], (ast.In, ast.NotIn)) \

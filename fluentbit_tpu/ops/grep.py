@@ -22,12 +22,14 @@ DFA (fluentbit_tpu.regex.dfa) runs over a ``[B, L] uint8`` batch as a
   columns (T2[s, c1*C+c2] = T[T[s,c1],c2]), cutting sequential scan steps
   by k at the cost of a larger (still VMEM-resident) table. k is chosen
   so the table stays under a size budget.
-- Multi-stride symbol packing: for even k the per-byte class gathers are
-  themselves fused two bytes at a time through a per-rule byte-PAIR
-  class table (``pair_maps[R, 65536] = class(b0)*C + class(b1)``),
-  halving the gather count of the super-symbol prepass — the same
-  pair-table trick the native twin uses (native/fbtpu_native.cpp
-  dfa_prepass_block).
+- Byte classing without a gather: a rule's byte→class map is a step
+  function of the byte with a handful of breakpoints (4-16 for the
+  benchmark's rules, 255 at worst), so the super-symbol prepass
+  computes ``class(b) = class_base + Σ_n (b >= run_start[n]) *
+  run_delta[n]`` in the same elementwise pass that pads and combines k
+  classes — an element gather costs 8-11 ns an element on a v5e,
+  whatever the table's size (PERF.md, PR 28), a compare-add next to
+  nothing.
 - Padding positions map to the EOL symbol class, which is absorbing after
   the first step — fixed shapes stay exact, no masking in the inner loop.
 - matched == (final_state == ACC): single comparison at scan end, no
@@ -70,10 +72,6 @@ from ..regex.dfa import ACC, DFA, EOL
 _TABLE_BUDGET = 4 * 1024 * 1024
 
 
-#: byte-pair class tables cost R * 65536 * 4 bytes; skip beyond this
-_PAIR_MAP_MAX_RULES = 32
-
-
 def choose_k(n_states: int, n_classes: int, budget: int = _TABLE_BUDGET) -> int:
     """Largest stride whose composed table fits the budget (strides up
     to 6 — small alphabets with few states compose deep)."""
@@ -84,6 +82,17 @@ def choose_k(n_states: int, n_classes: int, budget: int = _TABLE_BUDGET) -> int:
             break
         k += 1
     return k
+
+
+def class_runs(class_map: np.ndarray) -> Tuple[int, np.ndarray, np.ndarray]:
+    """A byte→class map as a step function of the byte: ``(base,
+    run_start, run_delta)`` with ``class_map[b] == base + sum(run_delta[
+    run_start <= b])`` for every byte ``b`` — the class of byte 0, the
+    byte values at which the class changes, and by how much."""
+    cm = np.asarray(class_map[:256], dtype=np.int32)
+    step = np.diff(cm)
+    start = np.flatnonzero(step) + 1
+    return int(cm[0]), start.astype(np.int32), step[start - 1]
 
 
 def compose_table(trans: np.ndarray, k: int) -> np.ndarray:
@@ -149,6 +158,10 @@ class GrepProgram:
         # shard over the rule axis — ops/mesh.py) and `FBTPU_PER_DFA_K=0`.
         self.k_by_rule = [choose_k(d.n_states, d.n_classes)
                           for d in self.dfas]
+        # byte classing as breakpoints (class_runs); decision() reads
+        # the per-rule counts on a mixed-k parent too
+        runs = [class_runs(d.class_map) for d in self.dfas]
+        self._class_runs = [int(st.size) for _, st, _ in runs]
         self._children: Optional[List["GrepProgram"]] = None
         self._inv_perm: Optional[np.ndarray] = None
         self._child_idxs: Optional[List[np.ndarray]] = None
@@ -193,34 +206,29 @@ class GrepProgram:
         flat = np.zeros((R, max_flat), dtype=np.int32)
         for r, t in enumerate(tables):
             flat[r, : t.size] = t.reshape(-1)
-        cmaps = np.zeros((R, 257), dtype=np.int32)
-        for r, d in enumerate(self.dfas):
-            cmaps[r] = d.class_map.astype(np.int32)
+        # the breakpoints padded to the widest rule with a start no
+        # uint8 reaches
+        n_runs = max(self._class_runs + [1])
+        run_start = np.full((R, n_runs), 256, dtype=np.int32)
+        run_delta = np.zeros((R, n_runs), dtype=np.int32)
+        for r, (_, st, dl) in enumerate(runs):
+            run_start[r, : st.size] = st
+            run_delta[r, : dl.size] = dl
         self._np = {
             "trans_flat": flat,
             "C": np.asarray([d.n_classes for d in self.dfas],
                             dtype=np.int32),
             "Ck": np.asarray([d.n_classes ** self.k for d in self.dfas],
                              dtype=np.int32),
-            "class_maps": cmaps,
+            "class_base": np.asarray([b for b, _, _ in runs],
+                                     dtype=np.int32),
+            "run_start": run_start,
+            "run_delta": run_delta,
             "eol_cls": np.asarray([d.eol_class for d in self.dfas],
                                   dtype=np.int32),
             "starts": np.asarray([d.start for d in self.dfas],
                                  dtype=np.int32),
         }
-        # even strides classify through a byte-PAIR table: one gather
-        # yields class(b0)*C + class(b1), halving the symbol-prep
-        # gathers (the fused multi-stride packing)
-        if self.k % 2 == 0 and R <= _PAIR_MAP_MAX_RULES:
-            pair_maps = np.zeros((R, 65536), dtype=np.int32)
-            w = np.arange(65536, dtype=np.int64)
-            for r, d in enumerate(self.dfas):
-                cm = d.class_map[:256].astype(np.int64)
-                pair_maps[r] = (cm[w & 255] * d.n_classes
-                                + cm[w >> 8]).astype(np.int32)
-            self._np["pair_maps"] = pair_maps
-        else:
-            self._np["pair_maps"] = None
         self.max_states = max(d.n_states for d in self.dfas)
         self._jit = None
         self._mat_lock = threading.Lock()
@@ -248,9 +256,10 @@ class GrepProgram:
     def decision(self) -> dict:
         """The resolved compile/kernel decisions, per rule: S/C before →
         after the reduction pass (regex.dfa ShrinkStats), the chosen
-        stride k, the k-group layout, and the scan/assoc resolution —
-        what bench's `shrink` stage records and the unlock tests assert
-        against. ``kernel_resolved`` is None until the program
+        stride k, the compare-adds a byte its classing costs
+        (``class_runs``), the k-group layout, and the scan/assoc
+        resolution — what bench's `shrink` stage records and the unlock
+        tests assert against. ``kernel_resolved`` is None until the program
         materializes on a backend (the resolution is a trace-time
         decision)."""
         rules = []
@@ -265,6 +274,7 @@ class GrepProgram:
                 "minimized": bool(st.minimized) if st else False,
                 "approx_of": st.approx_of if st else None,
                 "k": self.k_by_rule[r],
+                "class_runs": self._class_runs[r],
             })
         if self._children is not None:
             resolved = {c.kernel_resolved for c in self._children}
@@ -326,8 +336,7 @@ class GrepProgram:
             if self._jit is not None:
                 return
             t = self._np
-            self._tbl = {k: jnp.asarray(v) for k, v in t.items()
-                         if v is not None}
+            self._tbl = {k: jnp.asarray(v) for k, v in t.items()}
             self.kernel_resolved = self._resolve_kernel()
             kern = (self._match_assoc_impl
                     if self.kernel_resolved == "assoc"
@@ -364,18 +373,31 @@ class GrepProgram:
 
     # -- the kernel --
 
+    @staticmethod
+    def _byte_classes(t: dict, batch: "jnp.ndarray") -> "jnp.ndarray":
+        """byte → class, per rule: ``[R, B, L]`` u8 → i32, equal to
+        ``class_map[r][byte]``. The map's breakpoints as compare-adds
+        (class_runs: the count is static, a padded run adds 0) — no
+        gather over an ``[R, B, L]`` index, so XLA fuses it into the
+        pass that pads and combines the classes."""
+        byte = batch.astype(jnp.int32)
+        cls = jnp.broadcast_to(t["class_base"][:, None, None], byte.shape)
+        for n in range(t["run_start"].shape[1]):
+            start = lax.index_in_dim(t["run_start"], n, axis=1)  # [R,1]
+            delta = lax.index_in_dim(t["run_delta"], n, axis=1)
+            cls = cls + jnp.where(byte >= start[:, :, None],
+                                  delta[:, :, None], 0)
+        return cls
+
     def _super_symbols(self, t: dict, batch: "jnp.ndarray",
                        lengths: "jnp.ndarray") -> "jnp.ndarray":
         """bytes → per-rule k-byte super-symbols: [R, B, Lk]. ``t`` is
         the table pytree (whole under single-device jit, this device's
         shard under the partitioned program — the kernels are uniform
         over the leading rule axis, so both read identically)."""
-        if "pair_maps" in t:
-            return self._super_symbols_pairs(t, batch, lengths)
         R, B, L = batch.shape
         k = self.k
-        # byte → class, per rule
-        cls = jax.vmap(lambda cm, bt: cm[bt])(t["class_maps"], batch)  # [R,B,L] i32
+        cls = self._byte_classes(t, batch)  # [R,B,L] i32
         pos = jnp.arange(L, dtype=jnp.int32)
         pad = pos[None, None, :] >= lengths[:, :, None]  # [R,B,L]
         cls = jnp.where(pad, t["eol_cls"][:, None, None], cls)
@@ -391,57 +413,6 @@ class GrepProgram:
         comb = cls[..., 0]
         for j in range(1, k):
             comb = comb * t["C"][:, None, None] + cls[..., j]
-        return comb
-
-    def _super_symbols_pairs(self, t: dict, batch: "jnp.ndarray",
-                             lengths: "jnp.ndarray") -> "jnp.ndarray":
-        """Even-stride symbol packing through the byte-pair class
-        tables: one [R, 65536] gather per TWO bytes instead of one
-        class gather per byte, then k/2 pair-symbols combine at radix
-        C². Pad fix-up happens in pair space — fully-padded pairs
-        become the absorbing EOL pair, and the single possibly-mixed
-        pair at an odd length boundary is patched from the last valid
-        byte's class. Bit-identical to the per-byte path
-        (differentially tested in tests/test_ops_grep.py)."""
-        R, B, L = batch.shape
-        k = self.k
-        if L % 2:
-            batch = jnp.concatenate(
-                [batch, jnp.zeros((R, B, 1), dtype=batch.dtype)], axis=2)
-            L += 1
-        idx = (batch[..., 0::2].astype(jnp.int32)
-               + 256 * batch[..., 1::2].astype(jnp.int32))  # [R,B,L2]
-        pcls = jax.vmap(lambda pm, ix: pm[ix])(t["pair_maps"], idx)
-        L2 = L // 2
-        t2 = jnp.arange(L2, dtype=jnp.int32) * 2
-        eol_pair = t["eol_cls"] * t["C"] + t["eol_cls"]  # [R]
-        # boundary pair (first byte valid, second padded):
-        # class(last byte) * C + eol — one [R, B] gather, broadcast
-        # into the single position it can occupy
-        last_idx = jnp.clip(lengths - 1, 0)[..., None]       # [R,B,1]
-        last_b = jnp.take_along_axis(batch, last_idx, axis=2)
-        last_cls = jax.vmap(lambda cm, bt: cm[bt])(t["class_maps"],
-                                                   last_b)  # [R,B,1]
-        mixed = (last_cls * t["C"][:, None, None]
-                 + t["eol_cls"][:, None, None])
-        pcls = jnp.where(t2[None, None, :] + 1 == lengths[:, :, None],
-                         mixed, pcls)
-        pcls = jnp.where(t2[None, None, :] >= lengths[:, :, None],
-                         eol_pair[:, None, None], pcls)
-        # append EOL-pair block: >=1 full EOL super-symbol and rounds
-        # L2 to a multiple of k/2 (same arithmetic as the byte path —
-        # EOL is absorbing, extra tail symbols are no-ops)
-        k2 = k // 2
-        extra = (k2 - (L2 % k2)) % k2 + k2
-        pcls = jnp.concatenate(
-            [pcls, jnp.broadcast_to(eol_pair[:, None, None],
-                                    (R, B, extra))], axis=2)
-        Lk = pcls.shape[2] // k2
-        pcls = pcls.reshape(R, B, Lk, k2)
-        C2 = t["C"] * t["C"]
-        comb = pcls[..., 0]
-        for j in range(1, k2):
-            comb = comb * C2[:, None, None] + pcls[..., j]
         return comb
 
     def _match_impl(self, t: dict, batch: "jnp.ndarray",
@@ -641,11 +612,11 @@ class GrepProgram:
     def mesh_variant(self, mesh) -> str:
         """Which axis of the program shards across the mesh.
 
-        ``"batch"`` (default): B splits across devices, the transition/
-        pair-class tables replicate — right whenever the tables are
+        ``"batch"`` (default): B splits across devices, the transition
+        and class tables replicate — right whenever the tables are
         small relative to per-device memory. ``"rules"``: for large
-        rule sets the replicated tables dominate (R × C^k rows + the
-        R × 65536 pair maps), so the RULE axis shards instead — each
+        rule sets the replicated tables dominate (R × C^k rows), so
+        the RULE axis shards instead — each
         device holds 1/n of the tables and matches the full batch
         against its own rules. Gated on the replicated-table footprint
         crossing ``FBTPU_MESH_TABLE_BUDGET`` (default 64 MiB) or R ≥

@@ -72,14 +72,14 @@ PARTITION_RULES: Dict[str, Tuple[Tuple[str, Tuple[Any, ...]], ...]] = {
     # table leaf replicated (the post-shrink matrices are small
     # relative to per-device memory — mesh_variant gates the flip)
     "grep-batch": (
-        (r"^(trans_flat|class_maps|pair_maps|C|Ck|eol_cls|starts)$",
-         ()),
+        (r"^(trans_flat|class_base|run_start|run_delta|C|Ck|eol_cls"
+         r"|starts)$", ()),
     ),
     # grep rule-sharded variant: each device holds 1/n of the rules —
     # 2-D table leaves split on the rule axis, per-rule vectors too
     "grep-rules": (
-        (r"^(trans_flat|class_maps|pair_maps)$", (AXIS, None)),
-        (r"^(C|Ck|eol_cls|starts)$", (AXIS,)),
+        (r"^(trans_flat|run_start|run_delta)$", (AXIS, None)),
+        (r"^(class_base|C|Ck|eol_cls|starts)$", (AXIS,)),
     ),
     # flux sketch state leaves: replicated snapshots — every device
     # absorbs its batch shard into a full local copy, merged by

@@ -11,8 +11,7 @@ and log_to_metrics counters, including non-ASCII and truncated records
 are not well-formed UTF-8).
 
 Also here: the ops.batch.bucket_size pad-budget clamp regression
-(satellite: 65536-bucket × long-syslog max_len overflow) and the
-even-stride pair-table kernel equivalence.
+(satellite: 65536-bucket × long-syslog max_len overflow).
 """
 
 import json
@@ -407,47 +406,6 @@ def test_bucket_size_clamps_long_record_padding():
     assert bucket_size(5000, max_len=131072) == ((5000 + 63) // 64) * 64
     # short rows keep the plain bucket ladder
     assert bucket_size(20000, max_len=512) == 65536
-
-
-# ---------------------------------------------------------------------
-# even-stride pair-table packing ≡ per-byte path
-# ---------------------------------------------------------------------
-
-def test_pair_table_super_symbols_match_byte_path():
-    jax = pytest.importorskip("jax")  # noqa: F841
-    from fluentbit_tpu.ops import device
-    from fluentbit_tpu.ops.grep import GrepProgram
-    from fluentbit_tpu.regex import FlbRegex
-    from fluentbit_tpu.regex.dfa import compile_dfa
-
-    device.attach_async()
-    assert device.wait(120.0)
-    pat = "ERR(OR)?|time?out"
-    prog = GrepProgram([compile_dfa(pat)], 96)
-    assert prog.k % 2 == 0 and prog._np["pair_maps"] is not None
-    byte = GrepProgram([compile_dfa(pat)], 96)
-    byte._np["pair_maps"] = None  # force the per-byte prepass
-    rng = random.Random(6)
-    vals = ["ERROR x", "timeout", "timout", "ERR", "E", "", "zzz",
-            "x" * 95, "é ERROR é"]
-    vals += ["".join(rng.choice("ERtimeouxyz ") for _ in
-                     range(rng.randrange(0, 90))) for _ in range(80)]
-    B = len(vals)
-    batch = np.zeros((1, B, 96), np.uint8)
-    lens = np.zeros((1, B), np.int32)
-    for i, v in enumerate(vals):
-        bv = v.encode()[:96]
-        batch[0, i, :len(bv)] = np.frombuffer(bv, np.uint8)
-        lens[0, i] = len(bv)
-    lens[0, 0] = -1  # invalid row must never match on either path
-    m_pair = prog.match(batch, lens)
-    m_byte = byte.match(batch, lens)
-    assert (m_pair == m_byte).all()
-    rx = FlbRegex(pat)
-    for i, v in enumerate(vals):
-        if i == 0:
-            continue
-        assert bool(m_pair[0, i]) == rx.match(v)
 
 
 def test_auto_kernel_resolves_scan_on_cpu():

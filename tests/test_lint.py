@@ -452,8 +452,8 @@ def build(mesh, tspecs, axis):
     def step(t, batch, lengths):
         # pytree-structure membership is static per jit cache entry,
         # not tracer boolification — must stay quiet
-        if "pair_maps" in t:
-            base = t["pair_maps"]
+        if "bias" in t:
+            base = t["bias"]
         else:
             base = t["starts"]
         mask = (batch.sum(axis=2) + base[:, None] > 0) & (lengths >= 0)

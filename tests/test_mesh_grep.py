@@ -120,17 +120,23 @@ def test_mesh_assoc_kernel_bit_exact():
     assert np.array_equal(mask, _cpu_chain(("GET|POST", "50[0-9]$"), vals))
 
 
-def test_mesh_per_byte_prepass_bit_exact():
-    """Force the per-byte classifier (no pair tables) pre-materialize:
-    the partitioned program must not depend on the pair-map leaf."""
+def test_mesh_padded_class_runs_bit_exact():
+    """Two rules of one stride whose class maps have unequal breakpoint
+    counts: the shorter rule's padded runs (start 256, delta 0) ride
+    the partitioned program as replicated leaves and add nothing."""
     mesh = _mesh()
     vals = (CORPUS * 4)[:21]
-    prog = GrepProgram([compile_dfa("GET|POST")], 96)
-    if prog._np is not None:
-        prog._np["pair_maps"] = None
-    batch, lengths = _stage(vals, 1)
+    patterns = ("panic", "ra")  # one stride (k=6), 10 and 4 runs
+    prog = GrepProgram([compile_dfa(p) for p in patterns], 96)
+    assert prog._children is None and prog.mesh_variant(mesh) == "batch"
+    runs = [r["class_runs"] for r in prog.decision()["rules"]]
+    assert runs[0] != runs[1]
+    assert prog._np["run_start"].shape == (2, max(runs))
+    assert (prog._np["run_start"][int(np.argmin(runs)), min(runs):]
+            == 256).all()
+    batch, lengths = _stage(vals, 2)
     mask, _, _ = prog.match_mesh(mesh, batch, lengths)
-    assert np.array_equal(mask, _cpu_chain(("GET|POST",), vals))
+    assert np.array_equal(mask, _cpu_chain(patterns, vals))
 
 
 def test_rule_sharded_variant_bit_exact(monkeypatch):
@@ -177,6 +183,40 @@ def test_match_partition_rules_layer():
     assert specs["scalar"] == P()  # scalars never partition
     with pytest.raises(ValueError):
         match_partition_rules(((r"^starts$", P()),), tree)
+
+
+@pytest.mark.parametrize("table,sharded_dims", [
+    ("grep-batch", {}),
+    ("grep-rules", {"trans_flat": 2, "run_start": 2, "run_delta": 2,
+                    "class_base": 1, "C": 1, "Ck": 1, "eol_cls": 1,
+                    "starts": 1}),
+])
+def test_grep_partition_rules_name_every_leaf(table, sharded_dims):
+    """The registry places every leaf of the program's table pytree —
+    the class-run leaves on the rule axis in the rule-sharded variant,
+    replicated in the batch variant — and names nothing the pytree
+    does not hold (a stale name is dead text that hides a rename)."""
+    import re
+
+    from jax.sharding import PartitionSpec as P
+
+    from fluentbit_tpu.ops.mesh import PARTITION_RULES, partition_rules
+
+    prog = GrepProgram([compile_dfa(p) for p in ("GET", "50[0-9]$")], 96)
+    leaves = set(prog._np)
+    assert {"class_base", "run_start", "run_delta"} <= leaves
+    specs = match_partition_rules(partition_rules(table, "batch"), prog._np)
+    assert set(specs) == leaves
+    for name, spec in specs.items():
+        n = sharded_dims.get(name)
+        want = P() if n is None else P("batch", *([None] * (n - 1)))
+        assert spec == want, name
+    named = set()
+    for regex, _ in PARTITION_RULES[table]:
+        m = re.fullmatch(r"\^\(([A-Za-z_|]+)\)\$", regex)
+        assert m, f"{regex!r}: not an anchored alternation of leaf names"
+        named |= set(m.group(1).split("|"))
+    assert named == leaves
 
 
 def test_mesh_helpers():

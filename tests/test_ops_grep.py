@@ -10,8 +10,9 @@ import numpy as np
 import pytest
 
 from fluentbit_tpu.ops.batch import assemble, bucket_size
-from fluentbit_tpu.ops.grep import GrepProgram, choose_k, compose_table, program_for
-from fluentbit_tpu.regex.dfa import compile_dfa
+from fluentbit_tpu.ops.grep import (GrepProgram, choose_k, class_runs,
+                                    compose_table, program_for)
+from fluentbit_tpu.regex.dfa import DFA, compile_dfa
 
 APACHE2 = (
     r'^(?<host>[^ ]*) [^ ]* (?<user>[^ ]*) \[(?<time>[^\]]*)\] '
@@ -165,3 +166,161 @@ def test_assoc_kernel_sharded_matches_single_device():
     single = prog.match(batch, lengths)
     assert (mask == single).all()
     assert (counts == single.sum(axis=1)).all()
+
+
+# ---------------------------------------------------------------------
+# byte classing without a gather (class_runs → _byte_classes)
+# ---------------------------------------------------------------------
+
+#: the ten DFAs of the benchmark's configurations: grep-apache2's two
+#: rules, rewrite-syslog's eight (sketch-firehose builds none)
+BENCH_PATTERNS = [r"curl/8\.5", APACHE2, "sshd", "kernel:",
+                  r"systemd\[1\]", "ERROR", "WARN", "nginx",
+                  r"cron\[\d+\]", ".*OOM.*"]
+
+
+def _made_up_dfa(class_map256):
+    """A hand-built table over a made-up byte→class map (every
+    transition DEAD: only the classing is under test). EOL shares
+    class 0 — a uint8 map holds 256 ids."""
+    cm = np.concatenate([np.asarray(class_map256), [0]]).astype(np.uint8)
+    n_classes = int(cm.max()) + 1
+    return DFA(trans=np.zeros((3, n_classes), np.int32), class_map=cm,
+               start=2, n_states=3, n_classes=n_classes, pattern="made-up")
+
+
+_B = np.arange(256)
+MADE_UP_MAPS = {
+    "one-class": [np.zeros(256, int)],
+    "change-at-byte-1": [(_B >= 1).astype(int)],
+    "change-at-byte-255": [(_B >= 255).astype(int)],
+    "every-byte-its-own-class": [_B],
+    # two rules of unequal breakpoint counts in one program, same (S, C)
+    # so they share a stride: the shorter rule's runs are padding
+    "unequal-runs-padded": [(_B // 8) % 2, (_B >= 128).astype(int)],
+    "classes-fall-and-rise": [np.abs(_B // 32 - 3)],
+}
+
+
+def _check_classing(dfas):
+    """The compare-add classing alone: for all 256 byte values, every
+    rule's class is ``class_map[byte]``, and the breakpoint tables are
+    the map's runs padded to the program's widest rule."""
+    import jax.numpy as jnp
+
+    prog = GrepProgram(dfas, max_len=256)
+    assert prog._children is None
+    t = prog._np
+    R = len(dfas)
+    widest = 1
+    for r, d in enumerate(dfas):
+        base, start, delta = class_runs(d.class_map)
+        n = int(np.count_nonzero(np.diff(d.class_map[:256].astype(int))))
+        assert start.size == n == prog.decision()["rules"][r]["class_runs"]
+        assert base == d.class_map[0]
+        assert (t["run_start"][r, :n] == start).all()
+        assert (t["run_delta"][r, :n] == delta).all()
+        assert (t["run_start"][r, n:] == 256).all()  # no uint8 reaches it
+        assert (t["run_delta"][r, n:] == 0).all()
+        widest = max(widest, n)
+    assert t["run_start"].shape == t["run_delta"].shape == (R, widest)
+    batch = np.broadcast_to(_B.astype(np.uint8), (R, 1, 256))
+    got = np.asarray(GrepProgram._byte_classes(
+        {k: jnp.asarray(v) for k, v in t.items()}, jnp.asarray(batch)))
+    assert got.dtype == np.int32 and got.shape == (R, 1, 256)
+    for r, d in enumerate(dfas):
+        assert (got[r, 0] == d.class_map[:256]).all()
+
+
+@pytest.mark.parametrize("pattern", BENCH_PATTERNS)
+def test_byte_classes_equal_class_map_bench_rules(pattern):
+    _check_classing([compile_dfa(pattern)])
+
+
+@pytest.mark.parametrize("name", sorted(MADE_UP_MAPS))
+def test_byte_classes_equal_class_map_made_up(name):
+    _check_classing([_made_up_dfa(m) for m in MADE_UP_MAPS[name]])
+
+
+#: one pattern for each stride choose_k gives the benchmark's rules
+K_PATTERNS = {3: APACHE2, 4: r"systemd\[1\]", 5: r"curl/8\.5", 6: "sshd"}
+_HIT = {3: b'h - u [t] "GET /p Z" 200 7', 4: b"systemd[1]", 5: b"curl/8.5",
+        6: b"sshd"}
+
+
+def _lines_of_lengths(k, L, rng):
+    """Lines of length 0, 1, k-1, k, odd, L and longer than L (an
+    overflow row: length -1), each as a miss and — where the rule's
+    shortest hit fits — as a hit at the start, at the end and at an odd
+    offset (across a super-symbol boundary)."""
+    hit = _HIT[k]
+    out = [b"x" * (L + 5), None]
+    for n in sorted({0, 1, k - 1, k, k + 1, 2 * k - 1, 31, 77, L - 1, L}):
+        out.append(bytes(rng.choice(b"abc xyz[]/.0123") for _ in range(n)))
+        room = n - len(hit)
+        for lead in {0, room, room // 2 | 1} if room >= 0 else ():
+            if lead <= room:
+                out.append(b"q" * lead + hit + b"q" * (room - lead))
+    return out
+
+
+@pytest.mark.parametrize("kernel", ["scan", "assoc"])
+@pytest.mark.parametrize("k", sorted(K_PATTERNS))
+def test_program_verdicts_vs_native_and_re(k, kernel):
+    """Whole-program verdicts through the compare-add prepass, both
+    kernels, strides 3-6, the boundary lengths — against the host twin
+    (native.grep_match over the same records) and Python ``re``."""
+    import re
+
+    from fluentbit_tpu import native
+    from fluentbit_tpu.codec.events import encode_event
+    from fluentbit_tpu.regex import to_python_regex
+
+    L = 96
+    pattern = K_PATTERNS[k]
+    dfa = compile_dfa(pattern)
+    prog = GrepProgram([dfa], max_len=L, kernel=kernel, segment=4)
+    assert prog.k == k
+    lines = _lines_of_lengths(k, L, random.Random(k))
+    b = assemble(lines, max_len=L)
+    got = prog.match(b.batch[None], b.lengths[None])[0]
+    assert prog.kernel_resolved == kernel
+    valid = [i for i, ln in enumerate(lines)
+             if ln is not None and len(ln) <= L]
+    assert not got[[i for i in range(len(lines)) if i not in valid]].any()
+    rx = re.compile(to_python_regex(pattern), re.MULTILINE)
+    want_re = np.array([rx.search(lines[i].decode()) is not None
+                        for i in valid])
+    assert (got[valid] == want_re).all()
+    assert want_re.any() and not want_re.all()
+    if native.available():
+        chunk = b"".join(encode_event({"log": lines[i].decode()}, float(i))
+                         for i in valid)
+        mask, _, n = native.grep_match(
+            chunk, native.GrepTables([(b"log", dfa)]))
+        assert n == len(valid)
+        assert (got[valid] == mask[0]).all()
+
+
+@pytest.mark.parametrize("kernel", ["scan", "assoc"])
+@pytest.mark.parametrize("k", sorted(K_PATTERNS))
+def test_no_gather_in_symbols_scope(k, kernel):
+    """The lowered program classifies without a gather: no ``gather``
+    under the ``grep.symbols`` named scope (the scan and assoc scopes
+    keep theirs — the transition tables)."""
+    import re
+
+    import jax
+
+    prog = GrepProgram([compile_dfa(K_PATTERNS[k])], max_len=64,
+                       kernel=kernel)
+    prog._ensure_materialized()
+    planes = np.zeros((1, 8, 64), np.uint8)
+    lengths = np.zeros((1, 8), np.int32)
+    hlo = prog._jit.lower(planes, lengths).as_text(debug_info=True)
+    assert "/grep.symbols/" in hlo  # the scope names its operations
+    assert not re.search(r'grep\.symbols/[^"]*gather', hlo)
+    assert "stablehlo.gather" in hlo  # the transition tables' own
+    # and the prepass lowered alone, whatever its operations are named
+    alone = jax.jit(lambda p, n: prog._super_symbols(prog._tbl, p, n))
+    assert "gather" not in alone.lower(planes, lengths).as_text()
