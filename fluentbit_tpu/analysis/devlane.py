@@ -43,7 +43,7 @@ SCOPES = ("fluentbit_tpu/plugins/", "fluentbit_tpu/flux/")
 
 #: Calls that enter the jit/pjit/shard_map plane by simple name.
 DISPATCH_NAMES = frozenset({
-    "dispatch_mesh", "match_mesh", "match_sharded",
+    "dispatch_mesh", "match_mesh",
     "sharded_hll_update", "sharded_cms_update",
     "sharded_hll_registers", "sharded_cms_table",
     "sharded_segment_counts", "device_registers", "device_table",

@@ -5,8 +5,8 @@ The measured wall is launches-per-PCIe-crossing (ROADMAP item 1): every
 filter stage is its own jit/pjit launch with its own staging, and the
 verdict comes home as a mask the host scatters. Nothing in the tree
 could *see* or *gate* that cost — this module makes it reviewable. It
-walks, from each ``FilterPlugin.process_batch`` / ``filter_raw`` and
-the flux absorb entry, the call closure down to every
+walks, from each ``FilterPlugin.process_batch`` and the flux absorb
+entry, the call closure down to every
 ``DeviceLane.run``/``begin``/dispatch/jit/pjit/shard_map site (the
 tail-call + self-method inlining of ``analysis/batch.py`` plus the
 name-closure of ``devlane.py``) and emits a per-tag **device launch
@@ -96,7 +96,6 @@ GUARDED_LAUNCH_FNS = frozenset({"guarded_segment_counts", "staged_match"})
 #: Raw jit/pjit/shard_map dispatch terminals, by launch kind.
 KIND_BY_NAME = {
     "dispatch_mesh": "grep-mesh", "match_mesh": "grep-mesh",
-    "match_sharded": "grep-mesh",
     "sharded_segment_counts": "flux-segment-counts",
     "guarded_segment_counts": "flux-segment-counts",
     "staged_match": "grep-mesh",

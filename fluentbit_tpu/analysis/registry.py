@@ -37,9 +37,8 @@ __all__ = ["GuardEntry", "GUARDS", "LAUNCH_ENTRIES", "BUDGET_PARAMS",
 # -- fbtpu-xray (analysis/launchgraph.py) declarative plumbing ---------
 
 #: Chain entry points the launch-graph walker roots at: the batched
-#: plugin fast path, the raw grep path, and the flux absorb commit.
-LAUNCH_ENTRIES: Tuple[str, ...] = ("process_batch", "filter_raw",
-                                   "absorb_batch")
+#: plugin fast path and the flux absorb commit.
+LAUNCH_ENTRIES: Tuple[str, ...] = ("process_batch", "absorb_batch")
 
 #: Canonical evaluation point for the symbolic transfer-byte algebra —
 #: the committed analysis/launch_budget.json is evaluated here (2

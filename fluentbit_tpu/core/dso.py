@@ -173,7 +173,7 @@ def load_dso_plugin(path: str, registry=None):
         # itself (FLBPluginRegister), not the file
         return load_proxy_plugin(path, registry)
     # probe the export table BEFORE dlopen: a rejected object's static
-    # initializers must never run (ADVICE.md: the invariant regressed
+    # initializers must never run (round-5 advisor: the invariant regressed
     # when the proxy fallback made every stem loadable)
     _probe_exports(path, {symbol, "FLBPluginRegister"}, "plugin")
     try:

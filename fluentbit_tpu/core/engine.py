@@ -272,11 +272,8 @@ class Engine:
             "fluentbit", "device", "reattach_total",
             "Late/re-attach generations (the mesh lane swapped in "
             "live after earlier refusals)")
-        # fbtpu-shrink (DEVICE_PLANE.md "shrink"): compile-path DFA reduction
-        # outcomes plus the approximate first-pass mask's runtime
-        # economics — an approx mask that admits nearly everything is
-        # pure overhead, and these counters (not a mystery-slow ingest
-        # number) are how that reads on a dashboard
+        # fbtpu-shrink (DEVICE_PLANE.md "shrink"): compile-path DFA
+        # reduction outcomes
         self.m_shrink_states = m.counter(
             "fluentbit", "grep_shrink", "states_eliminated_total",
             "DFA states eliminated by the compile-path minimizer "
@@ -286,24 +283,6 @@ class Engine:
             "fluentbit", "grep_shrink", "classes_eliminated_total",
             "Byte classes eliminated by the post-minimization class "
             "remerge, summed over compiled rules", ("name",))
-        self.m_shrink_approx_admits = m.counter(
-            "fluentbit", "grep_shrink", "approx_admits_total",
-            "Per-(rule, record) admissions by the approximate "
-            "first-pass DFA mask (mask selectivity)", ("name",))
-        self.m_shrink_approx_rechecks = m.counter(
-            "fluentbit", "grep_shrink", "approx_rechecks_total",
-            "Records re-walked by the exact DFA (the union of all "
-            "rules' admissions — the recheck cost actually paid)",
-            ("name",))
-        self.m_shrink_approx_fp = m.counter(
-            "fluentbit", "grep_shrink", "approx_false_positives_total",
-            "Approximate-mask admissions the exact recheck rejected "
-            "(the measured FP the budget is enforced against)",
-            ("name",))
-        self.m_shrink_approx_disabled = m.counter(
-            "fluentbit", "grep_shrink", "approx_disabled_total",
-            "Approximate mode self-disabled: measured FP rate "
-            "exceeded tpu_approx_fp_budget", ("name",))
 
     # ------------------------------------------------------------------
     # configuration
@@ -1114,11 +1093,7 @@ class Engine:
             and not sp_active
             and not cond_routing  # per-record splits need decoded events
             and self._trace_ctx(ins) is None
-            and all(
-                getattr(f.plugin, "can_filter_raw", lambda: False)()
-                or f.plugin.can_process_batch()
-                for f in matching
-            )
+            and all(f.plugin.can_process_batch() for f in matching)
         )
         if raw_ok:
             # stateful chains are pinned to the global lock even when
@@ -1129,8 +1104,7 @@ class Engine:
             # Engine._ingest_lock -> InputInstance.ingest_lock order
             # (fbtpu-locksmith lock-order-cycle)
             parallel = all(
-                getattr(f.plugin, "thread_safe_raw", False)
-                and not getattr(f.plugin, "stateful_batch", False)
+                f.plugin.thread_safe_raw and not f.plugin.stateful_batch
                 for f in matching
             )
             # two lexical branches, not a lock alias: the locksmith
@@ -1381,7 +1355,7 @@ class Engine:
                         chunk.replace(data, n)
                     else:
                         chunk.n = n
-                    if getattr(plugin, "stateful_batch", False):
+                    if plugin.stateful_batch:
                         # marked BEFORE the call: a hook raising after
                         # partial emits must not trigger a full decode
                         # re-run (the tail continuation re-runs only
@@ -1392,12 +1366,6 @@ class Engine:
                         committed = True
                     with span("filter." + plugin.name):
                         got = plugin.process_batch(chunk)
-                if got is None and getattr(
-                        plugin, "can_filter_raw", None) is not None \
-                        and plugin.can_filter_raw():
-                    with span("filter." + plugin.name):
-                        got = plugin.filter_raw(data, tag, self,
-                                                n_records=n)
             except Exception:
                 log.exception("filter %s raw path failed", f.display_name)
                 got = None

@@ -111,7 +111,7 @@ class FilterPlugin(Plugin):
     ``can_process_batch()`` and implement ``process_batch(chunk)`` over a
     :class:`~fluentbit_tpu.core.chunk_batch.RawChunk` — the engine then
     routes whole appends through it on the raw ingest path (no Python
-    decode), exactly like filter_grep's ``filter_raw``. The hook returns
+    decode); it is the one raw hook the engine knows. The hook returns
     ``(n_records_out, data_out)`` or ``(n_out, data_out, n_in)`` (when
     the batch pass discovered the input record count), or None to
     decline — the engine then falls back to the bit-exact per-record
@@ -122,6 +122,12 @@ class FilterPlugin(Plugin):
     #: cross-record state): the engine may then run the chain for
     #: multiple inputs in parallel under per-input locks only
     thread_safe_raw: bool = False
+
+    #: True when ``process_batch`` commits side effects (emitter
+    #: re-emits, metric bumps) before it can decline: a decline after
+    #: such a hook finishes per record from that filter onward instead
+    #: of re-running the whole chain (core/chunk_batch.py)
+    stateful_batch: bool = False
 
     def filter(self, events: list, tag: str, engine) -> tuple:
         return (FilterResult.NOTOUCH, events)

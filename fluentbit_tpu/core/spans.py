@@ -166,11 +166,9 @@ class ShardedTimings:
     ``add`` — never a device-lane worker, which is a new thread per
     launch (what a worker measures goes to ``DeviceLane`` stats).
 
-    The mapping interface (iteration / item get / item set) is what the
-    benchmark's counter flattening and bench.py's reset-and-read use:
-    item reads return the cross-shard sum, item writes are the RESET
-    hook and store the value into every shard — meaningful for zero
-    only.
+    The read-only mapping interface (iteration / item get) is what
+    the benchmark's counter flattening uses: item reads return the
+    cross-shard sum.
     """
 
     def __init__(self, keys: tuple):
@@ -206,9 +204,3 @@ class ShardedTimings:
         with self._reg_lock:
             shards = list(self._shards)
         return sum(d[key] for d in shards)
-
-    def __setitem__(self, key, value) -> None:
-        with self._reg_lock:
-            shards = list(self._shards)
-        for d in shards:
-            d[key] = value

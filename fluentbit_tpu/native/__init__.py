@@ -367,7 +367,6 @@ class GrepTables:
                 "c_raw": st.c_raw if st is not None else None,
                 "minimized": bool(st.minimized) if st is not None
                 else False,
-                "approx_of": st.approx_of if st is not None else None,
                 "table_bytes": int(S * (C ** k) * 2),
             })
             tk = compose_supersteps(t, k)

@@ -288,7 +288,7 @@ def device_count() -> int:
 
 
 def status() -> dict:
-    """Attach state for diagnostics and the bench RESULT: retry-world
+    """Attach state for diagnostics and the benchmark's result: retry-world
     fields (attempt count, per-attempt error history — the most recent
     ``_RETRY_HISTORY_MAX`` entries — next retry ETA, attach
     generation) ride along with the original block."""

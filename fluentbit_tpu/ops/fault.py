@@ -47,8 +47,8 @@ What a lane guarantees per launch:
 
 Observability: ``fluentbit_device_*`` metrics via the engine's
 listener bridge (:func:`add_listener`), a ``"device"`` block in
-``/api/v1/health`` (:func:`health_block`), and :func:`snapshot` for the
-bench ``mesh.failover`` stats.
+``/api/v1/health`` (:func:`health_block`), and :func:`snapshot` (every
+lane's stats: the benchmark's lane counters, ``chip_smoke.py``).
 
 Cost model: each guarded launch runs on a fresh watched worker thread
 (~50-100 µs spawn). That is a deliberate trade — it buys the deadline
@@ -485,7 +485,7 @@ def reset() -> None:
 
 
 def snapshot() -> Dict[str, dict]:
-    """Per-lane failover stats (the bench ``mesh.failover`` block)."""
+    """Every lane's ``stats()``, by name."""
     return {name: ln.stats() for name, ln in lanes().items()}
 
 

@@ -34,13 +34,14 @@ DFA (fluentbit_tpu.regex.dfa) runs over a ``[B, L] uint8`` batch as a
   the first step — fixed shapes stay exact, no masking in the inner loop.
 - matched == (final_state == ACC): single comparison at scan end, no
   per-position accept reduction.
-- Kernel selection: ``kernel="auto"`` (default) picks scan vs assoc per
-  program shape at trace time — the sequential scan on host-CPU backends
-  (where the log2-depth compose tree's S× extra work is pure overhead:
-  an earlier CPU-host run measured it 300× slower there), the
-  parallel-in-time assoc
-  kernel on real accelerators when the state count is small enough for
-  the extra parallel work to ride otherwise-idle vector lanes.
+- Kernel selection: ``kernel="auto"`` (the default; only the argument
+  overrides it) picks scan vs assoc from the program's shape and the
+  attached platform at trace time — the sequential scan on host-CPU
+  backends (where the log2-depth compose tree's S× extra work is pure
+  overhead: an earlier CPU-host run measured it 300× slower there), the
+  parallel-in-time assoc kernel on an accelerator when ``S <= 64``.
+  Rules whose strides differ split into per-k child programs below the
+  rule-shard regime (``R < 64``).
 
 This module works on any JAX backend (tests force a CPU mesh); on TPU the
 gathers vectorize across the batch dimension.
@@ -114,7 +115,7 @@ class GrepProgram:
     """
 
     def __init__(self, dfas: Sequence[DFA], max_len: int = 512,
-                 kernel: Optional[str] = None, segment: int = 32,
+                 kernel: str = "auto", segment: int = 32,
                  plane_of: Optional[Sequence[int]] = None):
         if not HAVE_JAX:
             raise RuntimeError("jax is unavailable")
@@ -138,9 +139,7 @@ class GrepProgram:
         # Lk, trading S× more parallel work the TPU's lanes absorb;
         # "auto" = resolved per program shape + attached platform at
         # trace time (_resolve_kernel)
-        import os as _os
-        self.kernel = (kernel or
-                       _os.environ.get("FBTPU_GREP_KERNEL", "auto"))
+        self.kernel = kernel
         if self.kernel not in ("scan", "assoc", "auto"):
             raise ValueError(f"unknown grep kernel {self.kernel!r}")
         self.kernel_resolved: Optional[str] = None
@@ -155,7 +154,7 @@ class GrepProgram:
         # pinning the whole fleet to min(k): a literal rule's k=6 no
         # longer rides at a rich parser's k=3. The split is gated off
         # the rule-shard regime (large R wants ONE fused table set to
-        # shard over the rule axis — ops/mesh.py) and `FBTPU_PER_DFA_K=0`.
+        # shard over the rule axis — ops/mesh.py).
         self.k_by_rule = [choose_k(d.n_states, d.n_classes)
                           for d in self.dfas]
         # byte classing as breakpoints (class_runs); decision() reads
@@ -166,10 +165,9 @@ class GrepProgram:
         self._inv_perm: Optional[np.ndarray] = None
         self._child_idxs: Optional[List[np.ndarray]] = None
         distinct_ks = sorted(set(self.k_by_rule))
+        import os as _os
         min_shard_r = int(_os.environ.get("FBTPU_MESH_RULE_SHARD_R", "64"))
-        if (len(distinct_ks) > 1 and R < min_shard_r
-                and _os.environ.get("FBTPU_PER_DFA_K", "1").lower()
-                not in ("0", "off")):
+        if len(distinct_ks) > 1 and R < min_shard_r:
             self._child_idxs = [
                 np.asarray([i for i, kk in enumerate(self.k_by_rule)
                             if kk == k], dtype=np.int64)
@@ -192,7 +190,6 @@ class GrepProgram:
             self._np = None
             self._jit = None
             self._mat_lock = threading.Lock()
-            self._sharded_cache = {}
             self._mesh_cache = {}
             return
 
@@ -232,7 +229,6 @@ class GrepProgram:
         self.max_states = max(d.n_states for d in self.dfas)
         self._jit = None
         self._mat_lock = threading.Lock()
-        self._sharded_cache: dict = {}
         self._mesh_cache: dict = {}
 
     def _resolve_kernel(self) -> str:
@@ -258,10 +254,10 @@ class GrepProgram:
         after the reduction pass (regex.dfa ShrinkStats), the chosen
         stride k, the compare-adds a byte its classing costs
         (``class_runs``), the k-group layout, and the scan/assoc
-        resolution — what bench's `shrink` stage records and the unlock
-        tests assert against. ``kernel_resolved`` is None until the program
-        materializes on a backend (the resolution is a trace-time
-        decision)."""
+        resolution — what the benchmark's readers and references, the
+        smoke and the unlock tests read. ``kernel_resolved`` is None
+        until the program materializes on a backend (the resolution is
+        a trace-time decision)."""
         rules = []
         for r, d in enumerate(self.dfas):
             st = d.shrink
@@ -272,7 +268,6 @@ class GrepProgram:
                 "s": d.n_states,
                 "c": d.n_classes,
                 "minimized": bool(st.minimized) if st else False,
-                "approx_of": st.approx_of if st else None,
                 "k": self.k_by_rule[r],
                 "class_runs": self._class_runs[r],
             })
@@ -351,7 +346,7 @@ class GrepProgram:
             self._jit = jax.jit(impl)
             self._np = None  # tables now live on device; free host copy
             # the shrink/unlock audit line: S/C before→after, chosen
-            # stride, resolved kernel — what bench + tests assert
+            # stride, resolved kernel
             log.info("grep program materialized: %s", self.decision())
 
     def try_ready(self) -> bool:
@@ -545,68 +540,6 @@ class GrepProgram:
         the backend isn't up yet."""
         return np.asarray(self.dispatch(planes, lengths, first_match))
 
-    # -- multi-device (SPMD over a 1-D device mesh) --
-
-    def sharded_matcher(self, mesh, axis: str = "batch"):
-        """Build the SPMD matcher for ``mesh``: the batch dimension is
-        sharded across devices (the DP axis of SURVEY §2.4 — chunks →
-        fixed-width arrays), the per-rule transition tables replicate, and
-        global per-rule match counts reduce with ``lax.psum`` over ICI
-        (the metrics-reduction contract of BASELINE/SURVEY §2.4).
-
-        Returns ``fn(planes[K, B, L], lengths[K, B]) -> (mask[R, B],
-        counts[R])`` with ``B`` divisible by the mesh size; ``counts`` is
-        the global (all-device) per-rule match total.
-        """
-        from jax import shard_map
-        from jax.sharding import PartitionSpec as P
-
-        self._ensure_materialized()
-
-        def step(planes, lengths):
-            mask = self._impl(planes, lengths)
-            counts = lax.psum(
-                jnp.sum(mask.astype(jnp.int32), axis=1), axis_name=axis
-            )
-            return mask, counts
-
-        step.__name__ = self.program_name("_sharded")
-        return jax.jit(
-            shard_map(
-                step,
-                mesh=mesh,
-                in_specs=(P(None, axis, None), P(None, axis)),
-                out_specs=(P(None, axis), P()),
-            )
-        )
-
-    def match_sharded(self, mesh, batch: np.ndarray, lengths: np.ndarray):
-        """Pad B up to the mesh size and run the SPMD matcher over the
-        staged planes ``batch[K, B, L]``; returns (mask[R, B] numpy,
-        counts[R] numpy, matcher-padded batch size)."""
-        from .mesh import mesh_key, pad_to_devices
-
-        if self._children is not None:
-            masks, counts, bp = [], [], 0
-            for c in self._children:
-                m, ct, bp = c.match_sharded(mesh, batch, lengths)
-                masks.append(m)
-                counts.append(ct)
-            inv = self._inv_perm
-            return (np.concatenate(masks, axis=0)[inv],
-                    np.concatenate(counts, axis=0)[inv], bp)
-
-        B = batch.shape[1]
-        Bp = pad_to_devices(B, mesh.devices.size)
-        batch, lengths = _pad_rows(batch, lengths, Bp)
-        key = mesh_key(mesh)
-        fn = self._sharded_cache.get(key)
-        if fn is None:
-            fn = self.sharded_matcher(mesh, axis=mesh.axis_names[0])
-            self._sharded_cache[key] = fn
-        mask, counts = fn(jnp.asarray(batch), jnp.asarray(lengths))
-        return np.asarray(mask)[:, :B], np.asarray(counts), Bp
-
     # -- explicitly partitioned pjit program (the fbtpu-mesh plane) --
 
     def mesh_variant(self, mesh) -> str:
@@ -619,13 +552,13 @@ class GrepProgram:
         the RULE axis shards instead — each
         device holds 1/n of the tables and matches the full batch
         against its own rules. Gated on the replicated-table footprint
-        crossing ``FBTPU_MESH_TABLE_BUDGET`` (default 64 MiB) or R ≥
+        crossing ``ops.mesh.TABLE_BUDGET`` (64 MiB) or R ≥
         ``FBTPU_MESH_RULE_SHARD_R`` (default 64), and on R dividing the
         mesh evenly (no rule padding — a dead-rule pad row would cost a
         full batch scan)."""
         import os as _os
 
-        from .mesh import replicated_table_bytes
+        from .mesh import TABLE_BUDGET, replicated_table_bytes
 
         if self._children is not None:
             # k-split programs never rule-shard (the split is gated off
@@ -641,10 +574,8 @@ class GrepProgram:
             table_bytes = replicated_table_bytes(self._np)
         else:
             table_bytes = replicated_table_bytes(tbl)
-        budget = int(_os.environ.get("FBTPU_MESH_TABLE_BUDGET",
-                                     str(64 * 1024 * 1024)))
         min_r = int(_os.environ.get("FBTPU_MESH_RULE_SHARD_R", "64"))
-        if table_bytes * n_dev > budget or R >= min_r:
+        if table_bytes * n_dev > TABLE_BUDGET or R >= min_r:
             return "rules"
         return "batch"
 
@@ -664,8 +595,7 @@ class GrepProgram:
         WITHOUT the per-rule match totals: the counts are an O(R·B)
         reduction plus (batch variant) a cross-device ``psum`` — a
         sync point per segment launch — and the filter path never
-        reads them. Only match_mesh/bench/metrics consumers pay for
-        counts."""
+        reads them. Only match_mesh's callers pay for counts."""
         from jax import shard_map
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
@@ -733,9 +663,9 @@ class GrepProgram:
         # donation: arg 1 (batch) and arg 2 (lengths) are per-segment
         # staging buffers; donate exactly the subset whose sharded
         # (shape, dtype) matches an output — jax silently falls back to
-        # a copy (plus a warning) for anything else, which the mesh
-        # bench must never report as donated. Shapes vary per call, so
-        # the donate set is computed from dtypes on a canonical shape:
+        # a copy (plus a warning) for anything else, which donation_info
+        # must never report as donated. Shapes vary per call, so the
+        # donate set is computed from dtypes on a canonical shape:
         # lengths i32 [K, B] ↔ mask i32 [R, B] aliases when the planes
         # are one a rule (K == R); batch u8 never has an aliasable
         # output.
@@ -868,8 +798,8 @@ class GrepProgram:
 
     def donation_info(self, mesh, B: int = 64,
                       donate: str = "auto") -> dict:
-        """Compile-level donation status for the bench RESULT / tier-1
-        donation test: which staged args are declared donated, whether
+        """Compile-level donation status for the tier-1 donation
+        tests: which staged args are declared donated, whether
         the lowered module carries the input→output aliases
         (``tf.aliasing_output``), plus the variant and per-device batch
         share for a B-row segment."""
@@ -951,22 +881,17 @@ first_match_of = jax.jit(grep_first_match) if HAVE_JAX else None
 
 @functools.lru_cache(maxsize=64)
 def _cached_program(patterns: Tuple[str, ...], max_len: int,
-                    minimize: bool,
                     plane_of: Optional[Tuple[int, ...]]) -> "GrepProgram":
     from ..regex.dfa import compile_dfa
 
-    return GrepProgram([compile_dfa(p, minimize=minimize)
-                        for p in patterns], max_len, plane_of=plane_of)
+    return GrepProgram([compile_dfa(p) for p in patterns], max_len,
+                       plane_of=plane_of)
 
 
 def program_for(patterns: Sequence[str], max_len: int = 512,
                 plane_of: Optional[Sequence[int]] = None) -> "GrepProgram":
-    """Compiled-program cache keyed by the pattern tuple, the rule→plane
-    index (and the FBTPU_DFA_MIN toggle — the bench's minimization-off
-    differential must never be served a cached minimized program, or
-    vice versa)."""
-    from ..regex.dfa import minimize_enabled
-
+    """Compiled-program cache keyed by the pattern tuple and the
+    rule→plane index."""
     return _cached_program(
-        tuple(patterns), max_len, minimize_enabled(),
+        tuple(patterns), max_len,
         None if plane_of is None else tuple(int(p) for p in plane_of))

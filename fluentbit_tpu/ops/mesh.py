@@ -22,8 +22,7 @@ Also here:
   same matching ``jax.jit`` itself performs) so donation never degrades
   into the silent "Some donated buffers were not usable" copy fallback,
   and report which aliases actually landed in the lowered HLO
-  (``tf.aliasing_output``) for the bench RESULT and the tier-1
-  donation test.
+  (``tf.aliasing_output``) for the tier-1 donation tests.
 
 Everything degrades gracefully without jax: ``build_mesh`` returns
 None and the callers stay on their host twins.
@@ -47,7 +46,7 @@ except Exception:  # pragma: no cover - jax absent: host twins only
 __all__ = [
     "named_tree_map", "match_partition_rules", "build_mesh", "mesh_key",
     "mesh_info", "pad_to_devices", "aliasable_donations",
-    "donation_report", "replicated_table_bytes",
+    "donation_report", "replicated_table_bytes", "TABLE_BUDGET",
     "AXIS", "PARTITION_RULES", "partition_rules", "rule_spec",
 ]
 
@@ -133,11 +132,16 @@ def rule_spec(key: str, axis: str, name: str):
         f"partition-rule table {key!r} has no rule for leaf {name!r}")
 
 
+#: bytes of tables replicated across a mesh (footprint × devices) above
+#: which a program shards its RULE axis instead of the batch
+TABLE_BUDGET = 64 * 1024 * 1024
+
+
 def replicated_table_bytes(tables) -> int:
     """Total byte footprint of a program's table pytree (numpy dicts
     with possible None leaves, or device-array pytrees) — the number
     the batch-vs-rules partition decision weighs against
-    ``FBTPU_MESH_TABLE_BUDGET``. Centralized here (rather than inline
+    ``TABLE_BUDGET``. Centralized here (rather than inline
     per program) so every plane sizes its replication the same way —
     the fbtpu-shrink pass changes these shapes per DFA, and the mesh
     variant choice must follow the REAL post-reduction footprint."""
@@ -289,7 +293,7 @@ def aliasable_donations(mesh, in_specs: Sequence[tuple],
     """Indices of donatable inputs whose sharded (shape, dtype) exactly
     matches an output's — the subset jax can actually alias. Donating
     anything else is a silent no-op plus a compile-time warning (the
-    "copy fallback" the mesh bench must never hide), so the mesh
+    "copy fallback" ``donation_report`` must never hide), so the mesh
     matcher donates exactly this set.
 
     ``in_specs``/``out_specs``: sequences of

@@ -16,6 +16,13 @@ legacy/AND/OR verdict is applied as vector ops on the mask. Records whose
 field overflows ``tpu_max_record_len`` (or batches smaller than
 ``tpu_batch_records``) resolve on the CPU path with identical semantics.
 Surviving records are re-emitted byte-identical (raw span reuse).
+
+The raw ingest path enters through the one batched hook,
+``process_batch(chunk)``: it picks its engine from what it observes —
+the fused native walk or the native matcher while the device attaches
+and on a CPU backend, ``staged_match`` through the lane (on the mesh
+when one is engaged) once an accelerator is up — and every engine but
+the fused walk ends in one verdict → compaction tail.
 """
 
 from __future__ import annotations
@@ -474,20 +481,6 @@ class GrepFilter(FilterPlugin):
         ConfigMapEntry("tpu_max_record_len", "int", default=512,
                        desc="field byte length staged on device; longer "
                             "values resolve on the CPU fallback"),
-        # fbtpu-shrink approximate mode (DEVICE_PLANE.md "shrink"): run an
-        # over-approximated (smaller) DFA as a first-pass mask on the
-        # raw path and re-check admitted records exactly — output
-        # stays byte-identical; only the hot table shrinks
-        ConfigMapEntry("tpu_approx", "bool", default=False,
-                       desc="approximate first-pass DFA mask + exact "
-                            "recheck (also FBTPU_DFA_APPROX)"),
-        ConfigMapEntry("tpu_approx_states", "int", default=64,
-                       desc="state budget for the approximate DFA "
-                            "(<=64 also unlocks the assoc kernel)"),
-        ConfigMapEntry("tpu_approx_fp_budget", "double", default=0.5,
-                       desc="measured false-positive budget: approx "
-                            "mode self-disables when the mask's "
-                            "measured FP rate exceeds this fraction"),
     ]
 
     def init(self, instance, engine) -> None:
@@ -571,74 +564,13 @@ class GrepFilter(FilterPlugin):
                     log.warning("grep fused filter table build failed; "
                                 "fused raw path disabled", exc_info=True)
                     self._native_filter = None
-        self._init_approx(instance, engine)
         self._report_shrink(instance, engine)
 
-    def _init_approx(self, instance, engine) -> None:
-        """fbtpu-shrink approximate mode (opt-in, default off): build
-        the over-approximated mask tables. Rules whose exact DFA
-        already fits the state budget keep their exact tables in the
-        mask set (mask == exact for them — still sound); if NO rule
-        reduces, the mode stays off (pure overhead)."""
-        import os as _os
-
-        self._approx_tables = None
-        self._approx_info = None
-        self._approx_live = True
-        # measured-FP window counters: bumped from parallel ingest
-        # workers without a lock — increments may race and lose (benign
-        # staleness, same stance as ShardedTimings), the budget trip
-        # only needs the order of magnitude
-        self._approx_seen = 0
-        self._approx_fp = 0
-        from ..regex.dfa import approx_env_states
-
-        env_target = approx_env_states(self.tpu_approx_states)
-        if not (self.tpu_approx or env_target is not None):
-            return
-        if self._native_tables is None:
-            return
-        target = env_target if env_target is not None \
-            else self.tpu_approx_states
-        from .. import native as _native
-        from ..regex.dfa import approx_reduce
-
-        try:
-            reduced = [approx_reduce(r.dfa, target) for r in self.rules]
-            if not any(rd is not None for rd in reduced):
-                log.info("grep approx mode requested but every rule DFA "
-                         "already fits %d states; exact path serves",
-                         target)
-                return
-            self._approx_tables = _native.GrepTables(
-                [(r.ra.head.encode("utf-8"),
-                  rd if rd is not None else r.dfa)
-                 for r, rd in zip(self.rules, reduced)])
-            self._approx_info = [
-                None if rd is None else {
-                    "s_exact": rd.shrink.approx_of,
-                    "s": rd.n_states,
-                    "c": rd.n_classes,
-                    "depth": rd.shrink.approx_depth,
-                }
-                for rd in reduced
-            ]
-            log.info("grep approx mask engaged (target %d states): %s",
-                     target, self._approx_info)
-        except Exception:
-            log.warning("grep approximate-mask build failed; exact "
-                        "path serves", exc_info=True)
-            self._approx_tables = None
-
     def _report_shrink(self, instance, engine) -> None:
-        """fluentbit_grep_shrink_* compile-outcome counters (the
-        runtime admit/recheck/FP counters bump per chunk in
-        _approx_match_raw)."""
+        """fluentbit_grep_shrink_* compile-outcome counters."""
         if engine is None or getattr(engine, "m_shrink_states", None) \
                 is None:
             return
-        # plugin-name label, matching the per-chunk admit/recheck
-        # counters (_approx_match_raw) so one dashboard family reads
         label = (self.name,)
         elim_s = elim_c = 0
         for r in self.rules:
@@ -790,7 +722,7 @@ class GrepFilter(FilterPlugin):
             self._mesh_on = self._mesh is not None
         return self._mesh
 
-    def can_filter_raw(self) -> bool:
+    def can_process_batch(self) -> bool:
         """True when matching can run straight off chunk bytes: native
         scanner present, every rule addresses a simple top-level key,
         and an engine is available — the one-pass C++ DFA (always, once
@@ -806,17 +738,19 @@ class GrepFilter(FilterPlugin):
                  or self._program.try_ready())
         )
 
-    def filter_raw(self, data: bytes, tag: str, engine, n_records=None):
+    def process_batch(self, chunk):
         """Raw chunk-bytes matching → verdict → raw-span compaction.
-        Returns (n_records, new_data) or None to decline (the engine
+        Returns (n_out, new_data) — (n_out, new_data, n_in) where the
+        fused walk counted the input — or None to decline (the engine
         then falls back to the decode path). Byte-identical surviving
         records — the grep contract (grep.c:286-392).
 
-        Engine selection: the jax kernel runs when a non-CPU device is
-        attached (the point of the build); the one-pass C++ DFA twin
-        serves while the device is attaching and whenever jax would run
-        on its own CPU backend (a table-driven C loop beats the
-        sequential lax.scan there by orders of magnitude)."""
+        Engine selection, from the platform and the attach state: the
+        jax kernel runs when a non-CPU device is attached (the point of
+        the build); the one-pass C++ DFA twin serves while the device
+        is attaching and whenever jax would run on its own CPU backend
+        (a table-driven C loop beats the sequential lax.scan there by
+        orders of magnitude)."""
         import time as _time
 
         from .. import native
@@ -824,6 +758,7 @@ class GrepFilter(FilterPlugin):
 
         if not native.available():
             return None
+        data, n_records = chunk.as_bytes(), chunk.n
         tm = self.raw_timings
         # mesh first: when the partitioned pjit plane is engaged
         # (FBTPU_MESH — real multi-chip attach, or forced for the
@@ -834,41 +769,6 @@ class GrepFilter(FilterPlugin):
         use_native = self._native_tables is not None and mesh is None and (
             device.platform() == "cpu" or not self._program.try_ready()
         )
-        if use_native and self._approx_tables is not None \
-                and self._approx_live:
-            # fbtpu-shrink approximate mode: reduced-DFA first-pass
-            # mask, then the EXACT tables re-check only the admitted
-            # records — mask-False is definitive (the reduced machine
-            # over-approximates the language), so the final mask is
-            # exactly the exact chain's and every verdict downstream
-            # is byte-identical
-            t0 = _time.perf_counter()
-            got = self._approx_match_raw(data, engine, n_records)
-            if got is not None:
-                mask, offsets, n = got
-                tm.add("kernel_s", _time.perf_counter() - t0)
-                tm.add("records", n)
-                keep = self.keep_mask(mask)
-                n_keep = int(keep.sum())
-                if n_keep == n:
-                    return (n, data)
-                if n_keep == 0:
-                    return (0, b"")
-                with tm.timed("compact_s", "grep.compact"):
-                    # by design: this compact sits on the host-native
-                    # approx branch (no device launch reachable when
-                    # use_native holds) — no verdict crossed PCIe here
-                    # fbtpu-lint: allow(device-host-roundtrip)
-                    compacted = native.compact(data, offsets[: n + 1],
-                                               keep)
-                if compacted is not None:
-                    return (n_keep, compacted)
-                parts = [
-                    data[offsets[i]: offsets[i + 1]]
-                    for i in np.nonzero(keep)[0]
-                ]
-                return (n_keep, b"".join(parts))
-            # approx mask unavailable this chunk: exact paths serve
         if use_native and self._native_filter is not None:
             # fused path: extraction + prepass DFA + verdict + compaction
             # in ONE native pass; all-kept chunks return the input
@@ -893,15 +793,13 @@ class GrepFilter(FilterPlugin):
             )
             if got is None:
                 return None
-            mask, offsets, n = got
             tm.add("kernel_s", _time.perf_counter() - t0)
         else:
-            if n_records is not None and n_records < self.tpu_batch_records:
-                return None  # small batches: decode path is cheaper
+            # (declines under tpu_batch_records: decode is cheaper there)
             got = self._jax_match_raw(data, n_records, mesh=mesh)
             if got is None:
                 return None
-            mask, offsets, n = got
+        mask, offsets, n = got
         tm.add("records", n)
         keep = self.keep_mask(mask)
         n_keep = int(keep.sum())
@@ -918,86 +816,6 @@ class GrepFilter(FilterPlugin):
             for i in np.nonzero(keep)[0]
         ]
         return (n_keep, b"".join(parts))
-
-    def _approx_match_raw(self, data, engine, n_hint=None):
-        """Approximate mask → exact recheck over chunk bytes.
-
-        Returns the EXACT per-rule match matrix (mask[R, n] bool),
-        offsets and n — or None to fall back to the plain exact paths.
-        Soundness: the reduced DFAs over-approximate their rules'
-        languages (regex.dfa.approx_reduce), so a record the mask
-        rejects for rule r cannot match rule r exactly; only
-        mask-admitted records pay the exact walk. The measured FP rate
-        (admitted-but-exact-false) is tracked against
-        ``tpu_approx_fp_budget``: a mask that stopped paying for
-        itself self-disables instead of taxing every chunk."""
-        from .. import native
-
-        got = native.grep_match(
-            data, self._local_tables("_approx_tables"), n_hint=n_hint)
-        if got is None:
-            return None
-        amask, offsets, n = got
-        union = amask.any(axis=0)
-        n_adm = int(union.sum())
-        mask = np.zeros(amask.shape, dtype=bool)
-        n_true = 0
-        if n_adm == n:
-            # mask admitted everything: recheck the whole chunk via
-            # the plain exact tables (no compaction detour)
-            got2 = native.grep_match(
-                data, self._local_tables("_native_tables"), n_hint=n)
-            if got2 is None:
-                return None
-            mask = got2[0]
-            n_true = int(mask.any(axis=0).sum())
-        elif n_adm:
-            # by design: the approx-mask exact-recheck gather runs
-            # entirely on host bytes (use_native implies no device
-            # launch this chunk) — compacting the admitted records is
-            # what makes the reduced DFA pay for itself
-            # fbtpu-lint: allow(device-host-roundtrip)
-            sub = native.compact(data, offsets[: n + 1], union)
-            if sub is None:
-                idx0 = np.nonzero(union)[0]
-                sub = b"".join(data[offsets[i]: offsets[i + 1]]
-                               for i in idx0)
-            got2 = native.grep_match(
-                sub, self._local_tables("_native_tables"), n_hint=n_adm)
-            if got2 is None or got2[2] != n_adm:
-                return None
-            emask = got2[0]
-            mask[:, np.nonzero(union)[0]] = emask
-            n_true = int(emask.any(axis=0).sum())
-        # lock-free window counters (benign-staleness, see _init_approx)
-        self._approx_seen += n
-        self._approx_fp += n_adm - n_true
-        if engine is not None and getattr(
-                engine, "m_shrink_approx_admits", None) is not None:
-            label = (self.name,)
-            # admits are per (rule, record) — mask selectivity;
-            # rechecks are per record (the union the exact walk pays)
-            engine.m_shrink_approx_admits.inc(int(amask.sum()), label)
-            engine.m_shrink_approx_rechecks.inc(n_adm, label)
-            engine.m_shrink_approx_fp.inc(n_adm - n_true, label)
-        if self._approx_seen >= 8192:
-            fp_rate = self._approx_fp / max(self._approx_seen, 1)
-            if fp_rate > self.tpu_approx_fp_budget:
-                self._approx_live = False
-                log.warning(
-                    "grep approx mask disabled: measured FP rate %.3f "
-                    "exceeds tpu_approx_fp_budget %.3f (over %d "
-                    "records)", fp_rate, self.tpu_approx_fp_budget,
-                    self._approx_seen)
-                if engine is not None and getattr(
-                        engine, "m_shrink_approx_disabled", None) \
-                        is not None:
-                    engine.m_shrink_approx_disabled.inc(1, (self.name,))
-            else:
-                # rolling window: decay instead of one-shot judgement
-                self._approx_seen //= 2
-                self._approx_fp //= 2
-        return mask, offsets, n
 
     def _local_tables(self, attr: str):
         """This thread's private copy of a packed native table set (the

@@ -80,12 +80,6 @@ class ParserFilter(FilterPlugin):
                        desc="device match prefilter when the parser allows"),
         ConfigMapEntry("tpu_batch_records", "int", default=64),
         ConfigMapEntry("tpu_max_record_len", "int", default=512),
-        ConfigMapEntry("tpu_approx", "bool", default=False,
-                       desc="approximate (reduced) DFA for the batched "
-                            "match mask; the Python regex recheck "
-                            "keeps output byte-identical (also "
-                            "FBTPU_DFA_APPROX)"),
-        ConfigMapEntry("tpu_approx_states", "int", default=64),
     ]
 
     def init(self, instance, engine) -> None:
@@ -151,33 +145,8 @@ class ParserFilter(FilterPlugin):
 
                 if _native.available():
                     try:
-                        # fbtpu-shrink approximate mode: the batched
-                        # mask is ALREADY a mask→exact-recheck shape
-                        # (the Python regex with captures runs only on
-                        # admitted records, and a failed parse passes
-                        # the record through untouched — identical to
-                        # a mask miss), so an over-approximated mask
-                        # DFA is a drop-in: smaller hot table, byte-
-                        # identical output
-                        mask_dfa = p0.regex.dfa
-                        from ..regex.dfa import (approx_env_states,
-                                                 approx_reduce)
-
-                        env_target = approx_env_states(
-                            self.tpu_approx_states)
-                        if self.tpu_approx or env_target is not None:
-                            target = env_target if env_target is not None \
-                                else self.tpu_approx_states
-                            reduced = approx_reduce(mask_dfa, target)
-                            if reduced is not None:
-                                log.info(
-                                    "parser approx mask: S %d -> %d "
-                                    "(depth %d)", mask_dfa.n_states,
-                                    reduced.n_states,
-                                    reduced.shrink.approx_depth)
-                                mask_dfa = reduced
                         self._batch_tables = _native.GrepTables(
-                            [(key, mask_dfa)])
+                            [(key, p0.regex.dfa)])
                         self._batch_mode = "regex"
                         self._batch_key = key
                     except Exception:

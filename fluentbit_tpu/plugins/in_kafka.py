@@ -174,7 +174,7 @@ class KafkaInput(InputPlugin):
         # another member may have committed a VALID offset since, and a
         # stale marker would bypass OffsetFetch on reassignment and
         # reset the partition to latest/earliest (skipping or
-        # duplicating records — ADVICE.md low)
+        # duplicating records — round-5 advisor low)
         self._oor.clear()
         # fresh session: a stale pre-outage timestamp would turn the
         # FIRST transient heartbeat failure after rejoin into another

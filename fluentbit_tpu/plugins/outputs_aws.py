@@ -121,7 +121,7 @@ class S3Output(OutputPlugin):
                     "s3: upload_chunk_size cannot exceed total_file_size")
         self._fstore = FStore(self.store_dir)
         self._stream = self._fstore.stream(f"s3-{instance.name}")
-        # staging idempotence across RETRY redelivery (ADVICE.md): the
+        # staging idempotence across RETRY redelivery (round-5 advisor): the
         # engine redelivers the SAME chunk bytes after a failed part
         # upload / complete. A per-tag sidecar in its OWN stream
         # carries {digest: staged-at} for every staged-but-unacked
@@ -252,7 +252,7 @@ class S3Output(OutputPlugin):
                 _fp.fire("s3.complete")
             except _fp.FailpointError:
                 # parts uploaded, completion lost: redelivery follows —
-                # the ADVICE.md duplication window in its pure form
+                # the round-5 advisor's duplication window in its pure form
                 return False
         xml = ["<CompleteMultipartUpload>"]
         for p in parts:

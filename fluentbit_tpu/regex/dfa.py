@@ -26,7 +26,6 @@ DFA state ids: 0 = DEAD (absorbing reject), 1 = ACC (absorbing accept),
 
 from __future__ import annotations
 
-import os
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, FrozenSet, List, Optional, Tuple
@@ -151,23 +150,18 @@ def _build(nfa: _NFA, node: Node, start: int) -> int:
 @dataclass(frozen=True)
 class ShrinkStats:
     """What the compile-path reduction pass did to this DFA (the
-    fbtpu-shrink audit trail GrepProgram/GrepTables/bench report).
+    fbtpu-shrink audit trail GrepProgram/GrepTables report).
 
     ``s_raw``/``c_raw`` are the subset-construction shape, ``s``/``c``
     the shipped table's. ``minimized`` False means the pass was
-    explicitly disabled (``FBTPU_DFA_MIN=0`` / ``minimize=False`` — the
-    bench differential and the property tests' unminimized oracle).
-    ``approx_of``/``approx_depth`` are set only on approximate
-    reductions (:func:`approx_reduce`): the exact machine's state count
-    and the prefix depth the collapse kept."""
+    explicitly disabled (``minimize=False`` — the property tests'
+    unminimized oracle and ``analysis/shrink.py``'s rule)."""
 
     s_raw: int
     c_raw: int
     s: int
     c: int
     minimized: bool
-    approx_of: Optional[int] = None
-    approx_depth: Optional[int] = None
 
     @property
     def states_eliminated(self) -> int:
@@ -278,8 +272,7 @@ def _moore_minimize(trans: np.ndarray, start: int) -> Tuple[np.ndarray, int]:
 
     Kept as the independent minimality ORACLE the property tests check
     Hopcroft against (two implementations of the coarsest congruence
-    must agree on the block count), and as the reducer approx_reduce's
-    search loop calls where the collapsed machines are already tiny."""
+    must agree on the block count)."""
     S, C = trans.shape
     # initial partition: accepting (ACC) vs rest
     part = np.zeros(S, dtype=np.int64)
@@ -371,9 +364,8 @@ def _hopcroft_minimize(trans: np.ndarray, start: int
 def _prune_unreachable(trans: np.ndarray, start: int
                        ) -> Tuple[np.ndarray, int]:
     """Drop states unreachable from {start, DEAD, ACC} (dead-state
-    pruning). Subset construction never emits them, but the approximate
-    collapse does — a state whose every predecessor was redirected to
-    ACC would otherwise survive minimization as its own block."""
+    pruning): such a state would otherwise survive minimization as its
+    own block."""
     S, C = trans.shape
     reach = np.zeros(S, dtype=bool)
     reach[[DEAD, ACC, start]] = True
@@ -427,106 +419,9 @@ def _shrink_tables(trans: np.ndarray, start: int, class_map: np.ndarray
     return trans, start, class_map, n_classes
 
 
-def minimize_enabled() -> bool:
-    """The FBTPU_DFA_MIN kill switch (default on). Exists for the
-    bench's minimization-on/off differential and for pinning the
-    unminimized oracle in tests — production paths never set it."""
-    return os.environ.get("FBTPU_DFA_MIN", "1").lower() not in (
-        "0", "off", "false")
-
-
-def approx_env_states(default: int = 64) -> Optional[int]:
-    """Parse the ``FBTPU_DFA_APPROX`` opt-in: unset/``0``/``off`` →
-    None (approximate mode stays off — the default), a bare truthy
-    value (``1``/``on``) → the caller's default state target, an
-    integer > 1 → that state target."""
-    v = os.environ.get("FBTPU_DFA_APPROX", "").strip().lower()
-    if v in ("", "0", "off", "false"):
-        return None
-    try:
-        n = int(v)
-        return n if n > 1 else default
-    except ValueError:
-        return default
-
-
-def approx_reduce(dfa: DFA, max_states: int = 64) -> Optional[DFA]:
-    """Over-approximate reduction (arXiv 1710.08647's self-loop/collapse
-    shape): states deeper than a prefix depth d collapse into the
-    absorbing ACC, then the collapsed machine is pruned, exact-minimized
-    and class-remerged. Every transition is redirected *toward* accept
-    and never away, so L(exact) ⊆ L(approx) — a False from the reduced
-    machine is definitive, which is what makes it sound as a first-pass
-    mask in front of an exact recheck (the filter_parser(regex)
-    mask→recheck shape).
-
-    Binary-searches the largest d whose reduced machine fits
-    ``max_states`` (more prefix retained = fewer false admits). Returns
-    None when the exact DFA already fits (approximation would only add
-    false positives) or when even d=1 cannot fit the budget."""
-    if dfa.n_states <= max_states:
-        return None
-    trans = dfa.trans
-    S, C = trans.shape
-    # BFS depth from start over the byte/EOL classes
-    depth = np.full(S, np.iinfo(np.int64).max, dtype=np.int64)
-    depth[dfa.start] = 0
-    frontier = np.asarray([dfa.start], dtype=np.int64)
-    d = 0
-    while frontier.size:
-        d += 1
-        nxt = np.unique(trans[frontier].reshape(-1))
-        frontier = nxt[depth[nxt] > d]
-        depth[frontier] = d
-    max_depth = int(depth[depth < np.iinfo(np.int64).max].max())
-
-    def collapse(dcap: int):
-        part = np.arange(S, dtype=np.int64)
-        deep = depth > dcap
-        deep[[DEAD, ACC]] = False  # DEAD→ACC would admit everything
-        part[deep] = ACC
-        t = part[trans].astype(np.int32)
-        st = int(part[dfa.start])
-        t, st = _prune_unreachable(t, st)
-        t, st = _moore_minimize(t, st)  # collapsed machines are tiny
-        t, cmap, n_cls = _remerge_classes(t, dfa.class_map)
-        return t, st, cmap, n_cls
-
-    lo, hi, best = 1, max_depth, None
-    while lo <= hi:
-        mid = (lo + hi) // 2
-        t, st, cmap, n_cls = collapse(mid)
-        if t.shape[0] <= max_states:
-            best = (mid, t, st, cmap, n_cls)
-            lo = mid + 1
-        else:
-            hi = mid - 1
-    if best is None:
-        return None
-    dcap, t, st, cmap, n_cls = best
-    base = dfa.shrink
-    return DFA(
-        trans=t,
-        class_map=cmap,
-        start=st,
-        n_states=t.shape[0],
-        n_classes=n_cls,
-        pattern=dfa.pattern,
-        shrink=ShrinkStats(
-            s_raw=base.s_raw if base else dfa.n_states,
-            c_raw=base.c_raw if base else dfa.n_classes,
-            s=t.shape[0],
-            c=n_cls,
-            minimized=True,
-            approx_of=dfa.n_states,
-            approx_depth=dcap,
-        ),
-    )
-
-
 def compile_dfa(pattern, ignorecase: bool = False, dot_all: bool = False,
                 max_states: int = 4096,
-                minimize: Optional[bool] = None) -> DFA:
+                minimize: bool = True) -> DFA:
     """Compile a pattern (str or ParsedRegex) to a scan DFA.
 
     Raises UnsupportedRegex for non-DFA-expressible constructs; callers
@@ -534,10 +429,9 @@ def compile_dfa(pattern, ignorecase: bool = False, dot_all: bool = False,
 
     Every DFA leaving here has passed the fbtpu-shrink reduction pass —
     unreachable-state pruning, Hopcroft minimization, byte-class
-    remerging — unless ``minimize=False`` (or ``FBTPU_DFA_MIN=0``)
-    explicitly pins the raw subset table for a differential (bench's
-    on/off stage, the property tests' oracle). The language is
-    unchanged either way; only table shape differs.
+    remerging — unless ``minimize=False`` explicitly pins the raw
+    subset table for a differential (the property tests' oracle). The
+    language is unchanged either way; only table shape differs.
     """
     if isinstance(pattern, ParsedRegex):
         parsed = pattern
@@ -678,8 +572,6 @@ def compile_dfa(pattern, ignorecase: bool = False, dot_all: bool = False,
     trans = np.asarray(table, dtype=np.int32)
     class_map = sym_class[:257].astype(np.uint8)
     s_raw, c_raw = trans.shape[0], n_classes
-    if minimize is None:
-        minimize = minimize_enabled()
     if minimize:
         trans, start_id, class_map, n_classes = _shrink_tables(
             trans, start_id, class_map)

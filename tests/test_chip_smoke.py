@@ -1,10 +1,9 @@
 """chip_smoke.py and the launchers around it, from the no-chip side.
 
 What only the chip can show (``ok: true``) is shown there; here: the
-script refuses a CPU at once and alone in a directory, ``bench.py``
-prints no rate without a device, the compile cache goes where it is
-told, and no launcher's parent process initialises a backend that a
-child would need.
+script refuses a CPU at once and alone in a directory, the compile
+cache goes where it is told, and no launcher's parent process
+initialises a backend that a child would need.
 """
 
 import json
@@ -57,21 +56,12 @@ def test_chip_smoke_sets_no_platform_and_no_flags():
         assert name not in src.split('"""', 2)[2], name
 
 
-def test_bench_without_a_chip_exits_nonzero_and_prints_no_rate():
-    proc, _ = _run([os.path.join(REPO, "bench.py")])
-    assert proc.returncode != 0
-    assert "grep_ingest_lines_per_sec" not in proc.stdout
-    assert "lines_per_sec" not in proc.stdout
-    assert '"platform": "cpu"' in proc.stdout  # it says what it found
-
-
 def test_launcher_parents_never_initialise_a_backend():
     """A chip belongs to one process: the supervisor forks the worker,
-    bench.py spawns its children — neither parent may have touched a
-    backend by then (importing jax is fine, initialising is not)."""
+    and must not have touched a backend by then (importing jax is fine,
+    initialising is not)."""
     code = (
         "import sys\n"
-        "import bench\n"
         "import fluentbit_tpu.__main__, fluentbit_tpu.supervisor\n"
         "from fluentbit_tpu.__main__ import main\n"
         "assert main(['--supervisor', '--help']) in (0, 1)\n"

@@ -175,7 +175,7 @@ pipeline:
 
 
 def test_rejected_object_never_mapped(tmp_path):
-    """ADVICE.md: objects without a registration export must be
+    """round-5 advisor: objects without a registration export must be
     rejected BEFORE dlopen — their constructors must never run. The
     probe reads the ELF dynsym instead of loading the object."""
     import subprocess

@@ -105,7 +105,7 @@ def test_input_collect_and_cleanup(proxy_so):
 
 def test_api_table_matches_flb_api_header_layout(proxy_so, tmp_path,
                                                  monkeypatch):
-    """ADVICE.md (high): struct flb_api's custom_* entries sit at the
+    """round-5 advisor (high): struct flb_api's custom_* entries sit at the
     END (flb_api.h 'preserve ABI' comment). The demo output reads a
     property through custom_get_property (last pointer block) and calls
     output_log_check (slot 6) — a host table in flb_api.c assignment

@@ -130,7 +130,9 @@ def test_staged_multi_key_rules_raw_path():
             body.pop("log")
         bodies.append(body)
         buf += encode_event(body, float(i))
-    got = f.filter_raw(bytes(buf), "t", None, n_records=len(bodies))
+    from fluentbit_tpu.core.chunk_batch import RawChunk
+
+    got = f.process_batch(RawChunk(bytes(buf), "t", len(bodies)))
     assert got is not None
     n_keep, out = got
     kept = decode_events(bytes(out))
@@ -140,6 +142,61 @@ def test_staged_multi_key_rules_raw_path():
     # sanity: the expectation itself must depend on BOTH fields
     assert any(b.get("stream") == "stderr" for b in bodies)
     assert 0 < len(expected) < len(bodies)
+
+
+#: rule sets by ``logical_op`` (AND/OR take one kind of rule only)
+_BY_OP = {
+    "legacy": [("exclude", "log 404"), ("regex", "log ^(GET|POST)")],
+    "AND": [("regex", "log GET"), ("regex", "log 200$"),
+            ("logical_op", "AND")],
+    "OR": [("exclude", "log ^DELETE"), ("exclude", "log 500$"),
+           ("logical_op", "OR")],
+}
+
+
+@pytest.mark.parametrize("op", list(_BY_OP))
+@pytest.mark.parametrize("engine", ["fused", "native", "device"])
+def test_grep_process_batch_engines(engine, op):
+    """Every engine the raw hook chooses from — the fused native walk,
+    the native matcher, the staged device launch through the lane (on
+    the CPU backend here) — ends in the per-record chain's bytes, over
+    kept, dropped, missing-key and overflow rows."""
+    from fluentbit_tpu import native
+    from fluentbit_tpu.core.chunk_batch import RawChunk
+
+    if not native.available():
+        pytest.skip("native unavailable")
+    props = _BY_OP[op] + [("tpu_batch_records", "1"),
+                          ("tpu_max_record_len", "64")]
+    f = make_filter(props)
+    if f._program is None:
+        pytest.skip("device program unavailable")
+    if engine != "fused":
+        f._native_filter = None
+    if engine == "device":
+        if not f._program.try_ready():
+            pytest.skip("device program unavailable")
+        f._native_tables = None
+    events = make_events(300, seed=11, long_every=17)
+    n_long = len(range(0, 300, 17))
+    f_cpu = make_filter(props + [("tpu.enable", "off")])
+    _, kept = f_cpu.filter(list(events), "t", None)
+    assert 0 < len(kept) < len(events)
+    # an overflow row is decided both ways across the rule sets
+    assert any(len(e.body.get("log", "")) > 64 for e in kept) \
+        == (op != "legacy")
+    assert f.can_process_batch()
+    got = f.process_batch(
+        RawChunk(b"".join(e.raw for e in events), "t", None))
+    # the triple only where the fused walk counted its input
+    assert got[2:] == ((len(events),) if engine == "fused" else ())
+    assert got[0] == len(kept)
+    assert bytes(got[1]) == b"".join(e.raw for e in kept)
+    tm = f.raw_timings
+    assert tm["records"] == len(events)
+    on_device = engine == "device"
+    assert tm["device_records"] == (len(events) if on_device else 0)
+    assert tm["overflow_rows"] == (n_long if on_device else 0)
 
 
 def test_non_string_values_never_match():

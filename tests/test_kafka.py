@@ -700,7 +700,7 @@ def test_in_kafka_oor_partitions_bypass_offset_fetch():
     assert p._uncommitted
 
 def test_in_kafka_group_reset_clears_oor_markers():
-    """ADVICE.md (low): a rebalance (group reset) must clear
+    """round-5 advisor (low): a rebalance (group reset) must clear
     OFFSET_OUT_OF_RANGE markers — another member may have committed a
     valid offset since, so post-rebalance resolution for the partition
     must go through OffsetFetch again, not be reset to latest."""

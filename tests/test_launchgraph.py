@@ -54,7 +54,8 @@ def by_rule(findings, rule):
 
 BAD_MULTI_LAUNCH = """
 class F:
-    def filter_raw(self, data, tag, engine, n_records=None):
+    def process_batch(self, chunk):
+        data, n_records = chunk.data, chunk.n
         lane = self._lane()
         mask = lane.run(
             lambda: self._program.dispatch_mesh(self._mesh, data,
@@ -71,7 +72,8 @@ class F:
 
 GOOD_SINGLE_LAUNCH = """
 class F:
-    def filter_raw(self, data, tag, engine, n_records=None):
+    def process_batch(self, chunk):
+        data, n_records = chunk.data, chunk.n
         lane = self._lane()
         return lane.run(
             lambda: self._program.dispatch_mesh(self._mesh, data,
@@ -99,7 +101,8 @@ def test_multi_launch_interprocedural():
     # through self-method edges to find it
     src = """
 class F:
-    def filter_raw(self, data, tag, engine, n_records=None):
+    def process_batch(self, chunk):
+        data, n_records = chunk.data, chunk.n
         mask = self._match(data, n_records)
         return self._sketch(mask)
 
@@ -126,7 +129,8 @@ def test_multi_launch_branches_take_max_not_sum():
     # chain; a branch that returns must not chain into the fallthrough
     src = """
 class F:
-    def filter_raw(self, data, tag, engine, n_records=None):
+    def process_batch(self, chunk):
+        data, n_records = chunk.data, chunk.n
         lane = self._lane()
         if self._mesh is not None:
             return lane.run(
@@ -145,9 +149,9 @@ class F:
 
 def test_multi_launch_suppression():
     src = BAD_MULTI_LAUNCH.replace(
-        "    def filter_raw(self, data, tag, engine, n_records=None):",
+        "    def process_batch(self, chunk):",
         "    # fbtpu-lint: allow(device-multi-launch-chain)\n"
-        "    def filter_raw(self, data, tag, engine, n_records=None):")
+        "    def process_batch(self, chunk):")
     got = lint_source(src, _FIX)
     assert "device-multi-launch-chain" not in rules(got)
 
@@ -158,7 +162,8 @@ def test_multi_launch_suppression():
 
 BAD_DONATE_OFF = """
 class F:
-    def filter_raw(self, data, tag, engine, n_records=None):
+    def process_batch(self, chunk):
+        data, n_records = chunk.data, chunk.n
         lane = self._lane()
         return lane.run(
             lambda: self._program.dispatch_mesh(self._mesh, data,
@@ -202,7 +207,8 @@ def test_undonated_suppression():
 
 BAD_ROUNDTRIP = """
 class F:
-    def filter_raw(self, data, tag, engine, n_records=None):
+    def process_batch(self, chunk):
+        data, n_records = chunk.data, chunk.n
         lane = self._lane()
         mask = lane.run(
             lambda: self._program.dispatch_mesh(self._mesh, data,
@@ -215,7 +221,8 @@ class F:
 
 GOOD_MASK_ONLY = """
 class F:
-    def filter_raw(self, data, tag, engine, n_records=None):
+    def process_batch(self, chunk):
+        data, n_records = chunk.data, chunk.n
         lane = self._lane()
         mask = lane.run(
             lambda: self._program.dispatch_mesh(self._mesh, data,
@@ -243,7 +250,8 @@ def test_host_roundtrip_quiet_without_launch():
     # compact on a host-computed mask is not a PCIe roundtrip
     src = """
 class F:
-    def filter_raw(self, data, tag, engine, n_records=None):
+    def process_batch(self, chunk):
+        data, n_records = chunk.data, chunk.n
         mask = self._host(data)
         keep, n_kept = native.compact(data, mask)
         return keep
@@ -267,7 +275,8 @@ BAD_SYNC_IN_LOOP = """
 import numpy as np
 
 class F:
-    def filter_raw(self, data, tag, engine, n_records=None):
+    def process_batch(self, chunk):
+        data, n_records = chunk.data, chunk.n
         lane = self._lane()
         out = []
         for lo, hi in segment_bounds(n_records, 4096):
@@ -283,7 +292,8 @@ GOOD_FORCE_AFTER_LOOP = """
 import numpy as np
 
 class F:
-    def filter_raw(self, data, tag, engine, n_records=None):
+    def process_batch(self, chunk):
+        data, n_records = chunk.data, chunk.n
         lane = self._lane()
         flights = []
         for lo, hi in segment_bounds(n_records, 4096):
@@ -412,15 +422,14 @@ def graph():
 
 
 def test_shipped_grep_chain(graph):
-    ch = _chain(graph, "filter_grep.py::GrepFilter.filter_raw")
+    ch = _chain(graph, "filter_grep.py::GrepFilter.process_batch")
     assert ch["launches_per_segment"] == 1
     assert ch["staged"] is True
     assert ch["sync_hits"] == []          # overlap intact
     (site,) = [s for s in ch["sites"] if s["kind"] == "grep-mesh"]
     assert site["lane"] is True           # armor-guarded
-    # the exact-path compact is the one true roundtrip; the two
-    # approx-branch compacts are suppressed in source, not counted out
-    assert ch["scatter_passes"] == 3
+    # the one verdict -> compaction tail is the one true roundtrip
+    assert ch["scatter_passes"] == 1
 
 
 def test_shipped_flux_chain(graph):
@@ -463,7 +472,7 @@ def test_shipped_host_only_entries(graph):
 def test_shipped_transfer_budget_numbers(graph):
     env = canonical_env()
     assert env["Bp"] == 4096 and env["R"] == 2 and env["L"] == 512
-    grep = _chain(graph, "GrepFilter.filter_raw")["transfers"]
+    grep = _chain(graph, "GrepFilter.process_batch")["transfers"]
     # batch u8 [R,Bp,L] un-donated + lengths i32 [R,Bp] aliased
     assert grep["undonated_h2d_bytes_canonical"] == \
         env["R"] * env["Bp"] * env["L"]
@@ -572,7 +581,7 @@ def test_cli_graph_json():
     proc = _cli("--graph", "json")
     assert proc.returncode == 0, proc.stdout + proc.stderr
     data = json.loads(proc.stdout)
-    assert "GrepFilter.filter_raw" in "".join(data["chains"])
+    assert "GrepFilter.process_batch" in "".join(data["chains"])
     assert data["budget_regressions"] == []
     assert data["budget"] == _committed()["budget"]
 
@@ -672,21 +681,32 @@ def test_static_matches_dynamic_grep_chain(graph, monkeypatch):
     jax = pytest.importorskip("jax")
     if len(jax.devices()) < 2:
         pytest.skip("need a multi-device mesh")
-    static = _chain(graph, "GrepFilter.filter_raw")[
+    static = _chain(graph, "GrepFilter.process_batch")[
         "launches_per_segment"]
+    from fluentbit_tpu.ops import device, fault
+
     monkeypatch.setenv("FBTPU_MESH", "1")
     monkeypatch.setenv("FBTPU_SEGMENT_RECORDS", "128")
     n, seg = 700, 128
     n_segments = -(-n // seg)
-    e, ins = _grep_engine()
-    before = _lane_launches("grep")
-    e.input_log_append(ins, "bench", _log_chunk(n))
-    ins.pool.drain()
-    assert e.filters[0].plugin._mesh is not None  # lane engaged
-    observed = _lane_launches("grep") - before
+    # the "grep" lane is process-global: whatever breaker or shrunken
+    # mesh another file of this worker left on it is not this test's
+    fault.reset()
+    try:
+        e, ins = _grep_engine()
+        chunk = _log_chunk(n)
+        before = fault.snapshot().get("grep", {"launches": 0})
+        e.input_log_append(ins, "bench", chunk)
+        after = fault.snapshot().get("grep")
+        ins.pool.drain()
+    finally:
+        fault.reset()
+    assert e.filters[0].plugin._mesh is not None, (  # lane engaged
+        device.status(), after)
+    observed = after["launches"] - before["launches"]
     assert observed == n_segments * static, (
         f"analyzer says {static} launch(es)/segment × {n_segments} "
-        f"segments, the lane counted {observed}")
+        f"segments, the lane counted {observed}: {before} -> {after}")
 
 
 @pytest.mark.mesh

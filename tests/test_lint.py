@@ -1160,19 +1160,19 @@ _DEV_PATH = "fluentbit_tpu/plugins/filter_fixture.py"
 
 BAD_UNGUARDED_DISPATCH = """
 class F:
-    def filter_raw(self, data, tag, engine, n_records=None):
-        mask = self._program.dispatch_mesh(self._mesh, data, n_records)
+    def process_batch(self, chunk):
+        mask = self._program.dispatch_mesh(self._mesh, chunk.data, chunk.n)
         return mask
 """
 
 GOOD_GUARDED_DISPATCH = """
 class F:
-    def filter_raw(self, data, tag, engine, n_records=None):
+    def process_batch(self, chunk):
         lane = self._lane()
         return lane.run(
-            lambda: self._program.dispatch_mesh(self._mesh, data,
-                                                n_records),
-            lambda: self._host_mask(data, n_records),
+            lambda: self._program.dispatch_mesh(self._mesh, chunk.data,
+                                                chunk.n),
+            lambda: self._host_mask(chunk.data, chunk.n),
         )
 """
 
@@ -1241,9 +1241,9 @@ def test_unguarded_dispatch_scope_and_suppression():
     assert lint_source(BAD_UNGUARDED_DISPATCH,
                        "fluentbit_tpu/ops/fixture.py") == []
     suppressed = BAD_UNGUARDED_DISPATCH.replace(
-        "def filter_raw(self, data, tag, engine, n_records=None):",
-        "def filter_raw(self, data, tag, engine, n_records=None):  "
-        "# fbtpu-lint: allow(device-unguarded-dispatch) bench-only "
+        "def process_batch(self, chunk):",
+        "def process_batch(self, chunk):  "
+        "# fbtpu-lint: allow(device-unguarded-dispatch) "
         "diagnostic path, raw failure wanted")
     # (the launch-graph pack's structural undonated-buffer warning on
     # the bare dispatch_mesh site is a different rule and stays)
@@ -1376,3 +1376,39 @@ def test_shipped_kernel_paths_use_minimized_dfas():
     for mod in (og, fg, fp):
         assert "grep-unminimized-dfa" not in rules(
             lint_paths([mod.__file__])), mod.__name__
+
+
+# ---------------------------------------------------------------------
+# the census of environment switches (ROADMAP D4)
+# ---------------------------------------------------------------------
+
+#: every ``FBTPU_*`` name the package reads or documents. A new one is
+#: an option: justify it by two callers that need different values, or
+#: derive the value from what the program observes (ROADMAP D4); one
+#: that goes is deleted here with its last use.
+FBTPU_NAMES = frozenset("""
+FBTPU_ACCEL FBTPU_ATTACH_BACKOFF_S FBTPU_ATTACH_RETRIES
+FBTPU_ATTACH_WAIT_S FBTPU_COPY_WITNESS FBTPU_DEVICE_BREAKER_COOLDOWN
+FBTPU_DEVICE_BREAKER_FAILURES FBTPU_DEVICE_REGROW_AFTER
+FBTPU_DSO_API_PROBE FBTPU_FAILPOINTS FBTPU_FAILPOINTS_HTTP
+FBTPU_FAILPOINTS_SEED FBTPU_FLUX_MESH FBTPU_FLUX_SQL FBTPU_K4_BUDGET
+FBTPU_KTABLE_BUDGET FBTPU_LAUNCH_DEADLINE_S FBTPU_LOCK_WITNESS
+FBTPU_MESH FBTPU_MESH_RULE_SHARD_R FBTPU_NO_NATIVE FBTPU_NO_SIDECAR
+FBTPU_PLUGIN_ABI_VERSION FBTPU_SEGMENT_RECORDS FBTPU_STAGE_THREADS
+""".split())
+
+
+def test_environment_switch_census():
+    import re
+
+    seen = set()
+    for root, dirs, files in os.walk(os.path.join(REPO, "fluentbit_tpu")):
+        dirs[:] = [d for d in dirs if d not in ("__pycache__", "build")]
+        for name in files:
+            if name.endswith((".pyc", ".so", ".o")):
+                continue
+            with open(os.path.join(root, name), errors="ignore") as f:
+                seen.update(re.findall(r"FBTPU_[A-Z0-9_]+", f.read()))
+    assert len(FBTPU_NAMES) == 25
+    assert seen == FBTPU_NAMES, (sorted(seen - FBTPU_NAMES),
+                                 sorted(FBTPU_NAMES - seen))

@@ -124,7 +124,7 @@ def test_raw_path_declines_for_nested_accessor():
     for x in e.inputs + e.filters:
         x.configure()
         x.plugin.init(x, e)
-    assert not e.filters[0].plugin.can_filter_raw()
+    assert not e.filters[0].plugin.can_process_batch()
     buf = encode_event({"k": {"a": "x"}}, 1.0)
     assert e.input_log_append(ins, "t", buf) == 1
 

@@ -162,7 +162,7 @@ def test_assoc_kernel_sharded_matches_single_device():
     prog = GrepProgram(dfas, max_len=128, kernel="assoc", segment=8)
     devs = jax.devices()
     mesh = Mesh(np.asarray(devs[:8]), ("batch",))
-    mask, counts, _ = prog.match_sharded(mesh, batch, lengths)
+    mask, counts, _ = prog.match_mesh(mesh, batch, lengths)
     single = prog.match(batch, lengths)
     assert (mask == single).all()
     assert (counts == single.sum(axis=1)).all()

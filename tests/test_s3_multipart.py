@@ -242,7 +242,7 @@ def test_multipart_restart_resumes_upload(tmp_path, monkeypatch):
 
 def test_multipart_retry_redelivery_no_duplicate_staging(tmp_path,
                                                          monkeypatch):
-    """ADVICE.md (medium): flush staged the chunk, the part upload
+    """round-5 advisor (medium): flush staged the chunk, the part upload
     failed (failpoint on the part-upload site), the engine redelivered
     the same chunk — staging must be idempotent: every record appears
     exactly once across the uploaded parts."""

@@ -1,7 +1,8 @@
-"""Multi-device grep: shard_map over the virtual 8-device CPU mesh.
+"""Multi-device grep: ``match_mesh`` over the virtual 8-device CPU mesh.
 
-Validates the SPMD path (batch-dim sharding + psum match counts) against
-the single-device kernel, including the non-divisible-batch pad path.
+Validates the partitioned matcher (batch-dim sharding + psum match
+counts) against the single-device kernel, including the
+non-divisible-batch pad path.
 """
 
 import numpy as np
@@ -43,7 +44,7 @@ CORPUS = [
 def test_sharded_matches_single_device(n_dev):
     mesh = _mesh(n_dev)
     prog, batch, lengths = _stage(["GET|POST", "^kernel:", "50[0-9]$"], CORPUS)
-    mask, counts, padded = prog.match_sharded(mesh, batch, lengths)
+    mask, counts, padded = prog.match_mesh(mesh, batch, lengths)
     ref = prog.match(batch, lengths)
     assert padded % n_dev == 0
     assert np.array_equal(mask, ref)
@@ -54,5 +55,5 @@ def test_sharded_counts_are_global():
     mesh = _mesh(8)
     vals = [b"hit"] * 16 + [b"miss"] * 16
     prog, batch, lengths = _stage(["hit"], vals)
-    _, counts, _ = prog.match_sharded(mesh, batch, lengths)
+    _, counts, _ = prog.match_mesh(mesh, batch, lengths)
     assert counts.tolist() == [16]

@@ -1,6 +1,6 @@
 """fbtpu-shrink property tests — the compile-path reduction contract.
 
-Three layers of contract:
+Two layers of contract:
 
 - **Bit-exact minimization**: for randomized regexes, the minimized DFA
   (Hopcroft + dead-state pruning + byte-class remerge) accepts exactly
@@ -8,25 +8,17 @@ Three layers of contract:
   — including non-ASCII bytes, the empty string, and max_len
   boundaries — and the output is MINIMAL (no two distinct states
   equivalent; the Moore fixpoint is the independent oracle).
-- **Sound approximation**: the approximate reduction over-approximates
-  (L(exact) ⊆ L(approx)) — a mask miss is definitive — and the
-  end-to-end filter output stays byte-identical to the exact chain
-  even under forced tiny budgets, because the exact recheck owns the
-  final verdict.
 - **The unlock is observable**: GrepProgram exposes the S/C/k/kernel
   decision, the apache2 parser DFA demonstrably shrinks, and the
   ``fluentbit_grep_shrink_*`` counters move.
 """
 
-import os
 import random
 
 import numpy as np
-import pytest
 
 from fluentbit_tpu.ops.grep import GrepProgram, choose_k, program_for
-from fluentbit_tpu.regex.dfa import (ACC, approx_reduce, compile_dfa,
-                                     _moore_minimize)
+from fluentbit_tpu.regex.dfa import _moore_minimize, compile_dfa
 from fluentbit_tpu.regex.parser import UnsupportedRegex
 
 APACHE2 = (
@@ -160,36 +152,15 @@ def test_class_remerge_no_identical_columns():
 
 def test_apache2_shrink_and_unlock():
     """The acceptance shape: apache2 demonstrably shrinks (S and C),
-    and the approximate reduction opens the assoc gate AND gains a
-    stride level over today's k=3."""
+    and the minimized machine composes at least as deep a stride as
+    the raw one (k=3, the benchmark's scan child)."""
     d = compile_dfa(APACHE2)
     st = d.shrink
     assert st is not None and st.minimized
     assert st.s_raw > d.n_states          # Hopcroft merged states
     assert st.c_raw > d.n_classes         # class remerge shrank C
-    k_exact = choose_k(d.n_states, d.n_classes)
-    ap = approx_reduce(d, 64)
-    assert ap is not None
-    assert ap.n_states <= 64              # assoc-eligible
-    assert choose_k(ap.n_states, ap.n_classes) >= k_exact + 1
-    assert ap.shrink.approx_of == d.n_states
-
-
-def test_approx_is_language_superset():
-    rng = random.Random(3)
-    for pat in (APACHE2, r"req=[0-9a-f]{24} (GET|POST) /[a-z]+$"):
-        d = compile_dfa(pat)
-        ap = approx_reduce(d, 16)  # brutal budget: maximal FP surface
-        if ap is None:
-            continue
-        assert ap.n_states <= 16
-        inputs = _random_inputs(rng) + [
-            b'10.0.0.1 - u [t] "GET /a HTTP/1.1" 200 5 "r" "a"',
-            b"req=0123456789abcdef01234567 GET /path",
-        ]
-        for s in inputs:
-            if d.match_bytes(s):
-                assert ap.match_bytes(s), (pat, s)
+    assert choose_k(d.n_states, d.n_classes) == 3
+    assert choose_k(st.s_raw, st.c_raw) <= 3
 
 
 def _grep_engine(buf, **props):
@@ -226,66 +197,13 @@ def _mixed_chunk(n=2048, match_frac=0.4, seed=5):
     return bytes(buf)
 
 
-def test_approx_end_to_end_byte_identical_forced_low_budget():
-    """Forced-tiny approximate machines (8 states — huge FP surface)
-    must still produce byte-identical filter output: the exact recheck
-    owns the verdict."""
-    buf = _mixed_chunk()
-    _, _, exact = _grep_engine(buf)
-    for states in ("8", "16", "64"):
-        e, _, approx = _grep_engine(buf, tpu_approx="on",
-                                    tpu_approx_states=states)
-        plug = e.filters[0].plugin
-        assert plug._approx_tables is not None
-        assert approx == exact, f"states={states}"
-
-
-def test_approx_fp_budget_self_disables():
-    """A zero FP budget + a corpus the mask over-admits: after the
-    measurement window the mode must self-disable (and the disable is
-    a metric), with output byte-identical throughout."""
-    buf = _mixed_chunk(n=4096, match_frac=0.0, seed=9)
-    _, _, exact = _grep_engine(buf)
-    e, ins, out1 = _grep_engine(buf, tpu_approx="on",
-                                tpu_approx_states="8",
-                                tpu_approx_fp_budget="0.0")
-    plug = e.filters[0].plugin
-    assert plug._approx_tables is not None
-    outs = [out1]
-    for _ in range(3):  # push past the 8192-record window
-        e.input_log_append(ins, "b", buf)
-        outs.append(b"".join(bytes(c.buf) for c in ins.pool.drain()))
-    assert not plug._approx_live
-    assert e.m_shrink_approx_disabled.get(("grep",)) >= 1
-    assert all(o == exact for o in outs)
-
-
-def test_approx_no_engage_when_exact_already_fits():
-    buf = _mixed_chunk(n=256)
-    from fluentbit_tpu.core.engine import Engine
-
-    e = Engine()
-    f = e.filter("grep")
-    f.set("regex", "log GET")  # S far below any budget
-    f.set("tpu_approx", "on")
-    ins = e.input("dummy")
-    for x in e.inputs + e.filters:
-        x.configure()
-        x.plugin.init(x, e)
-    assert e.filters[0].plugin._approx_tables is None
-
-
 def test_shrink_metrics_wired_through_engine():
     buf = _mixed_chunk(n=2048, match_frac=0.1)
-    e, _, _ = _grep_engine(buf, tpu_approx="on")
+    e, _, _ = _grep_engine(buf)
     label = ("grep",)
-    assert e.m_shrink_states.get(label) > 0
-    assert e.m_shrink_classes.get(label) > 0
-    assert e.m_shrink_approx_admits.get(label) > 0
-    assert e.m_shrink_approx_rechecks.get(label) > 0
-    # admits are per (rule, record), rechecks per union record
-    assert e.m_shrink_approx_admits.get(label) >= \
-        e.m_shrink_approx_rechecks.get(label)
+    d = compile_dfa(APACHE2)
+    assert e.m_shrink_states.get(label) == d.shrink.states_eliminated > 0
+    assert e.m_shrink_classes.get(label) == d.shrink.classes_eliminated > 0
 
 
 def test_grep_program_exposes_decision():
@@ -324,13 +242,3 @@ def test_per_dfa_k_groups_split_and_bit_exact():
     for r, d in enumerate(dfas):
         exp = np.array([d.match_bytes(ln) for ln in lines])
         assert (got[r] == exp).all()
-
-
-def test_program_cache_keys_on_minimize_toggle(monkeypatch):
-    p1 = program_for(("cache_key_probe",), 64)
-    monkeypatch.setenv("FBTPU_DFA_MIN", "0")
-    p2 = program_for(("cache_key_probe",), 64)
-    assert p2 is not p1
-    assert not p2.dfas[0].shrink.minimized
-    monkeypatch.delenv("FBTPU_DFA_MIN")
-    assert program_for(("cache_key_probe",), 64) is p1

@@ -3,8 +3,8 @@ the profiler's own buffer and clock, lane and plugin counters where the
 work happens, named device programs.
 
 A forward frame goes socket → ``in_forward`` → grep → ``lib`` output
-under a CPU ``jax.profiler`` session. On a CPU backend ``filter_raw``
-takes the native twin, so the device lane is forced the way
+under a CPU ``jax.profiler`` session. On a CPU backend grep's
+``process_batch`` takes the native twin, so the device lane is forced the way
 ``test_launchgraph.py::test_static_matches_dynamic_grep_chain`` does it
 (``FBTPU_MESH=1``, ``tpu_batch_records 1``, skipped without a mesh).
 """
@@ -254,7 +254,7 @@ def test_bind_nests_and_restores_under_a_session(tmp_path):
     assert spans.current_ids() is None
 
 
-def test_sharded_timings_sum_reset_and_timed():
+def test_sharded_timings_sum_and_timed():
     tm = spans.ShardedTimings(("a_s", "n"))
     assert list(tm) == ["a_s", "n"] and "n" in tm and "x" not in tm
     tm.add("n", 2)
@@ -264,9 +264,7 @@ def test_sharded_timings_sum_reset_and_timed():
     assert tm["n"] == 5 and len(tm._shards) == 2
     with tm.timed("a_s", "grep.compact"):
         time.sleep(0.01)
-    assert 0.009 < tm["a_s"] < 5.0
-    tm["a_s"] = 0
-    assert tm["a_s"] == 0 and tm["n"] == 5
+    assert 0.009 < tm["a_s"] < 5.0 and tm["n"] == 5
 
 
 # ------------------------------------------ one frame, traced and not
@@ -750,7 +748,7 @@ def test_outside_wrappers_of_the_benchmark_are_still_called(monkeypatch):
     for attr in ("input_log_append", "flush_all"):
         setattr(engine, attr, counted(getattr(engine, attr), attr))
     plugin = engine.filters[0].plugin
-    for attr in ("filter_raw", "filter"):
+    for attr in ("process_batch", "filter"):
         setattr(plugin, attr, counted(getattr(plugin, attr), "grep"))
     ctx.start()
     try:
