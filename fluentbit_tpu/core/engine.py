@@ -21,6 +21,7 @@ from __future__ import annotations
 import asyncio
 import itertools
 import logging
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -71,6 +72,22 @@ class Task:
         # output name → processed payload (output-side processors run
         # once per route; retries reuse the cached bytes)
         self.processed: Dict[str, bytes] = {}
+
+
+#: the interpreter's GIL switch interval from the first engine start on
+#: (CPython's default is 5 ms). The engine's threads hand work to each
+#: other across waits that release the GIL — in_forward's absorb worker
+#: and a lane's launch thread come back from the device, a ctypes call
+#: or a lock while the event loop decodes the next frame — and a thread
+#: that wants the GIL back gets it only when the holder blocks or the
+#: interval runs out. At 5 ms in_forward's two stages cost each other
+#: 13 ms a 4,096-line frame and the overlap gained nothing. 1 ms keeps
+#: the absorb within 4 ms of running alone; 0.5 ms is no faster there
+#: and costs a chain that is Python on both threads (the sketch cell)
+#: 4.6 % in hand-overs where 1 ms costs it 2.1 % (PERF.md section 6,
+#: PR 30). A C call that holds the GIL (the codec's one ``unpack_from``
+#: a frame) is not cut short by any interval.
+GIL_SWITCH_INTERVAL_S = 0.001
 
 
 class _RawTail:
@@ -588,9 +605,15 @@ class Engine:
     # ------------------------------------------------------------------
 
     def start(self) -> None:
-        """Spawn the engine thread (flb_start → flb_engine_start)."""
+        """Spawn the engine thread (flb_start → flb_engine_start).
+        Shortens the interpreter's GIL switch interval to
+        ``GIL_SWITCH_INTERVAL_S`` where it is longer."""
         if self._thread is not None:
             raise RuntimeError("engine already started")
+        # process-wide and left in place at stop(): another engine of
+        # this process may still be running
+        if sys.getswitchinterval() > GIL_SWITCH_INTERVAL_S:
+            sys.setswitchinterval(GIL_SWITCH_INTERVAL_S)
         # storage + backlog recovery (flb_storage_create at
         # src/flb_engine.c:979; sb_segregate_chunks at :1129)
         if self.service.storage_path and self.storage is None:

@@ -45,6 +45,20 @@ protocol:
   ack timeout turns into RETRY + backoff, pausing the stream without
   losing a byte (resends dedup at the ledger).
 
+- **Decode beside absorb.** The server is a two-stage pipeline (the
+  reference's threaded-input shape, flb_input_thread.c): a connection
+  reads, unpacks and re-encodes on the engine's event loop, and every
+  absorb — dedup check, tenant metering, ``engine.input_log_append``
+  and all beneath it, ledger record — runs on ONE worker thread per
+  instance, first come first served. While the worker waits for the
+  device the loop decodes the connection's next frame; it holds that
+  one frame, reading nothing more, until the frame before is acked.
+  Acks are written on the loop, in arrival order, after the absorb
+  returned and the ledger holds the chunk. A chunk too small to be
+  worth the hand-over (``_INLINE_BYTES``: Message mode) is absorbed by
+  the loop itself while the worker is idle — still one absorb at a
+  time, since only the loop hands the worker its work.
+
 - **Armored client.** Per-upstream circuit breakers (core/guard.py,
   visible in /api/v1/health), UpstreamHA failover mid-stream, full-
   jitter backoff between attempts; when EVERY upstream refuses (a
@@ -56,6 +70,7 @@ protocol:
 from __future__ import annotations
 
 import asyncio
+import concurrent.futures
 import gzip
 import hashlib
 import logging
@@ -86,6 +101,15 @@ _TENANT_MAX_LEN = 128
 _PRIORITY_MAX = 7
 
 _NO_MSG = object()  # the Unpacker holds no complete message
+
+#: a chunk smaller than this (as V2 events) is absorbed on the loop when
+#: the worker is idle: handing it over and back costs about 0.1 ms each
+#: way, more than the decode of a next chunk that size could hide behind
+#: its absorb — Message mode sends one record a message
+_INLINE_BYTES = 4096
+
+# what one try at absorbing a chunk came to (ForwardInput._attempt)
+_ABSORBED, _DUPLICATE, _SHED, _DEFER = range(4)
 
 
 def _entries_to_events(entries) -> tuple:
@@ -155,9 +179,23 @@ class ForwardInput(InputPlugin):
         # plain ints mirror the exported counters for /api/v1/health
         # (the metrics registry has no read-back API)
         self.n_absorbed = 0
+        self.n_overlapped = 0
         self.n_deferred_acks = 0
         self.n_withheld_acks = 0
         self.n_shed_remote = 0
+        # the absorb stage: one thread, first come first served (made
+        # at the first frame). A connection has one try there at a time
+        # and holds one decoded frame behind it (_handle_conn), so the
+        # queue is never longer than the connections are many
+        self._absorber = concurrent.futures.ThreadPoolExecutor(
+            max_workers=1,
+            thread_name_prefix=f"flb-fw-{instance.display_name}")
+        # tries handed to the worker (counted on the loop) and tries it
+        # is through with (counted on the worker): unequal while an
+        # absorb is running or waiting its turn
+        self._tries_handed = 0
+        self._tries_done = 0
+        self._stopped = False
         m = engine.metrics
         self._m_dedup = m.counter(
             "fluentbit", "forward", "dedup_hits_total",
@@ -166,6 +204,10 @@ class ForwardInput(InputPlugin):
         self._m_absorbed = m.counter(
             "fluentbit", "forward", "absorbed_chunks_total",
             "Forward chunks absorbed into engine state", ("instance",))
+        self._m_overlapped = m.counter(
+            "fluentbit", "forward", "overlapped_chunks_total",
+            "Forward chunks decoded while an earlier chunk's absorb "
+            "was still running", ("instance",))
         self._m_deferred = m.counter(
             "fluentbit", "forward", "deferred_acks_total",
             "Acks delayed by quota/buffer backpressure", ("instance",))
@@ -175,7 +217,10 @@ class ForwardInput(InputPlugin):
             ("instance",))
 
     async def start_server(self, engine) -> None:
+        open_conns = set()
+
         async def handle(reader, writer):
+            open_conns.add(writer)
             try:
                 await self._handle_conn(reader, writer, engine)
             except (ConnectionError, asyncio.IncompleteReadError):
@@ -183,6 +228,7 @@ class ForwardInput(InputPlugin):
             except Exception:
                 log.exception("in_forward connection failed")
             finally:
+                open_conns.discard(writer)
                 close_quietly(writer)
 
         from ..core.tls import server_context
@@ -192,8 +238,19 @@ class ForwardInput(InputPlugin):
             ssl=server_context(self.instance),
         )
         self.bound_port = server.sockets[0].getsockname()[1]
-        async with server:
-            await server.serve_forever()
+        try:
+            # (not serve_forever(): cancelled, it waits for the
+            # connections before anything here could close them)
+            await asyncio.get_running_loop().create_future()
+        finally:
+            # cancelled (engine stop, hot reload): wait_closed() waits
+            # for the connections too, and an edge that stays connected
+            # would hold the stop up for good — close them; a handler
+            # still lets the frame it handed over run to its end
+            server.close()
+            for writer in list(open_conns):
+                close_quietly(writer)
+            await server.wait_closed()
 
     async def _handle_conn(self, reader, writer, engine) -> None:
         nonce = b""
@@ -205,38 +262,76 @@ class ForwardInput(InputPlugin):
             await writer.drain()
         u = Unpacker()
         authed = not self.shared_key
-        while True:
-            with span("forward.read") as sp:
-                data = await reader.read(65536)
-                sp.set_metadata(bytes=len(data))
-            if not data:
-                return
-            fed = False
+        # the task absorbing and acking the last decoded frame: the
+        # next one is decoded beside it and then waits for it, so
+        # absorbs and acks of a connection keep their arrival order
+        # and nothing more is read off the socket meanwhile (TCP flow
+        # control holds the peer back)
+        last = None
+        try:
             while True:
-                # one span per attempt to take a message off the
-                # Unpacker. A frame comes in several reads; until it is
-                # whole an attempt (done=0) costs the C codec one walk
-                # over its spans (native=1), or the Python walk a
-                # decode thrown away where the extension is not loaded
-                # or handed the bytes back (native=0)
-                with span("forward.unpack") as sp:
-                    if not fed:
-                        u.feed(data)
-                        fed = True
-                    msg = next(u, _NO_MSG)
-                    sp.set_metadata(done=int(msg is not _NO_MSG),
-                                    native=int(u.native))
-                if msg is _NO_MSG:
-                    break
-                if not isinstance(msg, (list, tuple)) or not msg:
-                    continue
-                if not authed:
-                    authed = self._check_ping(msg, nonce, writer)
+                with span("forward.read") as sp:
+                    data = await reader.read(65536)
+                    sp.set_metadata(bytes=len(data))
+                if not data:
+                    return
+                fed = False
+                while True:
+                    # one span per attempt to take a message off the
+                    # Unpacker. A frame comes in several reads; until
+                    # it is whole an attempt (done=0) costs the C codec
+                    # one walk over its spans (native=1), or the Python
+                    # walk a decode thrown away where the extension is
+                    # not loaded or handed the bytes back (native=0)
+                    with span("forward.unpack") as sp:
+                        if not fed:
+                            u.feed(data)
+                            fed = True
+                        msg = next(u, _NO_MSG)
+                        sp.set_metadata(done=int(msg is not _NO_MSG),
+                                        native=int(u.native))
+                    if msg is _NO_MSG:
+                        break
+                    if not isinstance(msg, (list, tuple)) or not msg:
+                        continue
                     if not authed:
-                        return
-                    await writer.drain()
-                    continue
-                await self._dispatch(msg, writer, engine)
+                        authed = self._check_ping(msg, nonce, writer)
+                        if not authed:
+                            return
+                        await writer.drain()
+                        continue
+                    frame = self._decode(msg)
+                    if frame is None:
+                        continue
+                    if last is not None:
+                        await last
+                        last = None
+                    if len(frame[1]) < _INLINE_BYTES:
+                        # a few events: nothing worth decoding beside
+                        # this absorb, and no task to carry it
+                        await self._finish(frame, writer, engine)
+                        continue
+                    # started here, beneath no bind: the task binds the
+                    # frame's chunk itself
+                    last = asyncio.ensure_future(
+                        self._finish(frame, writer, engine))
+                    # two passes of the loop before the next frame is
+                    # decoded. The first runs the task as far as the
+                    # hand-over (or a read that brought several frames
+                    # would decode them one absorb late); the second
+                    # goes through select(), which releases the GIL:
+                    # the worker starts on the frame now, not behind
+                    # the C unpack call that comes next (9 % of the
+                    # grep cell's lines/s, PERF.md section 6, PR 30)
+                    await asyncio.sleep(0)
+                    await asyncio.sleep(0)
+        finally:
+            if last is not None:
+                # a frame handed over is absorbed or not, never half:
+                # the peer may be gone, the absorb runs to its end (the
+                # ack then fails on the closed socket, which ends the
+                # connection like any lost link)
+                await last
 
     def _check_ping(self, msg, nonce: bytes, writer) -> bool:
         if msg[0] != "PING" or len(msg) < 6:
@@ -255,10 +350,13 @@ class ForwardInput(InputPlugin):
                             self.self_hostname, shared_key_digest]))
         return ok
 
-    async def _dispatch(self, msg, writer, engine) -> None:
+    def _decode(self, msg) -> Optional[tuple]:
+        """The loop's stage: one wire message → ``(tag, buf, n, option,
+        ack_ref, cid)`` with the entries re-encoded as V2 events, or
+        None for a message that is no chunk."""
         tag = msg[0]
         if not isinstance(tag, str):
-            return
+            return None
         if self.tag_prefix:
             tag = f"{self.tag_prefix}.{tag}"
         body = msg[1]
@@ -269,7 +367,7 @@ class ForwardInput(InputPlugin):
         else:
             # Message mode [tag, time, record, option?]
             if len(msg) < 3 or not isinstance(msg[2], dict):
-                return
+                return None
             opt_at = 3
         option = msg[opt_at] if len(msg) > opt_at \
             and isinstance(msg[opt_at], dict) else None
@@ -289,26 +387,31 @@ class ForwardInput(InputPlugin):
                 else:
                     entries = [[msg[1], msg[2]]]
                 buf, n = _entries_to_events(entries)
+            if self._tries_handed != self._tries_done:
+                # decoded while an earlier frame's absorb was running:
+                # the overlap the two stages exist for
+                self.n_overlapped += 1
+                self._m_overlapped.inc(1, (self.instance.display_name,))
+                with span("forward.overlap"):
+                    pass
+        return tag, buf, n, option, ack_ref, cid
+
+    async def _finish(self, frame, writer, engine) -> None:
+        """A decoded frame from its absorb to its ack."""
+        tag, buf, n, option, ack_ref, cid = frame
+        with bind(chunk=cid):
             if n:
-                if cid is not None and self._ledger is not None \
-                        and self._ledger.seen(cid):
-                    # redelivery inside the retry window: lost ack,
-                    # ambiguous-ack resend, or post-crash replay —
-                    # acked, absorbed zero times
-                    self._m_dedup.inc(1, (self.instance.display_name,))
-                else:
-                    tenant, priority = _wire_stamp(option)
-                    with span("forward.absorb"):
-                        absorbed = await self._absorb(
-                            engine, tag, buf, n, tenant, priority, cid)
-                    if not absorbed:
-                        # backpressure: NO ack — the peer's ack timeout
-                        # turns into RETRY+backoff, pausing the stream;
-                        # the resend dedups if a later pass absorbed it
-                        self.n_withheld_acks += 1
-                        self._m_withheld.inc(
-                            1, (self.instance.display_name,))
-                        return
+                tenant, priority = _wire_stamp(option)
+                absorbed = await self._absorb(
+                    engine, tag, buf, n, tenant, priority, cid)
+                if not absorbed:
+                    # backpressure: NO ack — the peer's ack timeout
+                    # turns into RETRY+backoff, pausing the stream;
+                    # the resend dedups if a later pass absorbed it
+                    self.n_withheld_acks += 1
+                    self._m_withheld.inc(
+                        1, (self.instance.display_name,))
+                    return
             if ack_ref is not None:
                 if _fp.ACTIVE:
                     try:
@@ -336,59 +439,49 @@ class ForwardInput(InputPlugin):
                       tenant, priority, cid: Optional[str]) -> bool:
         """Absorb one decoded chunk into engine state effectively once.
 
-        Meters the wire-stamped tenant (fleet-wide quota), stamps the
-        aggregator-side chunk, and converts DEFER verdicts into delayed
-        acks bounded by ``defer_ack_window``. Returns False when the
-        window exhausts — the caller withholds the ack entirely.
+        Each try (:meth:`_attempt`) runs on the worker, a small chunk's
+        here when the worker is idle; DEFER verdicts become delayed
+        acks bounded by ``defer_ack_window``, slept out here on the
+        loop so that other connections' chunks go on being absorbed
+        meanwhile. Returns False when the window exhausts —
+        the caller withholds the ack entirely.
         """
         ins = self.instance
         loop = asyncio.get_running_loop()
         deadline = loop.time() + self.defer_ack_window
-        led = self._ledger if cid is not None else None
         deferred = False
         while True:
-            if led is not None and led.seen(cid):
-                # a concurrent delivery of the same chunk absorbed it
-                # while this one slept in the defer loop — just ack
-                self._m_dedup.inc(1, (ins.display_name,))
-                return True
-            rc = None
-            if tenant is not None:
-                verdict = engine.qos.admit_stamped(tenant, len(buf))
-                if verdict == 2:  # SHED: consumed by the tenant's
-                    # declared overflow policy — acked, not absorbed
-                    # (the edge must not resend policy-shed bytes)
-                    self.n_shed_remote += 1
-                    return True
-                if verdict == 1:  # DEFER
-                    rc = -1
-            if rc is None:
-                stamped = tenant is not None
-                if stamped:
-                    # the stamp joins the pool key and lands on the
-                    # chunk; qos_exempt skips the LOCAL tenant's bucket
-                    # (the remote tenant was already metered above) —
-                    # input_log_append is synchronous, so no other
-                    # dispatch interleaves while these are set
-                    ins.pool.stamp = (tenant, priority)
-                    ins.qos_exempt = True
-                try:
-                    rc = engine.input_log_append(ins, tag, buf, n)
-                finally:
-                    if stamped:
-                        ins.pool.stamp = None
-                        ins.qos_exempt = False
-            if rc >= 0:
-                if led is not None:
-                    # durable BEFORE the ack leaves: an ack whose
-                    # absorb-record died with the process would turn
-                    # the peer's next resend into a double-absorb
-                    led.record(cid)
+            if self._stopped:
+                # after drain()/exit() nothing is absorbed: what came
+                # in now would miss the last flush — no ack, the peer
+                # resends it
+                raise ConnectionError("in_forward has stopped")
+            args = (engine, tag, buf, n, tenant, priority, cid)
+            idle = self._tries_handed == self._tries_done
+            self._tries_handed += 1
+            if idle and len(buf) < _INLINE_BYTES:
+                # the worker is idle and only this loop hands it work,
+                # so a try made here is still the one absorb running
+                got = self._attempt(*args)
+            else:
+                got = await loop.run_in_executor(
+                    self._absorber, self._attempt, *args)
+            if got == _ABSORBED:
                 self.n_absorbed += 1
                 self._m_absorbed.inc(1, (ins.display_name,))
                 return True
-            # rc == -1: backpressure (remote-tenant DEFER or local
-            # buffer/quota pause) — delay the ack and retry
+            if got == _DUPLICATE:
+                # redelivery inside the retry window: lost ack,
+                # ambiguous-ack resend, post-crash replay, or a
+                # concurrent delivery that was absorbed while this one
+                # slept in the defer loop — acked, absorbed zero times
+                self._m_dedup.inc(1, (ins.display_name,))
+                return True
+            if got == _SHED:
+                self.n_shed_remote += 1
+                return True
+            # backpressure (remote-tenant DEFER or local buffer/quota
+            # pause) — delay the ack and retry
             if not deferred:
                 deferred = True
                 self.n_deferred_acks += 1
@@ -402,10 +495,73 @@ class ForwardInput(InputPlugin):
                 hint = 0.05
             await asyncio.sleep(min(max(hint, 0.02), 0.25, remaining))
 
+    def _attempt(self, engine, tag: str, buf: bytes, n: int,
+                 tenant, priority, cid: Optional[str]) -> int:
+        """One try at absorbing a chunk: the dedup check, the
+        wire-stamped tenant's metering (fleet-wide quota), the append
+        with the stamp on the aggregator-side chunk, and the ledger
+        record. Tries run one at a time — on the worker, or on the loop
+        while the worker is idle (:meth:`_absorb`) — so nothing comes
+        between the check and the record, nor between setting the stamp
+        and clearing it. The frame's ``chunk`` is bound anew for the
+        spans of the thread that runs the try."""
+        ins = self.instance
+        led = self._ledger if cid is not None else None
+        try:
+            with bind(chunk=cid), span("forward.absorb"):
+                if led is not None and led.seen(cid):
+                    return _DUPLICATE
+                stamped = tenant is not None
+                if stamped:
+                    verdict = engine.qos.admit_stamped(tenant, len(buf))
+                    if verdict == 2:  # SHED: consumed by the tenant's
+                        # declared overflow policy — acked, not absorbed
+                        # (the edge must not resend policy-shed bytes)
+                        return _SHED
+                    if verdict == 1:
+                        return _DEFER
+                    # the stamp joins the pool key and lands on the
+                    # chunk; qos_exempt skips the LOCAL tenant's bucket
+                    # (the remote tenant was already metered above) —
+                    # the instance's tries run one at a time, so no
+                    # other dispatch interleaves while these are set
+                    ins.pool.stamp = (tenant, priority)
+                    ins.qos_exempt = True
+                try:
+                    # looked up on the engine at every call: whoever
+                    # wraps the method after init() is still called
+                    rc = engine.input_log_append(ins, tag, buf, n)
+                finally:
+                    if stamped:
+                        ins.pool.stamp = None
+                        ins.qos_exempt = False
+                if rc < 0:
+                    return _DEFER
+                if led is not None:
+                    # durable BEFORE the ack leaves: an ack whose
+                    # absorb-record died with the process would turn
+                    # the peer's next resend into a double-absorb
+                    led.record(cid)
+                return _ABSORBED
+        finally:
+            self._tries_done += 1
+
+    def drain(self, engine) -> None:
+        """Engine stop, before the last flush: the tries already handed
+        to the worker run to their end and the thread is joined, so
+        what they appended is flushed; later frames are refused."""
+        self._stopped = True
+        self._absorber.shutdown(wait=True)
+
+    def exit(self) -> None:
+        self._stopped = True
+        self._absorber.shutdown(wait=False)
+
     def health_block(self) -> dict:
         out = {
             "role": "server",
             "absorbed": self.n_absorbed,
+            "overlapped": self.n_overlapped,
             "deferred_acks": self.n_deferred_acks,
             "withheld_acks": self.n_withheld_acks,
             "shed_remote": self.n_shed_remote,

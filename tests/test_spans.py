@@ -295,11 +295,19 @@ def test_every_span_from_reencode_on_carries_the_chunk(runs):
              and e["name"] in SPANS_OF_A_FRAME[2:]]
     assert len(after) >= 12
     assert {e["stats"].get("chunk") for e in after} == {CHUNK}
-    # the lane worker's spans are on other threads and carry it too
-    engine_line = by_name(events, "forward.read")[0]["line"]
-    worker = [e for e in after if e["line"] != engine_line]
-    assert {e["name"] for e in worker} == {"lane.launch", "grep.dispatch",
-                                           "grep.force"}
+    # three threads carry it: the engine's loop decodes and acks, the
+    # input's worker absorbs, a lane worker runs each launch
+    loop_line = by_name(events, "forward.read")[0]["line"]
+    absorb_line = by_name(events, "forward.absorb")[0]["line"]
+    assert absorb_line != loop_line
+    on = {line: {e["name"] for e in after if e["line"] == line}
+          for line in {e["line"] for e in after}}
+    assert on.pop(loop_line) == {"forward.reencode", "forward.ack"}
+    assert on.pop(absorb_line) == {
+        "forward.absorb", "engine.append", "filter.grep", "grep.stage",
+        "lane.begin", "lane.wait", "grep.compact"}
+    assert set().union(*on.values()) == {"lane.launch", "grep.dispatch",
+                                         "grep.force"}
     # before the frame is whole nobody knows its chunk
     for e in by_name(events, "forward.read") \
             + by_name(events, "forward.unpack"):
@@ -345,6 +353,110 @@ def test_flush_spans_belong_to_no_frame(runs):
         + by_name(runs["events"], "output.flush")
     assert {e["name"] for e in flushes} == {"engine.flush",
                                             "output.flush"}
+    for e in flushes:
+        assert not {"chunk", "seg", "lane"} & set(e["stats"]), e
+
+
+@pytest.fixture(scope="module")
+def pipelined_events(mesh_env, tmp_path_factory):
+    """Two frames on one connection under a profiler session, the first
+    held in the filter until the second is decoded (``n_overlapped``
+    says so), then a third that finds the worker idle, and a flush. →
+    the session's events."""
+    jax = mesh_env
+    agg = Aggregator()
+    srv = agg.engine.inputs[0].plugin
+    try:
+        agg.send(frame("pipe-warm"), "pipe-warm")  # compiles
+        real = agg.grep.process_batch
+        held = []
+
+        def hold_the_first(chunk):
+            if not held:
+                held.append(wait_for(lambda: srv.n_overlapped >= 1))
+            return real(chunk)
+
+        agg.grep.process_batch = hold_the_first
+        trace_dir = str(tmp_path_factory.mktemp("pipelined"))
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        try:
+            with socket.create_connection(("127.0.0.1", agg.port)) as s:
+                s.settimeout(60)
+                s.sendall(frame("pipe-a") + frame("pipe-b"))
+                u, acks = Unpacker(), []
+                while len(acks) < 2:
+                    u.feed(s.recv(4096))
+                    acks.extend(msg["ack"] for msg in u)
+            agg.send(frame("pipe-c"), "pipe-c")
+            agg.output()
+        finally:
+            jax.profiler.stop_trace()
+            del agg.grep.process_batch
+        assert acks == ["pipe-a", "pipe-b"] and held == [True]
+        assert srv.n_overlapped == 1
+    finally:
+        agg.stop()
+    return read_events(trace_dir)
+
+
+@pytest.mark.mesh
+def test_worker_spans_carry_their_frames_chunk(pipelined_events):
+    """The input's worker absorbs frame after frame on one thread: each
+    span it opens carries the chunk of the frame it is absorbing, bound
+    anew for every frame, and so do the lane workers' beneath it."""
+    events = pipelined_events
+    absorbs = by_name(events, "forward.absorb")
+    assert [e["stats"]["chunk"] for e in sorted(
+        absorbs, key=lambda e: e["start"])] == ["pipe-a", "pipe-b",
+                                                "pipe-c"]
+    assert len({e["line"] for e in absorbs}) == 1
+    loop_line = by_name(events, "forward.read")[0]["line"]
+    assert absorbs[0]["line"] != loop_line
+    for absorb in absorbs:
+        beneath = [e for e in events if e["line"] != loop_line
+                   and inside(e, absorb)]
+        assert {e["name"] for e in beneath} >= {
+            "forward.absorb", "engine.append", "filter.grep",
+            "lane.begin", "lane.launch", "grep.force", "lane.wait"}
+        assert {e["stats"].get("chunk") for e in beneath} \
+            == {absorb["stats"]["chunk"]}
+    # nothing on the worker's thread lies outside an absorb
+    for e in events:
+        if e["line"] == absorbs[0]["line"]:
+            assert any(inside(e, absorb) for absorb in absorbs), e
+
+
+@pytest.mark.mesh
+def test_overlap_span_marks_the_overlapped_frame_alone(pipelined_events,
+                                                       runs):
+    events = pipelined_events
+    (overlap,) = by_name(events, "forward.overlap")
+    assert overlap["stats"]["chunk"] == "pipe-b"
+    assert overlap["line"] == by_name(events, "forward.read")[0]["line"]
+    first = min(by_name(events, "forward.absorb"),
+                key=lambda e: e["start"])
+    reencode_b = [e for e in by_name(events, "forward.reencode")
+                  if e["stats"]["chunk"] == "pipe-b"][0]
+    # b was decoded after a was handed to the worker and before a's
+    # absorb was over
+    reencode_a = [e for e in by_name(events, "forward.reencode")
+                  if e["stats"]["chunk"] == "pipe-a"][0]
+    assert reencode_a["end"] <= reencode_b["start"]
+    assert reencode_b["end"] <= overlap["start"] \
+        and overlap["end"] <= first["end"]
+    assert len(by_name(events, "forward.reencode")) == 3
+    # a frame sent alone overlaps nothing
+    assert not by_name(runs["events"], "forward.overlap")
+
+
+@pytest.mark.mesh
+def test_flush_spans_of_a_pipelined_run_belong_to_no_frame(
+        pipelined_events):
+    flushes = by_name(pipelined_events, "engine.flush") \
+        + by_name(pipelined_events, "output.flush")
+    assert {e["name"] for e in flushes} == {"engine.flush", "output.flush"}
     for e in flushes:
         assert not {"chunk", "seg", "lane"} & set(e["stats"]), e
 
