@@ -4,12 +4,14 @@
 Started by ``run.py`` before that process touches JAX; imports neither
 ``jax`` nor ``fluentbit_tpu``. It makes the configuration's corpus from
 the seed, writes it to files the aggregator's process reads after the
-window, and replays it over TCP loopback as Forward-mode frames
-``[tag, [[time, record], ...], {"chunk": id}]`` under the traffic file's
-kind (``traffic_kinds/<kind>.py``). Every frame is logged: when it was
-due, created, sent and acked (``time.monotonic_ns``, one clock for both
-processes on the one machine), and the wall-clock creation time its
-records carry as their Forward ``time``.
+window, and replays it over TCP loopback under the traffic file's kind
+(``traffic_kinds/<kind>.py``), packed as its ``mode`` says: ``forward``
+(the default) ``[tag, [[time, record], ...], {"chunk": id}]``, or
+``packed``, the same entries as one PackedForward ``bin``. Every frame
+is logged: when it was due, created, sent and acked
+(``time.monotonic_ns``, one clock for both processes on the one
+machine), and the wall-clock creation time its records carry as their
+Forward ``time``.
 
 Commands arrive as JSON lines on stdin (``connect``, ``go``); events
 leave as JSON lines on stdout (``corpus``, ``warm``, ``done``).
@@ -57,8 +59,9 @@ class Link:
     order); anything else breaks the link and fails the run."""
 
     def __init__(self, sock, tag: str, bodies: list, frame_lines: int,
-                 nonce: int):
+                 nonce: int, mode: str = "forward"):
         self.sock = sock
+        self.frame = wire.FRAMERS[mode]
         self.tag = wire.pack_str(tag)
         self.bodies = bodies
         self.frame_lines = frame_lines
@@ -81,9 +84,9 @@ class Link:
         created = time.monotonic_ns()
         wall = max(time.time_ns(), self._last_wall + 1)
         self._last_wall = wall
-        frame = wire.forward_frame(self.tag, wall,
-                                   self.bodies[lo:lo + self.frame_lines],
-                                   self.chunk_id(idx))
+        frame = self.frame(self.tag, wall,
+                           self.bodies[lo:lo + self.frame_lines],
+                           self.chunk_id(idx))
         row = [idx, phase, slot % self.slots, self.frame_lines, due_ns,
                created, wall, 0, 0]
         self.rows.append(row)
@@ -138,19 +141,25 @@ def write_corpus(work: str, bodies: list, labels: bytes) -> int:
     return total
 
 
+def length_bucket(bodies, labels, lo: int, n: int, buckets: list) -> int:
+    """The staging shape of the frame ``bodies[lo:lo + n]``: the length
+    bucket its longest line falls in, overflow rows aside — a frame with
+    one long line stages whole at the wider shape."""
+    longest = max((len(bodies[i]) for i in range(lo, lo + n)
+                   if not labels[i] & wire.LONG), default=0)
+    return next((b for b in buckets if longest <= b), buckets[-1])
+
+
 def warm_slots(bodies, labels, frame_lines: int, buckets: list) -> list:
     """One frame slot for each staging shape the cell's traffic makes:
-    the first slot whose longest line (overflow rows aside) falls in
-    each of the configuration's length buckets."""
+    the first slot whose frame falls in each of the configuration's
+    length buckets."""
     if not buckets:
         return [0]
     found = {}
     for slot in range(len(bodies) // frame_lines):
-        lo = slot * frame_lines
-        longest = max((len(bodies[i]) for i in range(lo, lo + frame_lines)
-                       if not labels[i] & wire.LONG), default=0)
-        bucket = next((b for b in buckets if longest <= b), buckets[-1])
-        found.setdefault(bucket, slot)
+        found.setdefault(length_bucket(bodies, labels, slot * frame_lines,
+                                       frame_lines, buckets), slot)
         if len(found) == len(buckets):
             break
     return [found[b] for b in sorted(found)]
@@ -183,7 +192,8 @@ def main(argv=None) -> int:
     sock = socket.create_connection(("127.0.0.1", port), timeout=30)
     sock.settimeout(None)
     sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
-    link = Link(sock, config["tag"], bodies, frame_lines, args.seed)
+    link = Link(sock, config["tag"], bodies, frame_lines, args.seed,
+                traffic.get("mode", "forward"))
     kind = load_py("traffic_kinds", traffic["kind"])
     try:
         warm = warm_slots(bodies, labels, frame_lines,

@@ -40,8 +40,9 @@ import stats  # noqa: E402
 import trace_reduce  # noqa: E402
 
 PREFIX = "fbtpu:"
-READ = "forward.read"   # the engine loop's thread is the line it is on
-WAIT = "lane.wait"      # idle under it belongs to the worker's spans
+WAIT = "lane.wait"      # the thread that absorbs is the line it is on;
+#                         idle under it belongs to the lane worker's spans
+READ = "forward.read"   # the engine loop's thread: what the first leaves
 
 
 def process_start() -> float:
@@ -192,16 +193,23 @@ def reduce_planes(planes: list):
 
 def _idle_by_span(gaps: list, threads: list) -> dict:
     """Idle gaps of the device → seconds by the innermost span open on
-    the engine loop's thread; where that is ``lane.wait``, by the
-    worker's innermost span under the same ``chunk`` and ``seg`` (the
-    device idle while the host waits for it is dispatch latency and
-    copy-out). What no span covers is ``unattributed``."""
-    engine = next((t for t in threads
-                   if any(k[0] == READ for _s, _e, k in t)), None)
+    the thread that holds ``lane.wait`` (since the input absorbs on a
+    worker of its own that is no longer the engine loop's); where that
+    span is ``lane.wait`` itself, by the lane worker's innermost span
+    under the same ``chunk`` and ``seg`` (the device idle while the host
+    waits for it is dispatch latency and copy-out). What that thread
+    leaves uncovered goes to the thread that holds ``forward.read``
+    (which comes first where no ``lane.wait`` is found), then to any
+    other thread's spans. What no span of the program covers is
+    ``unattributed``."""
+    def rank(thread):
+        names = {k[0] for _s, _e, k in thread}
+        return 0 if WAIT in names else 1 if READ in names else 2
+
     by, rest = {}, gaps
-    if engine is not None:
-        others = [sp for t in threads if t is not engine for sp in t]
-        for s, e, (name, st) in innermost(engine):
+    for mine in sorted(threads, key=rank):
+        others = [sp for t in threads if t is not mine for sp in t]
+        for s, e, (name, st) in innermost(mine):
             piece = stats.intersect(rest, [(s, e)])
             if not piece:
                 continue

@@ -13,15 +13,18 @@ def _table(readings):
 
 def module_ms_per_launch(readings, module: str, lane: str):
     """Device milliseconds of the modules whose name holds ``module``,
-    for each launch the lane counted in the traced interval."""
+    for each launch the lane counted in the traced interval. Where the
+    lane launched and the device ran modules, none of them by this
+    name, the kernel served nothing: 0, not nothing to read — a PR that
+    moves a rule to another kernel sees it here."""
     t, trace = _table(readings), readings["trace"]
-    if t is None or trace is None:
+    if t is None or trace is None or not t["modules"]:
         return None
-    mine = [s for name, s in t["modules"].items() if module in name]
     n = trace["counters"].get(f"lane.{lane}.launches")
-    if not mine or not n:
+    if not n:
         return None
-    return 1e3 * sum(mine) / n
+    return 1e3 * sum(s for name, s in t["modules"].items()
+                     if module in name) / n
 
 
 def idle_unattributed_share(readings):

@@ -9,7 +9,7 @@ configuration, built through ``fluentbit_tpu.create()`` and the config
 loader (``forward`` input → filter chain → ``lib`` output, ``Flush 1``).
 A **generator** process (``generator.py``, stdlib only, started before
 this process imports JAX) makes the seeded corpus and replays it over
-TCP loopback as Forward-mode frames under the cell's traffic file.
+TCP loopback as Forward frames under the cell's traffic file.
 
 Everything that belongs to one cell, configuration, traffic mix,
 traffic kind, per-layer metric or reference is a file of its own, found
@@ -42,6 +42,7 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
 import stats  # noqa: E402
+from generator import length_bucket  # noqa: E402
 from lookup import load_json, load_py  # noqa: E402
 import wire  # noqa: E402
 from spans import Recorder  # noqa: E402
@@ -293,7 +294,7 @@ class Pipeline:
         rec.wrap_attr(self.engine, "input_log_append", "append")
         rec.wrap_attr(self.engine, "flush_all", "flush")
         for plugin in self.filters:
-            for attr in ("filter_raw", "filter", "process_batch"):
+            for attr in ("filter", "process_batch"):
                 rec.wrap_attr(plugin, attr, f"filter:{plugin.name}")
 
 
@@ -365,23 +366,36 @@ def read_frames(work: str) -> list:
     return rows
 
 
-def expected_output(frames: list, bodies: list, labels: bytes):
-    """What the output must hold: for every acked frame, in order, the
-    lines whose construction label says the chain keeps them, as V2
-    events carrying the frame's time. → (sha256, bytes per frame)."""
-    kept_by_slot, digest, sizes = {}, hashlib.sha256(), []
-    for fr in frames:
-        if not fr["ack_ns"]:
-            sizes.append(0)
-            continue
-        key = (fr["slot"], fr["lines"])
+def kept_unchanged():
+    """The rule of a chain that passes records through or drops them:
+    the frame's lines whose construction label says the chain keeps
+    them, unchanged, as V2 events carrying the frame's time."""
+    kept_by_slot = {}
+
+    def one_frame(frame: dict, bodies: list, labels: bytes, wire) -> bytes:
+        key = (frame["slot"], frame["lines"])
         kept = kept_by_slot.get(key)
         if kept is None:
-            lo = fr["slot"] * fr["lines"]
+            lo = frame["slot"] * frame["lines"]
             kept = kept_by_slot[key] = [
-                bodies[i] for i in range(lo, lo + fr["lines"])
+                bodies[i] for i in range(lo, lo + frame["lines"])
                 if labels[i] & wire.KEEP]
-        part = wire.output_events(fr["wall_ns"], kept)
+        return wire.output_events(frame["wall_ns"], kept)
+    return one_frame
+
+
+def expected_output(frames: list, bodies: list, labels: bytes,
+                    reference=None):
+    """What the main sink must hold: for every acked frame, in order,
+    the bytes the configuration says the frame leaves there — its
+    reference's ``expected_output(frame, bodies, labels, wire)`` where
+    the module has one (a chain that transforms its records), else
+    :func:`kept_unchanged`. → (sha256, bytes per frame)."""
+    one_frame = getattr(reference, "expected_output", None) \
+        or kept_unchanged()
+    digest, sizes = hashlib.sha256(), []
+    for fr in frames:
+        part = one_frame(fr, bodies, labels, wire) if fr["ack_ns"] else b""
         digest.update(part)
         sizes.append(len(part))
     return digest.hexdigest(), sizes
@@ -415,6 +429,17 @@ def backlog_at_quarters(frames: list, start_ns: int, seconds: float):
     return out
 
 
+def tail_of(sample: list) -> dict:
+    """For the reader of the earlier lines: where the sample's tail
+    lies, whichever percentile the cell reports."""
+    out = {f"p{100 * q:g}": stats.percentile(sample, q)
+           for q in (0.5, 0.9, 0.95, 0.975, 0.99)
+           if stats.supported(len(sample), q)}
+    if sample:
+        out["max"] = max(sample)
+    return out
+
+
 def end_to_end(cell: Cell, frames: list, flushed: list, start_ns: int,
                seconds: float, setup_s: float) -> tuple:
     """The cell's end-to-end metrics, over all the work and all the time
@@ -431,6 +456,7 @@ def end_to_end(cell: Cell, frames: list, flushed: list, start_ns: int,
     values = {"setup_s": setup_s, "lines_per_s": acked_lines / seconds}
     for name, sample, q in (("ack_p50_ms", ack_ms, 0.5),
                             ("ack_p95_ms", ack_ms, 0.95),
+                            ("ack_p99_ms", ack_ms, 0.99),
                             ("flush_p95_ms", flush_ms, 0.95)):
         if stats.supported(len(sample), q):
             values[name] = stats.percentile(sample, q)
@@ -438,7 +464,27 @@ def end_to_end(cell: Cell, frames: list, flushed: list, start_ns: int,
     return {n: {"value": values[n], "unit": u}
             for n, u in units.items() if n in values}, \
         {"ack_samples": len(ack_ms), "flush_samples": len(flush_ms),
-         "acked_lines_in_window": acked_lines}
+         "acked_lines_in_window": acked_lines,
+         "ack_ms": tail_of(ack_ms), "flush_ms": tail_of(flush_ms)}
+
+
+def ack_by_length_bucket(win: list, bodies: list, labels: bytes,
+                         buckets: list) -> dict:
+    """For the reader of the earlier lines: the window's ack times by
+    the staging shape of the frame (``generator.length_bucket``)."""
+    by, bucket_of = {}, {}
+    for f in win:
+        if not f["ack_ns"] or not buckets:
+            continue
+        key = (f["slot"], f["lines"])
+        if key not in bucket_of:
+            bucket_of[key] = length_bucket(
+                bodies, labels, f["slot"] * f["lines"], f["lines"], buckets)
+        by.setdefault(bucket_of[key], []).append(
+            (f["ack_ns"] - f["due_ns"]) / 1e6)
+    return {str(b): {"frames": len(ms), "p50": stats.percentile(ms, 0.5),
+                     "p90": stats.percentile(ms, 0.9), "max": max(ms)}
+            for b, ms in sorted(by.items())}
 
 
 def per_layer(cell: Cell, readings: dict) -> dict:
@@ -457,24 +503,44 @@ def per_layer(cell: Cell, readings: dict) -> dict:
     return out
 
 
-def wire_checks(frames, win_delta, all_delta, gen_done, digest_out,
-                digest_exp, sink_bytes, exp_bytes, lanes, rehearse):
-    """The guarantees every configuration states, held to the program's
-    own counters and the generator's log. → (checks, skipped)."""
+def wire_numbers(frames, all_delta, digest_out, digest_exp, sink_bytes,
+                 exp_bytes) -> dict:
+    """The numbers the guarantees every configuration states are held
+    by, from the program's own counters and the generator's log. Every
+    comparison is exact: each has to be 0."""
     acked = [f for f in frames if f["ack_ns"]]
-    lines = sum(f["lines"] for f in acked)
+    return {
+        "frames_sent_not_acked": len(frames) - len(acked),
+        "withheld_acks": all_delta["forward.withheld_acks"],
+        "dedup_hits": all_delta["forward.dedup_hits"],
+        "absorbed_less_acked_frames":
+            all_delta["forward.absorbed"] - len(acked),
+        "records_in_less_acked_lines": all_delta["engine.records_in"]
+            - sum(f["lines"] for f in acked),
+        "raw_path_declines": all_delta["engine.raw_declines"],
+        "output_bytes_less_expected": sink_bytes - exp_bytes,
+        "output_sha256_differs": int(digest_out != digest_exp),
+    }
+
+
+def wire_checks(frames, win_delta, all_delta, gen_done, numbers,
+                exp_bytes, lanes, rehearse):
+    """The named verdicts over :func:`wire_numbers`, the connection and
+    the lanes the configuration names. → (checks, skipped)."""
     checks = {
         "connection_sound": gen_done["broken"] is None,
-        "every_sent_frame_acked": len(acked) == len(frames) > 0,
-        "no_withheld_acks": all_delta["forward.withheld_acks"] == 0,
-        "no_dedup_hits": all_delta["forward.dedup_hits"] == 0,
+        "every_sent_frame_acked":
+            numbers["frames_sent_not_acked"] == 0 < len(frames),
+        "no_withheld_acks": numbers["withheld_acks"] == 0,
+        "no_dedup_hits": numbers["dedup_hits"] == 0,
         "absorbed_equal_acked_frames":
-            all_delta["forward.absorbed"] == len(acked),
+            numbers["absorbed_less_acked_frames"] == 0,
         "records_in_equal_acked_lines":
-            all_delta["engine.records_in"] == lines,
-        "no_raw_path_declines": all_delta["engine.raw_declines"] == 0,
+            numbers["records_in_less_acked_lines"] == 0,
+        "no_raw_path_declines": numbers["raw_path_declines"] == 0,
         "output_equal_expected_survivors_in_order":
-            digest_out == digest_exp and sink_bytes == exp_bytes > 0,
+            numbers["output_sha256_differs"] == 0
+            and numbers["output_bytes_less_expected"] == 0 < exp_bytes,
     }
     device = {}
     for lane in lanes:
@@ -553,7 +619,9 @@ def measure(cell: Cell, args, gen: Generator, work: str) -> int:
         # drain: everything acked is handed to the output
         frames = read_frames(work)
         bodies, labels = read_corpus(work)
-        digest_exp, sizes = expected_output(frames, bodies, labels)
+        reference = load_py("reference", cell.config["name"])
+        digest_exp, sizes = expected_output(frames, bodies, labels,
+                                            reference)
         pipe.ctx.flush_now()
         deadline = time.monotonic() + 15
         while pipe.sink.n_bytes() < sum(sizes) \
@@ -574,10 +642,11 @@ def measure(cell: Cell, args, gen: Generator, work: str) -> int:
                               args.seconds, setup_s)
     win_delta = delta(after, before)
     all_delta = delta(end_counters, start_counters)
+    numbers = wire_numbers(frames, all_delta, digest_out, digest_exp,
+                           pipe.sink.n_bytes(), sum(sizes))
     checks, skipped = wire_checks(
-        frames, win_delta, all_delta, gen_done, digest_out, digest_exp,
-        pipe.sink.n_bytes(), sum(sizes), cell.config["device_lanes"],
-        args.rehearse)
+        frames, win_delta, all_delta, gen_done, numbers, sum(sizes),
+        cell.config["device_lanes"], args.rehearse)
 
     # the plain reference of this configuration
     per_slot = {}
@@ -588,7 +657,7 @@ def measure(cell: Cell, args, gen: Generator, work: str) -> int:
     line_counts = [per_slot.get(i // frame_lines, 0)
                    for i in range(len(labels))]
     t_ref = time.monotonic()
-    ref = load_py("reference", cell.config["name"]).checks({
+    ref = reference.checks({
         "cell": cell, "pipe": pipe, "bodies": bodies, "labels": labels,
         "line_counts": line_counts, "counters": all_delta,
         "rehearse": args.rehearse, "device": dev,
@@ -649,7 +718,9 @@ def measure(cell: Cell, args, gen: Generator, work: str) -> int:
                  "in_window": len(win), "broken": gen_done["broken"],
                  "backlog_at_quarters": backlog_at_quarters(
                      frames, start_ns, args.seconds)},
-         samples=samples, output={"sha256": digest_out,
+         samples=samples, ack_ms_by_length_bucket=ack_by_length_bucket(
+             win, bodies, labels, cell.config.get("length_buckets", [])),
+         output={"sha256": digest_out,
                                   "expected_sha256": digest_exp,
                                   "bytes": pipe.sink.n_bytes(),
                                   "chunks": len(pipe.sink.parts)},
@@ -660,6 +731,19 @@ def measure(cell: Cell, args, gen: Generator, work: str) -> int:
          trace=None if trace is None else {
              k: trace[k] for k in ("busy_s", "span_s", "window_s",
                                    "launches", "devices")})
+    # what was compared, beside its limit (every comparison is exact):
+    # last in the result's line and last on standard error, where the
+    # driver's record keeps it; the named checks say which verdict a
+    # non-zero ``failed_checks`` stands for
+    numbers["failed_checks"] = sum(1 for v in checks.values() if not v)
+    result["compared"] = {k: {"value": v, "limit": 0}
+                          for k, v in numbers.items()}
+    for name in sorted(k for k, v in checks.items() if not v):
+        print(f"failed check: {name}", file=sys.stderr)
+    for name, c in result["compared"].items():
+        print(f"compared {name}: {c['value']} (limit {c['limit']})",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0 if result["correct"] else 1
 

@@ -171,16 +171,18 @@ def test_every_cell_config_and_metric_resolves_by_file():
             tree = ast.parse(f.read())
         assert func in {n.name for n in tree.body
                         if isinstance(n, ast.FunctionDef)}, m["name"]
-        # the metric it moves is reported in every cell where this one is
+        # every per-layer metric names its cells, and the metric it
+        # moves is reported in every one of them
         moved = e2e[m["moves"]]
-        mine = set(m.get("workloads", cells))
+        mine = set(m["workloads"])
+        assert mine, m["name"]
         assert mine <= set(moved.get("workloads", cells)) and mine <= cells
 
 
 @pytest.mark.parametrize("path", [
     "generator.py", "wire.py", "lookup.py", "traffic_kinds/closed_loop.py",
     "traffic_kinds/open_loop.py", "corpora/grep_lines.py",
-    "corpora/firehose_events.py"])
+    "corpora/firehose_events.py", "corpora/syslog_lines.py"])
 def test_the_generator_imports_neither_jax_nor_the_program(path):
     stdlib = set(sys.stdlib_module_names) | {"wire", "lookup"}
     with open(os.path.join(BENCH, path)) as f:
@@ -206,3 +208,204 @@ def test_corpus_labels_are_the_same_work_for_every_seed():
     assert sum(1 for x in a[1] if x & 2) == sum(1 for x in b[1] if x & 2) == 0
     long_a = grep_lines.make(100000, 3, {})
     assert sum(1 for x in long_a[1] if x & 2) == 2
+
+
+# ------------------------------ what a configuration and a traffic file state
+
+PINNED_WALL = 1_790_000_000_123_456_789
+#: taken on the parent of the PR that brought ``mode`` and the reference's
+#: ``expected_output`` (commit d7ce668): a cell whose files state neither
+#: is generated and judged by the bytes it always was
+PARENT_FRAME_SHA256 = \
+    "0cf3af84daf170d1f4f7cccf6882ce0de9d922c819691dfc1e3ddecf243a6d17"
+PARENT_EXPECTED = (
+    "801cb3088e0d8cc8579cfb5de4dc3e4adbcccda73b2e77764e8f5b1e8d7489b7",
+    [86676, 0, 77614, 86676])
+
+
+@pytest.fixture(scope="module")
+def grep_frame():
+    """4,096 lines of the grep corpus under seed 7, as the generator
+    packs them: (config, bodies, labels)."""
+    sys.path.insert(0, os.path.join(BENCH, "corpora"))
+    import grep_lines
+
+    with open(os.path.join(BENCH, "configs", "grep-apache2.json")) as f:
+        config = json.load(f)
+    records, labels = grep_lines.make(4096, 7, config["corpus"]["params"])
+    return config, [wire.pack_str_map(r) for r in records], labels
+
+
+def some_frames():
+    return [{"slot": slot, "lines": 1024, "ack_ns": ack,
+             "wall_ns": 1_790_000_000_000_000_001 + i}
+            for i, (slot, ack) in enumerate(((0, 5), (2, 0), (3, 9),
+                                             (0, 11)))]
+
+
+def test_a_traffic_file_without_mode_sends_the_frame_it_always_did(
+        grep_frame):
+    import hashlib
+
+    config, bodies, _labels = grep_frame
+    with open(os.path.join(BENCH, "traffic", "catchup.json")) as f:
+        framer = wire.FRAMERS[json.load(f).get("mode", "forward")]
+    assert framer is wire.forward_frame
+    frame = framer(wire.pack_str(config["tag"]), PINNED_WALL, bodies,
+                   "%08x%08x" % (7, 0))
+    assert len(frame) == 522722
+    assert hashlib.sha256(frame).hexdigest() == PARENT_FRAME_SHA256
+
+
+def test_a_reference_without_expected_output_is_judged_as_before(
+        grep_frame):
+    import run
+
+    _config, bodies, labels = grep_frame
+    for name in ("grep-apache2", "sketch-firehose", "rewrite-syslog"):
+        with open(os.path.join(BENCH, "reference", name + ".py")) as f:
+            assert "def expected_output" not in f.read(), name
+    assert run.expected_output(some_frames(), bodies, labels) \
+        == PARENT_EXPECTED
+    assert run.expected_output(some_frames(), bodies, labels,
+                               reference=object()) == PARENT_EXPECTED
+
+
+def test_a_reference_with_expected_output_decides_the_main_sinks_check(
+        grep_frame):
+    """A toy reference whose chain upper-cases one field of what it
+    keeps: the check passes on the transformed bytes, fails on the sent
+    ones, and the per-frame sizes are the transformed ones."""
+    import hashlib
+    import types
+
+    import run
+
+    _config, bodies, labels = grep_frame
+
+    def upper(body: bytes) -> bytes:
+        record = wire.unpack_str_map(body)
+        record["log"] = record["log"].upper()
+        return wire.pack_str_map(record)
+
+    def one_frame(frame, bodies, labels, wire):
+        lo = frame["slot"] * frame["lines"]
+        return wire.output_events(frame["wall_ns"], [
+            upper(bodies[i]) for i in range(lo, lo + frame["lines"])
+            if labels[i] & wire.KEEP])
+
+    toy = types.SimpleNamespace(expected_output=one_frame)
+    frames = [f for f in some_frames() if f["ack_ns"]]
+    digest, sizes = run.expected_output(frames, bodies, labels, toy)
+    plain_digest, plain_sizes = run.expected_output(frames, bodies, labels)
+    assert sizes == plain_sizes and digest != plain_digest  # same lengths
+
+    def verdict(sink: bytes) -> bool:
+        n = len(frames)
+        counters = {"forward.withheld_acks": 0, "forward.dedup_hits": 0,
+                    "forward.absorbed": n, "engine.raw_declines": 0,
+                    "engine.records_in": sum(f["lines"] for f in frames)}
+        numbers = run.wire_numbers(
+            frames, counters, hashlib.sha256(sink).hexdigest(), digest,
+            len(sink), sum(sizes))
+        checks, _skipped = run.wire_checks(
+            frames, {}, counters, {"broken": None}, numbers, sum(sizes),
+            [], True)
+        others = {k: v for k, v in checks.items()
+                  if k != "output_equal_expected_survivors_in_order"}
+        assert all(others.values()), others
+        return checks["output_equal_expected_survivors_in_order"]
+
+    transformed = b"".join(one_frame(f, bodies, labels, wire)
+                           for f in frames)
+    sent = b"".join(run.kept_unchanged()(f, bodies, labels, wire)
+                    for f in frames)
+    assert verdict(transformed) is True
+    assert verdict(sent) is False
+    assert verdict(transformed[:-1]) is False
+
+
+def test_packed_frames_decode_to_the_events_of_the_forward_frame(
+        grep_frame):
+    """4,096 corpus lines framed both ways through ``in_forward``'s own
+    decode (CPU, no socket): the same tag, the same V2 events, the same
+    chunk id to ack."""
+    sys.path.insert(0, ROOT)
+    import fluentbit_tpu as flb
+    from fluentbit_tpu.plugins import net_forward
+
+    config, bodies, _labels = grep_frame
+    tag, chunk = wire.pack_str(config["tag"]), "%08x%08x" % (7, 3)
+    frames = {mode: framer(tag, PINNED_WALL, bodies, chunk)
+              for mode, framer in wire.FRAMERS.items()}
+    assert set(frames) == {"forward", "packed"}
+    assert frames["packed"].startswith(b"\x93" + tag + b"\xc6")   # bin32
+    assert frames["packed"].endswith(
+        b"\x82\xa4size\xcd\x10\x00\xa5chunk" + wire.pack_str(chunk))
+    assert wire.packed_forward_frame(tag, 5, [], "c") \
+        == b"\x93" + tag + b"\xc4\x00\x82\xa4size\x00\xa5chunk\xa1c"
+
+    ctx = flb.create(flush="50ms", grace="1")
+    ctx.input("forward", listen="127.0.0.1", port="0")
+    ctx.output("lib", match="*", callback=lambda _data, _tag: None)
+    ctx.start()
+    try:
+        plugin = next(i.plugin for i in ctx.engine.inputs
+                      if i.plugin.name == "forward")
+        decoded = {}
+        for mode, frame in frames.items():
+            unpacker = net_forward.Unpacker()
+            unpacker.feed(frame)
+            decoded[mode] = plugin._decode(next(unpacker))
+    finally:
+        ctx.stop()
+    for mode, (got_tag, buf, n, _option, ack_ref, _cid) in decoded.items():
+        assert (got_tag, n, ack_ref) == (config["tag"], 4096, chunk), mode
+        assert buf == wire.output_events(PINNED_WALL, bodies), mode
+    assert decoded["packed"][3]["size"] == 4096
+
+
+# -------------------------- the references guard the deployment, not a kernel
+
+def grep_patterns(pipeline: str) -> list:
+    with open(os.path.join(BENCH, "configs", pipeline)) as f:
+        return [line.split(None, 2)[2].strip() for line in f
+                if line.split(None, 1)[:1] in (["Regex"], ["Exclude"])]
+
+
+@pytest.mark.parametrize("name", ["grep-apache2", "rewrite-syslog"])
+def test_either_device_kernel_serves_a_child_and_the_host_does_not(name):
+    """The grep program with ``scan`` forced on every child, the S=10
+    rule's among them (on the chip ``auto`` gives that one ``assoc``):
+    the check holds. A child that never materialised — what it decides,
+    the host decides — fails it, and so does a filter without a
+    program."""
+    sys.path.insert(0, ROOT)
+    from lookup import load_py
+
+    from fluentbit_tpu.ops import device
+    from fluentbit_tpu.ops.grep import GrepProgram
+    from fluentbit_tpu.regex.dfa import compile_dfa
+
+    check = load_py("reference", name).children_on_device_kernels
+    patterns = grep_patterns("grep-apache2.conf")
+    assert len(patterns) == 2
+    dfas = [compile_dfa(p) for p in patterns]
+    assert sorted(d.n_states <= 64 for d in dfas) == [False, True]
+    assert device.wait(120)
+    for kernel in ("scan", "auto"):
+        program = GrepProgram(dfas, 512, kernel=kernel, plane_of=(0, 0))
+        assert len(program._children) == 2
+        assert check([program]) is False            # nothing materialised
+        assert program.try_ready()
+        assert {ch.kernel_resolved for ch in program._children} \
+            <= {"scan", "assoc"}
+        assert check([program]) is True, kernel
+    program._children[1].kernel_resolved = "assoc"  # as the chip resolves it
+    assert check([program]) is True
+    program._children[1].kernel_resolved = None     # resolved on the host
+    assert check([program]) is False
+    single = GrepProgram(dfas[:1], 512, kernel="scan")
+    assert single._children is None and check([single]) is False
+    assert single.try_ready() and check([single]) is True
+    assert check([]) is False

@@ -127,6 +127,46 @@ def test_idle_gaps_go_to_the_innermost_span_and_through_the_wait():
     assert sum(by.values()) == pytest.approx(t["idle_s"])
 
 
+def on_two_threads():
+    """PLANES as the program writes it since the input absorbs on a
+    worker of its own: read, unpack and re-encode stay on the loop's
+    thread, the absorb and everything beneath it — ``lane.wait`` among
+    it — move to a second one; the loop reads the next frame meanwhile."""
+    loop, lane, flux = (line["events"] for line in PLANES[0]["lines"])
+    moved = ("forward.absorb", "engine.append", "filter.grep", "lane.wait")
+    host = {"name": "/host:CPU", "lines": [
+        {"name": "MainThread", "events": [
+            e for e in loop if e[0][len("fbtpu:"):] not in moved]
+            + [ev("forward.read", 600, 950, bytes=9)]},
+        {"name": "flb-fw-forward.0", "events": [
+            e for e in loop if e[0][len("fbtpu:"):] in moved]},
+        {"name": "python", "events": lane},
+        {"name": "python", "events": flux}]}
+    return [host] + PLANES[1:]
+
+
+def test_idle_starts_from_the_thread_that_holds_the_wait():
+    """The wait on a second thread: the idle under it still goes to the
+    lane worker's spans, what the absorbing thread leaves uncovered goes
+    to the loop's, and the next frame's read (600-950), which lies under
+    the absorb, takes nothing of it."""
+    one, two = (spans.reduce_planes(p)["idle_by_span"]
+                for p in (PLANES, on_two_threads()))
+    want = dict(one, unattributed=50e-9)                  # 950-1000
+    want["forward.read"] = 60e-9 + 50e-9                  # and 900-950
+    assert {k for k, v in two.items() if v} == {k for k in want}
+    for name, seconds in want.items():
+        assert two[name] == pytest.approx(seconds), name
+    # from the loop's thread alone the same trace reads as it did before
+    # the reader was put right: the whole absorb under the next read
+    loop_only = spans._idle_by_span(
+        [(0, 400), (500, 700), (800, 1000)],
+        [[(s, s + d, (n[len("fbtpu:"):], st)) for n, s, d, st in
+          on_two_threads()[0]["lines"][0]["events"]]])
+    assert loop_only["forward.read"] == pytest.approx(60e-9 + 250e-9)
+    assert "grep.force" not in loop_only
+
+
 def test_modules_by_the_name_the_program_gave():
     t = spans.reduce_planes(PLANES)
     assert t["modules"] == {
@@ -173,7 +213,8 @@ def test_readers_over_the_table(one_table):
     assert spans.share(r, "l2m.query") is None
     assert spans.count_ratio(r, "forward.unpack", "l2m.query") is None
     assert spans.ms_per(r, "grep.force", "l2m.query") is None
-    assert trace.module_ms_per_launch(r, "flux_absorb", "grep") is None
+    # the lane launched, the device ran modules, none by this name
+    assert trace.module_ms_per_launch(r, "flux_absorb", "grep") == 0.0
     assert trace.module_ms_per_launch(r, "grep_scan", "flux") is None
     assert trace.module_ms_per_launch({"trace": None}, "grep_scan",
                                       "grep") is None
@@ -220,14 +261,17 @@ def test_every_new_metric_has_its_file_and_a_reader_that_exists():
     root = os.path.dirname(BENCH)
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    seen = 0
+    seen, theirs = 0, 0
     for m in bench["per_layer"]:
         with open(os.path.join(BENCH, "layer_metrics",
                                m["name"] + ".json")) as f:
             spec = json.load(f)
         module, func = spec["reader"].split(":")
+        theirs += '"reader": "program_' in open(os.path.join(
+            BENCH, "layer_metrics", m["name"] + ".json")).read()
         if module in ("program_spans", "program_trace"):
             assert callable(getattr(load_py("readers", module), func))
             assert m["workloads"], m["name"]
             seen += 1
-    assert seen == 9
+    # counted, not pinned: later PRs add such metrics
+    assert seen == theirs >= 9

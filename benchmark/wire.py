@@ -61,6 +61,41 @@ def forward_frame(tag: bytes, wall_ns: int, bodies: list,
                      pack_str_map({"chunk": chunk_id})))
 
 
+def bin_header(n: int) -> bytes:
+    if n <= 0xFF:
+        return struct.pack(">BB", 0xC4, n)
+    if n <= 0xFFFF:
+        return struct.pack(">BH", 0xC5, n)
+    return struct.pack(">BI", 0xC6, n)
+
+
+def pack_uint(n: int) -> bytes:
+    if n < 0x80:
+        return bytes((n,))
+    if n <= 0xFF:
+        return struct.pack(">BB", 0xCC, n)
+    if n <= 0xFFFF:
+        return struct.pack(">BH", 0xCD, n)
+    return struct.pack(">BI", 0xCE, n)
+
+
+def packed_forward_frame(tag: bytes, wall_ns: int, bodies: list,
+                         chunk_id: str) -> bytes:
+    """PackedForward, as upstream's ``out_forward`` sends it between two
+    Fluent Bits: ``[tag, bin(entry ‖ body ‖ entry ‖ body …), {"size":
+    n, "chunk": id}]`` — the same entries as :func:`forward_frame`'s,
+    concatenated in one ``bin`` and not in an array."""
+    entry = b"\x92" + event_time(wall_ns)
+    blob = (entry + entry.join(bodies)) if bodies else b""
+    return b"".join((b"\x93", tag, bin_header(len(blob)), blob,
+                     b"\x82", pack_str("size"), pack_uint(len(bodies)),
+                     pack_str("chunk"), pack_str(chunk_id)))
+
+
+#: how a traffic file's ``mode`` packs a frame; ``forward`` where it has none
+FRAMERS = {"forward": forward_frame, "packed": packed_forward_frame}
+
+
 def ack_message(chunk_id: str) -> bytes:
     """What in_forward answers: ``{"ack": chunk_id}``."""
     return _ACK_PREFIX + pack_str(chunk_id)
