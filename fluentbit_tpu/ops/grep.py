@@ -865,6 +865,160 @@ def _pad_rows(planes: np.ndarray, lengths: np.ndarray, Bp: int):
                 axis=1))
 
 
+class SpanProgram:
+    """One parser regex's capture spans on the device — the program
+    beside ``GrepProgram`` that returns more than a verdict.
+
+    ``dispatch(planes_u8[1, B, L], lengths[1, B]) -> (ok bool[B],
+    spans[B, G, 2])``: two dependent scans a launch over the tables of
+    ``regex.spans`` — right to left ``r_i = rev[r_{i+1}, cls(x[i])]``,
+    emitting a reverse state for every byte (``[L, B]`` i32), then left
+    to right ``(u_{i+1}, tags_i) = fwd[u_i, cls(x[i]), r_{i+1}]``, the
+    carry holding the ``2G`` offsets next to the walk state (a tag's
+    bit writes ``i``). Stride 1: one ``[B]`` gather a byte and a pass.
+    ``spans[b, g]`` is group ``g``'s (start, end) in bytes of the
+    staged value, (-1, -1) for a group the walk never entered and for
+    every group of a row that does not match; i16 where ``max_len``
+    fits it. Byte classing is ``GrepProgram._byte_classes`` (no
+    gather). One device: a parser launches unsharded, as
+    ``rewrite_tag`` does."""
+
+    n_planes = 1
+
+    def __init__(self, tables, max_len: int = 512):
+        if not HAVE_JAX:
+            raise RuntimeError("jax is unavailable")
+        self.tables = tables
+        self.max_len = max_len
+        self.names = list(tables.names)
+        self.span_dtype = np.int16 if max_len < (1 << 15) else np.int32
+        base, start, delta = class_runs(tables.class_map)
+        NW, C, NR = tables.fwd.shape
+        self._np = {
+            "rev_flat": np.ascontiguousarray(tables.rev.reshape(-1)),
+            "fwd_flat": np.ascontiguousarray(tables.fwd.reshape(-1)),
+            "class_base": np.asarray([base], dtype=np.int32),
+            "run_start": (start if start.size else
+                          np.asarray([256], np.int32))[None, :],
+            "run_delta": (delta if delta.size else
+                          np.asarray([0], np.int32))[None, :],
+        }
+        self._shape = (NW, C, NR)
+        self.kernel_resolved: Optional[str] = None
+        self._jit = None
+        self._mat_lock = threading.Lock()
+
+    def decision(self) -> dict:
+        """What was built, for the benchmark's references and the
+        log."""
+        NW, C, NR = self._shape
+        return {"pattern": self.tables.pattern, "groups": self.names,
+                "nfa_states": self.tables.nfa_states,
+                "walk_states": NW - 2, "classes": C, "reverse_states": NR,
+                "table_bytes": 4 * (NW * C * NR + NR * C),
+                "span_dtype": np.dtype(self.span_dtype).name,
+                "kernel_resolved": self.kernel_resolved}
+
+    def program_name(self) -> str:
+        NW, _C, NR = self._shape
+        return f"grep_spans_W{NW - 2}_R{NR}"
+
+    def _materialize(self) -> None:
+        with self._mat_lock:
+            if self._jit is not None:
+                return
+            tbl = {k: jnp.asarray(v) for k, v in self._np.items()}
+
+            def impl(planes, lengths):
+                return self._spans_impl(tbl, planes[0], lengths[0])
+
+            impl.__name__ = self.program_name()
+            self.kernel_resolved = "spans"
+            self._jit = jax.jit(impl)
+            self._np = None
+            log.info("span program materialized: %s", self.decision())
+
+    def try_ready(self) -> bool:
+        if self._jit is not None:
+            return True
+        from . import device
+
+        if not device.ready():
+            device.attach_async()
+            return False
+        self._materialize()
+        return True
+
+    def _spans_impl(self, t: dict, plane: "jnp.ndarray",
+                    lengths: "jnp.ndarray"):
+        B, L = plane.shape
+        _NW, C, NR = self._shape
+        tb = self.tables
+        G2 = 2 * len(self.names)
+        eol = jnp.int32(tb.eol_class)
+        with jax.named_scope("spans.symbols"):
+            cls = GrepProgram._byte_classes(t, plane[None])[0]  # [B, L]
+            pos = jnp.arange(L, dtype=jnp.int32)
+            cls = jnp.where(pos[None, :] >= lengths[:, None], eol, cls)
+            cls_t = cls.T  # [L, B]
+
+        def rev_step(r, c_i):
+            r = jnp.take(t["rev_flat"], r * C + c_i)
+            return r, r
+
+        # + 0*lengths: the carry's type follows the batch, as in
+        # _match_impl
+        r_eol = jnp.full((B,), tb.r_eol, dtype=jnp.int32) + 0 * lengths
+        with jax.named_scope("spans.reverse"):
+            # r_i for every byte; the set at the first EOL is the same
+            # whatever follows it, so the scan starts from there
+            _, rr = lax.scan(rev_step, r_eol, cls_t, reverse=True)
+        # what step i reads is r_{i+1}; past the last symbol: the empty
+        # set, id 0
+        r_next = jnp.concatenate(
+            [rr[1:], r_eol[None], jnp.zeros((1, B), jnp.int32)], axis=0)
+        cls_f = jnp.concatenate(
+            [cls_t, jnp.broadcast_to(eol, (1, B))], axis=0)  # [L+1, B]
+        mask = jnp.int32((1 << tb.state_bits) - 1)
+        bit = jnp.arange(G2, dtype=jnp.int32)[:, None]
+
+        def fwd_step(carry, x):
+            u, sp = carry
+            c_i, r_i, i = x
+            packed = jnp.take(t["fwd_flat"], (u * C + c_i) * NR + r_i)
+            tags = packed >> tb.state_bits
+            sp = jnp.where((tags[None, :] >> bit) & 1 != 0, i, sp)
+            return (packed & mask, sp), None
+
+        u0 = jnp.full((B,), tb.start, dtype=jnp.int32) + 0 * lengths
+        sp0 = jnp.full((G2, B), -1, dtype=jnp.int32) + 0 * lengths[None]
+        with jax.named_scope("spans.walk"):
+            (u, sp), _ = lax.scan(
+                fwd_step, (u0, sp0),
+                (cls_f, r_next, jnp.arange(L + 1, dtype=jnp.int32)))
+        ok = (u == 1) & (lengths >= 0)  # regex.spans.ACCEPTED
+        sp = jnp.where(ok[None, :], sp, -1)
+        return ok, sp.T.reshape(B, G2 // 2, 2).astype(self.span_dtype)
+
+    def dispatch(self, planes: np.ndarray, lengths: np.ndarray):
+        """Launch WITHOUT forcing (as ``GrepProgram.dispatch``): the one
+        staged plane ``[1, B, L]`` and its lengths ``[1, B]`` → device
+        ``(ok[B], spans[B, G, 2])``."""
+        if self._jit is None:
+            from . import device
+
+            if not device.wait(60.0):
+                raise RuntimeError(
+                    f"device backend not attached: {device.status()}")
+            self._materialize()
+        return self._jit(jnp.asarray(planes), jnp.asarray(lengths))
+
+    def spans(self, planes: np.ndarray, lengths: np.ndarray):
+        """Run and force: numpy ``(ok[B], spans[B, G, 2])``."""
+        ok, sp = self.dispatch(planes, lengths)
+        return np.asarray(ok), np.asarray(sp)
+
+
 def grep_first_match(mask):
     """``mask[R, B]`` (bool or i32) → ``[B]`` i32: the first rule in row
     order that accepts the record, or -1 — the reduction of a
@@ -895,3 +1049,18 @@ def program_for(patterns: Sequence[str], max_len: int = 512,
     return _cached_program(
         tuple(patterns), max_len,
         None if plane_of is None else tuple(int(p) for p in plane_of))
+
+
+@functools.lru_cache(maxsize=16)
+def _cached_span_program(pattern: str, max_len: int) -> "SpanProgram":
+    from ..regex import parse
+    from ..regex.spans import compile_spans
+
+    return SpanProgram(compile_spans(parse(pattern)), max_len)
+
+
+def span_program_for(pattern: str, max_len: int = 512) -> "SpanProgram":
+    """The span program of one parser regex, cached by pattern; raises
+    ``regex.spans.SpanDecline`` (or ``UnsupportedRegex``) with the
+    reason where the pattern lies outside the class it is exact in."""
+    return _cached_span_program(pattern, max_len)

@@ -11,11 +11,15 @@ by filter_parser / in_tail / multiline.
 success or ``None`` on parse failure — the (out_buf, out_time) contract
 of flb_parser_do.
 
-Device note: for regex parsers whose pattern is DFA-expressible the
-match decision can run vectorized on device (fluentbit_tpu.ops.grep) as
-a prefilter; capture extraction runs on the CPU for matching records
-(match-then-extract two-pass — the tagged-DFA single-pass is future
-work).
+Device note: for a regex parser whose pattern lies inside the class
+``regex.spans`` is exact in, filter_parser's batched path takes the
+named groups' offsets from the device (``ops.grep.SpanProgram``: two
+scans over the staged plane) and hands the cut fields to
+``Parser.do_fields`` — the one entry that ``do`` goes through too, so
+``Skip_Empty_Values``, ``Types`` and the ``Time_Key`` lookup mean the
+same whoever found the captures. Outside that class, and on the
+decoded-event path, the match decision may still run on the device as a
+prefilter and Python ``re`` extracts the captures of matching records.
 """
 
 from __future__ import annotations
@@ -134,8 +138,9 @@ class Parser:
 
     def do(self, text: str) -> Optional[Tuple[Dict[str, Any], Optional[float]]]:
         if self.fmt == "regex":
-            fields = self._do_regex(text)
-        elif self.fmt == "json":
+            got = self.regex.parse_record(text)
+            return None if got is None else self.do_fields(got)
+        if self.fmt == "json":
             fields = self._do_json(text)
         elif self.fmt == "logfmt":
             fields = self._do_logfmt(text)
@@ -184,18 +189,23 @@ class Parser:
                     fields[k] = caster(v)
         return fields
 
-    def _do_regex(self, text: str) -> Optional[Dict[str, Any]]:
-        got = self.regex.parse_record(text)
-        if got is None:
-            return None
+    def do_fields(self, captures: Dict[str, str]
+                  ) -> Optional[Tuple[Dict[str, Any], Optional[float]]]:
+        """The regex parser's back half, from the named captures of a
+        match (group order; a group that took no part left out) to
+        ``do``'s result: ``Skip_Empty_Values``, zero fields = parse
+        failure, ``Types``, the ``Time_Key`` lookup. Whoever found the
+        captures — Python ``re`` in ``do``, the device's spans in
+        filter_parser — the semantics are these."""
         fields: Dict[str, Any] = {}
-        for k, v in got.items():
+        for k, v in captures.items():
             if v == "" and self.skip_empty_values:
                 continue
             fields[k] = v
         if not fields:
             return None  # zero extracted fields = parse failure
-        return self._apply_types(fields)
+        fields = self._apply_types(fields)
+        return fields, self._extract_time(fields)
 
     def _do_json(self, text: str) -> Optional[Dict[str, Any]]:
         try:

@@ -28,7 +28,7 @@ the fused walk ends in one verdict → compaction tail.
 from __future__ import annotations
 
 import logging
-from typing import List, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -197,6 +197,45 @@ def host_mask(rules, plane_of, planes: np.ndarray, lengths: np.ndarray,
     return mask
 
 
+class SpanVerdict(NamedTuple):
+    """``staged_match(..., spans=True)``'s verdict: ``ok[n]`` bool,
+    ``spans[n, G, 2]`` (start, end) in bytes of the staged value (-1
+    where a group took no part or the row does not match), the staged
+    ``lengths[n]`` (-1 missing or not a string, -2 longer than
+    ``max_len``: rows the caller decides on the host) and ``planes``,
+    each segment's staged ``[cnt, L]`` u8 rows in order — the bytes the
+    spans cut."""
+
+    ok: np.ndarray
+    spans: np.ndarray
+    lengths: np.ndarray
+    planes: list
+
+
+def host_spans(regex, n_groups: int, plane: np.ndarray,
+               lengths: np.ndarray, cnt: int):
+    """Bit-exact host twin of the span program over one staged plane —
+    the DeviceLane fallback: ``FlbRegex.parse_spans``'s offsets (in
+    bytes) for every row with a length, nothing for the others."""
+    ok = np.zeros(cnt, dtype=bool)
+    spans = np.full((cnt, n_groups, 2), -1, dtype=np.int32)
+    for i in range(cnt):
+        li = int(lengths[i])
+        if li < 0:
+            continue
+        text = bytes(plane[i, :li]).decode("utf-8", "surrogateescape")
+        got = regex.parse_spans(text)
+        if got is None:
+            continue
+        if not text.isascii():
+            got = [(-1, -1) if s < 0 else tuple(
+                len(text[:x].encode("utf-8", "surrogateescape"))
+                for x in (s, e)) for s, e in got]
+        ok[i] = True
+        spans[i] = got
+    return ok, spans
+
+
 def decoded_match(rules, program, lane, events: list,
                   max_len: int) -> np.ndarray:
     """The decoded path's launch: stage each distinct field of
@@ -238,10 +277,10 @@ def decoded_match(rules, program, lane, events: list,
 
 def staged_match(rules, program, lane, tm, data, n_records, *,
                  max_len: int, min_records: int, mesh=None,
-                 first_match: bool = False):
+                 first_match: bool = False, spans: bool = False):
     """Device matching straight off chunk bytes, with double-buffered
-    staging — the one staged launch ``filter_grep`` and
-    ``filter_rewrite_tag`` share.
+    staging — the one staged launch ``filter_grep``,
+    ``filter_rewrite_tag`` and ``filter_parser`` share.
 
     The chunk's records split into fixed-size segments; each DISTINCT
     key the rules read is staged once a segment
@@ -260,13 +299,23 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
     the staged lengths are donated to the kernel where they can alias
     its output.
 
+    With ``spans`` the one rule's ``program`` is an
+    ``ops.grep.SpanProgram`` and the verdict a :class:`SpanVerdict`:
+    the named groups' offsets a row, from the device or from
+    :func:`host_spans`; rows without a staged value (-1, -2) are left
+    for the caller, which builds records and decides them on the host
+    per row. One device only (no ``mesh``).
+
     ``tm`` (the plugin's ``raw_timings``) takes ``extract_s``,
     ``kernel_s`` (wall less extraction), ``h2d_bytes`` (the planes and
-    their lengths), ``device_records`` and ``overflow_rows``.
-    Returns ``(verdict, offsets[n+1], n)`` — ``mask[R, n]`` bool, or
-    with ``first_match`` the ``[n]`` i32 first-match vector — or None
-    to decline (fewer than ``min_records`` records, or bytes the
-    staging walk cannot serve); nothing is counted on a decline."""
+    their lengths), ``device_records`` and ``overflow_rows``, and with
+    ``spans`` also ``d2h_bytes`` (the verdicts and offsets copied out
+    — a byte a row in the other kinds).
+    Returns ``(verdict, offsets[n+1], n)`` — ``mask[R, n]`` bool, with
+    ``first_match`` the ``[n]`` i32 first-match vector, with ``spans``
+    the :class:`SpanVerdict` — or None to decline (fewer than
+    ``min_records`` records, or bytes the staging walk cannot serve);
+    nothing is counted on a decline."""
     import os as _os
     import time as _time
 
@@ -301,6 +350,7 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
     extract_s = [0.0]
     lens_parts: list = []
     cnts: list = []
+    plane_parts: list = []  # spans: the staged rows the offsets cut
     offs_box = [offsets]  # filled by staging when not pre-scanned
 
     n_dev = mesh.devices.size if mesh is not None else 1
@@ -375,14 +425,19 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
         # enqueue + argument copy-in, then the wait for the
         # execution and the copy-out
         with span("grep.dispatch"):
-            out = program.dispatch(b, ln, first_match=first_match)
+            out = program.dispatch(b, ln) if spans else \
+                program.dispatch(b, ln, first_match=first_match)
         with span("grep.force"):
+            if spans:
+                return tuple(np.asarray(o) for o in out)
             return np.asarray(out)
 
     def dispatch(item):
         batch, lengths, cnt, si = item
         lens_parts.append(lengths[:, :cnt])
         cnts.append(cnt)
+        if spans:
+            plane_parts.append(batch[0, :cnt])
         tm.add("h2d_bytes", batch.nbytes + lengths.nbytes)
         if mesh is not None:
             # sharded launch through the device fault domain: the
@@ -413,6 +468,9 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
                 return forced(b, ln)
 
         def fallback(b=batch, ln=lengths, c=cnt):
+            if spans:
+                return host_spans(rules[0].regex, len(program.names),
+                                  b[0], ln[0], c)
             mask = host_mask(rules, plane_of, b, ln, c)
             return first_of_mask(mask) if first_match else mask
 
@@ -434,13 +492,22 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
     tm.add("extract_s", extract_s[0])
     tm.add("kernel_s", max(wall - extract_s[0], 0.0))
     offsets = offs_box[0]
-    verdict = np.concatenate(
-        [np.asarray(v)[..., :c] for v, c in zip(verdicts, cnts)], axis=-1)
     lengths = np.concatenate(lens_parts, axis=1)
-    # overflow rows (-2): decode just those records on the CPU
     overflow_rows = np.unique(np.nonzero(lengths == -2)[1])
     tm.add("device_records", n)
     tm.add("overflow_rows", len(overflow_rows))
+    if spans:
+        # the caller builds the records, and decides on the host those
+        # that staged no value
+        tm.add("d2h_bytes", sum(ok.nbytes + sp.nbytes
+                                for ok, sp in verdicts))
+        return SpanVerdict(
+            np.concatenate([ok[:c] for (ok, _), c in zip(verdicts, cnts)]),
+            np.concatenate([sp[:c] for (_, sp), c in zip(verdicts, cnts)]),
+            lengths[0], plane_parts), offsets, n
+    verdict = np.concatenate(
+        [np.asarray(v)[..., :c] for v, c in zip(verdicts, cnts)], axis=-1)
+    # overflow rows (-2): decode just those records on the CPU
     if len(overflow_rows):
         from ..codec.events import decode_events
 

@@ -17,24 +17,43 @@ record-accessor lookups). Python dicts cannot hold duplicates, so on key
 collision the parsed value wins — the same value a reference RA lookup
 would return.
 
-Device path: with a single DFA-expressible regex parser and a large
-append, the match decision runs vectorized on device
-(fluentbit_tpu.ops.grep) and capture extraction runs only for matching
-records (match-then-extract two-pass).
+Batched path (``process_batch``, the engine's raw hook), for one parser
+on a plain top-level key — byte-equal to the per-record chain:
 
-Batched fast path (``process_batch``): on the engine's raw ingest path
-whole chunks bypass per-record Python entirely —
+- **json** (no ``Time_Format``, no ``Reserve_Data`` / ``Preserve_Key``):
+  the fbtpu_codec C extension transcodes each record's JSON field
+  straight to msgpack (``parser_json_batch``), whole chunk, no device
+  program; anything it cannot serve declines to the per-record path.
+- **regex, on the chip**: once a non-CPU backend is attached and the
+  regex lies inside the class ``regex.spans`` is exact in, the chunk
+  goes through ``filter_grep.staged_match(..., spans=True)`` — the key
+  staged once a segment, the span program (``ops.grep.SpanProgram``)
+  launched through the ``grep`` DeviceLane on one device, the named
+  groups' offsets copied back — and records are built from the spans
+  without ``re``: fields cut from the staged value, then
+  ``Parser.do_fields`` (``Skip_Empty_Values``, zero fields = failure,
+  ``Types``, the ``Time_Key`` lookup and drop) and ``Reserve_Data`` /
+  ``Preserve_Key`` as the per-record path has them; an unmatched row's
+  bytes pass through untouched, a chunk with no match returns its
+  buffer. Rows the program cannot decide go to the host per row and are
+  counted (``host_rows``): a value longer than ``tpu_max_record_len``,
+  a missing key or a ``bin`` value, and a value with a byte past ASCII
+  (Python ``re`` reads characters where the automaton reads bytes, so
+  byte and character spans could differ). Outside the class (a
+  nullable loop body, a named group under a repetition, a possessive
+  quantifier, ``\\Z``, a back-reference …) the program is not built;
+  the reason is logged and shows in ``decision()``.
+- **regex, on the host** (while the device attaches, on a CPU backend,
+  under ``tpu_batch_records`` records, outside the class): the native
+  one-pass DFA computes the match mask off chunk bytes and Python
+  ``re`` extracts the captures of matching records. ``Reserve_Data``,
+  ``Preserve_Key``, ``Types`` and ``Time_Format`` are served here too.
 
-- json parser (plain key, defaults): the fbtpu_codec C extension
-  transcodes each record's JSON field straight to msgpack
-  (``parser_json_batch``), byte-exact with json.loads → pack_event;
-- regex parser: the native one-pass DFA (fluentbit_tpu.native) computes
-  the match mask off chunk bytes and capture extraction runs only for
-  matching records.
-
-Exotic options (reserve_data, preserve_key, time_format, record-
-accessor keys, multiple parsers, types) decline to the per-record path
-— identical output either way, just slower.
+Record-accessor keys and several parsers keep the per-record path
+(``filter``), where a single DFA-expressible regex parser on a large
+append still takes its match mask from the device
+(``_device_match_mask``) and extracts captures for matching records
+only.
 """
 
 from __future__ import annotations
@@ -42,14 +61,42 @@ from __future__ import annotations
 import logging
 from typing import List, Optional
 
+import numpy as np
+
 from .. import failpoints as _fp
 from ..codec.events import LogEvent
 from ..core.config import ConfigMapEntry
 from ..core.plugin import FilterPlugin, FilterResult, registry
 from ..core.record_accessor import RecordAccessor
+from ..core.spans import ShardedTimings, span
 
 
 log = logging.getLogger("flb")
+
+#: ``raw_timings`` keys of the batched regex path on the chip. The first
+#: six are the staged launch's (``filter_grep.staged_match``);
+#: ``build_s`` is the time from the spans to the chunk's new bytes,
+#: ``parsed`` the records it replaced, ``host_rows`` the rows decided on
+#: the host per row (overflow rows, a missing key or a ``bin`` value, a
+#: byte past ASCII)
+_TIMING_KEYS = ("extract_s", "kernel_s", "h2d_bytes", "d2h_bytes",
+                "device_records", "overflow_rows", "build_s", "parsed",
+                "host_rows")
+
+#: ``[[EventTime, {}], {`` — the head of an event whose time is the
+#: Forward protocol's ext and whose metadata is empty, before the body
+#: map's own header
+_EVENT_HEAD = b"\x92\x92\xd7\x00"
+
+
+class _KeyRule:
+    """What ``staged_match`` reads of a rule: the key and the regex."""
+
+    __slots__ = ("ra", "regex")
+
+    def __init__(self, key: str, regex):
+        self.ra = RecordAccessor(key)
+        self.regex = regex
 
 
 def _to_str(v) -> Optional[str]:
@@ -119,12 +166,17 @@ class ParserFilter(FilterPlugin):
                 self._prefilter = None
 
         # batched raw-path mode (process_batch): "json" = whole-chunk C
-        # transcode, "regex" = native DFA mask + captures for matches
-        # only. Option combinations outside these shapes keep the
-        # per-record path (bit-exact, just slower).
+        # transcode, "regex" = spans from the device where the regex is
+        # inside the span program's class, else the native DFA mask +
+        # captures for matches only. Record-accessor keys and several
+        # parsers keep the per-record path (bit-exact, just slower).
         self._batch_mode = None
         self._batch_key = None
         self._batch_tables = None
+        self._spans = None
+        self._span_rule = None
+        self._span_decline: Optional[str] = None
+        self.raw_timings = ShardedTimings(_TIMING_KEYS)
         p0 = self.parsers[0]
         if self.ra is None and len(self.parsers) == 1 and self.key_name:
             key = self.key_name.encode("utf-8")
@@ -154,6 +206,48 @@ class ParserFilter(FilterPlugin):
                             "parser native table build failed; batched "
                             "regex fast path disabled", exc_info=True)
                         self._batch_tables = None
+                if self._batch_mode == "regex" and self.tpu_enable:
+                    self._init_spans(p0)
+
+    def _init_spans(self, p0) -> None:
+        """Build the span program where the batched regex path will
+        launch it; a pattern outside its class declines with the
+        reason, and the host path serves as before."""
+        from ..regex import UnsupportedRegex
+        from ..regex.spans import SpanDecline
+
+        try:
+            from ..codec.msgpack import packb
+            from ..ops import device
+            from ..ops.grep import span_program_for
+
+            p0.regex._py()  # the host twin has to compile too
+            self._spans = span_program_for(p0.regex.pattern,
+                                           self.tpu_max_record_len)
+            self._span_rule = _KeyRule(self.key_name, p0.regex)
+            # the body {key: <the value>} and nothing else, from its
+            # map header on
+            self._single_pair = b"\x81" + packb(self.key_name)
+            device.wait()  # bounded; the host path serves until attached
+            self._spans.try_ready()
+        except (SpanDecline, UnsupportedRegex) as e:
+            self._span_decline = str(e)
+            log.info("parser %s: no span program, the host path serves: "
+                     "%s", p0.name, e)
+        except Exception:
+            self._spans = None
+            self._span_decline = "the span program could not be built"
+            log.debug("parser span program unavailable; host path "
+                      "serves", exc_info=True)
+
+    def decision(self) -> dict:
+        """What the batched path will do, and why not more."""
+        return {
+            "batch_mode": self._batch_mode,
+            "spans": None if self._spans is None
+            else self._spans.decision(),
+            "span_decline": self._span_decline,
+        }
 
     # -- per-record semantics --
 
@@ -167,26 +261,32 @@ class ParserFilter(FilterPlugin):
         """Try the parsers in order; build the replacement event."""
         for p in self.parsers:
             got = p.do(value)
-            if got is None:
-                continue
-            fields, ts = got
-            body = dict(fields)
-            if self.reserve_data:
-                for k, v in ev.body.items():
-                    if (
-                        self.ra is None
-                        and k == self.key_name
-                        and not self.preserve_key
-                    ):
-                        continue
-                    body.setdefault(k, v)
-            elif self.preserve_key and self.ra is None:
-                body.setdefault(self.key_name, ev.body.get(self.key_name))
-            new_ts = ev.timestamp if (ts is None or ts == 0) else ts
-            return LogEvent(
-                timestamp=new_ts, body=body, metadata=ev.metadata, raw=None
-            )
+            if got is not None:
+                return self._replace(ev.timestamp, ev.metadata, ev.body,
+                                     *got)
         return None
+
+    def _replace(self, timestamp, metadata, orig: dict, fields: dict,
+                 ts) -> LogEvent:
+        """The event a successful parse leaves: the parsed fields, the
+        originals ``Reserve_Data`` / ``Preserve_Key`` keep, the parsed
+        time where there is one."""
+        body = dict(fields)
+        if self.reserve_data:
+            for k, v in orig.items():
+                if (
+                    self.ra is None
+                    and k == self.key_name
+                    and not self.preserve_key
+                ):
+                    continue
+                body.setdefault(k, v)
+        elif self.preserve_key and self.ra is None:
+            body.setdefault(self.key_name, orig.get(self.key_name))
+        new_ts = timestamp if (ts is None or ts == 0) else ts
+        return LogEvent(
+            timestamp=new_ts, body=body, metadata=metadata, raw=None
+        )
 
     def _device_match_mask(self, values: List[Optional[str]]):
         """Vectorized match prefilter; None → row handled on CPU."""
@@ -247,7 +347,121 @@ class ParserFilter(FilterPlugin):
             return (n, data, n)  # nothing parseable: zero-copy
         return (n, out, n)
 
+    def _span_serves(self) -> bool:
+        """The platform gate (filter_grep's and rewrite_tag's): the
+        span program is built, a non-CPU backend is attached and the
+        program is on it."""
+        from ..ops import device
+
+        return (self._spans is not None
+                and device.platform() not in (None, "cpu")
+                and self._spans.try_ready())
+
+    def _lane(self):
+        """The DFA plane's fault domain: the span launch goes through
+        the process-global "grep" DeviceLane, as every launch of
+        ``staged_match`` does."""
+        from ..ops import fault
+
+        return fault.lane("grep")
+
     def _process_batch_regex(self, chunk):
+        """Spans from the device where it serves, records built from
+        them; else the host path below. → ``(n, bytes, n)``."""
+        from .filter_grep import staged_match
+
+        data = chunk.as_bytes()
+        if self._span_serves():
+            with span("parser.stage"):
+                got = staged_match(
+                    [self._span_rule], self._spans, self._lane(),
+                    self.raw_timings, data, chunk.n,
+                    max_len=self.tpu_max_record_len,
+                    min_records=self.tpu_batch_records, spans=True)
+            if got is not None:
+                return self._build_from_spans(data, *got)
+        return self._process_batch_host(chunk, data)
+
+    def _build_from_spans(self, data, res, offsets, n):
+        """Records from spans, without ``re``: a device row's fields are
+        cut from its staged value and go through ``Parser.do_fields``;
+        a row the program did not decide (no staged value, or a byte
+        past ASCII) is decoded and parsed on the host; every other
+        record's bytes pass through, in runs."""
+        from ..codec.events import decode_events, reencode_event
+        from ..codec.msgpack import EventTime
+
+        tm = self.raw_timings
+        p0 = self.parsers[0]
+        names = self._spans.names
+        need_orig = self.reserve_data or self.preserve_key
+        parts: list = []
+        kept_from = 0  # records [kept_from, i) pass through as one run
+        parsed = 0
+        with tm.timed("build_s", "parser.build", rows=n,
+                      parsed=int(res.ok.sum())):
+            host = res.lengths < 0
+            rows: list = []  # each row's staged bytes
+            at = 0
+            for plane in res.planes:
+                cnt, L = plane.shape
+                ln = res.lengths[at: at + cnt]
+                high = (plane >= 0x80) & (
+                    np.arange(L, dtype=np.int32)[None, :] < ln[:, None])
+                host[at: at + cnt] |= high.any(axis=1)
+                buf = plane.tobytes()
+                rows.extend(buf[i * L: i * L + max(int(ln[i]), 0)]
+                            for i in range(cnt))
+                at += cnt
+            tm.add("host_rows", int(host.sum()))
+            todo = np.nonzero(res.ok | host)[0]
+            try:
+                for i, sp in zip(todo.tolist(), res.spans[todo].tolist()):
+                    rec = data[offsets[i]: offsets[i + 1]]
+                    if host[i]:
+                        ev = decode_events(rec)[0]
+                        v = self._get_value(ev.body)
+                        new_ev = self._apply(ev, v) \
+                            if v is not None else None
+                    else:
+                        value = rows[i]
+                        got = p0.do_fields({
+                            name: value[s:e].decode("ascii")
+                            for name, (s, e) in zip(names, sp) if s >= 0})
+                        if got is None:
+                            new_ev = None
+                        elif rec.startswith(_EVENT_HEAD) \
+                                and rec[12] == 0x80 and (
+                                    not need_orig or rec.startswith(
+                                        self._single_pair, 13)):
+                            # [[EventTime, {}], body], and where the
+                            # originals count the body is {key: value}:
+                            # nothing to decode
+                            new_ev = self._replace(
+                                EventTime.from_bytes(rec[4:12]), {},
+                                {self.key_name: value.decode("ascii")}
+                                if need_orig else {}, *got)
+                        else:
+                            ev = decode_events(rec)[0]
+                            new_ev = self._replace(
+                                ev.timestamp, ev.metadata, ev.body, *got)
+                    if new_ev is None:
+                        continue
+                    if kept_from < i:
+                        parts.append(data[offsets[kept_from]: offsets[i]])
+                    parts.append(reencode_event(new_ev))
+                    kept_from = i + 1
+                    parsed += 1
+            except (ValueError, IndexError):
+                return None  # a record that does not decode: decline
+            tm.add("parsed", parsed)
+            if not parts:
+                return (n, data, n)  # nothing parsed: zero-copy
+            if kept_from < n:
+                parts.append(data[offsets[kept_from]: offsets[n]])
+            return (n, b"".join(parts), n)
+
+    def _process_batch_host(self, chunk, data):
         """Native one-pass DFA mask over chunk bytes; the regex (with
         captures) runs only for records the mask admits — mask-false
         records skip the Python regex entirely (the DFA is the
@@ -256,7 +470,6 @@ class ParserFilter(FilterPlugin):
         from .. import native
         from ..codec.events import decode_events, reencode_event
 
-        data = chunk.as_bytes()
         got = native.grep_match(data, self._batch_tables, n_hint=chunk.n)
         if got is None:
             return None

@@ -204,6 +204,17 @@ class FlbRegex:
             return (m.group(0),) + tuple(m.group(i) for _, i in ordered)
         return (m.group(0),) + m.groups()
 
+    def parse_spans(self, text: str):
+        """The named groups' ``(start, end)`` in group order, in
+        characters of ``text`` ((-1, -1) for a group that took no part)
+        — ``parse_record``'s offsets, what the device's span program is
+        held to. None when the pattern does not match."""
+        py = self._py()
+        m = py.search(text)
+        if m is None:
+            return None
+        return [m.span(i) for i in sorted(py.groupindex.values())]
+
     def parse_record(self, text) -> Optional[Dict[str, str]]:
         """Named-capture extraction (flb_regex_parse with callback per
         named group). Returns None when the pattern does not match."""

@@ -139,6 +139,7 @@ class _Parser:
         self.group_count = 0
         self.ignorecase = ignorecase
         self.dot_all = dot_all
+        self.possessive = False
 
     # -- cursor helpers --
 
@@ -216,6 +217,7 @@ class _Parser:
             return True
         if self.peek() == "+":  # possessive — same language
             self.next()
+            self.possessive = True
         return False
 
     def _try_braces(self, atom: Node) -> Optional[Rep]:
@@ -459,6 +461,9 @@ class ParsedRegex:
     n_groups: int
     group_names: dict  # index -> name
     pattern: str
+    #: a possessive quantifier was read as its greedy twin (the same
+    #: language for a verdict, not the same path for capture spans)
+    possessive: bool = False
 
 
 def parse(pattern: str, ignorecase: bool = False, dot_all: bool = False) -> ParsedRegex:
@@ -478,4 +483,4 @@ def parse(pattern: str, ignorecase: bool = False, dot_all: bool = False) -> Pars
             walk(n.node)
 
     walk(root)
-    return ParsedRegex(root, p.group_count, names, pattern)
+    return ParsedRegex(root, p.group_count, names, pattern, p.possessive)

@@ -459,9 +459,20 @@ def test_shipped_rewrite_tag_chain(graph):
     assert ch["scatter_passes"] == 2
 
 
+def test_shipped_parser_chain(graph):
+    # the parser's regex mode launches its span program through the
+    # same staged helper (one lane-guarded launch a segment) and builds
+    # records from the spans: no compaction pass re-walks the chunk
+    ch = _chain(graph, "filter_parser.py::ParserFilter.process_batch")
+    assert ch["launches_per_segment"] == 1
+    assert ch["sync_hits"] == []
+    (site,) = ch["sites"]
+    assert site["what"] == "staged_match" and site["lane"] is True
+    assert ch["scatter_passes"] == 0
+
+
 def test_shipped_host_only_entries(graph):
-    for suffix in ("filter_parser.py::ParserFilter.process_batch",
-                   "flux/plugin.py::FluxFilter.process_batch",
+    for suffix in ("flux/plugin.py::FluxFilter.process_batch",
                    "filter_log_to_metrics.py::LogToMetricsFilter"
                    ".process_batch"):
         ch = _chain(graph, suffix)

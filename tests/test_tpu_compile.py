@@ -161,6 +161,24 @@ def test_config3_assoc_child_compiles_for_one_chip(one_chip, k, n_rules,
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+@pytest.mark.parametrize("length", [256, 512])
+def test_span_program_compiles_for_one_chip(one_chip, length):
+    """parser-apache2's program: the two dependent scans over the one
+    staged plane, the ``[L, B]`` reverse states between them, ``(ok[B],
+    spans[B, 9, 2] i16)`` out."""
+    from fluentbit_tpu.ops.grep import span_program_for
+
+    prog = span_program_for(APACHE2, 512)
+    compiled = jax.jit(prog._spans_impl).lower(
+        {k: sds(v.shape, v.dtype, one_chip)
+         for k, v in prog._np.items()},
+        sds((SEGMENT, length), jnp.uint8, one_chip),
+        sds((SEGMENT,), jnp.int32, one_chip)).compile()
+    for sh in compiled.output_shardings:
+        assert sh.device_set == {one_chip._device}
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 def test_first_match_reduction_compiles_for_one_chip(one_chip):
     from fluentbit_tpu.ops.grep import grep_first_match
 
