@@ -8,7 +8,7 @@ deployment size, and holds the result to the program's own counters:
 
 - *grep*   ``conf/baseline1-grep.conf``'s filter (apache2, S=690 → scan
            kernel) plus one small ``Exclude`` rule on the same key
-           (S ≤ 64 → assoc kernel on an accelerator), ≥ 1,000,000 seeded
+           (S=10: its own per-stride child, scan too), ≥ 1,000,000 seeded
            access-log lines over ≥ 3 flush windows, a handful longer
            than ``tpu_max_record_len`` (the overflow-row contract).
 - *sketch* ``conf/baseline4-metrics.yaml``'s two ``log_to_metrics``
@@ -55,7 +55,7 @@ SEGMENT = 4096                # filter_grep's default segment size
 FLUSH_WINDOWS = 3             # the corpus spans at least this many
 ATTACH_TIMEOUT_S = 600.0
 TENANTS = (b"acme", b"globex", b"initech", b"umbrella")
-SMALL_EXCLUDE = r"log curl/8\.5"   # S <= 64: the assoc-resolved child
+SMALL_EXCLUDE = r"log curl/8\.5"   # S=10, k=5: the second child
 
 
 class SmokeFailure(Exception):
@@ -445,9 +445,9 @@ def grep_phase(dev: dict, n_records: int, mesh_sample: bool) -> None:
             f"corpus spanned {counts['flush_windows']} flush windows")
     kernels = sorted((d["kernel_resolved"], d["max_states"] <= 64)
                      for d in decisions)
-    require(kernels == [("assoc", True), ("scan", False)],
-            f"expected one scan-resolved (S>64) and one assoc-resolved "
-            f"(S<=64) child, got {kernels}")
+    require(kernels == [("scan", False), ("scan", True)],
+            f"expected two scan-resolved children, one on each side of "
+            f"S=64, got {kernels}")
     if mesh_sample:
         grep_mesh_vs_one_program(plugin, pushes[0])
         st = lane_report("grep")
@@ -478,40 +478,69 @@ def grep_phase(dev: dict, n_records: int, mesh_sample: bool) -> None:
             "the filter kept everything or nothing")
 
 
-def grep_launch_probe(prog) -> None:
-    """Where one segment's launch spends its time, per child and length
-    bucket: staged planes to the device, the compiled kernel alone, and
-    the whole forced launch — medians of 7 on the host's clock, ending
-    in a forced result. A smoke observation to aim the tracing PR with,
-    not a benchmark: one run, no warm-up policy, no spread."""
+def grep_launch_probe(prog, batches=(SEGMENT,)) -> None:
+    """Where one segment's launch spends its time, per child, kernel,
+    batch and length bucket: staged planes to the device, the compiled
+    kernel alone, and the whole forced launch — medians of 7 (of 3
+    where a call takes seconds) on the host's clock, ending in a forced
+    result. Every child is timed on BOTH kernels (its own program, and
+    a twin built with the other ``kernel=``), the two held to the same
+    verdicts, so each smoke repeats the probe ``_resolve_kernel``'s rule
+    rests on (PERF.md, PR 33). A smoke observation, not a benchmark: one
+    run, no warm-up policy, no spread."""
     import jax
     import numpy as np
 
+    from fluentbit_tpu.ops.grep import GrepProgram
+
     def median_ms(fn):
+        # 7 calls, or 3 once they have taken 2 s (assoc at S=690)
         times = []
-        for _ in range(7):
+        while len(times) < 7 and (len(times) < 3 or sum(times) < 2e3):
             t0 = time.perf_counter()
             fn()
             times.append((time.perf_counter() - t0) * 1e3)
         return round(sorted(times)[len(times) // 2], 3)
 
+    rng = np.random.default_rng(SEED)
     for child in prog._children or [prog]:
         K = child.n_planes  # the distinct staged planes its rules read
-        for L in (256, 512):
-            batch = np.full((K, SEGMENT, L), ord("a"), np.uint8)
-            lengths = np.full((K, SEGMENT), L // 2, np.int32)
-            dev = [jax.device_put(batch), jax.device_put(lengths)]
-            say(stage="grep:launch_probe", kernel=child.kernel_resolved,
-                max_states=child.max_states, k=child.k,
-                rules=len(child.dfas), shape=[K, SEGMENT, L],
-                h2d_ms=median_ms(lambda: [
+        twins = {}
+        for kern in ("scan", "assoc"):
+            twin = child
+            if kern != child.kernel_resolved:
+                twin = GrepProgram(child.dfas, child.max_len, kernel=kern,
+                                   plane_of=child.plane_of)
+                twin.n_planes = K
+                twin._ensure_materialized()
+            twins[kern] = twin
+        for B in batches:
+            for L in (256, 512):
+                batch = rng.integers(32, 127, (K, B, L), dtype=np.uint8)
+                lengths = rng.integers(0, L + 1, (K, B), dtype=np.int32)
+                dev = [jax.device_put(batch), jax.device_put(lengths)]
+                h2d_ms = median_ms(lambda: [
                     jax.device_put(a).block_until_ready()
-                    for a in (batch, lengths)]),
-                kernel_ms=median_ms(
-                    lambda: child._jit(*dev).block_until_ready()),
-                forced_launch_ms=median_ms(
-                    lambda: np.asarray(child.dispatch(batch, lengths))),
-                note="smoke observation, one run, not a benchmark")
+                    for a in (batch, lengths)])
+                masks = {}
+                for kern, twin in twins.items():
+                    t0 = time.perf_counter()
+                    masks[kern] = np.asarray(twin._jit(*dev))
+                    first_ms = round((time.perf_counter() - t0) * 1e3, 1)
+                    say(stage="grep:launch_probe", kernel=kern,
+                        resolved=child.kernel_resolved,
+                        max_states=child.max_states, k=child.k,
+                        rules=len(child.dfas), shape=[K, B, L],
+                        h2d_ms=h2d_ms, first_call_ms=first_ms,
+                        kernel_ms=median_ms(
+                            lambda: twin._jit(*dev).block_until_ready()),
+                        forced_launch_ms=median_ms(
+                            lambda: np.asarray(
+                                twin.dispatch(batch, lengths))),
+                        note="smoke observation, one run, not a benchmark")
+                require(np.array_equal(masks["scan"], masks["assoc"]),
+                        f"scan and assoc verdicts differ at {[K, B, L]} "
+                        f"(S={child.max_states}, k={child.k})")
 
 
 def grep_mesh_vs_one_program(plugin, payload: str) -> None:

@@ -57,7 +57,7 @@ Six rule families (see ANALYSIS.md for the full contract):
   which a ``GrepProgram``/``GrepTables`` build is reachable must not
   also reach an unminimized-DFA source (raw ``DFA(...)`` construction,
   ``compile_dfa(minimize=False)``) — an un-reduced table silently
-  closes the assoc gate and shrinks the stride budget
+  grows the tables and shrinks the stride budget
   (analysis.shrink; DEVICE_PLANE.md "shrink").
 - **launch graph / transfer budget** (`device-multi-launch-chain`,
   `device-undonated-buffer`, `device-host-roundtrip`,

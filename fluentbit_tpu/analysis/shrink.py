@@ -1,7 +1,7 @@
 """grep-unminimized-dfa rule.
 
 fbtpu-shrink (DEVICE_PLANE.md "shrink") moves the whole kernel-table
-economy — assoc eligibility, stride depth, native table cache footprint, mesh
+economy — stride depth, gathered table size, native table cache footprint, mesh
 replication size — onto one invariant: every ``DFA`` that reaches
 ``GrepProgram`` / ``GrepTables`` / ``GrepFilterTables`` passed through
 the compile-path reduction pass (``regex.dfa.compile_dfa``: Hopcroft
@@ -9,7 +9,7 @@ minimization, dead-state pruning, byte-class remerge). A hand-built
 ``DFA(...)`` table, or a ``compile_dfa(..., minimize=False)`` escape
 hatch wired into a production path, silently re-bloats S and C — the
 kernel still produces correct verdicts, so nothing at runtime notices
-that the assoc gate closed and the stride dropped until a bench round
+that the tables grew and the stride dropped until a bench round
 asks where the throughput went.
 
 ``grep-unminimized-dfa`` makes the invariant machine-checked (the
@@ -105,7 +105,7 @@ class UnminimizedDfaRule(Rule):
                    "reduction (raw DFA(...) construction or "
                    "compile_dfa(minimize=False)) reaches GrepProgram/"
                    "GrepTables — the kernel runs on an un-minimized "
-                   "table, silently closing the assoc gate and "
+                   "table, silently growing the tables and "
                    "shrinking the stride (regex/dfa.py)")
 
     def check(self, module: Module) -> List[Finding]:
@@ -162,7 +162,7 @@ class UnminimizedDfaRule(Rule):
                     f"{kind} reaches a GrepProgram/GrepTables build "
                     f"(via {info.node.name!r}) without the fbtpu-shrink "
                     f"reduction pass — the kernel table ships "
-                    f"un-minimized, closing the assoc gate and "
+                    f"un-minimized, growing the tables and "
                     f"shrinking the stride budget (regex/dfa.py "
                     f"compile_dfa)",
                     extra_lines=(info.node.lineno,))

@@ -34,14 +34,16 @@ DFA (fluentbit_tpu.regex.dfa) runs over a ``[B, L] uint8`` batch as a
   the first step — fixed shapes stay exact, no masking in the inner loop.
 - matched == (final_state == ACC): single comparison at scan end, no
   per-position accept reduction.
-- Kernel selection: ``kernel="auto"`` (the default; only the argument
-  overrides it) picks scan vs assoc from the program's shape and the
-  attached platform at trace time — the sequential scan on host-CPU
-  backends (where the log2-depth compose tree's S× extra work is pure
-  overhead: an earlier CPU-host run measured it 300× slower there), the
-  parallel-in-time assoc kernel on an accelerator when ``S <= 64``.
-  Rules whose strides differ split into per-k child programs below the
-  rule-shard regime (``R < 64``).
+- Kernel selection: ``kernel="auto"`` (the default) is the sequential
+  scan on every platform; the parallel-in-time assoc kernel runs only
+  where the constructor's ``kernel="assoc"`` asks for it (the
+  differential tests, ``chip_smoke.py``'s probe). A launch's time is
+  its count of gathered elements, and assoc gathers S× scan's: on a
+  v5e at ``[1, 4096, 256]`` the benchmark's S=10 rule took 21.1 ms on
+  assoc and 2.3 on scan, its S=690 rule 2,707 and 3.3, and scan won at
+  every shape down to 256 rows (PERF.md, PR 33); on a host CPU assoc
+  measured 300× slower. Rules whose strides differ split into per-k
+  child programs below the rule-shard regime (``R < 64``).
 
 This module works on any JAX backend (tests force a CPU mesh); on TPU the
 gathers vectorize across the batch dimension.
@@ -132,13 +134,12 @@ class GrepProgram:
         #: whole and gather their own rules' from them)
         self.n_planes = max(self.plane_of, default=-1) + 1
         # kernel variant: "scan" = sequential lax.scan of table gathers
-        # (Lk serialized steps, minimal FLOPs); "assoc" = parallel-in-
-        # time function composition (segments scanned as transition
-        # FUNCTIONS over all states, then a log2-depth tree of
-        # compositions) — sequential depth m + log2(Lk/m) instead of
-        # Lk, trading S× more parallel work the TPU's lanes absorb;
-        # "auto" = resolved per program shape + attached platform at
-        # trace time (_resolve_kernel)
+        # (Lk serialized steps, R·B gathered elements a step); "assoc" =
+        # parallel-in-time function composition (segments scanned as
+        # transition FUNCTIONS over all states, then a log2-depth tree
+        # of compositions) — sequential depth m + log2(Lk/m) instead of
+        # Lk, for S× the gathered elements; "auto" = scan, and why
+        # (_resolve_kernel)
         self.kernel = kernel
         if self.kernel not in ("scan", "assoc", "auto"):
             raise ValueError(f"unknown grep kernel {self.kernel!r}")
@@ -232,20 +233,15 @@ class GrepProgram:
         self._mesh_cache: dict = {}
 
     def _resolve_kernel(self) -> str:
-        """Scan-vs-assoc per program shape, decided at trace time (the
-        attached platform is known by then). The scan kernel's Lk
-        serialized gathers are cheap on a host CPU where the assoc
-        tree's S× parallel work is pure overhead (an earlier CPU-host
-        run: 300× slower there); assoc pays off only when idle vector lanes
-        absorb that work — a real accelerator and a small state count."""
-        if self.kernel != "auto":
-            return self.kernel
-        from . import device
-
-        plat = device.platform()
-        if plat in (None, "cpu"):
-            return "scan"
-        return "assoc" if self.max_states <= 64 else "scan"
+        """``auto`` is scan, whatever the shape and the platform. On a
+        v5e the compiled kernels alone, both over each of the five
+        children of the grep and rewrite configurations at B ∈ {256,
+        1,024, 4,096}, L ∈ {256, 512} (PERF.md, PR 33): scan 0.8-15 ms,
+        assoc 2.2-21× that at S ≤ 12 and 200-1,100× at S=690 — assoc
+        gathers ``[R, B, G2, S]`` elements a step where scan gathers
+        ``[R, B]``, and a gather costs the same 8-11 ns an element
+        whatever idles beside it. On a host CPU it was 300× slower."""
+        return "scan" if self.kernel == "auto" else self.kernel
 
     # -- fbtpu-shrink decision surface --
 
@@ -441,10 +437,11 @@ class GrepProgram:
         per-segment function table; segments then combine in a
         log2(G)-deep tree of compositions ``(f∘g)[s] = g[f[s]]``
         (take_along_axis over the state axis). Sequential depth drops
-        from Lk to m + log2(G) — the S× extra parallel work is exactly
-        what the TPU's vector lanes absorb, where the scan kernel's
-        serialized gather chain leaves them idle. Bit-identical to
-        _match_impl (differentially tested)."""
+        from Lk to m + log2(G) at S× the gathered elements, and the
+        elements are what a launch's time counts, on the chip as on a
+        host CPU (``_resolve_kernel`` has the measurements), so ``auto``
+        never resolves to it. Bit-identical to _match_impl
+        (differentially tested), reachable by ``kernel="assoc"`` alone."""
         R, B, L = batch.shape
         m = self.segment
         S = self.max_states

@@ -94,9 +94,9 @@ def test_mesh_bit_exact_vs_cpu_chain(n_rows):
 
 
 def test_mesh_max_states_boundary_programs():
-    """The apache2 parser DFA (S=690 — far past the assoc gate, scan
-    kernel, k capped by the table budget) and a tiny literal (deep k,
-    assoc-eligible S) both survive partitioning bit-exactly."""
+    """The apache2 parser DFA (S=690, k capped by the table budget) and
+    a tiny literal (deep k, a handful of states) — both on the scan
+    kernel — survive partitioning bit-exactly."""
     mesh = _mesh()
     vals = (CORPUS * 11)[:59]  # uneven tail on every device
     for patterns in ((APACHE2,), ("panic",), (APACHE2, "panic")):

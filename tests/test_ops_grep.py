@@ -149,6 +149,85 @@ def test_assoc_kernel_bit_exact_vs_scan():
     assert (got_assoc[0] == expect).all()
 
 
+def _conf_patterns(path):
+    """The rule regexes of a pipeline file's filter section, in file
+    order: filter_grep's ``Regex``/``Exclude <key> <regex>`` and
+    rewrite_tag's ``Rule <key> <regex> <tag> <keep>``."""
+    from fluentbit_tpu.config_format import load_config_file
+
+    out = []
+    for section in load_config_file(path).sections:
+        if section.name != "filter":
+            continue
+        for key, value in section.properties:
+            if key.lower() in ("regex", "exclude"):
+                out.append(value.split(None, 1)[1])
+            elif key.lower() == "rule":
+                out.append(value.split()[1])
+    return out
+
+
+#: the pipeline files of the benchmark's two verdict configurations and
+#: the corpus module each one's cell sends
+KERNEL_RULE_CONFIGS = {
+    "grep-apache2": ("benchmark/configs/grep-apache2.conf", "grep_lines",
+                     [(690, 3), (10, 5)]),
+    "rewrite-syslog": ("conf/baseline3-rewrite.conf", "syslog_lines",
+                       [(12, 4), (9, 5), (7, 6)]),
+}
+
+
+@pytest.mark.parametrize("config", sorted(KERNEL_RULE_CONFIGS))
+def test_every_bench_child_resolves_to_scan_and_assoc_agrees(config):
+    """The kernel rule as the chip measured it (PERF.md, PR 33): every
+    child of the grep and rewrite configurations resolves to scan, on
+    an accelerator as on the CPU, whatever its state count; the assoc
+    kernel, reachable by the constructor argument alone, gives the same
+    ``[R, B]`` verdicts on 256 lines of the cell's own corpus (512-
+    bucket lines and overflow rows among them)."""
+    import os
+    import sys
+
+    from fluentbit_tpu.ops import device
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    conf, corpus, shapes = KERNEL_RULE_CONFIGS[config]
+    dfas = [compile_dfa(p) for p in _conf_patterns(os.path.join(repo, conf))]
+    plane_of = (0,) * len(dfas)  # every rule reads ``log``
+    children = GrepProgram(dfas, 512, plane_of=plane_of)._children
+    assert [(c.max_states, c.k) for c in children] == shapes
+    was = device._platform
+    try:
+        for plat in ("tpu", "cpu"):
+            device._platform = plat
+            assert [c._resolve_kernel() for c in children] \
+                == ["scan"] * len(children), plat
+    finally:
+        device._platform = was
+
+    bench = os.path.join(repo, "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from lookup import load_py
+
+    records, _labels = load_py("corpora", corpus).make(
+        256, 20261002, {"bucket512_every": 40, "overflow_every": 100})
+    lines = [r["log"].encode() for r in records]
+    b = assemble(lines, max_len=512)
+    assert (b.lengths > 256).any() and (b.lengths < 0).any()
+    got = {}
+    for kern in ("scan", "assoc"):
+        prog = GrepProgram(dfas, 512, kernel=kern, plane_of=plane_of)
+        got[kern] = prog.match(b.batch[None], b.lengths[None])
+        assert {c.kernel_resolved for c in prog._children} == {kern}
+    assert got["scan"].shape == (len(dfas), 256)
+    assert (got["scan"] == got["assoc"]).all()
+    assert got["scan"].any(axis=1).all()  # every rule has its lines
+    want = np.array([[len(ln) <= 512 and d.match_bytes(ln) for ln in lines]
+                     for d in dfas])
+    assert (got["scan"] == want).all()
+
+
 def test_assoc_kernel_sharded_matches_single_device():
     import jax
     from jax.sharding import Mesh

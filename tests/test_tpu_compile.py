@@ -130,7 +130,8 @@ def test_grep_child_compiles_for_one_chip(one_chip, pattern, kernel, impl,
 
 
 #: BASELINE config 3's eight rewrite_tag rules (conf/baseline3-rewrite
-#: .conf): all S <= 64, so all assoc on the chip, in three stride groups
+#: .conf): all S <= 12, in three stride groups; scan on the chip since
+#: PR 33, assoc by the constructor's argument only
 CONFIG3 = ("sshd", "kernel:", r"systemd\[1\]", "ERROR", "WARN", "nginx",
            r"cron\[\d+\]", ".*OOM.*")
 
@@ -138,19 +139,23 @@ CONFIG3 = ("sshd", "kernel:", r"systemd\[1\]", "ERROR", "WARN", "nginx",
 @pytest.mark.parametrize("length", [256, 512])
 @pytest.mark.parametrize("k,n_rules", [(6, 5), (5, 2), (4, 1)],
                          ids=["k6x5", "k5x2", "k4x1"])
-def test_config3_assoc_child_compiles_for_one_chip(one_chip, k, n_rules,
-                                                   length):
+@pytest.mark.parametrize("kernel,impl", [("scan", "_match_impl"),
+                                         ("assoc", "_match_assoc_impl")],
+                         ids=["scan", "assoc"])
+def test_config3_child_compiles_for_one_chip(one_chip, kernel, impl, k,
+                                             n_rules, length):
     """rewrite-syslog's program: each per-stride child takes the ONE
-    staged plane ``[1, B, L]`` and gathers its rules' inputs from it
-    (k=6 children had never been compiled for the chip)."""
+    staged plane ``[1, B, L]`` and gathers its rules' inputs from it —
+    on the scan kernel, which is what the chip runs, and on assoc, which
+    the probe and the differential tests still build."""
     prog = GrepProgram([compile_dfa(p) for p in CONFIG3], 512,
-                       kernel="assoc", plane_of=(0,) * len(CONFIG3))
+                       kernel=kernel, plane_of=(0,) * len(CONFIG3))
     child = next(c for c in prog._children if c.k == k)
     assert len(child.dfas) == n_rules and child.n_planes == 1
     assert child.plane_of == (0,) * n_rules
 
     def step(tables, planes, lengths):
-        return child._match_assoc_impl(
+        return getattr(child, impl)(
             tables, *child._gather_planes(planes, lengths))
 
     compiled = jax.jit(step).lower(
@@ -188,16 +193,18 @@ def test_first_match_reduction_compiles_for_one_chip(one_chip):
 
 
 def test_kernel_selection_rule_matches_what_was_compiled():
-    """The accelerator arm of ``_resolve_kernel`` — which no CPU test
-    process ever takes — picks exactly the (pattern, kernel) pairs
-    compiled above: assoc at S <= 64, scan beyond."""
+    """``_resolve_kernel`` on an accelerator — the platform no CPU test
+    process is attached to — picks scan on both sides of the old
+    ``S <= 64`` line, as on the CPU: the (pattern, scan) pairs compiled
+    above are what ships (the chip's probe, PERF.md PR 33)."""
     from fluentbit_tpu.ops import device
 
     was = device._platform
     device._platform = "tpu"
     try:
         assert _program(APACHE2, "auto")._resolve_kernel() == "scan"
-        assert _program(SMALL, "auto")._resolve_kernel() == "assoc"
+        assert _program(SMALL, "auto")._resolve_kernel() == "scan"
+        assert _program(SMALL, "assoc")._resolve_kernel() == "assoc"
     finally:
         device._platform = was
     assert _program(SMALL, "auto")._resolve_kernel() == "scan"  # cpu
