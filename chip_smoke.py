@@ -32,6 +32,10 @@ fallback that reports success.
 
     python chip_smoke.py            # one chip (what the driver runs)
     python chip_smoke.py --chips 4  # the 4-device mesh paths only
+    python chip_smoke.py --rules-sweep CONF MAKER 1,20,50
+                                    # no phase: the compiled match
+                                    # programs alone for the first R
+                                    # grep rules of CONF (rules_sweep)
 
 Every line of stdout is one JSON object; the LAST line is
 ``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}``
@@ -478,16 +482,22 @@ def grep_phase(dev: dict, n_records: int, mesh_sample: bool) -> None:
             "the filter kept everything or nothing")
 
 
-def grep_launch_probe(prog, batches=(SEGMENT,)) -> None:
+def grep_launch_probe(prog, batches=(SEGMENT,), kernels=("scan", "assoc"),
+                      stage=None) -> None:
     """Where one segment's launch spends its time, per child, kernel,
     batch and length bucket: staged planes to the device, the compiled
     kernel alone, and the whole forced launch — medians of 7 (of 3
     where a call takes seconds) on the host's clock, ending in a forced
-    result. Every child is timed on BOTH kernels (its own program, and
-    a twin built with the other ``kernel=``), the two held to the same
-    verdicts, so each smoke repeats the probe ``_resolve_kernel``'s rule
-    rests on (PERF.md, PR 33). A smoke observation, not a benchmark: one
-    run, no warm-up policy, no spread."""
+    result. Every child is timed on each of ``kernels`` (its own
+    program, and a twin built with the other ``kernel=``), all held to
+    the same verdicts, so each smoke repeats the probe
+    ``_resolve_kernel``'s rule rests on (PERF.md, PR 33);
+    ``kernels=("scan",)`` builds no twin (at R=50 the assoc twin would
+    gather S times 200,000 elements a step: PERF.md, PR 34).
+    ``stage(K, B, L)`` → ``(planes u8[K, B, L], lengths i32[K, B])``
+    gives the probe its rows (printable random bytes without it). A
+    smoke observation, not a benchmark: one run, no warm-up policy, no
+    spread."""
     import jax
     import numpy as np
 
@@ -503,10 +513,15 @@ def grep_launch_probe(prog, batches=(SEGMENT,)) -> None:
         return round(sorted(times)[len(times) // 2], 3)
 
     rng = np.random.default_rng(SEED)
+
+    def random_rows(K, B, L):
+        return (rng.integers(32, 127, (K, B, L), dtype=np.uint8),
+                rng.integers(0, L + 1, (K, B), dtype=np.int32))
+
     for child in prog._children or [prog]:
         K = child.n_planes  # the distinct staged planes its rules read
         twins = {}
-        for kern in ("scan", "assoc"):
+        for kern in kernels:
             twin = child
             if kern != child.kernel_resolved:
                 twin = GrepProgram(child.dfas, child.max_len, kernel=kern,
@@ -516,8 +531,7 @@ def grep_launch_probe(prog, batches=(SEGMENT,)) -> None:
             twins[kern] = twin
         for B in batches:
             for L in (256, 512):
-                batch = rng.integers(32, 127, (K, B, L), dtype=np.uint8)
-                lengths = rng.integers(0, L + 1, (K, B), dtype=np.int32)
+                batch, lengths = (stage or random_rows)(K, B, L)
                 dev = [jax.device_put(batch), jax.device_put(lengths)]
                 h2d_ms = median_ms(lambda: [
                     jax.device_put(a).block_until_ready()
@@ -538,9 +552,89 @@ def grep_launch_probe(prog, batches=(SEGMENT,)) -> None:
                             lambda: np.asarray(
                                 twin.dispatch(batch, lengths))),
                         note="smoke observation, one run, not a benchmark")
-                require(np.array_equal(masks["scan"], masks["assoc"]),
-                        f"scan and assoc verdicts differ at {[K, B, L]} "
-                        f"(S={child.max_states}, k={child.k})")
+                first = next(iter(masks.values()))
+                require(all(np.array_equal(first, m)
+                            for m in masks.values()),
+                        f"{' and '.join(masks)} verdicts differ at "
+                        f"{[K, B, L]} (S={child.max_states}, k={child.k})")
+
+
+def rules_sweep(conf: str, maker: str, sizes) -> None:
+    """The compiled match programs alone along a rule axis: for each
+    ``R`` of ``sizes`` the first ``R`` grep rules of pipeline file
+    ``conf`` as the filter builds them (``program_for`` with the
+    filter's ``plane_of``: per-stride children under
+    ``FBTPU_MESH_RULE_SHARD_R``), probed child by child on the scan
+    kernel (``grep_launch_probe``) over ``SEGMENT`` records of the
+    benchmark's corpus maker ``maker`` staged at L=256 and L=512 (a
+    longer value is an overflow row), and the whole program's verdicts
+    held to Python's ``re`` on the same rows. How a launch's device
+    time grows with the list, without the pipeline around it."""
+    import importlib.util
+    import re
+
+    import numpy as np
+
+    from fluentbit_tpu.config_format import load_config_file
+    from fluentbit_tpu.core.plugin import Properties
+    from fluentbit_tpu.ops.grep import program_for
+    from fluentbit_tpu.plugins.filter_grep import (parse_grep_rules,
+                                                   plane_index)
+
+    section = next(sec for sec in load_config_file(conf).sections
+                   if sec.name == "filter"
+                   and sec.get("name", "").lower() == "grep")
+    props = Properties()
+    for k, v in section.properties:
+        props.set(k, v)
+    rules = parse_grep_rules(props)
+    max_len = int(section.get("tpu_max_record_len", 512))
+    accessors, plane_of = plane_index(rules)
+    require(len(accessors) == 1 and not accessors[0].parts,
+            "the sweep stages one plane: rules on one top-level key")
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(maker)))
+    spec = importlib.util.spec_from_file_location("sweep_corpus", maker)
+    corpus = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(corpus)
+    records, _labels = corpus.make(SEGMENT, SEED, {})
+    values = [r[accessors[0].head].encode() for r in records]
+
+    def stage(K, B, L):
+        planes = np.zeros((K, B, L), dtype=np.uint8)
+        lengths = np.full((K, B), -2, dtype=np.int32)
+        for j, v in enumerate(values[:B]):
+            if len(v) <= L:
+                planes[:, j, :len(v)] = np.frombuffer(v, dtype=np.uint8)
+                lengths[:, j] = len(v)
+        return planes, lengths
+
+    for R in sizes:
+        patterns = tuple(r.pattern for r in rules[:R])
+        prog = program_for(patterns, max_len, plane_of=plane_of[:R])
+        require(prog.try_ready(), f"the {R}-rule program did not attach")
+        children = prog._children or [prog]
+        say(stage="rules_sweep:program", rules=R,
+            children=[{"k": c.k, "rules": len(c.dfas),
+                       "max_states": c.max_states,
+                       "table_mb": round(
+                           c._tbl["trans_flat"].nbytes / 1e6, 1)}
+                      for c in children],
+            elements_256=prog.scan_elements(SEGMENT, 256),
+            elements_512=prog.scan_elements(SEGMENT, 512))
+        grep_launch_probe(prog, kernels=("scan",), stage=stage)
+        for L in (256, 512):
+            planes, lengths = stage(1, SEGMENT, L)
+            got = np.asarray(prog.match(planes, lengths))
+            want = np.array([[len(v) <= L and re.search(p, v.decode())
+                              is not None for v in values]
+                             for p in patterns])
+            require(np.array_equal(got, want),
+                    f"{R} rules at L={L}: the program and re differ on "
+                    f"{int((got != want).sum())} verdicts")
+            say(stage="rules_sweep:verdicts", rules=R, L=L, equal=True,
+                matches=int(got.sum()),
+                rows_matched=int(got.any(axis=0).sum()))
 
 
 def grep_mesh_vs_one_program(plugin, payload: str) -> None:
@@ -801,10 +895,23 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="1 (default): both phases on one chip; 4: only "
                          "the grep mesh and the flux merge across 4")
+    ap.add_argument("--rules-sweep", nargs=3, default=None,
+                    metavar=("CONF", "MAKER", "SIZES"),
+                    help="instead of the phases: the compiled match "
+                         "programs alone for the first R grep rules of "
+                         "pipeline file CONF, R in SIZES (1,20,50), over "
+                         "records of the benchmark's corpus maker MAKER "
+                         "(rules_sweep)")
     args = ap.parse_args(argv)
     dev = None
     try:
-        dev = run(args.chips)
+        if args.rules_sweep:
+            conf, maker, sizes = args.rules_sweep
+            dev = attach(1)
+            rules_sweep(os.path.abspath(conf), os.path.abspath(maker),
+                        [int(n) for n in sizes.split(",")])
+        else:
+            dev = run(args.chips)
     except BaseException as e:  # noqa: BLE001 - every failure is a verdict
         import traceback
 

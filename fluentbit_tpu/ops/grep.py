@@ -107,6 +107,13 @@ def compose_table(trans: np.ndarray, k: int) -> np.ndarray:
     return compose_supersteps(trans, k)
 
 
+def scan_steps(L: int, k: int) -> int:
+    """Super-steps the scan kernel takes over a plane of ``L`` bytes at
+    stride ``k``: the bytes rounded up to whole strides, and one stride
+    of EOL after them (``_super_symbols``) — ``⌈L/k⌉ + 1``."""
+    return -(-L // k) + 1
+
+
 class GrepProgram:
     """R compiled DFAs fused into one device program.
 
@@ -292,6 +299,18 @@ class GrepProgram:
         return (f"grep_{self.kernel_resolved}_S{self.max_states}"
                 f"_k{self.k}{suffix}")
 
+    def scan_elements(self, B: int, L: int) -> int:
+        """Gathered elements one launch over ``[K, B, L]`` planes steps
+        through on the scan kernel: every rule of every child reads one
+        table entry a row and super-step, ``Σ R_c · B · scan_steps(L,
+        k_c)`` — what a launch's device time counts in (8-11 ns an
+        element on a v5e from a child's table of up to 58 MB, 16-25
+        from one of 144 MB and more; PERF.md, PRs 28, 33 and 34). From
+        shapes alone; the assoc kernel gathers ``S``× more and is not
+        counted here."""
+        return sum(len(c.dfas) * B * scan_steps(L, c.k)
+                   for c in self._children or [self])
+
     def _merge_rule_axis(self, parts):
         """Reassemble per-child rule rows into the caller's order (one
         small jitted program, ``jit_grep_merge``)."""
@@ -393,12 +412,11 @@ class GrepProgram:
         pad = pos[None, None, :] >= lengths[:, :, None]  # [R,B,L]
         cls = jnp.where(pad, t["eol_cls"][:, None, None], cls)
         # append EOL block: guarantees >=1 EOL and rounds L to multiple of k
-        extra = (k - (L % k)) % k + k
+        Lk = scan_steps(L, k)
         eol_block = jnp.broadcast_to(
-            t["eol_cls"][:, None, None], (R, B, extra)
+            t["eol_cls"][:, None, None], (R, B, Lk * k - L)
         )
         cls = jnp.concatenate([cls, eol_block], axis=2)
-        Lk = cls.shape[2] // k
         cls = cls.reshape(R, B, Lk, k)
         # combine k classes into one super-symbol, per-rule radix C_r
         comb = cls[..., 0]
@@ -919,6 +937,12 @@ class SpanProgram:
     def program_name(self) -> str:
         NW, _C, NR = self._shape
         return f"grep_spans_W{NW - 2}_R{NR}"
+
+    def scan_elements(self, B: int, L: int) -> int:
+        """Gathered elements of one launch, as
+        ``GrepProgram.scan_elements`` counts them: one a row and byte
+        in the reverse pass (``L`` steps), one in the walk (``L + 1``)."""
+        return B * (2 * L + 1)
 
     def _materialize(self) -> None:
         with self._mat_lock:

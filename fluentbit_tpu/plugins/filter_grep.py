@@ -52,13 +52,17 @@ class _RawDecline(Exception):
 
 #: ``raw_timings`` keys. ``records`` counts every record the raw path
 #: served (native matcher or device); ``device_records``,
-#: ``overflow_rows`` and ``h2d_bytes`` count the DEVICE lane only —
-#: records whose segment was launched through the grep DeviceLane, the
-#: overflow rows among them (longer than ``tpu_max_record_len``,
-#: resolved on the CPU after the launch), and the staged bytes handed
-#: to the launch (the distinct [K, Bp, L] planes + their lengths)
+#: ``overflow_rows``, ``h2d_bytes``, ``d2h_bytes`` and ``scan_elements``
+#: count the DEVICE lane only — records whose segment was launched
+#: through the grep DeviceLane, the overflow rows among them (longer
+#: than ``tpu_max_record_len``, resolved on the CPU after the launch),
+#: the staged bytes handed to the launch (the distinct [K, Bp, L] planes
+#: + their lengths), the verdict bytes copied back (``mask[R, Bp]``: a
+#: byte a rule and row) and the gathered elements the launched program
+#: steps through (``GrepProgram.scan_elements``)
 _TIMING_KEYS = ("extract_s", "kernel_s", "compact_s", "records",
-                "device_records", "overflow_rows", "h2d_bytes")
+                "device_records", "overflow_rows", "h2d_bytes",
+                "d2h_bytes", "scan_elements")
 
 
 def _len_bucket(n: int, cap: int) -> int:
@@ -308,9 +312,12 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
 
     ``tm`` (the plugin's ``raw_timings``) takes ``extract_s``,
     ``kernel_s`` (wall less extraction), ``h2d_bytes`` (the planes and
-    their lengths), ``device_records`` and ``overflow_rows``, and with
-    ``spans`` also ``d2h_bytes`` (the verdicts and offsets copied out
-    — a byte a row in the other kinds).
+    their lengths), ``d2h_bytes`` (what the forced launch copies out:
+    ``mask[R, Bp]`` a byte each — four on the mesh, whose verdict is
+    i32 — the ``[Bp]`` i32 first-match vector, or with ``spans`` the
+    verdicts and offsets), ``scan_elements`` (the gathered elements the
+    launched program steps through, from the staged shape),
+    ``device_records`` and ``overflow_rows``.
     Returns ``(verdict, offsets[n+1], n)`` — ``mask[R, n]`` bool, with
     ``first_match`` the ``[n]`` i32 first-match vector, with ``spans``
     the :class:`SpanVerdict` — or None to decline (fewer than
@@ -421,10 +428,13 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
                 item = stage(s, e)
             yield item + (si,)
 
+    def launch_ids(b) -> dict:
+        return {"rules": len(rules), "planes": K, "L": b.shape[2]}
+
     def forced(b, ln):
         # enqueue + argument copy-in, then the wait for the
         # execution and the copy-out
-        with span("grep.dispatch"):
+        with span("grep.dispatch", **launch_ids(b)):
             out = program.dispatch(b, ln) if spans else \
                 program.dispatch(b, ln, first_match=first_match)
         with span("grep.force"):
@@ -439,6 +449,8 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
         if spans:
             plane_parts.append(batch[0, :cnt])
         tm.add("h2d_bytes", batch.nbytes + lengths.nbytes)
+        tm.add("scan_elements",
+               program.scan_elements(batch.shape[1], batch.shape[2]))
         if mesh is not None:
             # sharded launch through the device fault domain: the
             # launch closure re-stages (fresh device_put + donation)
@@ -456,13 +468,12 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
                 if m is None:
                     # mesh shrunk below 2 devices: serve unsharded
                     return forced(b, ln)
-                with span("grep.dispatch"):
+                with span("grep.dispatch", **launch_ids(b)):
                     out, _, _b2, _bp = program.dispatch_mesh(
                         m, b, ln, with_counts=False,
                         first_match=first_match)
                 with span("grep.force"):
-                    out = np.asarray(out)
-                    return out if first_match else out.astype(bool)
+                    return np.asarray(out)  # the mask as i32, as copied
         else:
             def launch(b=batch, ln=lengths):
                 return forced(b, ln)
@@ -496,17 +507,19 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
     overflow_rows = np.unique(np.nonzero(lengths == -2)[1])
     tm.add("device_records", n)
     tm.add("overflow_rows", len(overflow_rows))
+    copied = (part for v in verdicts for part in v) if spans else verdicts
+    tm.add("d2h_bytes", sum(part.nbytes for part in copied))
     if spans:
         # the caller builds the records, and decides on the host those
         # that staged no value
-        tm.add("d2h_bytes", sum(ok.nbytes + sp.nbytes
-                                for ok, sp in verdicts))
         return SpanVerdict(
             np.concatenate([ok[:c] for (ok, _), c in zip(verdicts, cnts)]),
             np.concatenate([sp[:c] for (_, sp), c in zip(verdicts, cnts)]),
             lengths[0], plane_parts), offsets, n
     verdict = np.concatenate(
         [np.asarray(v)[..., :c] for v, c in zip(verdicts, cnts)], axis=-1)
+    if not first_match:
+        verdict = verdict.astype(bool, copy=False)  # the mesh's is i32
     # overflow rows (-2): decode just those records on the CPU
     if len(overflow_rows):
         from ..codec.events import decode_events
@@ -868,7 +881,8 @@ class GrepFilter(FilterPlugin):
                 return None
         mask, offsets, n = got
         tm.add("records", n)
-        keep = self.keep_mask(mask)
+        with span("grep.verdict", rules=len(self.rules)):
+            keep = self.keep_mask(mask)
         n_keep = int(keep.sum())
         if n_keep == n:
             return (n, data)

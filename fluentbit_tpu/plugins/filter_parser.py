@@ -74,14 +74,14 @@ from ..core.spans import ShardedTimings, span
 log = logging.getLogger("flb")
 
 #: ``raw_timings`` keys of the batched regex path on the chip. The first
-#: six are the staged launch's (``filter_grep.staged_match``);
+#: seven are the staged launch's (``filter_grep.staged_match``);
 #: ``build_s`` is the time from the spans to the chunk's new bytes,
 #: ``parsed`` the records it replaced, ``host_rows`` the rows decided on
 #: the host per row (overflow rows, a missing key or a ``bin`` value, a
 #: byte past ASCII)
 _TIMING_KEYS = ("extract_s", "kernel_s", "h2d_bytes", "d2h_bytes",
-                "device_records", "overflow_rows", "build_s", "parsed",
-                "host_rows")
+                "scan_elements", "device_records", "overflow_rows",
+                "build_s", "parsed", "host_rows")
 
 #: ``[[EventTime, {}], {`` — the head of an event whose time is the
 #: Forward protocol's ext and whose metadata is empty, before the body

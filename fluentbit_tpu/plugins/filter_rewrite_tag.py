@@ -60,16 +60,17 @@ def _to_text(v) -> Optional[str]:
 
 
 #: ``raw_timings`` keys of the batched path. ``extract_s``,
-#: ``kernel_s``, ``h2d_bytes``, ``device_records`` and ``overflow_rows``
+#: ``kernel_s``, ``h2d_bytes``, ``d2h_bytes``, ``scan_elements``,
+#: ``device_records`` and ``overflow_rows``
 #: are the staged launch's (``filter_grep.staged_match``: the device
 #: lane only); ``records`` counts every record ``process_batch``
 #: served; ``emit_s`` is the time from the verdict to the last emitter
 #: append (grouping, ``native.compact``, ``add_record`` and the
 #: pipeline re-entry under it), ``emits`` the emitter appends and
 #: ``emit_backpressure`` those the emitter refused (originals kept)
-_TIMING_KEYS = ("extract_s", "kernel_s", "h2d_bytes", "device_records",
-                "overflow_rows", "records", "emit_s", "emits",
-                "emit_backpressure")
+_TIMING_KEYS = ("extract_s", "kernel_s", "h2d_bytes", "d2h_bytes",
+                "scan_elements", "device_records", "overflow_rows",
+                "records", "emit_s", "emits", "emit_backpressure")
 
 
 class RewriteRule:

@@ -8,8 +8,12 @@ covered by ``chip_smoke.py`` (run on the chip machine) and by
 ``tests/test_tpu_compile.py`` (compiles for a described chip).
 """
 
+import glob
+import json
 import os
 import sys
+
+import pytest
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 # CPU attach is near-instant; a generous deadline keeps the device path
@@ -57,3 +61,28 @@ def pytest_configure(config):
         "--xla_force_host_platform_device_count, set above) — the fast "
         "flux/sharding subset runs unmarked in tier-1; the full mesh "
         "matrix is additionally marked slow")
+
+
+@pytest.fixture(scope="session")
+def counters_of_declared_metrics() -> set:
+    """The program's counters that a declared data-only per-layer metric
+    of the benchmark reads: both terms of a ``counters:ratio``, and the
+    plugin's ``scan_elements`` of an ``element_cost:ns_per_element`` —
+    what the "every timing key feeds a metric or a check" tests hold a
+    plugin's ``_TIMING_KEYS`` to."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "BENCHMARK.json")) as f:
+        declared = {m["name"] for m in json.load(f)["per_layer"]}
+    read = set()
+    for path in glob.glob(os.path.join(repo, "benchmark", "layer_metrics",
+                                       "*.json")):
+        if os.path.basename(path)[:-5] not in declared:
+            continue
+        with open(path) as f:
+            spec = json.load(f)
+        args = spec["args"]
+        if spec["reader"] == "counters:ratio":
+            read |= {args["num"], args["den"]}
+        elif spec["reader"] == "element_cost:ns_per_element":
+            read.add(f"filter.{args['plugin']}.scan_elements")
+    return read

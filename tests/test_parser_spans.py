@@ -326,6 +326,10 @@ def test_batched_device_path_equals_the_host_chain(gate_open, name):
                     or len(r["log"]) > 512 or not r["log"].isascii())
     assert tm["host_rows"] == host_rows > long_rows
     assert tm["d2h_bytes"] > 0 and tm["build_s"] > 0 and tm["parsed"] > 0
+    # a row and byte in the reverse pass, one in the walk and its EOL:
+    # Bp * (2 L + 1) at the staged bucket (L a power of two)
+    assert tm["scan_elements"] >= 300 * (2 * 64 + 1) \
+        and tm["scan_elements"] % 2 == 0
 
 
 def test_chunk_with_no_match_returns_its_buffer(gate_open):
@@ -404,8 +408,8 @@ def test_failed_launch_gives_the_hosts_spans(gate_open):
 
 
 PARSER_KEYS = ("extract_s", "kernel_s", "h2d_bytes", "d2h_bytes",
-               "device_records", "overflow_rows", "build_s", "parsed",
-               "host_rows")
+               "scan_elements", "device_records", "overflow_rows",
+               "build_s", "parsed", "host_rows")
 
 
 def test_parser_spans_and_their_ids(gate_open, monkeypatch):
@@ -442,26 +446,17 @@ def test_parser_spans_and_their_ids(gate_open, monkeypatch):
 
 @pytest.mark.parametrize(
     "key", [k for k in PARSER_KEYS if k != "extract_s"])
-def test_every_parser_timing_key_feeds_a_metric_or_a_check(key):
+def test_every_parser_timing_key_feeds_a_metric_or_a_check(
+        key, counters_of_declared_metrics):
     """An always-on counter that nothing reads is only a cost: each key
-    is the numerator or denominator of a declared per-layer metric of
-    the benchmark, or read by a named check of the configuration's
-    plain reference. (``extract_s`` is the shared launch's:
-    ``staged_match`` adds it for every client, and ``kernel_s``, which
-    a metric reads, is the wall time less it.)"""
+    is read by a declared per-layer metric of the benchmark
+    (``conftest.py``), or by a named check of the configuration's plain reference. (``extract_s``
+    is the shared launch's: ``staged_match`` adds it for every client,
+    and ``kernel_s``, which a metric reads, is the wall time less it.)"""
     from fluentbit_tpu.plugins.filter_parser import _TIMING_KEYS
 
     assert set(_TIMING_KEYS) == set(PARSER_KEYS)
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        declared = {m["name"] for m in json.load(f)["per_layer"]}
-    read = set()
-    for path in glob.glob(os.path.join(REPO, "benchmark",
-                                       "layer_metrics", "*.json")):
-        with open(path) as f:
-            spec = json.load(f)
-        if spec["reader"] == "counters:ratio" \
-                and os.path.basename(path)[:-5] in declared:
-            read |= {spec["args"]["num"], spec["args"]["den"]}
+    read = counters_of_declared_metrics
     with open(os.path.join(REPO, "benchmark", "reference",
                            "parser-apache2.py")) as f:
         reference = f.read()

@@ -722,9 +722,13 @@ def test_lane_workers_make_no_timing_shards(mesh_env):
     tm = plugin.raw_timings
     assert len(tm._shards) == 1  # the one ingest thread
     assert tm["device_records"] == 1600 and tm["kernel_s"] > 0
-    # the seven keys the benchmark and the smoke read, and no other
+    # the nine keys the benchmark and the smoke read, and no other
     assert set(tm) == {"extract_s", "kernel_s", "compact_s", "records",
-                       "device_records", "overflow_rows", "h2d_bytes"}
+                       "device_records", "overflow_rows", "h2d_bytes",
+                       "d2h_bytes", "scan_elements"}
+    # the mesh's verdict is copied out as i32: four bytes a rule and row
+    assert tm["d2h_bytes"] >= 4 * 1600 and tm["d2h_bytes"] % (4 * 200) == 0
+    assert tm["scan_elements"] >= 1600 * (512 // plugin._program.k + 1)
 
 
 # ------------------------------------------------- plugin raw_timings
@@ -790,31 +794,21 @@ def test_every_timing_key_this_pr_adds_feeds_a_metric(key):
 
 #: ``rewrite_tag``'s ``raw_timings`` (the staged launch's keys through
 #: the helper it shares with grep, and its own three)
-REWRITE_KEYS = ("extract_s", "kernel_s", "h2d_bytes", "device_records",
-                "overflow_rows", "records", "emit_s", "emits",
-                "emit_backpressure")
+REWRITE_KEYS = ("extract_s", "kernel_s", "h2d_bytes", "d2h_bytes",
+                "scan_elements", "device_records", "overflow_rows",
+                "records", "emit_s", "emits", "emit_backpressure")
 
 
 @pytest.mark.parametrize("key", REWRITE_KEYS)
-def test_every_rewrite_timing_key_feeds_a_metric_or_a_check(key):
-    """The same rule for ``rewrite_tag``'s keys: each is the numerator
-    of a declared data-only metric, or read by a named check of the
-    configuration's plain reference."""
-    import json
-
+def test_every_rewrite_timing_key_feeds_a_metric_or_a_check(
+        key, counters_of_declared_metrics):
+    """The same rule for ``rewrite_tag``'s keys: each is read by a
+    declared data-only metric (``conftest.py``), or by a named check of
+    the configuration's plain reference."""
     from fluentbit_tpu.plugins.filter_rewrite_tag import _TIMING_KEYS
 
     assert set(_TIMING_KEYS) == set(REWRITE_KEYS)
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        declared = {m["name"] for m in json.load(f)["per_layer"]}
-    numerators = set()
-    for path in glob.glob(os.path.join(REPO, "benchmark",
-                                       "layer_metrics", "*.json")):
-        with open(path) as f:
-            spec = json.load(f)
-        if spec["reader"] == "counters:ratio" \
-                and os.path.basename(path)[:-5] in declared:
-            numerators.add(spec["args"]["num"])
+    numerators = counters_of_declared_metrics
     with open(os.path.join(REPO, "benchmark", "reference",
                            "rewrite-syslog.py")) as f:
         reference = f.read()

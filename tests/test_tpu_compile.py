@@ -166,6 +166,49 @@ def test_config3_child_compiles_for_one_chip(one_chip, kernel, impl, k,
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+def _tenants_patterns():
+    """``benchmark/configs/grep-tenants.conf``'s 50 ``Exclude log``
+    patterns, in file order."""
+    from fluentbit_tpu.config_format import load_config_file
+
+    conf = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "grep-tenants.conf")
+    section = next(s for s in load_config_file(conf).sections
+                   if s.name == "filter")
+    return [v.split(None, 1)[1] for k, v in section.properties
+            if k.lower() == "exclude"]
+
+
+@pytest.mark.parametrize("length", [256, 512])
+@pytest.mark.parametrize("k,n_rules", [(2, 5), (3, 38), (4, 6), (5, 1)],
+                         ids=["k2x5", "k3x38", "k4x6", "k5x1"])
+def test_tenants_child_compiles_for_one_chip(one_chip, k, n_rules, length):
+    """grep-tenants' program (PR 34): 50 rules on one key, under
+    ``FBTPU_MESH_RULE_SHARD_R``, so one scan child a stride; each takes
+    the one staged plane ``[1, B, L]`` and makes ``[R_c, B, L]`` of it
+    on the device, every rule classed at its child's widest rule's
+    breakpoints."""
+    patterns = _tenants_patterns()
+    prog = GrepProgram([compile_dfa(p) for p in patterns], 512,
+                       plane_of=(0,) * len(patterns))
+    child = next(c for c in prog._children if c.k == k)
+    assert len(child.dfas) == n_rules and child.n_planes == 1
+
+    def step(tables, planes, lengths):
+        return child._match_impl(
+            tables, *child._gather_planes(planes, lengths))
+
+    compiled = jax.jit(step).lower(
+        _table_shapes(child, one_chip),
+        sds((1, SEGMENT, length), jnp.uint8, one_chip),
+        sds((1, SEGMENT), jnp.int32, one_chip)).compile()
+    assert compiled.output_shardings.device_set == {one_chip._device}
+    # [38, 4096, L/3 + 1] i32 super-symbols and their transpose at the
+    # widest child: well inside one chip's 16 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
 @pytest.mark.parametrize("length", [256, 512])
 def test_span_program_compiles_for_one_chip(one_chip, length):
     """parser-apache2's program: the two dependent scans over the one
