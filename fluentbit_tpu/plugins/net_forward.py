@@ -47,11 +47,21 @@ protocol:
 
 - **Decode beside absorb.** The server is a two-stage pipeline (the
   reference's threaded-input shape, flb_input_thread.c): a connection
-  reads, unpacks and re-encodes on the engine's event loop, and every
-  absorb — dedup check, tenant metering, ``engine.input_log_append``
-  and all beneath it, ledger record — runs on ONE worker thread per
-  instance, first come first served. While the worker waits for the
-  device the loop decodes the connection's next frame; it holds that
+  reads and decodes on the engine's event loop, and every absorb —
+  dedup check, tenant metering, ``engine.input_log_append`` and all
+  beneath it, ledger record — runs on ONE worker thread per instance,
+  first come first served. The loop's decode of a chunk (Forward or
+  PackedForward) is one C call, ``fbtpu_codec.forward_cut``: it finds
+  the message's end, proves that every entry is a ``[time, map]`` whose
+  wire bytes are what the codec itself would pack, and writes the V2
+  events from those bytes with the GIL released — no Python object per
+  entry, nothing packed again (:class:`Unpacker`). A message it cannot
+  prove that of (Message mode, the handshake, non-canonical msgpack,
+  an odd entry) and a process without the extension take the object
+  path, whole: ``unpack_from`` → :meth:`ForwardInput._decode` →
+  :func:`_entries_to_events`, the reference the cut is held to byte
+  for byte (``tests/test_forward_cut.py``). While the worker waits for
+  the device the loop decodes the connection's next frame; it holds that
   one frame, reading nothing more, until the frame before is acked.
   Acks are written on the loop, in arrival order, after the absorb
   returned and the ledger holds the chunk. A chunk too small to be
@@ -78,10 +88,12 @@ import os
 import socket
 import time
 from types import SimpleNamespace
-from typing import Optional
+from typing import NamedTuple, Optional
 
+from ..codec import _native_codec
+from ..codec import msgpack as _msgpack
 from ..codec.events import encode_event
-from ..codec.msgpack import EventTime, OutOfData, Unpacker, packb
+from ..codec.msgpack import EventTime, OutOfData, packb
 from ..core.config import ConfigMapEntry
 from ..core.guard import io_deadline
 from ..core.plugin import FLUSH_CHUNK, FlushResult, InputPlugin, \
@@ -112,8 +124,54 @@ _INLINE_BYTES = 4096
 _ABSORBED, _DUPLICATE, _SHED, _DEFER = range(4)
 
 
+class CutChunk(NamedTuple):
+    """A chunk as ``fbtpu_codec.forward_cut`` hands it over: the
+    entries are V2 events already, counted, and were never objects."""
+
+    tag: str
+    #: the V2 buffer — or, with ``n`` < 0, a PackedForward blob left
+    #: uncut because its option map names a compression
+    events: bytes
+    n: int
+    option: Optional[dict]
+
+
+class Unpacker(_msgpack.Unpacker):
+    """The connection's Unpacker. ``next()`` asks the C codec for the
+    chunk cut first and yields a :class:`CutChunk`; what the cut hands
+    back (``FallbackError``), and every message where the extension is
+    not loaded, is the base class's to decode into objects. The same
+    three outcomes either way: a message, ``StopIteration`` while it is
+    not whole, ``native`` saying whether C served the call.
+
+    (The benchmark and the tests time the loop's decode by subclassing
+    *this* name and wrapping ``feed`` and ``__next__``: the cut lies
+    beneath ``__next__`` for that reason too.)"""
+
+    def __init__(self, buf: bytes = b"", chunks: bool = True):
+        super().__init__(buf)
+        # False over a PackedForward blob's entries: none is a chunk
+        self._chunks = chunks
+
+    def __next__(self):
+        mod = _native_codec.load() if self._chunks else None
+        if mod is not None:
+            try:
+                got = mod.forward_cut(self._buf, self._pos)
+            except mod.FallbackError:
+                pass  # not a chunk of canonical entries: objects decide
+            else:
+                self.native = True
+                if got is None:
+                    raise StopIteration
+                tag, events, n, option, self._pos = got
+                return CutChunk(tag, events, n, option)
+        return super().__next__()
+
+
 def _entries_to_events(entries) -> tuple:
-    """Forward entries [[time, record], ...] → (encoded V2 buffer, n)."""
+    """Forward entries [[time, record], ...] → (encoded V2 buffer, n).
+    The object path's re-encode, and the oracle of the C cut."""
     out = bytearray()
     n = 0
     for entry in entries:
@@ -125,6 +183,35 @@ def _entries_to_events(entries) -> tuple:
         out += encode_event(record, ts)
         n += 1
     return bytes(out), n
+
+
+def _inflated(blob, option) -> bytes:
+    if option and option.get("compressed") == "gzip":
+        return gzip.decompress(blob)
+    return bytes(blob)
+
+
+def _chunk_events(msg, option) -> tuple:
+    """A chunk-shaped message → ``(V2 buffer, n, cut)``; ``cut`` says
+    that C wrote the events from the wire bytes and no entry was ever an
+    object."""
+    if isinstance(msg, CutChunk):
+        if msg.n >= 0:
+            return msg.events, msg.n, True
+        # a compressed blob comes uncut: inflate, then the same walk
+        blob = _inflated(msg.events, option)
+        mod = _native_codec.load()
+        try:
+            return (*mod.forward_cut_entries(blob), True)
+        except mod.FallbackError:
+            entries = list(Unpacker(blob, chunks=False))
+    elif isinstance(msg[1], (bytes, memoryview)):
+        entries = list(Unpacker(_inflated(msg[1], option), chunks=False))
+    elif isinstance(msg[1], (list, tuple)):
+        entries = msg[1]
+    else:
+        entries = [[msg[1], msg[2]]]
+    return (*_entries_to_events(entries), False)
 
 
 def _wire_stamp(option) -> tuple:
@@ -180,6 +267,7 @@ class ForwardInput(InputPlugin):
         # (the metrics registry has no read-back API)
         self.n_absorbed = 0
         self.n_overlapped = 0
+        self.n_cut = 0
         self.n_deferred_acks = 0
         self.n_withheld_acks = 0
         self.n_shed_remote = 0
@@ -208,6 +296,11 @@ class ForwardInput(InputPlugin):
             "fluentbit", "forward", "overlapped_chunks_total",
             "Forward chunks decoded while an earlier chunk's absorb "
             "was still running", ("instance",))
+        self._m_cut = m.counter(
+            "fluentbit", "forward", "cut_chunks_total",
+            "Forward chunks whose V2 events were cut from the wire "
+            "bytes in C (the others were decoded into objects and "
+            "packed again)", ("instance",))
         self._m_deferred = m.counter(
             "fluentbit", "forward", "deferred_acks_total",
             "Acks delayed by quota/buffer backpressure", ("instance",))
@@ -282,7 +375,10 @@ class ForwardInput(InputPlugin):
                     # it is whole an attempt (done=0) costs the C codec
                     # one walk over its spans (native=1), or the Python
                     # walk a decode thrown away where the extension is
-                    # not loaded or handed the bytes back (native=0)
+                    # not loaded or handed the bytes back (native=0).
+                    # The attempt that finds a chunk whole (done=1) is
+                    # its whole decode where the C cut serves: the walk
+                    # and the V2 events, GIL released
                     with span("forward.unpack") as sp:
                         if not fed:
                             u.feed(data)
@@ -352,41 +448,43 @@ class ForwardInput(InputPlugin):
 
     def _decode(self, msg) -> Optional[tuple]:
         """The loop's stage: one wire message → ``(tag, buf, n, option,
-        ack_ref, cid)`` with the entries re-encoded as V2 events, or
-        None for a message that is no chunk."""
-        tag = msg[0]
-        if not isinstance(tag, str):
-            return None
+        ack_ref, cid)`` with the entries as V2 events, or None for a
+        message that is no chunk."""
+        if isinstance(msg, CutChunk):
+            tag, option = msg.tag, msg.option
+        else:
+            tag = msg[0]
+            if not isinstance(tag, str):
+                return None
+            body = msg[1]
+            if isinstance(body, (bytes, memoryview, list, tuple)):
+                # PackedForward / CompressedPackedForward, or Forward mode
+                opt_at = 2
+            else:
+                # Message mode [tag, time, record, option?]
+                if len(msg) < 3 or not isinstance(msg[2], dict):
+                    return None
+                opt_at = 3
+            option = msg[opt_at] if len(msg) > opt_at \
+                and isinstance(msg[opt_at], dict) else None
         if self.tag_prefix:
             tag = f"{self.tag_prefix}.{tag}"
-        body = msg[1]
-        packed = isinstance(body, (bytes, memoryview))
-        if packed or isinstance(body, (list, tuple)):
-            # PackedForward / CompressedPackedForward, or Forward mode
-            opt_at = 2
-        else:
-            # Message mode [tag, time, record, option?]
-            if len(msg) < 3 or not isinstance(msg[2], dict):
-                return None
-            opt_at = 3
-        option = msg[opt_at] if len(msg) > opt_at \
-            and isinstance(msg[opt_at], dict) else None
         ack_ref = option.get("chunk") if option else None
         cid = self._chunk_key(ack_ref)
-        # the chunk id is taken before the re-encode so that every span
-        # of this frame from here to the ack carries it
+        # the chunk id is taken before the events are made so that every
+        # span of this frame from here to the ack carries it
         with bind(chunk=cid):
-            with span("forward.reencode"):
-                if packed:
-                    blob = bytes(body)
-                    if option and option.get("compressed") == "gzip":
-                        blob = gzip.decompress(blob)
-                    entries = list(Unpacker(blob))
-                elif opt_at == 2:
-                    entries = body
-                else:
-                    entries = [[msg[1], msg[2]]]
-                buf, n = _entries_to_events(entries)
+            # one span a frame around whatever produces the V2 buffer:
+            # next to nothing where the Unpacker's cut already has
+            # (cut=1), the object path's re-encode where it has not
+            with span("forward.reencode") as sp:
+                buf, n, cut = _chunk_events(msg, option)
+                sp.set_metadata(cut=int(cut))
+            if cut:
+                self.n_cut += 1
+                self._m_cut.inc(1, (self.instance.display_name,))
+                with span("forward.cut"):
+                    pass
             if self._tries_handed != self._tries_done:
                 # decoded while an earlier frame's absorb was running:
                 # the overlap the two stages exist for
@@ -562,6 +660,7 @@ class ForwardInput(InputPlugin):
             "role": "server",
             "absorbed": self.n_absorbed,
             "overlapped": self.n_overlapped,
+            "cut": self.n_cut,
             "deferred_acks": self.n_deferred_acks,
             "withheld_acks": self.n_withheld_acks,
             "shed_remote": self.n_shed_remote,

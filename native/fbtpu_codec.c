@@ -13,6 +13,14 @@
  *   - V2 [[ts, meta], body] and legacy [ts, body] records both map to
  *     LogEvent(timestamp, body, metadata, raw-span)
  *
+ * Two entry points build no object per record at all, and run with the
+ * GIL released: in_forward's loop takes a Forward / PackedForward chunk
+ * off the wire with forward_cut (the message's end, a proof that every
+ * entry's bytes are canonical, and the V2 events copied from them;
+ * forward_cut_entries is the same walk over an inflated blob), and
+ * unpack_from's decode stays the path of every message that proof does
+ * not cover. parser_json_batch transcodes a whole chunk the same way.
+ *
  * Reference precedent: the hot decode loop is C in fluent-bit too
  * (lib/msgpack-c via flb_log_event_decoder, src/flb_log_event_decoder.c).
  */
@@ -21,6 +29,7 @@
 #include <Python.h>
 
 #include <stdint.h>
+#include <stdlib.h>
 #include <string.h>
 
 static PyObject *g_logevent = NULL;   /* codec.events.LogEvent */
@@ -680,7 +689,15 @@ static int utf8_valid(const uint8_t *p, long long n) {
     const uint8_t *end = p + n;
     while (p < end) {
         uint8_t c = *p;
-        if (c < 0x80) { p++; continue; }
+        if (c < 0x80) {
+            /* log lines are mostly ASCII: eight bytes a test */
+            uint64_t w;
+            while (end - p >= 8 && (memcpy(&w, p, 8),
+                                    !(w & 0x8080808080808080ULL)))
+                p += 8;
+            if (p < end && *p < 0x80) p++;
+            continue;
+        }
         if (c < 0xC2) return 0;
         if (c < 0xE0) {
             if (end - p < 2 || (p[1] & 0xC0) != 0x80) return 0;
@@ -1424,6 +1441,259 @@ static PyObject *py_parser_json_batch(PyObject *self, PyObject *args) {
     return res;
 }
 
+/* ------------------------------------------------------------------ */
+/* in_forward's chunk cut — a Forward or PackedForward message straight
+ * to the V2 event buffer, with no Python object for any entry.
+ *
+ * The object path (unpack_from → net_forward._decode →
+ * _entries_to_events → pack_event) decodes every [time, record] entry
+ * and packs it again as [[time, {}], record]. Where the wire's bytes
+ * are canonical — what decode → pack_obj would write back, which
+ * mp_canonical proves — the event is the entry's own bytes with two
+ * put in: 0x92 0x92 <time> 0x80 <record>. Everything up to the result
+ * tuple touches the caller's buffer and one malloc'ed output alone, so
+ * it runs with the GIL released: the device lane's thread dispatches
+ * while the loop cuts. Whatever is not proven — another message shape,
+ * an entry that is not a fixarray of [time, map], a time that is not an
+ * int, a float64 or an EventTime (the object path puts the clock in
+ * nil's place), non-canonical bytes, a torn blob — is FallbackError for
+ * the whole message, and the object path, the reference this cut is
+ * held to, runs as it always did. */
+
+#define CUT_OK       0
+#define CUT_MORE     1   /* the buffer does not hold the message whole */
+#define CUT_FALLBACK 2   /* the Python walk decides */
+#define CUT_NOMEM    3
+
+/* below this many bytes a walk is shorter than a hand-over of the GIL */
+#define CUT_NOGIL_MIN 4096
+
+/* the most the events of `span` bytes of entries can take: an entry is
+ * three bytes or more, its event two bytes longer */
+static size_t cut_capacity(size_t span) { return span + 2 * (span / 3) + 2; }
+
+static int is_map_hdr(uint8_t b) {
+    return (b >= 0x80 && b <= 0x8F) || b == 0xDE || b == 0xDF;
+}
+
+/* entries [time, map] from p: `want` of them (an array's), or as many
+ * as end at `end` exactly (a bin's stream: want < 0). The events go to
+ * `out`, which holds cut_capacity(end - p); *nx is where the walk
+ * stopped. mp_canonical bounds every read by `end`. */
+static int cut_entries(const uint8_t *p, const uint8_t *end, long long want,
+                       uint8_t *out, size_t *out_len, long long *n_out,
+                       const uint8_t **nx) {
+    uint8_t *o = out;
+    long long n = 0;
+    while (want < 0 ? p < end : n < want) {
+        if (end - p < 3 || p[0] != 0x92) return CUT_FALLBACK;
+        const uint8_t *t = p + 1, *rec, *next;
+        uint8_t b = *t;
+        if (!(b < 0x80 || b >= 0xE0 || b == 0xCB || b == 0xD7
+              || (b >= 0xCC && b <= 0xD3)))
+            return CUT_FALLBACK;
+        if (mp_canonical(t, end, 0, &rec) || rec >= end
+                || !is_map_hdr(*rec) || mp_canonical(rec, end, 0, &next))
+            return CUT_FALLBACK;
+        *o++ = 0x92;
+        *o++ = 0x92;
+        memcpy(o, t, (size_t)(rec - t));
+        o += rec - t;
+        *o++ = 0x80;
+        memcpy(o, rec, (size_t)(next - rec));
+        o += next - rec;
+        p = next;
+        n++;
+    }
+    if (want < 0 && p != end) return CUT_FALLBACK;
+    *out_len = (size_t)(o - out);
+    *n_out = n;
+    *nx = p;
+    return CUT_OK;
+}
+
+/* the big-endian length word of a header: n bytes at p, or -1 where
+ * `end` comes first */
+static long long mp_be(const uint8_t *p, const uint8_t *end, int n) {
+    if (end - p < n) return -1;
+    long long v = 0;
+    while (n--) v = (v << 8) | *p++;
+    return v;
+}
+
+/* does the map at p, which ends at `end`, hold the str key `key`? */
+static int map_has_key(const uint8_t *p, const uint8_t *end,
+                       const char *key, long long keylen) {
+    int w = *p == 0xDE ? 2 : *p == 0xDF ? 4 : 0;
+    long long n = w ? mp_be(p + 1, end, w) : (*p & 0x0F), klen;
+    p += 1 + w;
+    for (long long i = 0; i < n; i++) {
+        const uint8_t *k = mp_str_hdr(p, end, &klen);
+        if (k && klen == keylen && klen <= end - k
+                && memcmp(k, key, (size_t)klen) == 0)
+            return 1;
+        if (!(p = mp_skip_span(p, end, 0))
+                || !(p = mp_skip_span(p, end, 0)))
+            return 0;
+    }
+    return 0;
+}
+
+typedef struct {
+    uint8_t first;                 /* 0x92 or 0x93: the message's header */
+    const uint8_t *tag, *body, *stop, *opt, *msg_end;
+    long long tag_len, want, n;
+} cut_msg;
+
+/* what follows a chunk's body at `at`: in a message of three a map that
+ * ends the message (→ m->opt), in a message of two nothing */
+static int cut_option(cut_msg *m, const uint8_t *at) {
+    const uint8_t *end = m->msg_end;
+    if (m->first == 0x92) return at == end ? CUT_OK : CUT_FALLBACK;
+    if (at >= end || !is_map_hdr(*at) || mp_skip_span(at, end, 0) != end)
+        return CUT_FALLBACK;
+    m->opt = at;
+    return CUT_OK;
+}
+
+/* the one message [str tag, array | bin, map?] at p: where it ends and
+ * where its entries lie — `want` of them from `body` (Forward mode), or
+ * those of the bin [body, stop) with `opt` found already (want < 0),
+ * which stays uncut (n = -1) where the option names a compression */
+static int cut_shape(const uint8_t *p, const uint8_t *end, cut_msg *m) {
+    if (p >= end) return CUT_MORE;
+    m->first = *p;
+    if (m->first != 0x92 && m->first != 0x93) return CUT_FALLBACK;
+    /* told before the walk: HELO, PING, an ack, an entry of a stream */
+    if (end - p >= 2 && !((p[1] >= 0xA0 && p[1] <= 0xBF)
+                          || (p[1] >= 0xD9 && p[1] <= 0xDB)))
+        return CUT_FALLBACK;
+    int bad = 0;
+    /* depth 1, as unpack_from walks: the same nesting is refused */
+    m->msg_end = mp_walk(p, end, 1, &bad);
+    if (!m->msg_end) return bad ? CUT_FALLBACK : CUT_MORE;
+    end = m->msg_end;  /* nothing below reads past the walked span */
+    m->tag = mp_str_hdr(p + 1, end, &m->tag_len);
+    if (!m->tag || m->tag_len >= end - m->tag) return CUT_FALLBACK;
+    /* the body: an array of entries (Forward mode) or a bin of them */
+    const uint8_t *q = m->tag + m->tag_len;
+    uint8_t b = *q;
+    int w = b == 0xC4 ? 1 : (b == 0xC5 || b == 0xDC) ? 2
+          : (b == 0xC6 || b == 0xDD) ? 4 : 0;
+    if (!w && (b < 0x90 || b > 0x9F)) return CUT_FALLBACK;  /* Message mode */
+    long long len = w ? mp_be(q + 1, end, w) : (b & 0x0F);
+    if (len < 0) return CUT_FALLBACK;
+    m->body = q + 1 + w;
+    if (b < 0xC4 || b > 0xC6) {
+        m->want = len;
+        m->stop = end;  /* the array ends where its entries do */
+        return CUT_OK;
+    }
+    m->want = -1;
+    if (len > end - m->body) return CUT_FALLBACK;
+    m->stop = m->body + len;
+    if (cut_option(m, m->stop)) return CUT_FALLBACK;
+    if (m->opt && map_has_key(m->opt, end, "compressed", 10)) m->n = -1;
+    return CUT_OK;
+}
+
+/* forward_cut(buf, pos) — unpack_from's contract (None while the
+ * message at pos is not whole, FallbackError where the Python walk must
+ * decide) for a chunk-shaped Forward message, with the entries never
+ * built: → (tag, events, n, option, end). `n` is counted, whatever a
+ * `size` option says; `option` is the decoded map or None. A bin whose
+ * option map has a `compressed` key comes back uncut, as `events` with
+ * n = -1: the caller inflates it and calls forward_cut_entries. */
+static PyObject *py_forward_cut(PyObject *self, PyObject *args) {
+    Py_buffer view;
+    Py_ssize_t pos;
+    if (!PyArg_ParseTuple(args, "y*n", &view, &pos)) return NULL;
+    if (pos < 0 || pos > view.len) {
+        PyBuffer_Release(&view);
+        PyErr_SetString(PyExc_ValueError, "forward_cut: pos out of range");
+        return NULL;
+    }
+    const uint8_t *base = (const uint8_t *)view.buf, *after;
+    cut_msg m;
+    memset(&m, 0, sizeof m);
+    uint8_t *out = NULL;
+    size_t out_len = 0;
+    /* from here to the result objects: the view and `out` alone */
+    PyThreadState *unlocked = view.len - pos >= CUT_NOGIL_MIN
+        ? PyEval_SaveThread() : NULL;
+    int rc = cut_shape(base + pos, base + view.len, &m);
+    if (rc == CUT_OK && m.n >= 0) {
+        out = malloc(cut_capacity((size_t)(m.stop - m.body)));
+        rc = out ? cut_entries(m.body, m.stop, m.want, out, &out_len, &m.n,
+                               &after) : CUT_NOMEM;
+        if (rc == CUT_OK && m.want >= 0) rc = cut_option(&m, after);
+    }
+    if (unlocked) PyEval_RestoreThread(unlocked);
+    PyObject *res = NULL, *tag = NULL, *events = NULL, *option = NULL;
+    if (rc == CUT_MORE) {
+        res = Py_None;
+        Py_INCREF(res);
+    } else if (rc == CUT_FALLBACK) {
+        PyErr_SetString(g_fallback, "not a chunk of canonical entries");
+    } else if (rc == CUT_NOMEM) {
+        PyErr_NoMemory();
+    } else {
+        tag = PyUnicode_DecodeUTF8((const char *)m.tag, m.tag_len, "replace");
+        events = m.n < 0
+            ? PyBytes_FromStringAndSize((const char *)m.body, m.stop - m.body)
+            : PyBytes_FromStringAndSize((const char *)out, out_len);
+        if (m.opt) {
+            rd r = {m.opt, m.msg_end, 0};
+            option = decode_obj(&r);  /* a handful of objects */
+            if (!option && PyErr_ExceptionMatches(g_truncated)) {
+                PyErr_Clear();  /* the walk and the decoder parted */
+                PyErr_SetString(g_fallback, "span walk and decode disagree");
+            }
+        } else {
+            option = Py_None;
+            Py_INCREF(option);
+        }
+        if (tag && events && option)
+            res = Py_BuildValue("(OOLOn)", tag, events, m.n, option,
+                                (Py_ssize_t)(m.msg_end - base));
+        Py_XDECREF(tag);
+        Py_XDECREF(events);
+        Py_XDECREF(option);
+    }
+    free(out);
+    PyBuffer_Release(&view);
+    return res;
+}
+
+/* forward_cut_entries(buf) → (events, n): the entry walk alone over a
+ * concatenated stream of [time, record] entries — what a compressed
+ * PackedForward blob inflates to. FallbackError as above. */
+static PyObject *py_forward_cut_entries(PyObject *self, PyObject *arg) {
+    Py_buffer view;
+    if (PyObject_GetBuffer(arg, &view, PyBUF_SIMPLE) < 0) return NULL;
+    const uint8_t *base = (const uint8_t *)view.buf, *nx;
+    uint8_t *out;
+    size_t out_len = 0;
+    long long n = 0;
+    int rc = CUT_NOMEM;
+    Py_BEGIN_ALLOW_THREADS
+    out = malloc(cut_capacity((size_t)view.len));
+    if (out)
+        rc = cut_entries(base, base + view.len, -1, out, &out_len, &n, &nx);
+    Py_END_ALLOW_THREADS
+    PyObject *res = NULL;
+    if (rc == CUT_FALLBACK)
+        PyErr_SetString(g_fallback, "not a stream of canonical entries");
+    else if (rc == CUT_NOMEM)
+        PyErr_NoMemory();
+    else
+        res = Py_BuildValue("(y#L)", (const char *)out, (Py_ssize_t)out_len,
+                            n);
+    free(out);
+    PyBuffer_Release(&view);
+    return res;
+}
+
 /* unpack_from(buf, pos) — the streaming Unpacker's fast path
  * (codec/msgpack.Unpacker.__next__). The span walk comes first and
  * builds nothing: a message the buffer does not hold whole yet costs
@@ -1496,6 +1766,14 @@ static PyMethodDef methods[] = {
      "unpack_from(buf, pos) → (obj, end) for the one msgpack object "
      "at pos, None while the buffer does not hold it whole; raises "
      "FallbackError when the Python walk must decide"},
+    {"forward_cut", py_forward_cut, METH_VARARGS,
+     "forward_cut(buf, pos) → (tag, events, n, option, end) for the "
+     "Forward / PackedForward chunk at pos, its entries cut to V2 "
+     "events with no object built; None and FallbackError as "
+     "unpack_from"},
+    {"forward_cut_entries", py_forward_cut_entries, METH_O,
+     "forward_cut_entries(buf) → (events, n) for a concatenated "
+     "stream of [time, record] entries; raises FallbackError"},
     {"_init", py_init, METH_VARARGS,
      "register the LogEvent and EventTime classes"},
     {NULL, NULL, 0, NULL},

@@ -95,6 +95,48 @@ for _ in range(100):  # pack side round-trips
     body = {"s": "y" * rng.randrange(300), "l": [1, {"k": (2, 3)}],
             "b": bytes(range(rng.randrange(50)))}
     mod.pack_event(EventTime(1, 2), {}, body)
+# --- in_forward's chunk cut: whole, torn and hostile messages ---
+from fluentbit_tpu.codec.msgpack import packb
+entries = [[EventTime(1700000000 + i, i) if i %% 3 else 1700000000 + i,
+            {"log": "x" * rng.randrange(0, 200), "n": i - 30,
+             "d": {"a": [1.5, b"b", None]}}] for i in range(60)]
+blob = b"".join(packb(e) for e in entries)
+want = b"".join(encode_event(rec, ts) for ts, rec in entries)
+chunks = [packb(["app", entries, {"chunk": "c", "size": 60}]),
+          packb(["app", blob, {"chunk": "c", "size": 7}]),
+          packb(["app", entries])]
+others = [packb(["app", blob, {"compressed": "gzip"}]),
+          packb(["app", 1700000000, {"k": "v"}, {"chunk": "m"}]),
+          packb(["PING", "host", b"salt", "digest", "", ""]),
+          packb(["app", [[1, {"k": 1}, None]], {"chunk": "three"}])]
+for fr in chunks:
+    tag, events, n, option, end = mod.forward_cut(b"\x00" + fr, 1)
+    assert (tag, events, n, end) == ("app", want, 60, 1 + len(fr))
+assert mod.forward_cut(others[0], 0)[1:3] == (blob, -1)
+assert mod.forward_cut_entries(blob) == (want, 60)
+for fr in chunks + others:
+    for cut in range(0, len(fr), 1 if len(fr) < 400 else 7):
+        torn = bytes(fr[:cut])      # a heap copy that ends at the tear
+        try:
+            assert mod.forward_cut(torn, 0) is None
+        except mod.FallbackError:
+            pass  # told from its first bytes: not a chunk
+    for _ in range(150):
+        mut = bytearray(fr)
+        for _ in range(rng.randrange(1, 10)):
+            mut[rng.randrange(len(mut))] = rng.randrange(256)
+        hostile = bytes(mut[: rng.randrange(1, len(mut) + 1)])
+        for call in (lambda b: mod.forward_cut(b, 0),
+                     lambda b: mod.forward_cut(b, len(b) // 2),
+                     mod.forward_cut_entries, lambda b: mod.unpack_from(b, 0)):
+            try:
+                call(hostile)
+            except ValueError:
+                pass  # handed back or malformed is fine; a fault is not
+try:
+    mod.forward_cut(b"\x92\xa1t" + b"\x91" * 100000 + b"\x90", 0)
+except mod.FallbackError:
+    pass  # depth bound
 print("ASAN_DRIVER_OK")
 """
 
@@ -132,6 +174,10 @@ def test_native_data_plane_under_asan(tmp_path):
         "ASAN_OPTIONS": "detect_leaks=0:abort_on_error=1:exitcode=99",
         "JAX_PLATFORMS": "cpu",
         "PYTHONPATH": REPO,
+        # every bytes object from the system allocator, so that a torn
+        # message of a few hundred bytes has redzones too (pymalloc's
+        # arenas have none: a read past a small buffer would go unseen)
+        "PYTHONMALLOC": "malloc",
         # exercise the pool dispatch under ASan too
         "FBTPU_THREADS_NO_HW_CAP": "1",
         "FBTPU_DFA_THREADS": "4",

@@ -8,6 +8,7 @@ device launch is to the worker — a wait with the GIL released. Every
 behaviour is a case of ``test_forward_pipeline``.
 """
 
+import gzip
 import logging
 import socket
 import sys
@@ -17,12 +18,17 @@ import time
 import pytest
 
 import fluentbit_tpu as flb
+from fluentbit_tpu.codec import _native_codec
 from fluentbit_tpu.codec.events import decode_events
 from fluentbit_tpu.codec.msgpack import Unpacker, packb
 from fluentbit_tpu.core.plugin import FilterPlugin, registry
 from fluentbit_tpu.plugins import net_forward
 
 WAIT_S = 20.0
+#: whether the C cut serves here (``fbtpu_codec.forward_cut``): without
+#: a toolchain, or under ``FBTPU_NO_NATIVE``, every frame takes the
+#: object path and the cases hold for it
+HAVE_CUT = _native_codec.load() is not None
 
 
 def wait_for(cond, timeout=WAIT_S, interval=0.005):
@@ -152,6 +158,35 @@ def frame(chunk: str, n=8, tag="app", pad=600, **option) -> bytes:
     return packb([tag, entries, {"chunk": chunk, **option}])
 
 
+def odd_frame(chunk: str, n=8) -> bytes:
+    """``frame``'s records in entries of three elements: what the C cut
+    hands back whole, and the object path trims to ``[time, record]``."""
+    entries = [[1700000000 + i, {"chunk": chunk, "i": i, "pad": "x" * 600},
+                None] for i in range(n)]
+    return packb(["app", entries, {"chunk": chunk}])
+
+
+def packed_frame(chunk: str, n=8, compress=False, **option) -> bytes:
+    """PackedForward as ``out_forward`` frames it, gzip'd or not."""
+    blob = b"".join(
+        packb([1700000000 + i, {"chunk": chunk, "i": i, "pad": "x" * 600}])
+        for i in range(n))
+    option = {"size": n, "chunk": chunk, **option}
+    if compress:
+        blob, option["compressed"] = gzip.compress(blob), "gzip"
+    return packb(["app", blob, option])
+
+
+@pytest.fixture
+def no_codec(monkeypatch):
+    """From the call on, the state ``FBTPU_NO_NATIVE=1`` leaves the
+    codec in: ``load()`` gives None and every message is objects."""
+    def switch():
+        monkeypatch.setattr(_native_codec, "_mod", None)
+        monkeypatch.setattr(_native_codec, "_tried", True)
+    return switch
+
+
 class SpanLog:
     """Stands where ``net_forward.span`` is: names and threads."""
 
@@ -207,6 +242,7 @@ def case_acks_leave_in_send_order(agg, **_):
     edge.send(b"".join(frame(c) for c in ids))
     assert edge.acks(len(ids)) == ids
     assert agg.srv.n_absorbed == len(ids)
+    assert agg.srv.n_cut == (len(ids) if HAVE_CUT else 0)
     assert agg.srv.n_overlapped >= 1
     # one worker, first come first served: the chunks entered the
     # filter in the order they were sent
@@ -251,12 +287,16 @@ def case_handover_full_stops_reading(agg, monkeypatch, **_):
             fed.append(len(data))
             return super().feed(data)
 
-    decoded = []
-    real = net_forward._entries_to_events
+    decoded = []  # entries of each frame the loop has made events of
+    real = net_forward._chunk_events
+
+    def chunk_events(msg, option):
+        buf, n, cut = real(msg, option)
+        decoded.append(n)
+        return buf, n, cut
+
     monkeypatch.setattr(net_forward, "Unpacker", CountingUnpacker)
-    monkeypatch.setattr(
-        net_forward, "_entries_to_events",
-        lambda entries: decoded.append(len(entries)) or real(entries))
+    monkeypatch.setattr(net_forward, "_chunk_events", chunk_events)
     agg.gate.open.clear()
     edge = agg.connect()
     a, b = frame("a"), frame("b")
@@ -397,7 +437,9 @@ def case_wrappers_set_after_init_see_every_call(agg, monkeypatch, **_):
     """What the benchmark's ``install_spans`` does after the pipeline
     has started: a subclass in ``net_forward.Unpacker``, a wrapper in
     ``net_forward._entries_to_events``, an instance attribute over
-    ``engine.input_log_append``."""
+    ``engine.input_log_append``. A frame the C cut serves is decoded
+    beneath ``__next__`` alone; one it hands back goes through all
+    three names as it always did."""
     calls = {"feed": 0, "next": 0, "entries": 0, "append": 0}
     threads = {"entries": set(), "append": set()}
 
@@ -424,6 +466,16 @@ def case_wrappers_set_after_init_see_every_call(agg, monkeypatch, **_):
         threads["append"].add(threading.current_thread().name)
         return real_append(*a, **kw)
 
+    def packed(chunk, entry):
+        return packb(["app", b"".join(
+            packb(entry(chunk, i)) for i in range(8)), {"chunk": chunk}])
+
+    def plain(chunk, i):
+        return [1700000000 + i, {"chunk": chunk, "i": i, "pad": "x" * 600}]
+
+    def of_three(chunk, i):  # the cut hands the whole message back
+        return plain(chunk, i) + [None]
+
     monkeypatch.setattr(net_forward, "Unpacker", TimedUnpacker)
     monkeypatch.setattr(net_forward, "_entries_to_events", entries_seen)
     agg.engine.input_log_append = append_seen
@@ -432,18 +484,26 @@ def case_wrappers_set_after_init_see_every_call(agg, monkeypatch, **_):
         ids = ["w0", "w1", "w2", "w3"]
         edge.send(b"".join(frame(c) for c in ids))
         assert edge.acks(4) == ids
-        packed = packb(["app", b"".join(
-            packb([1700000000 + i, {"chunk": "p", "i": i, "pad": "x" * 600}])
-            for i in range(8)), {"chunk": "p"}])
-        edge.send(packed)  # PackedForward: an inner Unpacker as well
+        edge.send(packed("p", plain))
         assert edge.acks(1) == ["p"]
+        served = agg.srv.n_cut
+        assert served == (5 if HAVE_CUT else 0)
+        assert calls["entries"] == 5 - served and calls["next"] >= 5
+        edge.send(packb(["app", [of_three("o", i) for i in range(8)],
+                         {"chunk": "o"}]))
+        edge.send(packed("q", of_three))  # an inner Unpacker as well
+        assert edge.acks(2) == ["o", "q"]
         edge.close()
     finally:
         del agg.engine.input_log_append
-    assert calls["entries"] == 5 and calls["append"] == 5
-    assert calls["feed"] >= 2 and calls["next"] >= 5 + 8
+    assert agg.srv.n_cut == served
+    assert calls["entries"] == 7 - served and calls["append"] == 7
+    assert calls["feed"] >= 2 and calls["next"] >= 7 + 8
     assert len(threads["append"]) == 1
     assert not threads["append"] & threads["entries"]
+    agg.ctx.flush_now()
+    wait_for(lambda: len(agg.records()) == 8 * 7)
+    assert [r["i"] for r in agg.records()] == list(range(8)) * 7
 
 
 def case_same_chunk_on_two_connections_is_absorbed_once(agg, **_):
@@ -539,16 +599,125 @@ def case_small_chunks_are_absorbed_on_the_loop_in_order(agg, **_):
     edge.close()
 
 
+def case_cut_and_object_path_land_the_same_chunk_bytes(
+        agg, monkeypatch, no_codec, **_):
+    """Forward, PackedForward and gzip frames, and one small enough for
+    the loop's own absorb: what the engine is handed, and what the
+    output gets, does not say which path decoded the frame. The counter,
+    ``health_block()`` and the ``forward.cut`` span count the frames the
+    C cut served, and only those."""
+    spans = SpanLog()
+    monkeypatch.setattr(net_forward, "span", spans)
+    appended = []
+    real_append = agg.engine.input_log_append
+
+    def append(ins, tag, data, n_records=None):
+        appended.append((tag, bytes(data), n_records))
+        return real_append(ins, tag, data, n_records)
+
+    monkeypatch.setattr(agg.engine, "input_log_append", append)
+
+    def frames(run: str) -> list:
+        # the same entries under chunk ids of the run's own: the ledger
+        # would drop a second delivery, and the id is in no event
+        def entries(n, pad, more=()):
+            return [[1700000000 + i, {"i": i, "pad": "x" * pad}, *more]
+                    for i in range(n)]
+
+        def blob(n):
+            return b"".join(packb(e) for e in entries(n, 600))
+
+        return [
+            packb(["app", entries(8, 600), {"chunk": run + "-fwd"}]),
+            packb(["app", blob(8), {"size": 8, "chunk": run + "-bin"}]),
+            packb(["app", gzip.compress(blob(8)),
+                   {"chunk": run + "-gz", "compressed": "gzip"}]),
+            packb(["app", entries(2, 4), {"chunk": run + "-small"}]),
+            packb(["app", entries(8, 600, (None,)), {"chunk": run + "-odd"}]),
+        ]
+
+    kinds = ["-fwd", "-bin", "-gz", "-small", "-odd"]
+    edge = agg.connect()
+    edge.send(b"".join(frames("cut")))
+    assert edge.acks(5) == ["cut" + k for k in kinds]
+    served = 4 if HAVE_CUT else 0
+    assert agg.srv.n_cut == agg.srv.health_block()["cut"] == served
+    assert spans.count("forward.cut") == served
+    assert spans.count("forward.reencode") == 5
+    text = agg.engine.metrics.to_prometheus()
+    assert "fluentbit_forward_cut_chunks_total" in text
+    if HAVE_CUT:
+        assert f'fluentbit_forward_cut_chunks_total{{instance="' \
+               f'{agg.srv.instance.display_name}"}} 4' in text
+    with_cut, appended[:] = list(appended), []
+    no_codec()
+    edge.send(b"".join(frames("obj")))
+    assert edge.acks(5) == ["obj" + k for k in kinds]
+    assert agg.srv.n_cut == spans.count("forward.cut") == served
+    assert spans.count("forward.reencode") == 10
+    assert appended == with_cut and len(with_cut) == 5
+    assert [n for _t, _d, n in with_cut] == [8, 8, 8, 2, 8]
+    agg.ctx.flush_now()
+    wait_for(lambda: len(agg.records()) == 2 * 34)
+    assert agg.records()[:34] == agg.records()[34:]
+    edge.close()
+
+
+def case_torn_frame_absorbs_nothing_on_either_path(agg, no_codec, **_):
+    """``forward.partial_write``: the frame stops mid-entry and the link
+    dies. Nothing of it reaches the engine, with the cut or without;
+    the next connection's whole frame does."""
+    for run, whole in (("cut", frame), ("packed", packed_frame),
+                       ("objects", frame)):
+        if run == "objects":
+            no_codec()
+        data = whole(f"torn-{run}")
+        for stop in (1, 9, len(data) // 2, len(data) - 1):
+            edge = agg.connect()
+            edge.send(data[:stop])
+            time.sleep(0.05)
+            edge.close()
+        edge = agg.connect()
+        edge.send(whole(f"whole-{run}"))
+        assert edge.acks(1) == [f"whole-{run}"]
+        edge.close()
+    assert agg.srv.n_absorbed == agg.gate.entered == 3
+    assert agg.srv.n_cut == (2 if HAVE_CUT else 0)
+    assert {r["chunk"] for r in agg.records()} <= {
+        "whole-cut", "whole-packed", "whole-objects"}
+    assert sorted(agg.srv._ledger.snapshot()) == [
+        "whole-cut", "whole-objects", "whole-packed"]
+
+
+def case_cut_and_handed_back_frames_keep_their_order(agg, **_):
+    """Frames the C cut serves between frames it hands back, on one
+    connection: absorbed and acked in the order sent."""
+    agg.gate.pause_s = 0.005
+    edge = agg.connect()
+    ids = [f"k{i:02d}" for i in range(16)]
+    makers = (frame, odd_frame, packed_frame, odd_frame,
+              lambda c: packed_frame(c, compress=True))
+    edge.send(b"".join(makers[i % 5](c) for i, c in enumerate(ids)))
+    assert edge.acks(len(ids)) == ids
+    assert agg.srv.n_absorbed == agg.gate.entered == len(ids)
+    assert agg.srv.n_cut == (10 if HAVE_CUT else 0)
+    agg.ctx.flush_now()
+    wait_for(lambda: len(agg.records()) == 8 * len(ids))
+    assert [r["chunk"] for r in agg.records() if r["i"] == 0] == ids
+    edge.close()
+
+
 CASES = [v for k, v in sorted(globals().items()) if k.startswith("case_")]
 
 
 @pytest.mark.parametrize("case", CASES,
                          ids=[c.__name__[5:] for c in CASES])
-def test_forward_pipeline(case, tmp_path, monkeypatch, caplog):
+def test_forward_pipeline(case, tmp_path, monkeypatch, caplog, no_codec):
     props = {"defer_ack_window": "0.4", **getattr(case, "props", {})}
     agg = Aggregator(tmp_path, **props)
     try:
-        case(agg=agg, monkeypatch=monkeypatch, caplog=caplog)
+        case(agg=agg, monkeypatch=monkeypatch, caplog=caplog,
+             no_codec=no_codec)
     finally:
         agg.stop()
     assert agg.workers() == []

@@ -507,6 +507,26 @@ def test_unpack_spans_say_whether_the_extension_served_them(runs):
 
 
 @pytest.mark.mesh
+def test_cut_span_marks_the_frame_the_c_cut_served(runs):
+    """``forward.reencode`` stays one span a frame (three metrics divide
+    by its count) and says ``cut=0|1``; ``forward.cut`` is written for
+    exactly the frames whose events C cut from the wire bytes, on the
+    loop's thread, with the frame's ``chunk``: a benchmark run whose
+    ``input.cut_per_frame`` reads under 1 measured the object path."""
+    from fluentbit_tpu.codec import _native_codec
+
+    served = int(_native_codec.load() is not None)
+    events = runs["events"]
+    (reencode,) = by_name(events, "forward.reencode")
+    assert reencode["stats"]["cut"] == served
+    cut = by_name(events, "forward.cut")
+    assert len(cut) == served
+    for e in cut:
+        assert e["stats"]["chunk"] == CHUNK
+        assert e["line"] == reencode["line"] and reencode["end"] <= e["start"]
+
+
+@pytest.mark.mesh
 def test_untraced_frame_records_nothing(runs):
     assert runs["span_off"] is spans.NOOP
     assert runs["bind_off"] is spans.NOOP
@@ -826,7 +846,11 @@ def test_every_rewrite_timing_key_feeds_a_metric_or_a_check(
 def test_outside_wrappers_of_the_benchmark_are_still_called(monkeypatch):
     """``benchmark/run.py::install_spans`` subclasses the Unpacker,
     rebinds ``_entries_to_events`` and setattr's wrappers on the engine
-    and the plugins: each name must still be looked up at call time."""
+    and the plugins: each name must still be looked up at call time.
+    The C cut of a chunk lies beneath the Unpacker's ``__next__``; a
+    frame it hands back (here: entries of three elements) is re-encoded
+    beneath ``_entries_to_events``."""
+    from fluentbit_tpu.codec import _native_codec
     from fluentbit_tpu.plugins import net_forward
 
     calls = {}
@@ -856,19 +880,24 @@ def test_outside_wrappers_of_the_benchmark_are_still_called(monkeypatch):
     plugin = engine.filters[0].plugin
     for attr in ("process_batch", "filter"):
         setattr(plugin, attr, counted(getattr(plugin, attr), "grep"))
+    handed_back = packb(["app", [[1700000000 + i, {"log": OK_LINE}, None]
+                                 for i in range(8)], {"chunk": "back"}])
     ctx.start()
     try:
         port = wait_for(lambda: engine.inputs[0].plugin.bound_port)
         with socket.create_connection(("127.0.0.1", port)) as s:
             s.settimeout(30)
-            s.sendall(frame(n=8))
-            assert s.recv(4096)
+            for data in (frame(n=8), handed_back):
+                s.sendall(data)
+                assert s.recv(4096)
         ctx.flush_now()
         wait_for(lambda: got)
     finally:
         ctx.stop()
-    assert calls["feed"] >= 1 and calls["next"] >= 2
-    assert calls["reencode"] == 1 and calls["input_log_append"] == 1
+    cut = int(_native_codec.load() is not None)
+    assert engine.inputs[0].plugin.n_cut == cut
+    assert calls["feed"] >= 2 and calls["next"] >= 4
+    assert calls["reencode"] == 2 - cut and calls["input_log_append"] == 2
     assert calls["grep"] >= 1 and calls["flush_all"] >= 1
 
 
