@@ -43,7 +43,7 @@ from .plugin import (
     registry as default_registry,
 )
 from .scheduler import backoff_full_jitter
-from .spans import span, spanned
+from .spans import span, spanned, unwatch_gc, watch_gc
 
 log = logging.getLogger("flb.engine")
 
@@ -134,6 +134,7 @@ class Engine:
         self._backlog: List[Chunk] = []  # recovered chunks to re-dispatch
         self.loop: Optional[asyncio.AbstractEventLoop] = None
         self._thread: Optional[threading.Thread] = None
+        self._gc_hook = None  # core/spans.py::watch_gc, while running
         self._started = threading.Event()
         self._stopping = False
         self._stop_event = threading.Event()  # wakes threaded collectors
@@ -283,8 +284,14 @@ class Engine:
             "fluentbit", "device", "lane_seconds",
             "Seconds of a lane's launches since start, by phase: spawn "
             "(begin to worker running), run (launch closure on the "
-            "worker), blocked (finish waiting on the worker)",
+            "worker), blocked (finish waiting on the worker), wake "
+            "(the worker done to the waiting thread running again)",
             ("lane", "phase"))
+        self.m_device_slow_launches = m.counter(
+            "fluentbit", "device", "launches_over_1s_total",
+            "Device launches whose closure ran for more than a second "
+            "on the lane's worker (a stalled launch, or a first one "
+            "that compiled)", ("lane",))
         self.m_device_reattach = m.counter(
             "fluentbit", "device", "reattach_total",
             "Late/re-attach generations (the mesh lane swapped in "
@@ -656,6 +663,9 @@ class Engine:
         from ..ops import fault as _fault
 
         _fault.add_listener(self._on_device_event)
+        # a cyclic-GC pass under a profiler session is a span on the
+        # thread it ran on (core/spans.py); released in stop()
+        self._gc_hook = watch_gc()
         if self.service.profiler_port:
             threading.Thread(target=self._serve_profiler, daemon=True,
                              name="flb-profiler").start()
@@ -955,6 +965,9 @@ class Engine:
             # always release the module-global listeners: a teardown
             # error must not pin this engine (and its metrics) forever
             _fp.remove_listener(self._on_failpoint_trigger)
+            if self._gc_hook is not None:
+                unwatch_gc(self._gc_hook)
+                self._gc_hook = None
             try:
                 from ..ops import fault as _fault
 
@@ -991,6 +1004,8 @@ class Engine:
             self.m_device_timeouts.inc(1, (lane,))
         elif event == "failure":
             self.m_device_failures.inc(1, (lane,))
+        elif event == "slow_launch":
+            self.m_device_slow_launches.inc(1, (lane,))
         elif event == "device_lost":
             self.m_device_lost.inc(1, (lane,))
         elif event == "breaker":
@@ -1764,11 +1779,12 @@ class Engine:
         """``fluentbit_device_lane_seconds{lane,phase}``: where each
         device lane's launches spent their time, summed since start —
         spawn (begin → worker running), run (the launch closure on the
-        worker), blocked (finish waiting on the worker)."""
+        worker), blocked (finish waiting on the worker), wake (the
+        worker done → the waiting thread running again)."""
         from ..ops import fault as _fault
 
         for lane, st in _fault.snapshot().items():
-            for phase in ("spawn", "run", "blocked"):
+            for phase in ("spawn", "run", "blocked", "wake"):
                 self.m_device_lane_seconds.set(st[phase + "_s"],
                                                (lane, phase))
 

@@ -29,6 +29,13 @@ never from a stack.
 Granularity rule: a span or a counter update per socket read, message,
 chunk, segment or launch — never per record.
 
+A pass of the cyclic collector is a span too (``gc.collect`` with
+``gen`` and ``collected``), written by a ``gc.callbacks`` hook that
+the engine installs at its start (:func:`watch_gc`) and takes out at
+its stop: it lands on the thread the pass ran on, inside the frame it
+delayed, and inherits that frame's ids. While no session is active the
+hook returns after ``enabled()``.
+
 **Counters** are always on: :class:`ShardedTimings` is the
 ``raw_timings`` object a filter plugin hangs on itself (seconds and
 counts, summed across ingest threads on read). A key earns its place by
@@ -40,6 +47,7 @@ from __future__ import annotations
 
 import contextvars
 import functools
+import gc
 import sys
 import threading
 import time
@@ -137,6 +145,38 @@ def current_ids() -> Optional[dict]:
     """The ids bound here, to carry onto another thread (None while no
     session is active)."""
     return _ids.get() if enabled() else None
+
+
+# ------------------------------------------------------------ gc passes
+
+_gc_span = None    # the span of the pass that is running (one at a time)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_span
+    if phase == "start":
+        # (a second engine's hook finds the pass's span open already)
+        if _gc_span is None and enabled():
+            _gc_span = span("gc.collect", gen=info["generation"])
+            _gc_span.__enter__()
+    elif _gc_span is not None:
+        sp, _gc_span = _gc_span, None
+        sp.set_metadata(collected=info["collected"])
+        sp.__exit__(None, None, None)
+
+
+def watch_gc():
+    """Install the ``gc.callbacks`` hook for one running engine → the
+    handle :func:`unwatch_gc` takes. Each engine of a process has a
+    hook object of its own (so one's stop leaves the other's in), and a
+    pass is still one span: whichever runs first opens and closes it."""
+    hook = functools.partial(_on_gc)
+    gc.callbacks.append(hook)
+    return hook
+
+
+def unwatch_gc(hook) -> None:
+    gc.callbacks.remove(hook)
 
 
 class _Timed:

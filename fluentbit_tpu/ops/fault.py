@@ -74,6 +74,11 @@ from ..core.spans import bind, current_ids, span
 
 log = logging.getLogger("flb.device.fault")
 
+#: a launch whose closure runs longer is counted (``launches_over_1s``)
+#: and logged. 1.7x the slowest launch of any benchmark cell (0.58 s):
+#: part of the counter's definition, like a histogram's edge — no knob
+SLOW_LAUNCH_S = 1.0
+
 __all__ = [
     "DeviceLane", "DeviceLostError", "lane", "lanes", "reset",
     "snapshot", "health_block", "add_listener", "remove_listener",
@@ -145,9 +150,10 @@ _listeners: List[Callable[[str, str, object], None]] = []
 
 def add_listener(cb: Callable[[str, str, object], None]) -> None:
     """Register ``cb(lane_name, event, value)``. Events: ``fallback``,
-    ``timeout``, ``failure``, ``device_lost``, ``short_circuit``,
-    ``breaker`` (value = new state name), ``mesh_devices`` (value =
-    current device count), ``reattach`` (value = attach generation)."""
+    ``timeout``, ``failure``, ``slow_launch``, ``device_lost``,
+    ``short_circuit``, ``breaker`` (value = new state name),
+    ``mesh_devices`` (value = current device count), ``reattach``
+    (value = attach generation)."""
     with _listener_lock:
         if cb not in _listeners:
             _listeners.append(cb)
@@ -176,7 +182,7 @@ class _Flight:
     """One in-flight watched launch (the lane's begin/finish handle)."""
 
     __slots__ = ("launch", "fallback", "denied", "deadline", "done",
-                 "box", "t_begin", "ids")
+                 "box", "t_begin", "t_done", "ids")
 
     def __init__(self, launch, fallback, denied: bool, deadline: float,
                  t_begin: float = 0.0):
@@ -187,6 +193,7 @@ class _Flight:
         self.done = threading.Event()
         self.box: dict = {}
         self.t_begin = t_begin  # begin() entered: spawn_s counts from it
+        self.t_done = 0.0       # stamped before done.set(): wake_s
         # the caller's span ids (chunk, seg) while a profiler session
         # runs: the worker's spans carry them across the thread hop
         self.ids: dict = current_ids() or {}
@@ -230,8 +237,15 @@ class DeviceLane:
             # seconds, summed over launches: begin() entered → the
             # worker running (thread create, start, wait to be
             # scheduled); the launch closure on the worker; finish()
-            # blocked on the worker
+            # blocked on the worker; and of that, done.set() → the
+            # waiting thread running again (so blocked_s is what was
+            # left of run_s, plus wake_s)
             "spawn_s": 0.0, "run_s": 0.0, "blocked_s": 0.0,
+            "wake_s": 0.0,
+            # launches whose closure ran past SLOW_LAUNCH_S, each
+            # logged: one stalled launch among thousands is invisible
+            # in the sums above
+            "launches_over_1s": 0,
         }
         self._lost = 0           # devices shrunk out of the mesh
         self._ok_since_shrink = 0  # healthy launches on the shrunk mesh
@@ -341,10 +355,20 @@ class DeviceLane:
             flight.box["error"] = e
         finally:
             t_done = time.perf_counter()
+            slow = t_done - t_run > SLOW_LAUNCH_S
             with self._lock:
                 self._stats["spawn_s"] += t_run - flight.t_begin
                 self._stats["run_s"] += t_done - t_run
+                self._stats["launches_over_1s"] += slow
+            flight.t_done = time.perf_counter()
             flight.done.set()
+            if slow:
+                notify(self.name, "slow_launch", 1)
+                log.warning(
+                    "device lane %s: a launch ran for %.3fs (spawn "
+                    "%.4fs) — over %.0fs; ids %s", self.name,
+                    t_done - t_run, t_run - flight.t_begin,
+                    SLOW_LAUNCH_S, flight.ids or "none (no session)")
 
     def begin(self, launch, fallback,
               deadline: Optional[float] = None) -> _Flight:
@@ -381,8 +405,12 @@ class DeviceLane:
         t_wait = time.perf_counter()
         with bind(lane=self.name, **flight.ids), span("lane.wait"):
             done = flight.done.wait(flight.deadline)
+        t_woke = time.perf_counter()
         with self._lock:
-            self._stats["blocked_s"] += time.perf_counter() - t_wait
+            self._stats["blocked_s"] += t_woke - t_wait
+            if done:
+                self._stats["wake_s"] += \
+                    t_woke - max(flight.t_done, t_wait)
         if not done:
             # wedged launch: abandon the worker (daemon thread; its
             # eventual result lands in a box nobody reads) and serve

@@ -69,6 +69,7 @@ try:
 except Exception:  # pragma: no cover
     HAVE_JAX = False
 
+from ..core.spans import span
 from ..regex.dfa import ACC, DFA, EOL
 
 # table budget for k-byte super-stepping (bytes); C^k columns * S rows * 4
@@ -536,17 +537,25 @@ class GrepProgram:
         fields; they cross to the device ONCE, whatever the number of
         rules or per-k children that read them. → ``mask[R, B]`` bool,
         or with ``first_match`` the ``[B]`` i32 first-match vector."""
-        planes, lengths = jnp.asarray(planes), jnp.asarray(lengths)
+        # the copy-in and the enqueue apart: two spans inside the
+        # caller's grep.dispatch, once a launch whatever the children
+        with span("grep.put"):
+            planes, lengths = jnp.asarray(planes), jnp.asarray(lengths)
+        n = len(self._children) if self._children is not None else 1
+        with span("grep.call", children=n):
+            mask = self._enqueue(planes, lengths)
+            return first_match_of(mask) if first_match else mask
+
+    def _enqueue(self, planes, lengths):
+        """The jitted calls over planes that are on the device."""
         if self._children is not None:
             # per-k child programs: every child launches (async) before
             # the merge touches any result, so the k-groups overlap the
             # same way double-buffered segments do
-            mask = self._merge_rule_axis(
-                [c.dispatch(planes, lengths) for c in self._children])
-        else:
-            self._ensure_materialized()
-            mask = self._jit(planes, lengths)
-        return first_match_of(mask) if first_match else mask
+            return self._merge_rule_axis(
+                [c._enqueue(planes, lengths) for c in self._children])
+        self._ensure_materialized()
+        return self._jit(planes, lengths)
 
     def match(self, planes: np.ndarray, lengths: np.ndarray,
               first_match: bool = False) -> np.ndarray:
@@ -772,32 +781,39 @@ class GrepProgram:
                 # variant): normalize to the unpadded batch
                 parts = [p[:, :B] for p in parts]
                 Bp = B
-            mask = self._merge_rule_axis(parts)
-            counts = (self._merge_rule_axis(count_parts)
-                      if with_counts else None)
-            if first_match:
-                mask = first_match_of(mask)
+            # each child has written its own grep.put and grep.call;
+            # the merge is the parent's part of the enqueue
+            with span("grep.call", children=len(self._children)):
+                mask = self._merge_rule_axis(parts)
+                counts = (self._merge_rule_axis(count_parts)
+                          if with_counts else None)
+                if first_match:
+                    mask = first_match_of(mask)
             return mask, counts, B, Bp
 
         h = self._mesh_handle(mesh, donate, with_counts)
         B = batch.shape[1]
-        if h.variant == "batch":
-            Bp = pad_to_devices(B, h.n_devices)
-            batch, lengths = _pad_rows(batch, lengths, Bp)
-        else:
-            Bp = B
-            idx = list(self.plane_of)
-            batch, lengths = batch[idx], lengths[idx]
-        bd = jax.device_put(np.ascontiguousarray(batch, dtype=np.uint8),
-                            h.sh_b)
-        ld = jax.device_put(np.ascontiguousarray(lengths, dtype=np.int32),
-                            h.sh_l)
-        if with_counts:
-            mask_i32, counts = h.fn(h.tables, bd, ld)
-        else:
-            mask_i32, counts = h.fn(h.tables, bd, ld), None
-        if first_match:
-            mask_i32 = first_match_of(mask_i32)
+        # the host's share of the copy-in (padding, the rules variant's
+        # gather, the contiguous copy) and the two transfers
+        with span("grep.put"):
+            if h.variant == "batch":
+                Bp = pad_to_devices(B, h.n_devices)
+                batch, lengths = _pad_rows(batch, lengths, Bp)
+            else:
+                Bp = B
+                idx = list(self.plane_of)
+                batch, lengths = batch[idx], lengths[idx]
+            bd = jax.device_put(
+                np.ascontiguousarray(batch, dtype=np.uint8), h.sh_b)
+            ld = jax.device_put(
+                np.ascontiguousarray(lengths, dtype=np.int32), h.sh_l)
+        with span("grep.call", children=1):
+            if with_counts:
+                mask_i32, counts = h.fn(h.tables, bd, ld)
+            else:
+                mask_i32, counts = h.fn(h.tables, bd, ld), None
+            if first_match:
+                mask_i32 = first_match_of(mask_i32)
         return mask_i32, counts, B, Bp
 
     def match_mesh(self, mesh, batch: np.ndarray, lengths: np.ndarray,
@@ -1032,7 +1048,10 @@ class SpanProgram:
                 raise RuntimeError(
                     f"device backend not attached: {device.status()}")
             self._materialize()
-        return self._jit(jnp.asarray(planes), jnp.asarray(lengths))
+        with span("grep.put"):
+            planes, lengths = jnp.asarray(planes), jnp.asarray(lengths)
+        with span("grep.call", children=1):
+            return self._jit(planes, lengths)
 
     def spans(self, planes: np.ndarray, lengths: np.ndarray):
         """Run and force: numpy ``(ok[B], spans[B, G, 2])``."""

@@ -400,7 +400,10 @@ class ForwardInput(InputPlugin):
                     if frame is None:
                         continue
                     if last is not None:
-                        await last
+                        # the loop has a decoded frame and the worker
+                        # is not free: the wait is this frame's
+                        with span("forward.await", chunk=frame[5]):
+                            await last
                         last = None
                     if len(frame[1]) < _INLINE_BYTES:
                         # a few events: nothing worth decoding beside
@@ -562,8 +565,11 @@ class ForwardInput(InputPlugin):
                 # so a try made here is still the one absorb running
                 got = self._attempt(*args)
             else:
-                got = await loop.run_in_executor(
-                    self._absorber, self._attempt, *args)
+                # forward.absorb (the worker's thread) lies inside it
+                # in time: what is left over is the two thread hops
+                with span("forward.handover"):
+                    got = await loop.run_in_executor(
+                        self._absorber, self._attempt, *args)
             if got == _ABSORBED:
                 self.n_absorbed += 1
                 self._m_absorbed.inc(1, (ins.display_name,))

@@ -271,9 +271,10 @@ def test_sharded_timings_sum_and_timed():
 
 
 SPANS_OF_A_FRAME = ("forward.read", "forward.unpack", "forward.reencode",
-                    "forward.absorb", "engine.append", "filter.grep",
-                    "grep.stage", "lane.begin", "lane.launch",
-                    "grep.dispatch", "grep.force", "lane.wait",
+                    "forward.handover", "forward.absorb", "engine.append",
+                    "filter.grep", "grep.stage", "lane.begin",
+                    "lane.launch", "grep.dispatch", "grep.put",
+                    "grep.call", "grep.force", "lane.wait",
                     "grep.compact", "forward.ack")
 
 
@@ -293,7 +294,7 @@ def test_every_span_from_reencode_on_carries_the_chunk(runs):
     # (the flush timer's spans may fall in between: they are no frame's)
     after = [e for e in events if start <= e["start"] and e["end"] <= end
              and e["name"] in SPANS_OF_A_FRAME[2:]]
-    assert len(after) >= 12
+    assert len(after) >= 15
     assert {e["stats"].get("chunk") for e in after} == {CHUNK}
     # three threads carry it: the engine's loop decodes and acks, the
     # input's worker absorbs, a lane worker runs each launch
@@ -302,11 +303,13 @@ def test_every_span_from_reencode_on_carries_the_chunk(runs):
     assert absorb_line != loop_line
     on = {line: {e["name"] for e in after if e["line"] == line}
           for line in {e["line"] for e in after}}
-    assert on.pop(loop_line) == {"forward.reencode", "forward.ack"}
+    assert on.pop(loop_line) == {"forward.reencode", "forward.handover",
+                                 "forward.ack"}
     assert on.pop(absorb_line) == {
         "forward.absorb", "engine.append", "filter.grep", "grep.stage",
         "lane.begin", "lane.wait", "grep.compact"}
     assert set().union(*on.values()) == {"lane.launch", "grep.dispatch",
+                                         "grep.put", "grep.call",
                                          "grep.force"}
     # before the frame is whole nobody knows its chunk
     for e in by_name(events, "forward.read") \
@@ -541,9 +544,289 @@ def test_untraced_frame_records_nothing(runs):
 def test_output_bytes_equal_with_and_without_a_session(runs):
     assert runs["traced"] and runs["traced"] == runs["untraced"]
     assert runs["traced"] == runs["cold"]
+    # the traced frame went through the hand-over and the split dispatch
+    for name in ("forward.handover", "grep.put", "grep.call"):
+        assert by_name(runs["events"], name), name
     from fluentbit_tpu.codec.events import decode_events
 
     assert len(decode_events(runs["traced"])) == N_LINES - N_LINES // 4
+
+
+# ------------------------- the hand-overs and the halves of a dispatch
+
+
+@pytest.mark.mesh
+def test_handover_is_on_the_loops_thread_around_the_absorb(runs):
+    """``forward.handover`` − ``forward.absorb`` by ``chunk`` is the two
+    thread hops (``input.hop_ms_per_frame``)."""
+    events = runs["events"]
+    (over,) = by_name(events, "forward.handover")
+    (absorb,) = by_name(events, "forward.absorb")
+    assert over["line"] == by_name(events, "forward.read")[0]["line"]
+    assert absorb["line"] != over["line"] and inside(absorb, over)
+    assert over["stats"]["chunk"] == absorb["stats"]["chunk"] == CHUNK
+    (reencode,) = by_name(events, "forward.reencode")
+    (ack,) = by_name(events, "forward.ack")
+    assert reencode["end"] <= over["start"] and over["end"] <= ack["start"]
+
+
+@pytest.mark.mesh
+def test_await_span_marks_the_frame_that_waited_for_the_worker(
+        pipelined_events, runs):
+    """``forward.await`` is opened only when the loop holds a decoded
+    frame while an earlier one is still with the worker, and carries the
+    chunk of the frame that waits."""
+    events = pipelined_events
+    (wait,) = by_name(events, "forward.await")
+    assert wait["stats"]["chunk"] == "pipe-b"
+    assert wait["line"] == by_name(events, "forward.read")[0]["line"]
+    absorb = {e["stats"]["chunk"]: e
+              for e in by_name(events, "forward.absorb")}
+    over = {e["stats"]["chunk"]: e
+            for e in by_name(events, "forward.handover")}
+    assert set(over) == set(absorb) == {"pipe-a", "pipe-b", "pipe-c"}
+    # it waited for a's absorb to end, then its own hand-over began
+    assert wait["start"] <= absorb["pipe-a"]["end"] <= wait["end"]
+    assert wait["end"] <= over["pipe-b"]["start"]
+    for chunk in over:
+        assert inside(absorb[chunk], over[chunk]), chunk
+    # a frame sent alone waits for nobody
+    assert not by_name(runs["events"], "forward.await")
+
+
+@pytest.fixture(scope="module")
+def span_program_events(tmp_path_factory):
+    """One chunk through ``filter_parser``'s span program (the platform
+    gate forced open), once to compile and once under a profiler session
+    beneath a bound ``chunk``. → the session's events."""
+    jax = pytest.importorskip("jax")
+    from fluentbit_tpu.codec.events import encode_event
+    from fluentbit_tpu.core.chunk_batch import RawChunk
+    from fluentbit_tpu.core.engine import Engine
+    from fluentbit_tpu.parsers import create_parser
+    from fluentbit_tpu.ops import device
+
+    assert device.wait(120)
+    saved = device.platform
+    device.platform = lambda: "tpu"
+    try:
+        e = Engine()
+        e.parsers["apache2"] = create_parser(
+            "apache2", Format="regex", Regex=APACHE2)
+        f = e.filter("parser")
+        for k, v in {"key_name": "log", "parser": "apache2",
+                     "tpu_batch_records": "1"}.items():
+            f.set(k, v)
+        e.input("dummy")
+        for x in e.inputs + e.filters:
+            x.configure()
+            x.plugin.init(x, e)
+        plugin = e.filters[0].plugin
+        data = b"".join(encode_event({"log": OK_LINE}, float(i))
+                        for i in range(40))
+        if not plugin._span_serves():
+            pytest.skip("no span program")
+        plugin.process_batch(RawChunk(data, "t", 40))  # compiles
+        trace_dir = str(tmp_path_factory.mktemp("span_program"))
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        try:
+            with spans.bind(chunk="sp-0001"):
+                n, _out, _n_in = plugin.process_batch(
+                    RawChunk(data, "t", 40))
+        finally:
+            jax.profiler.stop_trace()
+        assert n == 40 and plugin.raw_timings["parsed"] == 80
+    finally:
+        device.platform = saved
+    return read_events(trace_dir)
+
+
+@pytest.mark.mesh
+@pytest.mark.parametrize("path", ["mesh", "one_chip", "span_program"])
+def test_put_and_call_lie_inside_the_dispatch(path, request):
+    """``grep.dispatch`` in two: the copy-in (``grep.put``) and the
+    jitted calls with the merge (``grep.call``, with ``children``), on
+    the lane worker's thread with the launch's ``lane``, ``chunk`` and
+    ``seg`` — on the sharded path (one put and one call a child), on
+    one chip (``rewrite_tag``'s program) and in the span program."""
+    fixture = {"mesh": "runs", "one_chip": "rewrite_events",
+               "span_program": "span_program_events"}[path]
+    events = request.getfixturevalue(fixture)
+    if isinstance(events, dict):
+        events = events["events"]
+    dispatches = by_name(events, "grep.dispatch")
+    assert dispatches
+    halves = by_name(events, "grep.put") + by_name(events, "grep.call")
+    for d in dispatches:
+        mine = [e for e in halves if inside(e, d) and e["line"] == d["line"]]
+        puts = [e for e in mine if e["name"] == "grep.put"]
+        calls = [e for e in mine if e["name"] == "grep.call"]
+        assert puts and calls, (path, d)
+        for e in mine:
+            for key in ("lane", "chunk", "seg"):
+                assert e["stats"][key] == d["stats"][key], (e, key)
+        assert d["stats"]["lane"] == "grep"
+        assert all(c["stats"]["children"] >= 1 for c in calls)
+        assert min(p["end"] for p in puts) <= min(c["start"] for c in calls)
+        if path != "mesh":
+            # one copy-in a launch, whatever the children
+            assert len(puts) == 1 and len(calls) == 1
+            assert puts[0]["end"] <= calls[0]["start"]
+    # none outside a dispatch
+    assert all(any(inside(e, d) for d in dispatches) for e in halves)
+    # the force is not part of either (of its own launch: the next
+    # segment's dispatch may run beside it on another thread)
+    for f in by_name(events, "grep.force"):
+        assert not any(inside(f, e) for e in halves
+                       if e["line"] == f["line"]
+                       and e["stats"]["seg"] == f["stats"]["seg"])
+
+
+@pytest.mark.mesh
+def test_children_share_one_put_on_one_chip_and_have_their_own_on_the_mesh(
+        mesh_env, tmp_path):
+    """The grep cell's program has two per-stride children. On one chip
+    the planes cross once (one ``grep.put``, one ``grep.call`` with
+    ``children=2``); on the mesh each child places the host planes
+    itself (a donated buffer cannot be shared), so a launch holds a put
+    and a call a child, and the parent's merge is a call of its own."""
+    import numpy as np
+
+    from fluentbit_tpu.ops.batch import assemble
+    from fluentbit_tpu.ops.grep import GrepProgram
+    from fluentbit_tpu.ops.mesh import build_mesh
+    from fluentbit_tpu.regex.dfa import compile_dfa
+
+    jax = mesh_env
+    prog = GrepProgram([compile_dfa(r"curl/8\.5"), compile_dfa(APACHE2)],
+                       512, plane_of=(0, 0))
+    assert len(prog._children) == 2
+    b = assemble([OK_LINE.encode()] * 16, max_len=512)
+    planes, lengths = b.batch[None], b.lengths[None]
+    mesh = build_mesh(8)
+    want = np.asarray(prog.dispatch(planes, lengths))           # compiles
+    out, _c, B, _bp = prog.dispatch_mesh(mesh, planes, lengths,
+                                         with_counts=False)
+    assert (np.asarray(out).astype(bool)[:, :B] == want).all()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with spans.span("grep.dispatch", path="one"):
+            np.asarray(prog.dispatch(planes, lengths))
+        with spans.span("grep.dispatch", path="mesh"):
+            np.asarray(prog.dispatch_mesh(mesh, planes, lengths,
+                                          with_counts=False)[0])
+    finally:
+        jax.profiler.stop_trace()
+    events = read_events(str(tmp_path))
+    one, sharded = sorted(by_name(events, "grep.dispatch"),
+                          key=lambda e: e["start"])
+    assert (one["stats"]["path"], sharded["stats"]["path"]) \
+        == ("one", "mesh")
+
+    def halves(outer, name):
+        return sorted((e for e in by_name(events, name)
+                       if inside(e, outer)), key=lambda e: e["start"])
+
+    assert len(halves(one, "grep.put")) == 1
+    assert [e["stats"]["children"] for e in halves(one, "grep.call")] == [2]
+    assert len(halves(sharded, "grep.put")) == 2
+    assert [e["stats"]["children"]
+            for e in halves(sharded, "grep.call")] == [1, 1, 2]
+    # put, call, put, call, merge: none inside another
+    seq = sorted(halves(sharded, "grep.put") + halves(sharded, "grep.call"),
+                 key=lambda e: e["start"])
+    assert [e["name"] for e in seq] == ["grep.put", "grep.call"] * 2 \
+        + ["grep.call"]
+    for x, y in zip(seq, seq[1:]):
+        assert x["end"] <= y["start"]
+
+
+# ----------------------------------------------------- a GC pass
+
+
+def gc_hooks() -> list:
+    import gc
+
+    return [cb for cb in gc.callbacks
+            if getattr(cb, "func", None) is spans._on_gc]
+
+
+def test_gc_pass_is_a_span_under_a_session_and_nothing_without(tmp_path):
+    import gc
+
+    jax = pytest.importorskip("jax")
+    hook = spans.watch_gc()
+    was_on = gc.isenabled()
+    gc.disable()  # no pass but the ones forced here
+    try:
+        gc.collect(2)  # no session: the hook returns after enabled()
+        assert spans._gc_span is None
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            junk = [[] for _ in range(10)]
+            for x in junk:
+                x.append(x)  # ten cycles for the pass to collect
+            del junk, x
+            with spans.bind(chunk="gc-frame"):
+                gc.collect(2)
+            assert spans._gc_span is None
+        finally:
+            jax.profiler.stop_trace()
+        gc.collect(2)
+    finally:
+        if was_on:
+            gc.enable()
+        spans.unwatch_gc(hook)
+    (one,) = by_name(read_events(str(tmp_path)), "gc.collect")
+    assert one["stats"]["gen"] == 2 and one["stats"]["collected"] >= 10
+    # it lands inside the frame it delayed
+    assert one["stats"]["chunk"] == "gc-frame"
+    assert hook not in __import__("gc").callbacks
+
+
+def test_gc_hook_is_installed_while_an_engine_runs(tmp_path):
+    """One hook a running engine, gone after its stop; with two engines
+    a pass is still one span."""
+    import gc
+
+    jax = pytest.importorskip("jax")
+    before = len(gc_hooks())
+    a = flb.create(flush="50ms", grace="1")
+    a.input("dummy", tag="t", dummy='{"log":"x"}', rate="1")
+    a.output("null", match="*")
+    b = flb.create(flush="50ms", grace="1")
+    b.input("dummy", tag="t", dummy='{"log":"x"}', rate="1")
+    b.output("null", match="*")
+    a.start()
+    try:
+        assert len(gc_hooks()) == before + 1
+        b.start()
+        try:
+            assert len(gc_hooks()) == before + 2
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                gc.collect(2)
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            b.stop()
+        assert len(gc_hooks()) == before + 1
+    finally:
+        a.stop()
+    assert len(gc_hooks()) == before
+    forced = [e for e in by_name(read_events(str(tmp_path)), "gc.collect")
+              if e["stats"]["gen"] == 2]
+    assert len(forced) >= 1
+    # never two spans for one pass: no two of them overlap
+    forced.sort(key=lambda e: e["start"])
+    for x, y in zip(forced, forced[1:]):
+        assert x["end"] <= y["start"]
 
 
 # ----------------------------------- a rewrite_tag frame, traced
@@ -686,6 +969,61 @@ def test_lane_stats_seconds_grow_over_a_launch():
     assert st2["blocked_s"] - st["blocked_s"] < 0.019
 
 
+def test_lane_wake_seconds_are_the_end_of_the_blocked_ones():
+    """``wake_s``: the worker's ``done.set()`` → the waiting thread
+    running again; ``blocked_s`` is what was left of ``run_s`` plus
+    that."""
+    lane = fault.DeviceLane("spans-wake")
+    assert lane.stats()["wake_s"] == 0.0
+
+    def launch():
+        time.sleep(0.02)
+        return 7
+
+    assert lane.run(launch, lambda: -1) == 7
+    st = lane.stats()
+    assert 0.0 < st["wake_s"] <= st["blocked_s"]
+    assert st["wake_s"] < 0.015 < st["blocked_s"]
+    # a launch that was over before the caller came to wait: nobody was
+    # woken, the wait returns at once and counts from its own start
+    fl = lane.begin(launch, lambda: -1)
+    time.sleep(0.05)
+    assert lane.finish(fl) == 7
+    st2 = lane.stats()
+    assert 0.0 <= st2["wake_s"] - st["wake_s"] < 0.005
+    assert st2["wake_s"] <= st2["blocked_s"]
+    # a launch that timed out woke nobody
+    lane3 = fault.DeviceLane("spans-wake-late", deadline=0.01)
+    assert lane3.run(lambda: time.sleep(0.2), lambda: -1) == -1
+    assert lane3.stats()["wake_s"] == 0.0 < lane3.stats()["blocked_s"]
+
+
+def test_a_launch_over_a_second_is_counted_and_logged_once(caplog):
+    """The B11 catcher: sums hide one stalled launch among thousands,
+    ``launches_over_1s`` and its log line do not."""
+    from fluentbit_tpu import failpoints
+
+    lane = fault.DeviceLane("spans-slow")
+    seen = []
+    fault.add_listener(listener := lambda *a: seen.append(a))
+    failpoints.enable("device.launch_hang", "1*hang(1200)")
+    try:
+        with caplog.at_level("WARNING", logger="flb.device.fault"):
+            assert lane.run(lambda: 7, lambda: -1) == 7   # hangs 1.2 s
+            assert lane.run(lambda: 8, lambda: -1) == 8   # does not
+    finally:
+        failpoints.reset()
+        fault.remove_listener(listener)
+    st = lane.stats()
+    assert st["launches"] == st["ok"] == 2
+    assert st["launches_over_1s"] == 1 and st["run_s"] >= 1.2
+    lines = [r.getMessage() for r in caplog.records
+             if "a launch ran for" in r.getMessage()]
+    assert len(lines) == 1 and "spans-slow" in lines[0]
+    assert [a for a in seen if a[1] == "slow_launch"] \
+        == [("spans-slow", "slow_launch", 1)]
+
+
 def test_lane_seconds_on_health_and_prometheus():
     ctx = flb.create(flush="50ms", grace="1")
     ctx.input("dummy", tag="t", dummy='{"log":"x"}', rate="1")
@@ -700,13 +1038,29 @@ def test_lane_seconds_on_health_and_prometheus():
                 ctx.engine.metrics.to_prometheus()))
     finally:
         ctx.stop()
-    for phase in ("spawn", "run", "blocked"):
+    for phase in ("spawn", "run", "blocked", "wake"):
         assert re.search(
             r'fluentbit_device_lane_seconds\{lane="grep",phase="%s"\} '
             r'[0-9.e+-]+' % phase, text), phase
     lanes = fault.health_block()["lanes"]["grep"]
     assert lanes["run_s"] >= 0.005 and "spawn_s" in lanes \
         and "blocked_s" in lanes
+    assert 0.0 < lanes["wake_s"] <= lanes["blocked_s"]
+    assert lanes["launches_over_1s"] == 0
+
+
+def test_slow_launches_are_a_prometheus_counter_by_lane():
+    ctx = flb.create(flush="50ms", grace="1")
+    ctx.input("dummy", tag="t", dummy='{"log":"x"}', rate="1")
+    ctx.output("null", match="*")
+    ctx.start()
+    try:
+        fault.notify("grep", "slow_launch", 1)
+        text = ctx.engine.metrics.to_prometheus()
+    finally:
+        ctx.stop()
+    assert re.search(
+        r'fluentbit_device_launches_over_1s_total\{lane="grep"\} 1\b', text)
 
 
 @pytest.mark.mesh

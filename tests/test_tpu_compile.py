@@ -214,9 +214,13 @@ def test_span_program_compiles_for_one_chip(one_chip, length):
     """parser-apache2's program: the two dependent scans over the one
     staged plane, the ``[L, B]`` reverse states between them, ``(ok[B],
     spans[B, 9, 2] i16)`` out."""
-    from fluentbit_tpu.ops.grep import span_program_for
+    from fluentbit_tpu.ops.grep import SpanProgram
+    from fluentbit_tpu.regex import parse
+    from fluentbit_tpu.regex.spans import compile_spans
 
-    prog = span_program_for(APACHE2, 512)
+    # its own program: the cached one drops its host tables (``_np``)
+    # once another test of this process has dispatched it
+    prog = SpanProgram(compile_spans(parse(APACHE2)), 512)
     compiled = jax.jit(prog._spans_impl).lower(
         {k: sds(v.shape, v.dtype, one_chip)
          for k, v in prog._np.items()},
