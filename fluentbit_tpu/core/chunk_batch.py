@@ -10,12 +10,16 @@ recognise their own re-entered records without touching the
 engine-global ``_ingest_src`` (which the parallel raw path must not
 share across inputs).
 
-``double_buffered`` is the depth-2 dispatch pipeline of the engine's
-batched filter path: host msgpack extraction (staging) of segment N+1
-overlaps the in-flight device kernel of segment N, and each result is
-forced one segment behind its dispatch. On a real accelerator the
-overlap hides the host staging walk behind the DFA scan; on the CPU
-backend it degrades to the sequential order at no extra cost.
+``double_buffered`` is the depth-2 dispatch pipeline INSIDE one chunk
+of the engine's batched filter path: host msgpack extraction (staging)
+of segment N+1 overlaps the in-flight device kernel of segment N, and
+each result is forced one segment behind its dispatch. On a real
+accelerator the overlap hides the host staging walk behind the DFA
+scan; on the CPU backend it degrades to the sequential order at no
+extra cost. A chunk of one segment has nothing to overlap inside it:
+ACROSS chunks the same overlap comes from ``RawChunk.begun`` — the
+input begins the next chunk's launch while this one is collected and
+committed (``plugins/net_forward.py``, "Launch beside commit").
 
 The hook contract (machine-checked by fbtpu-lint's batch-exactness
 pack, ``fluentbit_tpu.analysis.batch`` — see ANALYSIS.md):
@@ -49,17 +53,28 @@ class RawChunk:
     n       : record count, or None until a stage discovers it
     src     : the appending InputInstance (emitter re-entry guard)
     engine  : the owning engine (metrics, emitter access)
+    begun   : the FIRST filter's launch over ``data``, begun before the
+              append had its turn (``FilterPlugin.begin_batch`` through
+              ``Engine.input_log_prelaunch``), or None; the filter
+              takes it with ``take_begun()``
     """
 
-    __slots__ = ("data", "tag", "n", "src", "engine")
+    __slots__ = ("data", "tag", "n", "src", "engine", "begun")
 
     def __init__(self, data, tag: str, n: Optional[int] = None,
-                 src=None, engine=None):
+                 src=None, engine=None, begun=None):
         self.data = data
         self.tag = tag
         self.n = n
         self.src = src
         self.engine = engine
+        self.begun = begun
+
+    def take_begun(self):
+        """The launch begun on this chunk ahead of its turn, once: it
+        is the taker's to finish or drop from here on."""
+        begun, self.begun = self.begun, None
+        return begun
 
     def replace(self, data, n: Optional[int]) -> None:
         """Swap in a filter's output (count may be unknown again)."""

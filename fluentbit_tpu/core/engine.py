@@ -1024,11 +1024,81 @@ class Engine:
     # ingest path (reference: flb_input_log_append → input_chunk_append_raw)
     # ------------------------------------------------------------------
 
+    def _raw_chain(self, ins: InputInstance, tag: str) -> tuple:
+        """``(matching, cond_routing, raw_ok)`` for an append of ``ins``
+        under ``tag``: the filters its route reaches, whether an output
+        routes it per record, and whether the chain can run straight
+        off the chunk's bytes. Reads only, under no lock."""
+        matching = [f for f in self.filters if f.route.matches(tag)]
+        # flux-backed tasks don't need decoded events — their hidden
+        # flux filter (in `matching`) absorbs on the raw chain, so they
+        # must not force the decode path (that is the whole point)
+        sp_active = (
+            self.sp is not None
+            and self.sp.tasks
+            and ins is not self.sp.emitter_instance
+            and any(t.matches(tag) and t.flux is None
+                    for t in self.sp.tasks)
+        )
+        cond_routing = any(
+            o.route_condition is not None and o.route.matches(tag)
+            for o in self.outputs
+        )
+        raw_ok = (
+            not ins.processors
+            and not sp_active
+            and not cond_routing  # per-record splits need decoded events
+            and self._trace_ctx(ins) is None
+            and all(f.plugin.can_process_batch() for f in matching)
+        )
+        return matching, cond_routing, raw_ok
+
+    def input_log_prelaunch(self, ins: InputInstance, tag: Optional[str]):
+        """The way to begin the device launch of an append that has not
+        had its turn yet: where ``input_log_append(ins, tag, data, n)``
+        would run the raw chain and its FIRST filter offers the begin
+        half of its launch (``FilterPlugin.begin_batch``), →
+        ``begin(data, n_records)``, which stages ``data``, begins that
+        launch and returns its handle (or None: the filter declined),
+        to be given back as ``input_log_append(..., begun=handle)``;
+        else None, and there is nothing to begin.
+
+        Pure of side effects, the lookup and the call: no lock of the
+        engine's, no pool, no quota bucket, no metric, no emitter —
+        staging reads the bytes, the launch is compute, and the verdict
+        is committed by the append alone, so ``begin`` may run on any
+        thread. The caller owns the handle: whatever the append comes
+        to, it calls ``handle.drop()`` afterwards (a no-op once the
+        filter has finished it)."""
+        tag = tag or ins.tag or ins.plugin.name
+        matching, _cond, raw_ok = self._raw_chain(ins, tag)
+        if not raw_ok or not matching:
+            return None
+        first = matching[0]
+        begin_batch = getattr(first.plugin, "begin_batch", None)
+        if begin_batch is None:
+            return None
+
+        def begin(data: bytes, n_records: Optional[int]):
+            try:
+                return begin_batch(data, n_records)
+            except Exception:
+                log.exception("filter %s could not begin its launch",
+                              first.display_name)
+                return None
+
+        return begin
+
     @spanned("engine.append")
     def input_log_append(self, ins: InputInstance, tag: Optional[str],
-                         data: bytes, n_records: Optional[int] = None) -> int:
+                         data: bytes, n_records: Optional[int] = None,
+                         begun=None) -> int:
         """Append encoded log events; runs processors then the filter chain
         synchronously (src/flb_input_chunk.c:3078), then writes the chunk.
+        ``begun``: this append's launch from :meth:`input_log_prelaunch`;
+        it rides on the raw chain's chunk to the first filter and no
+        further (an append that never gets there leaves it to the
+        caller).
 
         Returns number of records written (post-filter), or -1 when the
         append was rejected by backpressure (reference
@@ -1111,28 +1181,7 @@ class Engine:
         # parallel (the global lock stops serializing independent
         # tags; reference threaded inputs + per-input chunk
         # maps, src/flb_input_thread.c:225).
-        matching = [f for f in self.filters if f.route.matches(tag)]
-        # flux-backed tasks don't need decoded events — their hidden
-        # flux filter (in `matching`) absorbs on the raw chain, so they
-        # must not force the decode path (that is the whole point)
-        sp_active = (
-            self.sp is not None
-            and self.sp.tasks
-            and ins is not self.sp.emitter_instance
-            and any(t.matches(tag) and t.flux is None
-                    for t in self.sp.tasks)
-        )
-        cond_routing = any(
-            o.route_condition is not None and o.route.matches(tag)
-            for o in self.outputs
-        )
-        raw_ok = (
-            not ins.processors
-            and not sp_active
-            and not cond_routing  # per-record splits need decoded events
-            and self._trace_ctx(ins) is None
-            and all(f.plugin.can_process_batch() for f in matching)
-        )
+        matching, cond_routing, raw_ok = self._raw_chain(ins, tag)
         if raw_ok:
             # stateful chains are pinned to the global lock even when
             # every filter is thread_safe_raw: a stateful hook's side
@@ -1151,11 +1200,11 @@ class Engine:
             if parallel:
                 with ins.ingest_lock:
                     got = self._ingest_raw(ins, tag, data, matching,
-                                           n_records)
+                                           n_records, begun)
             else:
                 with self._ingest_lock:
                     got = self._ingest_raw(ins, tag, data, matching,
-                                           n_records)
+                                           n_records, begun)
             if isinstance(got, _RawTail):
                 # a mid-chain decline after committed side effects:
                 # finish per-record OUTSIDE the raw-path lock scope —
@@ -1354,7 +1403,7 @@ class Engine:
         return n_records
 
     def _ingest_raw(self, ins, tag: str, data: bytes, matching,
-                    n_records: Optional[int]):
+                    n_records: Optional[int], begun=None):
         """Append without Python decode. Returns the appended record
         count, None (caller falls back to the decode path: native
         unavailable / a pure-prefix filter decline), or a ``_RawTail``
@@ -1380,7 +1429,7 @@ class Engine:
         n = n_records
         # one chunk view travels the whole chain: the record count one
         # filter discovers is reused as the next one's n_hint
-        chunk = RawChunk(data, tag, n, src=ins, engine=self)
+        chunk = RawChunk(data, tag, n, src=ins, engine=self, begun=begun)
         deltas = []  # metric updates deferred until the chain commits:
         committed = False  # True once a stateful hook's effects are out
         for fi, f in enumerate(matching):
@@ -1407,6 +1456,7 @@ class Engine:
             except Exception:
                 log.exception("filter %s raw path failed", f.display_name)
                 got = None
+            chunk.begun = None  # the first filter's, or nobody's
             if got is None:
                 self.m_filter_batch_decline.inc(1, (f.display_name,))
                 if not committed:

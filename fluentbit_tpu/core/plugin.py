@@ -116,6 +116,19 @@ class FilterPlugin(Plugin):
     the batch pass discovered the input record count), or None to
     decline — the engine then falls back to the bit-exact per-record
     path, so exotic option combinations cost nothing but the fallback.
+
+    A filter whose ``process_batch`` waits for a device launch may also
+    define ``begin_batch(data, n_records)`` (this class does not, and
+    the engine looks for the name): the side-effect-free first
+    half of that call — stage ``data`` and begin the launch, commit
+    nothing, touch no counter — returning a handle with ``drop()`` (or
+    None). An input that holds an append's bytes before the append's
+    turn begins the FIRST matching filter's launch through
+    ``Engine.input_log_prelaunch``; ``process_batch`` then finds the
+    handle on its chunk (``RawChunk.take_begun()``) and must check that
+    it was made for these bytes and this configuration before it
+    finishes it in place of staging again. A handle is finished or
+    dropped, never left.
     """
 
     #: True when the raw/batched path is pure (immutable config, no

@@ -182,11 +182,16 @@ class _Flight:
     """One in-flight watched launch (the lane's begin/finish handle)."""
 
     __slots__ = ("launch", "fallback", "denied", "deadline", "done",
-                 "box", "t_begin", "t_done", "ids")
+                 "box", "t_begin", "t_done", "ids", "id")
 
     def __init__(self, launch, fallback, denied: bool, deadline: float,
-                 t_begin: float = 0.0):
+                 t_begin: float = 0.0, id: int = 0):
         self.launch = launch
+        # the lane's count of launches when this one was begun: the
+        # ``launch`` of lane.begin/launch/wait and of every span the
+        # closure opens (grep.dispatch/put/call/force), so a reader can
+        # tell two open flights' spans apart
+        self.id = id
         self.fallback = fallback
         self.denied = denied
         self.deadline = deadline
@@ -203,11 +208,19 @@ class DeviceLane:
     """Fault domain for one device plane (see module docstring).
 
     ``begin``/``finish`` split the guarded launch so callers can keep
-    their staging/kernel overlap (``double_buffered``): ``begin``
-    starts the watched worker and returns immediately; ``finish``
-    waits (bounded), applies breaker/fallback policy, and returns the
-    final host-side result. ``run`` = begin + finish for unpipelined
-    callers (the flux sketch updates).
+    work beside the kernel: ``begin`` starts the watched worker and
+    returns immediately; ``finish`` waits (bounded), applies
+    breaker/fallback policy, and returns the final host-side result.
+    Two callers do: ``double_buffered`` stages a chunk's next segment
+    between the two, and ``in_forward`` begins a connection's next
+    frame while the frame before it is collected, committed and acked
+    (``staged_match(begin=True)``), so the two halves of one launch may
+    run on different threads and several flights of a lane may be open
+    at once (``begun_in_flight`` counts the launches begun so; each
+    flight's spans carry its ``launch`` number). Every flight that was
+    begun is finished, used or not: ``ok`` + ``failures`` + ``timeouts``
+    + ``short_circuits`` add up to ``launches``. ``run`` = begin +
+    finish for unpipelined callers (the flux sketch updates).
     """
 
     def __init__(self, name: str, failures: Optional[int] = None,
@@ -246,7 +259,12 @@ class DeviceLane:
             # logged: one stalled launch among thousands is invisible
             # in the sums above
             "launches_over_1s": 0,
+            # launches begun while another flight of the lane had not
+            # been finished: a chunk's next segment (double_buffered),
+            # or in_forward's next frame begun ahead of its absorb
+            "begun_in_flight": 0,
         }
+        self._open = 0           # flights begun and not finished
         self._lost = 0           # devices shrunk out of the mesh
         self._ok_since_shrink = 0  # healthy launches on the shrunk mesh
         self._mesh = None        # cached mesh for (_mesh_key)
@@ -346,7 +364,8 @@ class DeviceLane:
                     _fp.fire("mesh.device_lost")
                 except _fp.FailpointError as e:
                     raise DeviceLostError(str(e)) from None
-            with bind(lane=self.name, **flight.ids), span("lane.launch"):
+            with bind(lane=self.name, launch=flight.id, **flight.ids), \
+                    span("lane.launch"):
                 out = flight.launch()
             if _fp.ACTIVE:
                 _fp.fire("device.dispatch")
@@ -360,15 +379,17 @@ class DeviceLane:
                 self._stats["spawn_s"] += t_run - flight.t_begin
                 self._stats["run_s"] += t_done - t_run
                 self._stats["launches_over_1s"] += slow
-            flight.t_done = time.perf_counter()
-            flight.done.set()
             if slow:
+                # (before the waiter is woken: whoever saw the launch
+                # end finds it counted, heard and logged)
                 notify(self.name, "slow_launch", 1)
                 log.warning(
                     "device lane %s: a launch ran for %.3fs (spawn "
                     "%.4fs) — over %.0fs; ids %s", self.name,
                     t_done - t_run, t_run - flight.t_begin,
                     SLOW_LAUNCH_S, flight.ids or "none (no session)")
+            flight.t_done = time.perf_counter()
+            flight.done.set()
 
     def begin(self, launch, fallback,
               deadline: Optional[float] = None) -> _Flight:
@@ -381,15 +402,19 @@ class DeviceLane:
         t_begin = time.perf_counter()
         with self._lock:
             self._stats["launches"] += 1
+            launch_id = self._stats["launches"]
+            self._stats["begun_in_flight"] += self._open > 0
+            self._open += 1
         if not self.breaker.allow():
             with self._lock:
                 self._stats["short_circuits"] += 1
             notify(self.name, "short_circuit", 1)
-            return _Flight(launch, fallback, denied=True, deadline=0.0)
+            return _Flight(launch, fallback, denied=True, deadline=0.0,
+                           id=launch_id)
         fl = _Flight(launch, fallback, denied=False,
                      deadline=self.deadline if deadline is None
-                     else deadline, t_begin=t_begin)
-        with span("lane.begin", lane=self.name):
+                     else deadline, t_begin=t_begin, id=launch_id)
+        with span("lane.begin", lane=self.name, launch=launch_id):
             threading.Thread(target=self._watched, args=(fl,),
                              daemon=True,
                              name=f"flb-lane-{self.name}").start()
@@ -399,11 +424,20 @@ class DeviceLane:
         """Resolve one guarded launch to its final host result: the
         device verdict on success, the bit-exact fallback on denial,
         failure, or deadline expiry. Nothing is committed until this
-        returns — a soft-killed worker's late result is discarded."""
+        returns — a soft-killed worker's late result is discarded. The
+        deadline runs from here, not from ``begin``."""
+        try:
+            return self._resolve(flight)
+        finally:
+            with self._lock:
+                self._open -= 1
+
+    def _resolve(self, flight: _Flight):
         if flight.denied:
             return self._fall_back(flight, record=False)
         t_wait = time.perf_counter()
-        with bind(lane=self.name, **flight.ids), span("lane.wait"):
+        with bind(lane=self.name, launch=flight.id, **flight.ids), \
+                span("lane.wait"):
             done = flight.done.wait(flight.deadline)
         t_woke = time.perf_counter()
         with self._lock:

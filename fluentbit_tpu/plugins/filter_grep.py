@@ -27,7 +27,9 @@ the fused walk ends in one verdict → compaction tail.
 
 from __future__ import annotations
 
+import functools
 import logging
+import threading
 from typing import List, NamedTuple, Optional, Tuple
 
 import numpy as np
@@ -279,9 +281,69 @@ def decoded_match(rules, program, lane, events: list,
     return mask
 
 
+class Begun:
+    """A chunk's staged launch, begun and not yet finished —
+    ``staged_match(..., begin=True)``'s handle. It holds the flight,
+    the staged planes and lengths (the fallback's and the overflow
+    rows' input) and what it was made for: the very ``data`` object,
+    the ``rules`` list, the ``program`` and the kind of verdict. Handed
+    back as ``staged_match(..., begun=handle)`` it is finished there if
+    it still answers that call (:meth:`finish_for`), dropped if not.
+    It is ended once, by whoever comes first; whoever holds a handle it
+    cannot use drops it — the lane counts every flight finished."""
+
+    __slots__ = ("data", "made_for", "_lane", "_flight", "_finish",
+                 "_spent", "_lock")
+
+    def __init__(self, data, made_for: tuple, lane, flight, finish):
+        self.data = data
+        self.made_for = made_for
+        self._lane = lane
+        self._flight = flight
+        self._finish = finish
+        self._spent = False
+        self._lock = threading.Lock()  # a leaf: held over one swap
+
+    def _spend(self):
+        """``(flight, finish)`` for the one caller that gets to end the
+        launch, None for every other; the staged planes and the chunk's
+        bytes go with them, not with a handle that is kept about."""
+        with self._lock:
+            if self._spent:
+                return None
+            self._spent = True
+        mine = self._flight, self._finish
+        self.data = self._flight = self._finish = None
+        return mine
+
+    def finish_for(self, tm, data, *made_for):
+        """The verdict (``staged_match``'s result) if this launch
+        answers ``staged_match`` over ``data`` for ``made_for`` — the
+        same objects, not equal ones: a reloaded filter's rules are
+        another list — and has not been ended; else it is dropped and
+        ``NotImplemented`` says so."""
+        if data is self.data \
+                and all(a is b for a, b in zip(made_for, self.made_for)):
+            mine = self._spend()
+            if mine is not None:
+                return mine[1](tm)
+        self.drop()
+        return NotImplemented
+
+    def drop(self) -> None:
+        """End a launch nobody will use: the flight is finished (its
+        verdict, the device's or the fallback's, is thrown away) and
+        nothing is counted in any ``raw_timings``. A no-op on a handle
+        that was finished or dropped before."""
+        mine = self._spend()
+        if mine is not None:
+            self._lane.finish(mine[0])
+
+
 def staged_match(rules, program, lane, tm, data, n_records, *,
                  max_len: int, min_records: int, mesh=None,
-                 first_match: bool = False, spans: bool = False):
+                 first_match: bool = False, spans: bool = False,
+                 begin: bool = False, begun: Optional[Begun] = None):
     """Device matching straight off chunk bytes, with double-buffered
     staging — the one staged launch ``filter_grep``,
     ``filter_rewrite_tag`` and ``filter_parser`` share.
@@ -310,8 +372,26 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
     for the caller, which builds records and decides them on the host
     per row. One device only (no ``mesh``).
 
-    ``tm`` (the plugin's ``raw_timings``) takes ``extract_s``,
-    ``kernel_s`` (wall less extraction), ``h2d_bytes`` (the planes and
+    **In two halves.** With ``begin`` a chunk of ONE segment is staged
+    and its launch begun (``lane.begin``), and a :class:`Begun` comes
+    back instead of a verdict — no ``tm`` is touched, nothing is
+    committed, so any thread may do it ahead of the chunk's turn
+    (``in_forward`` does, for a connection's next frame, while the
+    frame before it is collected and acked: ``begin_batch``). With
+    ``begun`` — that handle, given back by the chunk's own absorb — the
+    call goes straight to ``lane.finish`` and the rest below, if the
+    handle still serves (same ``data`` object, ``rules``, ``program``
+    and kind of verdict); else the handle is dropped and the call
+    starts over. Without either, both halves run back to back here, and
+    a chunk of several segments keeps ``double_buffered`` inside it
+    (``begin`` declines such a chunk: None).
+
+    ``tm`` (the plugin's ``raw_timings``) is written by the finishing
+    half alone, once a chunk whichever half ran where. It takes
+    ``extract_s``, ``kernel_s`` (the finishing call's wall less the
+    extraction done inside it: what the chunk waited for its launch
+    **in series** — the whole launch where both halves ran here, what
+    was left of it where it was begun ahead), ``h2d_bytes`` (the planes and
     their lengths), ``d2h_bytes`` (what the forced launch copies out:
     ``mask[R, Bp]`` a byte each — four on the mesh, whose verdict is
     i32 — the ``[Bp]`` i32 first-match vector, or with ``spans`` the
@@ -330,6 +410,11 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
     from ..core.chunk_batch import double_buffered, segment_bounds
     from ..ops.batch import bucket_size
 
+    made_for = (rules, program, first_match, spans)
+    if begun is not None:
+        got = begun.finish_for(tm, data, *made_for)
+        if got is not NotImplemented:
+            return got
     if not isinstance(data, bytes):
         data = bytes(data)
     # default matches a bucket_size rung exactly: a full segment
@@ -354,7 +439,10 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
     K = len(keys)
     bounds = segment_bounds(n, seg)
     multi = len(bounds) > 1
+    if begin and multi:
+        return None  # the segments overlap each other: double_buffered
     extract_s = [0.0]
+    sent = [0, 0]  # h2d bytes, gathered elements: counted at the finish
     lens_parts: list = []
     cnts: list = []
     plane_parts: list = []  # spans: the staged rows the offsets cut
@@ -448,9 +536,8 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
         cnts.append(cnt)
         if spans:
             plane_parts.append(batch[0, :cnt])
-        tm.add("h2d_bytes", batch.nbytes + lengths.nbytes)
-        tm.add("scan_elements",
-               program.scan_elements(batch.shape[1], batch.shape[2]))
+        sent[0] += batch.nbytes + lengths.nbytes
+        sent[1] += program.scan_elements(batch.shape[1], batch.shape[2])
         if mesh is not None:
             # sharded launch through the device fault domain: the
             # launch closure re-stages (fresh device_put + donation)
@@ -494,50 +581,69 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
         # one of the two (fbtpu-armor)
         return lane.finish(pending)
 
-    t_all = _time.perf_counter()
+    def finish(tm, pending=None):
+        """From the wait for the launch on: the verdicts collected,
+        the counters, the overflow rows. ``pending``: the one segment's
+        flight where ``begin`` dispatched it already."""
+        t_all = _time.perf_counter()
+        staged_before = extract_s[0]
+        try:
+            verdicts = [collect(pending)] if pending is not None else \
+                double_buffered(stages(), dispatch, collect)
+        except _RawDecline:
+            return None
+        wall = _time.perf_counter() - t_all
+        tm.add("extract_s", extract_s[0])
+        tm.add("kernel_s",
+               max(wall - (extract_s[0] - staged_before), 0.0))
+        tm.add("h2d_bytes", sent[0])
+        tm.add("scan_elements", sent[1])
+        offsets = offs_box[0]
+        lengths = np.concatenate(lens_parts, axis=1)
+        overflow_rows = np.unique(np.nonzero(lengths == -2)[1])
+        tm.add("device_records", n)
+        tm.add("overflow_rows", len(overflow_rows))
+        copied = (part for v in verdicts for part in v) if spans else verdicts
+        tm.add("d2h_bytes", sum(part.nbytes for part in copied))
+        if spans:
+            # the caller builds the records, and decides on the host those
+            # that staged no value
+            return SpanVerdict(
+                np.concatenate([ok[:c] for (ok, _), c in zip(verdicts, cnts)]),
+                np.concatenate([sp[:c] for (_, sp), c in zip(verdicts, cnts)]),
+                lengths[0], plane_parts), offsets, n
+        verdict = np.concatenate(
+            [np.asarray(v)[..., :c] for v, c in zip(verdicts, cnts)], axis=-1)
+        if not first_match:
+            verdict = verdict.astype(bool, copy=False)  # the mesh's is i32
+        # overflow rows (-2): decode just those records on the CPU
+        if len(overflow_rows):
+            from ..codec.events import decode_events
+
+            with span("grep.overflow", rows=len(overflow_rows)):
+                for b_idx in overflow_rows:
+                    rec = bytes(data[offsets[b_idx]: offsets[b_idx + 1]])
+                    body = decode_events(rec)[0].body
+                    if first_match:
+                        # the per-record rule walk, break on first match
+                        verdict[b_idx] = next(
+                            (r for r, rule in enumerate(rules)
+                             if rule_matches(rule, body)), -1)
+                        continue
+                    for r, rule in enumerate(rules):
+                        if lengths[plane_of[r], b_idx] == -2:
+                            verdict[r, b_idx] = rule_matches(rule, body)
+        return verdict, offsets, n
+
+    if not begin:
+        return finish(tm)
     try:
-        verdicts = double_buffered(stages(), dispatch, collect)
+        (item,) = stages()
+        flight = dispatch(item)
     except _RawDecline:
         return None
-    wall = _time.perf_counter() - t_all
-    tm.add("extract_s", extract_s[0])
-    tm.add("kernel_s", max(wall - extract_s[0], 0.0))
-    offsets = offs_box[0]
-    lengths = np.concatenate(lens_parts, axis=1)
-    overflow_rows = np.unique(np.nonzero(lengths == -2)[1])
-    tm.add("device_records", n)
-    tm.add("overflow_rows", len(overflow_rows))
-    copied = (part for v in verdicts for part in v) if spans else verdicts
-    tm.add("d2h_bytes", sum(part.nbytes for part in copied))
-    if spans:
-        # the caller builds the records, and decides on the host those
-        # that staged no value
-        return SpanVerdict(
-            np.concatenate([ok[:c] for (ok, _), c in zip(verdicts, cnts)]),
-            np.concatenate([sp[:c] for (_, sp), c in zip(verdicts, cnts)]),
-            lengths[0], plane_parts), offsets, n
-    verdict = np.concatenate(
-        [np.asarray(v)[..., :c] for v, c in zip(verdicts, cnts)], axis=-1)
-    if not first_match:
-        verdict = verdict.astype(bool, copy=False)  # the mesh's is i32
-    # overflow rows (-2): decode just those records on the CPU
-    if len(overflow_rows):
-        from ..codec.events import decode_events
-
-        with span("grep.overflow", rows=len(overflow_rows)):
-            for b_idx in overflow_rows:
-                rec = bytes(data[offsets[b_idx]: offsets[b_idx + 1]])
-                body = decode_events(rec)[0].body
-                if first_match:
-                    # the per-record rule walk, break on first match
-                    verdict[b_idx] = next(
-                        (r for r, rule in enumerate(rules)
-                         if rule_matches(rule, body)), -1)
-                    continue
-                for r, rule in enumerate(rules):
-                    if lengths[plane_of[r], b_idx] == -2:
-                        verdict[r, b_idx] = rule_matches(rule, body)
-    return verdict, offsets, n
+    return Begun(data, made_for, lane, flight,
+                 functools.partial(finish, pending=flight))
 
 
 @registry.register
@@ -588,8 +694,6 @@ class GrepFilter(FilterPlugin):
         # blocks plugin init or ingest — records run the bit-exact CPU
         # path until the device is up (an earlier round's CLI was
         # un-killable for minutes inside eager jax init).
-        import threading
-
         self._program = None
         self._native_tables = None
         self._native_filter = None
@@ -834,21 +938,12 @@ class GrepFilter(FilterPlugin):
         import time as _time
 
         from .. import native
-        from ..ops import device
 
         if not native.available():
             return None
         data, n_records = chunk.as_bytes(), chunk.n
         tm = self.raw_timings
-        # mesh first: when the partitioned pjit plane is engaged
-        # (FBTPU_MESH — real multi-chip attach, or forced for the
-        # simulated lane) it IS the device path, native serves staging
-        mesh = self._grep_mesh()
-        # platform check FIRST: on a CPU-backend host try_ready() would
-        # needlessly materialize the jax program that will never run
-        use_native = self._native_tables is not None and mesh is None and (
-            device.platform() == "cpu" or not self._program.try_ready()
-        )
+        mesh, use_native = self._raw_engine()
         if use_native and self._native_filter is not None:
             # fused path: extraction + prepass DFA + verdict + compaction
             # in ONE native pass; all-kept chunks return the input
@@ -876,7 +971,8 @@ class GrepFilter(FilterPlugin):
             tm.add("kernel_s", _time.perf_counter() - t0)
         else:
             # (declines under tpu_batch_records: decode is cheaper there)
-            got = self._jax_match_raw(data, n_records, mesh=mesh)
+            got = self._jax_match_raw(data, n_records, mesh=mesh,
+                                      begun=chunk.take_begun())
             if got is None:
                 return None
         mask, offsets, n = got
@@ -898,6 +994,36 @@ class GrepFilter(FilterPlugin):
         ]
         return (n_keep, b"".join(parts))
 
+    def _raw_engine(self) -> tuple:
+        """``(mesh, use_native)``: what serves a raw chunk now."""
+        from ..ops import device
+
+        # mesh first: when the partitioned pjit plane is engaged
+        # (FBTPU_MESH — real multi-chip attach, or forced for the
+        # simulated lane) it IS the device path, native serves staging
+        mesh = self._grep_mesh()
+        # platform check FIRST: on a CPU-backend host try_ready() would
+        # needlessly materialize the jax program that will never run
+        use_native = self._native_tables is not None and mesh is None and (
+            device.platform() == "cpu" or not self._program.try_ready()
+        )
+        return mesh, use_native
+
+    def begin_batch(self, data: bytes, n_records):
+        """``process_batch``'s launch, begun ahead of the chunk's turn
+        (``FilterPlugin.begin_batch``): where the staged device launch
+        would serve ``data``, stage it and begin it → the
+        :class:`Begun` that ``process_batch`` finds on its chunk; None
+        where another engine serves or the launch declines."""
+        from .. import native
+
+        if not native.available():
+            return None
+        mesh, use_native = self._raw_engine()
+        if use_native:
+            return None
+        return self._jax_match_raw(data, n_records, mesh=mesh, begin=True)
+
     def _local_tables(self, attr: str):
         """This thread's private copy of a packed native table set (the
         multi-input scaling fix: concurrent ingest workers each walk
@@ -909,10 +1035,11 @@ class GrepFilter(FilterPlugin):
             setattr(tls, attr, t)
         return t
 
-    def _jax_match_raw(self, data, n_records, mesh=None):
+    def _jax_match_raw(self, data, n_records, mesh=None, **halves):
         """Device-kernel raw matching (``staged_match``): returns
-        (mask[R, n], offsets[n+1], n) or None to decline."""
+        (mask[R, n], offsets[n+1], n) or None to decline; with
+        ``begin`` the begun launch, with ``begun`` its finish."""
         return staged_match(
             self.rules, self._program, self._lane(), self.raw_timings,
             data, n_records, max_len=self.tpu_max_record_len,
-            min_records=self.tpu_batch_records, mesh=mesh)
+            min_records=self.tpu_batch_records, mesh=mesh, **halves)

@@ -174,7 +174,7 @@ class ParserFilter(FilterPlugin):
         self._batch_key = None
         self._batch_tables = None
         self._spans = None
-        self._span_rule = None
+        self._span_rules = None  # the one rule, as staged_match's list
         self._span_decline: Optional[str] = None
         self.raw_timings = ShardedTimings(_TIMING_KEYS)
         p0 = self.parsers[0]
@@ -224,7 +224,7 @@ class ParserFilter(FilterPlugin):
             p0.regex._py()  # the host twin has to compile too
             self._spans = span_program_for(p0.regex.pattern,
                                            self.tpu_max_record_len)
-            self._span_rule = _KeyRule(self.key_name, p0.regex)
+            self._span_rules = [_KeyRule(self.key_name, p0.regex)]
             # the body {key: <the value>} and nothing else, from its
             # map header on
             self._single_pair = b"\x81" + packb(self.key_name)
@@ -365,19 +365,32 @@ class ParserFilter(FilterPlugin):
 
         return fault.lane("grep")
 
+    def _staged(self, data, n_records, **halves):
+        """The span verdict from the shared staged launch, or one of
+        its two halves."""
+        from .filter_grep import staged_match
+
+        return staged_match(
+            self._span_rules, self._spans, self._lane(),
+            self.raw_timings, data, n_records,
+            max_len=self.tpu_max_record_len,
+            min_records=self.tpu_batch_records, spans=True, **halves)
+
+    def begin_batch(self, data: bytes, n_records):
+        """The regex mode's span launch begun ahead of the chunk's
+        turn (``FilterPlugin.begin_batch``)."""
+        if not self._span_serves():  # (no span program but in regex mode)
+            return None
+        return self._staged(data, n_records, begin=True)
+
     def _process_batch_regex(self, chunk):
         """Spans from the device where it serves, records built from
         them; else the host path below. → ``(n, bytes, n)``."""
-        from .filter_grep import staged_match
-
         data = chunk.as_bytes()
         if self._span_serves():
             with span("parser.stage"):
-                got = staged_match(
-                    [self._span_rule], self._spans, self._lane(),
-                    self.raw_timings, data, chunk.n,
-                    max_len=self.tpu_max_record_len,
-                    min_records=self.tpu_batch_records, spans=True)
+                got = self._staged(data, chunk.n,
+                                   begun=chunk.take_begun())
             if got is not None:
                 return self._build_from_spans(data, *got)
         return self._process_batch_host(chunk, data)

@@ -240,6 +240,22 @@ class RewriteTagFilter(FilterPlugin):
     def can_process_batch(self) -> bool:
         return self._batch_tables is not None
 
+    def _staged(self, data, n_records, **halves):
+        """The first-match verdict from the shared staged launch, or
+        one of its two halves."""
+        return staged_match(
+            self.rules, self._program, self._lane(), self.raw_timings,
+            data, n_records, max_len=self.tpu_max_record_len,
+            min_records=self.tpu_batch_records, first_match=True,
+            **halves)
+
+    def begin_batch(self, data: bytes, n_records):
+        """``process_batch``'s launch begun ahead of the chunk's turn
+        (``FilterPlugin.begin_batch``): no emit, no counter."""
+        if not self._device_serves():
+            return None
+        return self._staged(data, n_records, begin=True)
+
     def process_batch(self, chunk):
         from .. import native
         from ..codec.events import decode_events, fast_count_records
@@ -260,10 +276,8 @@ class RewriteTagFilter(FilterPlugin):
         with span("rewrite.stage"):
             got = None
             if self._device_serves():
-                got = staged_match(
-                    self.rules, self._program, self._lane(), tm, data,
-                    chunk.n, max_len=self.tpu_max_record_len,
-                    min_records=self.tpu_batch_records, first_match=True)
+                got = self._staged(data, chunk.n,
+                                   begun=chunk.take_begun())
             if got is None:
                 # the host twin: while the device attaches, on a CPU
                 # backend, and for what the staged launch declines

@@ -60,14 +60,35 @@ protocol:
   an odd entry) and a process without the extension take the object
   path, whole: ``unpack_from`` → :meth:`ForwardInput._decode` →
   :func:`_entries_to_events`, the reference the cut is held to byte
-  for byte (``tests/test_forward_cut.py``). While the worker waits for
-  the device the loop decodes the connection's next frame; it holds that
-  one frame, reading nothing more, until the frame before is acked.
-  Acks are written on the loop, in arrival order, after the absorb
-  returned and the ledger holds the chunk. A chunk too small to be
-  worth the hand-over (``_INLINE_BYTES``: Message mode) is absorbed by
-  the loop itself while the worker is idle — still one absorb at a
-  time, since only the loop hands the worker its work.
+  for byte (``tests/test_forward_cut.py``). While the worker is on a
+  frame the loop decodes the connection's next ones; it holds
+  ``1 + _DECODE_AHEAD`` decoded frames behind the one with the worker,
+  reading nothing more, until the oldest is acked (TCP flow control
+  holds the peer beyond that). Acks are written on the loop, in
+  arrival order, after the absorb returned and the ledger holds the
+  chunk. A chunk too small to be worth the hand-over
+  (``_INLINE_BYTES``: Message mode) is absorbed by the loop itself
+  while the worker is idle — still one absorb at a time, since only
+  the loop hands the worker its work.
+
+- **Launch beside commit.** A frame decoded while the frame before it
+  is still with the worker has its device launch begun at once
+  (``engine.input_log_prelaunch``: the first matching filter stages
+  the frame's bytes and begins its guarded launch, on the instance's
+  second thread) — copy-in, the jitted calls and the kernel then run
+  while the worker collects, compacts, appends, records and acks the
+  frame before. The frame's own absorb, when its turn comes on the one
+  ordered worker, is all it ever was, except that the filter finds its
+  launch begun and goes straight to the wait. At most two launches a
+  connection are open: the frame with the worker and the one behind
+  it. Nothing is committed ahead of a frame's turn: the dedup check,
+  the tenant's metering, backpressure, the append, the ledger's record
+  and the ack stay on the worker, one try at a time, in arrival order.
+  A launch begun for a frame that is then not absorbed (a duplicate, a
+  shed, a stop, a filter reloaded in between) is finished and dropped,
+  never left open; a deferred frame keeps it for its retry. A frame
+  that finds the worker idle is handed over as ever: a lone frame
+  waits for no partner.
 
 - **Armored client.** Per-upstream circuit breakers (core/guard.py,
   visible in /api/v1/health), UpstreamHA failover mid-stream, full-
@@ -80,6 +101,7 @@ protocol:
 from __future__ import annotations
 
 import asyncio
+import collections
 import concurrent.futures
 import gzip
 import hashlib
@@ -119,6 +141,16 @@ _NO_MSG = object()  # the Unpacker holds no complete message
 #: way, more than the decode of a next chunk that size could hide behind
 #: its absorb — Message mode sends one record a message
 _INLINE_BYTES = 4096
+
+#: decoded frames a connection holds beyond the one whose launch is
+#: begun behind the worker's. With 0 the decode of frame N+2 starts when
+#: frame N is acked: where it takes as long as N+1's commit the worker
+#: is free before N+2 is decoded, and N+2 is launched in series after
+#: all (grep `.catchup`: three frames in ten, the worker idle 21 %);
+#: with 1 it is decoded by then and every frame's launch is begun ahead
+#: (PERF.md section 6, PR 37). A constant: nothing in reach of an
+#: operator keys on it
+_DECODE_AHEAD = 1
 
 # what one try at absorbing a chunk came to (ForwardInput._attempt)
 _ABSORBED, _DUPLICATE, _SHED, _DEFER = range(4)
@@ -268,6 +300,7 @@ class ForwardInput(InputPlugin):
         self.n_absorbed = 0
         self.n_overlapped = 0
         self.n_cut = 0
+        self.n_prelaunched = 0
         self.n_deferred_acks = 0
         self.n_withheld_acks = 0
         self.n_shed_remote = 0
@@ -278,6 +311,14 @@ class ForwardInput(InputPlugin):
         self._absorber = concurrent.futures.ThreadPoolExecutor(
             max_workers=1,
             thread_name_prefix=f"flb-fw-{instance.display_name}")
+        # the launch stage: one more thread, which stages a frame and
+        # begins its launch while the worker is on the frame before
+        # (made at the first such frame); `_begun` holds the launches
+        # begun and not yet finished or dropped
+        self._stager = concurrent.futures.ThreadPoolExecutor(
+            max_workers=1,
+            thread_name_prefix=f"flb-fw-pre-{instance.display_name}")
+        self._begun: set = set()
         # tries handed to the worker (counted on the loop) and tries it
         # is through with (counted on the worker): unequal while an
         # absorb is running or waiting its turn
@@ -301,6 +342,10 @@ class ForwardInput(InputPlugin):
             "Forward chunks whose V2 events were cut from the wire "
             "bytes in C (the others were decoded into objects and "
             "packed again)", ("instance",))
+        self._m_prelaunched = m.counter(
+            "fluentbit", "forward", "prelaunched_chunks_total",
+            "Forward chunks whose device launch was begun while an "
+            "earlier chunk was still being absorbed", ("instance",))
         self._m_deferred = m.counter(
             "fluentbit", "forward", "deferred_acks_total",
             "Acks delayed by quota/buffer backpressure", ("instance",))
@@ -355,12 +400,27 @@ class ForwardInput(InputPlugin):
             await writer.drain()
         u = Unpacker()
         authed = not self.shared_key
-        # the task absorbing and acking the last decoded frame: the
-        # next one is decoded beside it and then waits for it, so
-        # absorbs and acks of a connection keep their arrival order
-        # and nothing more is read off the socket meanwhile (TCP flow
-        # control holds the peer back)
-        last = None
+        loop = asyncio.get_running_loop()
+        # the connection's frames that are decoded and not yet through,
+        # oldest first: each one's task waits for the task before it,
+        # then absorbs and acks its frame, so absorbs and acks keep
+        # their arrival order; the handler reads nothing more while
+        # 1 + _DECODE_AHEAD of them stand behind the worker's (TCP
+        # flow control holds the peer back)
+        chain: collections.deque = collections.deque()
+
+        async def settle(keep: int, cid) -> None:
+            """Wait until at most ``keep`` frames are not through."""
+            while len(chain) > keep:
+                if not chain[0].done():
+                    # the loop holds a decoded frame (``cid``) and may
+                    # not go on: the wait is that frame's
+                    with span("forward.await", chunk=cid):
+                        await asyncio.wait((chain[0],))
+                chain.popleft().result()
+            while chain and chain[0].done():
+                chain.popleft().result()
+
         try:
             while True:
                 with span("forward.read") as sp:
@@ -399,38 +459,99 @@ class ForwardInput(InputPlugin):
                     frame = self._decode(msg)
                     if frame is None:
                         continue
-                    if last is not None:
-                        # the loop has a decoded frame and the worker
-                        # is not free: the wait is this frame's
-                        with span("forward.await", chunk=frame[5]):
-                            await last
-                        last = None
+                    cid = frame[5]
                     if len(frame[1]) < _INLINE_BYTES:
                         # a few events: nothing worth decoding beside
-                        # this absorb, and no task to carry it
+                        # this absorb, no launch worth beginning ahead
+                        # of it, and no task to carry it
+                        await settle(0, cid)
                         await self._finish(frame, writer, engine)
                         continue
+                    # two launches a connection at most, the worker's
+                    # frame's and this one's: whatever else stands
+                    # before this frame has to be through
+                    await settle(1, cid)
+                    pre = None
+                    begin = engine.input_log_prelaunch(
+                        self.instance, frame[0]) \
+                        if chain and not self._stopped else None
+                    if begin is not None:
+                        # the worker is on the frame before: this one's
+                        # staging and launch begin now, beside it
+                        pre = loop.run_in_executor(
+                            self._stager, self._prelaunch, begin, frame)
                     # started here, beneath no bind: the task binds the
                     # frame's chunk itself
-                    last = asyncio.ensure_future(
-                        self._finish(frame, writer, engine))
-                    # two passes of the loop before the next frame is
-                    # decoded. The first runs the task as far as the
-                    # hand-over (or a read that brought several frames
-                    # would decode them one absorb late); the second
-                    # goes through select(), which releases the GIL:
-                    # the worker starts on the frame now, not behind
-                    # the C unpack call that comes next (9 % of the
-                    # grep cell's lines/s, PERF.md section 6, PR 30)
+                    chain.append(asyncio.ensure_future(self._in_turn(
+                        chain[-1] if chain else None, frame, pre,
+                        writer, engine)))
+                    await settle(1 + _DECODE_AHEAD, cid)
+                    # then two passes of the loop before the next frame
+                    # is decoded. The first runs a task whose turn has
+                    # come as far as the hand-over (it is woken by the
+                    # same completion as this handler, after it — or
+                    # the worker would sit idle through the next
+                    # decode, and a read that brought several frames
+                    # would decode them one absorb late); the second goes
+                    # through select(), which releases the GIL: the
+                    # worker starts on the frame now, not behind the C
+                    # unpack call that comes next (9 % of the grep
+                    # cell's lines/s, PERF.md section 6, PR 30)
                     await asyncio.sleep(0)
                     await asyncio.sleep(0)
         finally:
-            if last is not None:
+            if chain:
                 # a frame handed over is absorbed or not, never half:
                 # the peer may be gone, the absorb runs to its end (the
                 # ack then fails on the closed socket, which ends the
-                # connection like any lost link)
-                await last
+                # connection like any lost link), and the frames behind
+                # it end with it, their launches dropped
+                await asyncio.wait(chain)
+                for task in chain:
+                    task.result()
+
+    def _prelaunch(self, begin, frame):
+        """The launch stage (its own thread): stage a decoded frame and
+        begin the first filter's launch over it (``begin``, from
+        ``engine.input_log_prelaunch``) → the handle its absorb takes
+        along, or None where the filter declined."""
+        _tag, buf, n, _option, _ack_ref, cid = frame
+        with bind(chunk=cid):
+            begun = begin(buf, n)
+            if begun is not None:
+                self._begun.add(begun)
+                self.n_prelaunched += 1
+                self._m_prelaunched.inc(1, (self.instance.display_name,))
+                # written empty, as forward.cut and forward.overlap
+                # are, for exactly the frames whose launch was begun
+                # ahead: grep.stage and lane.begin beneath this bind
+                # carry the time
+                with span("forward.prelaunch"):
+                    pass
+        return begun
+
+    async def _in_turn(self, prev, frame, pre, writer, engine) -> None:
+        """A decoded frame's task: after the frame before it is
+        through, its absorb and its ack (:meth:`_finish`); whatever
+        ends the frame, the launch begun for it is not left open."""
+        begun = None
+        try:
+            try:
+                if prev is not None:
+                    # (not `await prev`: a cancel of this task would
+                    # cancel the frame before it with it)
+                    await asyncio.wait((prev,))
+                    prev.result()
+            finally:
+                # (begun beside `prev`: long done, as a rule)
+                if pre is not None:
+                    begun = await pre
+            await self._finish(frame, writer, engine, begun)
+        finally:
+            if begun is not None:
+                # a no-op once a filter has finished it
+                begun.drop()
+                self._begun.discard(begun)
 
     def _check_ping(self, msg, nonce: bytes, writer) -> bool:
         if msg[0] != "PING" or len(msg) < 6:
@@ -497,14 +618,15 @@ class ForwardInput(InputPlugin):
                     pass
         return tag, buf, n, option, ack_ref, cid
 
-    async def _finish(self, frame, writer, engine) -> None:
-        """A decoded frame from its absorb to its ack."""
+    async def _finish(self, frame, writer, engine, begun=None) -> None:
+        """A decoded frame from its absorb to its ack; ``begun``: its
+        launch, where one was begun ahead (the caller's to drop)."""
         tag, buf, n, option, ack_ref, cid = frame
         with bind(chunk=cid):
             if n:
                 tenant, priority = _wire_stamp(option)
                 absorbed = await self._absorb(
-                    engine, tag, buf, n, tenant, priority, cid)
+                    engine, tag, buf, n, tenant, priority, cid, begun)
                 if not absorbed:
                     # backpressure: NO ack — the peer's ack timeout
                     # turns into RETRY+backoff, pausing the stream;
@@ -537,7 +659,8 @@ class ForwardInput(InputPlugin):
         return str(ack_ref)
 
     async def _absorb(self, engine, tag: str, buf: bytes, n: int,
-                      tenant, priority, cid: Optional[str]) -> bool:
+                      tenant, priority, cid: Optional[str],
+                      begun=None) -> bool:
         """Absorb one decoded chunk into engine state effectively once.
 
         Each try (:meth:`_attempt`) runs on the worker, a small chunk's
@@ -557,7 +680,7 @@ class ForwardInput(InputPlugin):
                 # in now would miss the last flush — no ack, the peer
                 # resends it
                 raise ConnectionError("in_forward has stopped")
-            args = (engine, tag, buf, n, tenant, priority, cid)
+            args = (engine, tag, buf, n, tenant, priority, cid, begun)
             idle = self._tries_handed == self._tries_done
             self._tries_handed += 1
             if idle and len(buf) < _INLINE_BYTES:
@@ -600,55 +723,71 @@ class ForwardInput(InputPlugin):
             await asyncio.sleep(min(max(hint, 0.02), 0.25, remaining))
 
     def _attempt(self, engine, tag: str, buf: bytes, n: int,
-                 tenant, priority, cid: Optional[str]) -> int:
-        """One try at absorbing a chunk: the dedup check, the
-        wire-stamped tenant's metering (fleet-wide quota), the append
-        with the stamp on the aggregator-side chunk, and the ledger
-        record. Tries run one at a time — on the worker, or on the loop
-        while the worker is idle (:meth:`_absorb`) — so nothing comes
-        between the check and the record, nor between setting the stamp
-        and clearing it. The frame's ``chunk`` is bound anew for the
-        spans of the thread that runs the try."""
-        ins = self.instance
-        led = self._ledger if cid is not None else None
+                 tenant, priority, cid: Optional[str],
+                 begun=None) -> int:
+        """One try at absorbing a chunk (:meth:`_try`), on the thread
+        that runs it: the frame's ``chunk`` is bound anew for its
+        spans, and a launch begun ahead that the try did not use is
+        dropped here, where waiting for the device holds nobody else up
+        — unless the try was deferred: the retry takes it along."""
         try:
             with bind(chunk=cid), span("forward.absorb"):
-                if led is not None and led.seen(cid):
-                    return _DUPLICATE
-                stamped = tenant is not None
-                if stamped:
-                    verdict = engine.qos.admit_stamped(tenant, len(buf))
-                    if verdict == 2:  # SHED: consumed by the tenant's
-                        # declared overflow policy — acked, not absorbed
-                        # (the edge must not resend policy-shed bytes)
-                        return _SHED
-                    if verdict == 1:
-                        return _DEFER
-                    # the stamp joins the pool key and lands on the
-                    # chunk; qos_exempt skips the LOCAL tenant's bucket
-                    # (the remote tenant was already metered above) —
-                    # the instance's tries run one at a time, so no
-                    # other dispatch interleaves while these are set
-                    ins.pool.stamp = (tenant, priority)
-                    ins.qos_exempt = True
-                try:
-                    # looked up on the engine at every call: whoever
-                    # wraps the method after init() is still called
-                    rc = engine.input_log_append(ins, tag, buf, n)
-                finally:
-                    if stamped:
-                        ins.pool.stamp = None
-                        ins.qos_exempt = False
-                if rc < 0:
-                    return _DEFER
-                if led is not None:
-                    # durable BEFORE the ack leaves: an ack whose
-                    # absorb-record died with the process would turn
-                    # the peer's next resend into a double-absorb
-                    led.record(cid)
-                return _ABSORBED
+                got = self._try(engine, tag, buf, n, tenant, priority,
+                                cid, begun)
+                if begun is not None and got != _DEFER:
+                    begun.drop()
+                return got
         finally:
             self._tries_done += 1
+
+    def _try(self, engine, tag: str, buf: bytes, n: int,
+             tenant, priority, cid: Optional[str], begun) -> int:
+        """The dedup check, the wire-stamped tenant's metering
+        (fleet-wide quota), the append with the stamp on the
+        aggregator-side chunk, and the ledger record. Tries run one at
+        a time — on the worker, or on the loop while the worker is idle
+        (:meth:`_absorb`) — so nothing comes between the check and the
+        record, nor between setting the stamp and clearing it."""
+        ins = self.instance
+        led = self._ledger if cid is not None else None
+        if led is not None and led.seen(cid):
+            return _DUPLICATE
+        stamped = tenant is not None
+        if stamped:
+            verdict = engine.qos.admit_stamped(tenant, len(buf))
+            if verdict == 2:  # SHED: consumed by the tenant's
+                # declared overflow policy — acked, not absorbed
+                # (the edge must not resend policy-shed bytes)
+                return _SHED
+            if verdict == 1:
+                return _DEFER
+            # the stamp joins the pool key and lands on the
+            # chunk; qos_exempt skips the LOCAL tenant's bucket
+            # (the remote tenant was already metered above) —
+            # the instance's tries run one at a time, so no
+            # other dispatch interleaves while these are set
+            ins.pool.stamp = (tenant, priority)
+            ins.qos_exempt = True
+        try:
+            # looked up on the engine at every call: whoever
+            # wraps the method after init() is still called
+            # (the launch rides along only where there is one:
+            # a wrapper of the four-argument call still fits)
+            rc = engine.input_log_append(ins, tag, buf, n) \
+                if begun is None else engine.input_log_append(
+                    ins, tag, buf, n, begun=begun)
+        finally:
+            if stamped:
+                ins.pool.stamp = None
+                ins.qos_exempt = False
+        if rc < 0:
+            return _DEFER
+        if led is not None:
+            # durable BEFORE the ack leaves: an ack whose
+            # absorb-record died with the process would turn
+            # the peer's next resend into a double-absorb
+            led.record(cid)
+        return _ABSORBED
 
     def drain(self, engine) -> None:
         """Engine stop, before the last flush: the tries already handed
@@ -656,10 +795,18 @@ class ForwardInput(InputPlugin):
         what they appended is flushed; later frames are refused."""
         self._stopped = True
         self._absorber.shutdown(wait=True)
+        self._stager.shutdown(wait=True)
+        # no frame is absorbed from here on: the launches begun for
+        # frames that were still waiting their turn are finished now,
+        # not whenever their tasks come to see the stop
+        for begun in list(self._begun):
+            begun.drop()
+        self._begun.clear()
 
     def exit(self) -> None:
         self._stopped = True
         self._absorber.shutdown(wait=False)
+        self._stager.shutdown(wait=False)
 
     def health_block(self) -> dict:
         out = {
@@ -667,6 +814,7 @@ class ForwardInput(InputPlugin):
             "absorbed": self.n_absorbed,
             "overlapped": self.n_overlapped,
             "cut": self.n_cut,
+            "prelaunched": self.n_prelaunched,
             "deferred_acks": self.n_deferred_acks,
             "withheld_acks": self.n_withheld_acks,
             "shed_remote": self.n_shed_remote,

@@ -207,3 +207,135 @@ def test_non_string_values_never_match():
         ev.body["n"] = 123  # int field
     _, kept = f.filter(list(events), "t", None)
     assert kept == []  # Regex-miss ⇒ EXCLUDE in legacy mode
+
+
+# --------------------------------------- the staged launch in two halves
+
+_HALVES_RULES = [("exclude", "log 404"), ("regex", "log ^(GET|POST)"),
+                 ("exclude", "log /path/7")]
+_HALVES_SPANS = r"^(?<method>[A-Z]+) (?<path>[^ ]*) HTTP/1\.1 (?<code>\d+)$"
+
+
+def _halves_data(n=200):
+    """Kept, dropped and missing-key rows, one row that alone stages at
+    L=512 and overflow rows past ``tpu_max_record_len``."""
+    events = make_events(n, seed=23, long_every=41)
+    events[5].body["log"] = "GET /" + "p" * 400 + " HTTP/1.1 200"
+    return b"".join(encode_event(e.body, float(i))
+                    for i, e in enumerate(events)), n
+
+
+def _halves_setup(kind, path):
+    """→ ``(rules, program, lane, how)``: ``staged_match``'s arguments
+    for one kind of verdict on one path, with a lane of their own."""
+    from fluentbit_tpu.ops import fault
+    from fluentbit_tpu.ops.grep import span_program_for
+    from fluentbit_tpu.plugins.filter_parser import _KeyRule
+    from fluentbit_tpu.regex import FlbRegex
+
+    lane = fault.DeviceLane(f"t-halves-{kind}-{path}")
+    mesh = lane.current_mesh() if path == "mesh" else None
+    if path == "mesh" and mesh is None:
+        pytest.skip("need a multi-device mesh")
+    how = {"max_len": 512, "min_records": 1, "mesh": mesh}
+    if kind == "spans":
+        program = span_program_for(_HALVES_SPANS, 512)
+        rules = [_KeyRule("log", FlbRegex(_HALVES_SPANS))]
+        how["spans"] = True
+    else:
+        f = make_filter(_HALVES_RULES + [("tpu_batch_records", "1")])
+        if f._program is None or not f._program.try_ready():
+            pytest.skip("device program unavailable")
+        rules, program = f.rules, f._program
+        how["first_match"] = kind == "first_match"
+    return rules, program, lane, how
+
+
+def _same_verdict(kind, a, b) -> None:
+    import numpy as np
+
+    (va, offs_a, n_a), (vb, offs_b, n_b) = a, b
+    assert n_a == n_b and np.array_equal(offs_a, offs_b)
+    if kind == "spans":
+        assert np.array_equal(va.ok, vb.ok) and va.ok.any()
+        assert np.array_equal(va.spans, vb.spans)
+        assert np.array_equal(va.lengths, vb.lengths)
+        assert all(np.array_equal(p, q)
+                   for p, q in zip(va.planes, vb.planes))
+    else:
+        assert va.dtype == vb.dtype and np.array_equal(va, vb)
+        assert va.any()
+
+
+@pytest.mark.parametrize("kind,path", [
+    ("mask", "one_chip"), ("first_match", "one_chip"),
+    ("spans", "one_chip"), ("mask", "mesh"), ("first_match", "mesh")])
+def test_begin_then_finish_equals_the_one_call(kind, path):
+    """``staged_match(begin=True)`` then ``staged_match(begun=...)`` is
+    the one call bit for bit — verdict, offsets, count and every
+    ``raw_timings`` count — for the three clients' verdicts, on one
+    chip's path and the mesh's, with overflow rows and an L=512 row;
+    the begin half touches no counter, and the two flights of the lane
+    are both finished."""
+    from fluentbit_tpu.core.spans import ShardedTimings
+    from fluentbit_tpu.plugins.filter_grep import (_TIMING_KEYS, Begun,
+                                                   staged_match)
+
+    rules, program, lane, how = _halves_setup(kind, path)
+    data, n = _halves_data()
+    tm_one, tm_two = ShardedTimings(_TIMING_KEYS), ShardedTimings(_TIMING_KEYS)
+    one = staged_match(rules, program, lane, tm_one, data, n, **how)
+    begun = staged_match(rules, program, lane, None, data, n, begin=True,
+                         **how)
+    assert isinstance(begun, Begun)
+    assert lane.stats()["launches"] == 2 and lane.stats()["ok"] == 1
+    assert all(tm_two[k] == 0 for k in _TIMING_KEYS)
+    two = staged_match(rules, program, lane, tm_two, data, n, begun=begun,
+                       **how)
+    _same_verdict(kind, one, two)
+    for key in ("device_records", "overflow_rows", "h2d_bytes",
+                "d2h_bytes", "scan_elements"):
+        assert tm_one[key] == tm_two[key] > 0, key
+    assert tm_two["extract_s"] > 0 and tm_two["kernel_s"] > 0
+    st = lane.stats()
+    assert st["launches"] == st["ok"] == 2 and st["begun_in_flight"] == 0
+    begun.drop()  # ended already: nothing more is finished
+    assert lane.stats()["ok"] == 2
+
+
+def test_a_chunk_of_two_segments_is_not_begun_ahead(monkeypatch):
+    from fluentbit_tpu.plugins.filter_grep import staged_match
+
+    monkeypatch.setenv("FBTPU_SEGMENT_RECORDS", "128")
+    rules, program, lane, how = _halves_setup("mask", "one_chip")
+    data, n = _halves_data()
+    assert staged_match(rules, program, lane, None, data, n, begin=True,
+                        **how) is None
+    assert lane.stats()["launches"] == 0
+    # under the configured minimum: declined before any staging, too
+    assert staged_match(rules, program, lane, None, data, n, begin=True,
+                        **{**how, "min_records": 1000}) is None
+
+
+def test_a_handle_made_for_other_bytes_or_rules_is_dropped():
+    """The finishing call checks what the handle was made for — the
+    very bytes, rules and program — and a handle that does not answer
+    it is finished and thrown away, the call starting over."""
+    from fluentbit_tpu.core.spans import ShardedTimings
+    from fluentbit_tpu.plugins.filter_grep import _TIMING_KEYS, staged_match
+
+    rules, program, lane, how = _halves_setup("mask", "one_chip")
+    data, n = _halves_data()
+    tm = ShardedTimings(_TIMING_KEYS)
+    one = staged_match(rules, program, lane, tm, data, n, **how)
+    for other in ({"data": bytes(bytearray(data))},
+                  {"rules": list(rules)}):
+        begun = staged_match(rules, program, lane, None, data, n,
+                             begin=True, **how)
+        before = lane.stats()["launches"]
+        got = staged_match(other.get("rules", rules), program, lane, tm,
+                           other.get("data", data), n, begun=begun, **how)
+        _same_verdict("mask", one, got)
+        st = lane.stats()
+        assert st["launches"] == before + 1 == st["ok"]  # staged anew
+    assert tm["device_records"] == 3 * n

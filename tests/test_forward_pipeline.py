@@ -6,6 +6,12 @@ Loopback, no chip: the filter chain is one raw-path filter (``fwd_gate``)
 whose ``process_batch`` can be held on an ``Event``, which is what a
 device launch is to the worker — a wait with the GIL released. Every
 behaviour is a case of ``test_forward_pipeline``.
+
+The ``staged`` cases put ``filter_grep`` before the gate, on the staged
+launch through the "grep" lane (jax's CPU backend stands where the chip
+is): the filter that offers the begin half of its launch, so that a
+frame decoded behind a busy worker has its launch begun ahead of its
+turn (``forward.prelaunch``).
 """
 
 import gzip
@@ -18,11 +24,14 @@ import time
 import pytest
 
 import fluentbit_tpu as flb
+from fluentbit_tpu import failpoints
 from fluentbit_tpu.codec import _native_codec
 from fluentbit_tpu.codec.events import decode_events
 from fluentbit_tpu.codec.msgpack import Unpacker, packb
 from fluentbit_tpu.core.plugin import FilterPlugin, registry
+from fluentbit_tpu.ops import fault
 from fluentbit_tpu.plugins import net_forward
+from fluentbit_tpu.plugins.filter_grep import GrepFilter
 
 WAIT_S = 20.0
 #: whether the C cut serves here (``fbtpu_codec.forward_cut``): without
@@ -74,10 +83,18 @@ def _register_gate():
             return chunk.n, chunk.as_bytes()
 
 
-class Aggregator:
-    """forward input → gate filter → lib output."""
+#: the staged cases' rules: a record passes unless its ``chunk`` says drop
+#: (legacy mode: the first rule that decides a record decides it)
+GREP_RULES = {"exclude": "chunk ^drop", "regex": "chunk ."}
 
-    def __init__(self, tmp_path=None, **props):
+
+class Aggregator:
+    """forward input → gate filter → lib output; ``staged``: forward
+    input → grep on the staged launch → gate filter → lib output, and a
+    closed gate holds a frame before its grep as well."""
+
+    def __init__(self, tmp_path=None, staged=False, monkeypatch=None,
+                 **props):
         _register_gate()
         self.threads_before = set(threading.enumerate())
         svc = {"flush": "50ms", "grace": "2"}
@@ -85,17 +102,39 @@ class Aggregator:
             svc["storage.path"] = str(tmp_path / "agg")
         self.ctx = flb.create(**svc)
         self.ctx.input("forward", listen="127.0.0.1", port="0", **props)
+        if staged:
+            fault.reset()  # a lane of this aggregator's own
+            # every grep instance (a reloaded one too) on the staged
+            # launch: what a chip attached would choose
+            monkeypatch.setattr(GrepFilter, "_raw_engine",
+                                lambda self: (None, False))
+            self.ctx.filter("grep", match="*", tpu_batch_records="1",
+                            **GREP_RULES)
         self.ctx.filter("fwd_gate", match="*")
         self.got = []
         self.ctx.output("lib", match="*",
                         callback=lambda d, t: self.got.append((t, bytes(d))))
         self.engine = self.ctx.engine
         self.srv = self.engine.inputs[0].plugin
-        self.gate = self.engine.filters[0].plugin
+        self.gate = self.engine.filters[-1].plugin
+        self.grep_entered = 0
+        if staged:
+            real = GrepFilter.process_batch
+
+            def held(plugin, chunk):
+                self.grep_entered += 1
+                assert self.gate.open.wait(WAIT_S), "never opened"
+                return real(plugin, chunk)
+
+            monkeypatch.setattr(GrepFilter, "process_batch", held)
         self.ctx.start()
         self.port = wait_for(lambda: self.srv.bound_port)
         self.stopped = False
         self.edges = []
+
+    def lane(self) -> dict:
+        """The "grep" lane's counters (the staged cases' own lane)."""
+        return fault.lane("grep").stats()
 
     def connect(self) -> "Edge":
         self.edges.append(Edge(self.port))
@@ -243,7 +282,10 @@ def case_acks_leave_in_send_order(agg, **_):
     assert edge.acks(len(ids)) == ids
     assert agg.srv.n_absorbed == len(ids)
     assert agg.srv.n_cut == (len(ids) if HAVE_CUT else 0)
-    assert agg.srv.n_overlapped >= 1
+    # every frame but the first was decoded while the one before it was
+    # with the worker: a frame that has waited its turn is handed over
+    # before the loop decodes the next, not after
+    assert agg.srv.n_overlapped >= len(ids) - 2
     # one worker, first come first served: the chunks entered the
     # filter in the order they were sent
     assert agg.gate.entered == len(ids)
@@ -299,21 +341,25 @@ def case_handover_full_stops_reading(agg, monkeypatch, **_):
     monkeypatch.setattr(net_forward, "_chunk_events", chunk_events)
     agg.gate.open.clear()
     edge = agg.connect()
-    a, b = frame("a"), frame("b")
-    c = frame("c", n=64, pad=16384)  # 1 MB
-    sender = threading.Thread(target=edge.send, args=(a + b + c,))
+    # one frame for the worker and as many as the loop may hold decoded
+    # behind it, then one of 1 MB
+    ids = ["a", "b", "c"][:2 + net_forward._DECODE_AHEAD]
+    held = b"".join(frame(c) for c in ids)
+    big = frame("z", n=64, pad=16384)
+    sender = threading.Thread(target=edge.send, args=(held + big,))
     sender.start()
-    wait_for(lambda: agg.srv.n_overlapped == 1)
+    wait_for(lambda: agg.srv.n_overlapped == len(ids) - 1)
     time.sleep(0.3)
-    # a is being absorbed, b waits beside it, c is nowhere: the handler
-    # has not taken another byte off the connection (what the kernel's
-    # buffers do not hold either stops the peer: TCP flow control)
-    assert decoded == [8, 8]
-    assert sum(fed) <= len(a) + len(b) + 65536 < len(a + b + c)
+    # a is being absorbed, the others wait beside it, z is nowhere: the
+    # handler has not taken another byte off the connection (what the
+    # kernel's buffers do not hold either stops the peer: TCP flow
+    # control)
+    assert decoded == [8] * len(ids)
+    assert sum(fed) <= len(held) + 65536 < len(held + big)
     agg.gate.open.set()
-    assert edge.acks(3) == ["a", "b", "c"]
+    assert edge.acks(len(ids) + 1) == ids + ["z"]
     sender.join(WAIT_S)
-    assert not sender.is_alive() and decoded == [8, 8, 64]
+    assert not sender.is_alive() and decoded == [8] * len(ids) + [64]
     edge.close()
 
 
@@ -707,6 +753,309 @@ def case_cut_and_handed_back_frames_keep_their_order(agg, **_):
     edge.close()
 
 
+# ------------------------------------- the staged cases: launch beside commit
+
+
+def staged(case):
+    case.staged = True
+    return case
+
+
+def lane_is_clean(agg, launches: int) -> None:
+    """Every flight that was begun was finished, on the device."""
+    st = agg.lane()
+    assert st["launches"] == st["ok"] == launches, st
+    for key in ("failures", "timeouts", "fallback_segments", "abandoned",
+                "short_circuits"):
+        assert st[key] == 0, (key, st)
+
+
+@staged
+def case_second_of_two_frames_is_prelaunched_a_lone_one_is_not(
+        agg, monkeypatch, **_):
+    spans = SpanLog()
+    monkeypatch.setattr(net_forward, "span", spans)
+    edge = agg.connect()
+    edge.send(frame("lone"))
+    assert edge.acks(1) == ["lone"]
+    assert agg.srv.n_prelaunched == 0 and agg.lane()["begun_in_flight"] == 0
+    assert spans.count("forward.prelaunch") == 0
+    agg.gate.open.clear()
+    edge.send(frame("a") + frame("b"))
+    wait_for(lambda: agg.srv.n_prelaunched == 1)
+    # b's launch is begun, on the instance's second thread, while a has
+    # not had its own; nothing of b is committed
+    assert agg.grep_entered == 2 and agg.gate.entered == 1
+    assert agg.lane()["launches"] == 2 and agg.srv.n_absorbed == 1
+    assert [t for n, t in spans.names if n == "forward.prelaunch"][0] \
+        .startswith("flb-fw-pre-")
+    assert agg.srv._ledger.snapshot() == {"lone": 1}
+    agg.gate.open.set()
+    assert edge.acks(2) == ["a", "b"]
+    # the span, the counter, the health block and the lane agree
+    assert spans.count("forward.prelaunch") == 1
+    assert agg.srv.health_block()["prelaunched"] == 1
+    assert agg.lane()["begun_in_flight"] == 1
+    name = agg.srv.instance.display_name
+    assert f'fluentbit_forward_prelaunched_chunks_total{{instance="' \
+           f'{name}"}} 1' in agg.engine.metrics.to_prometheus()
+    # b's absorb found its launch begun: three launches for three frames
+    lane_is_clean(agg, 3)
+    assert agg.engine.filters[0].plugin.raw_timings["device_records"] == 24
+    edge.close()
+
+
+@staged
+def case_prelaunch_leaves_bytes_order_and_acks_as_they_were(
+        agg, monkeypatch, **_):
+    """The same frames with the begin half and with it taken away: the
+    bytes handed on, their order and the acks' order do not say which."""
+    agg.gate.pause_s = 0.005
+
+    def run(name: str) -> None:
+        ids = [f"{name}{i:02d}" for i in range(12)]
+        edge = agg.connect()
+        # the records say nothing of the run: `chunk` is the frame's
+        # place (every fourth frame is dropped whole), the option's id
+        # is the run's own
+        edge.send(b"".join(packb(["app", [
+            [1700000000 + k, {"chunk": ("drop" if i % 4 == 3 else "")
+                              + f"{i:02d}", "i": k, "pad": "x" * 600}]
+            for k in range(8)], {"chunk": c}]) for i, c in enumerate(ids)))
+        assert edge.acks(len(ids)) == ids
+        edge.close()
+        agg.ctx.flush_now()
+
+    run("a")
+    wait_for(lambda: len(agg.records()) == 8 * 9)
+    with_half, agg.got[:] = [d for _t, d in agg.got], []
+    begun, launches = agg.srv.n_prelaunched, agg.lane()["launches"]
+    assert begun >= 6 and launches == 12
+    monkeypatch.delattr(GrepFilter, "begin_batch")
+    run("b")
+    wait_for(lambda: len(agg.records()) == 8 * 9)
+    assert agg.srv.n_prelaunched == begun
+    assert b"".join(d for _t, d in agg.got) == b"".join(with_half)
+    assert [r["chunk"] for r in agg.records() if r["i"] == 0] == [
+        f"{i:02d}" for i in range(12) if i % 4 != 3]
+    lane_is_clean(agg, 24)
+
+
+@staged
+def case_no_ack_before_the_ledger_with_a_launch_begun_ahead(
+        agg, monkeypatch, **_):
+    at_ack = []
+    real = net_forward.packb
+
+    def packb_seeing(obj, *a, **kw):
+        if isinstance(obj, dict) and "ack" in obj:
+            at_ack.append((obj["ack"], agg.srv.n_absorbed,
+                           agg.srv._ledger.snapshot().get(obj["ack"])))
+        return real(obj, *a, **kw)
+
+    monkeypatch.setattr(net_forward, "packb", packb_seeing)
+    agg.gate.open.clear()
+    edge = agg.connect()
+    edge.send(frame("a") + frame("b"))
+    wait_for(lambda: agg.srv.n_prelaunched == 1)
+    # b's launch runs: nothing on the wire, nothing counted, nothing in
+    # the ledger, nothing handed on
+    assert edge.acks(1, timeout=0.3) == []
+    assert agg.srv.n_absorbed == 0 and agg.gate.entered == 0
+    assert agg.srv._ledger.snapshot() == {}
+    agg.gate.open.set()
+    assert edge.acks(2) == ["a", "b"]
+    assert at_ack == [("a", 1, 1), ("b", 2, 1)]
+    lane_is_clean(agg, 2)
+    edge.close()
+
+
+@staged
+def case_duplicate_of_a_chunk_in_flight_has_its_launch_dropped(agg, **_):
+    """The same chunk id on a second connection, behind another frame
+    there, while its first delivery is held: its launch is begun ahead,
+    the worker then finds the first delivery's record — absorbed once,
+    and the launch nobody used is finished."""
+    agg.gate.open.clear()
+    first, second = agg.connect(), agg.connect()
+    first.send(frame("dup"))
+    wait_for(lambda: agg.grep_entered == 1)
+    second.send(frame("x") + frame("dup"))
+    wait_for(lambda: agg.srv.n_prelaunched == 1)
+    agg.gate.open.set()
+    assert first.acks(1) == ["dup"] and second.acks(2) == ["x", "dup"]
+    assert agg.gate.entered == 2 and agg.srv.n_absorbed == 2
+    assert agg.srv._ledger.snapshot() == {"dup": 1, "x": 1}
+    assert agg.srv._ledger.dedup_hits == 1
+    lane_is_clean(agg, 3)  # dup, x, and dup's unused one
+    wait_for(lambda: agg.srv._begun == set())
+    first.close()
+    second.close()
+
+
+@staged
+def case_deferred_frame_keeps_the_launch_begun_for_it(agg, **_):
+    """DEFER, then the quota allows: the retry takes the handle along,
+    the frame is absorbed once, from the launch begun ahead."""
+    t = agg.engine.qos.tenant("slow", rate=1.0, overflow="defer")
+    assert t.bucket.try_take(100_000)
+    agg.gate.open.clear()
+    edge = agg.connect()
+    edge.send(frame("a") + frame("b", tenant="slow"))
+    wait_for(lambda: agg.srv.n_prelaunched == 1)
+    agg.gate.open.set()
+    assert edge.acks(1) == ["a"]
+    wait_for(lambda: agg.srv.n_deferred_acks == 1)
+    time.sleep(0.1)  # a retry or two, each deferred again
+    assert agg.lane()["launches"] == 2 and agg.lane()["ok"] == 1
+    t.bucket.capacity = t.bucket.tokens = 1e9
+    assert edge.acks(1) == ["b"]
+    assert agg.srv.n_absorbed == 2 and agg.srv.n_withheld_acks == 0
+    assert agg.srv._ledger.snapshot() == {"a": 1, "b": 1}
+    lane_is_clean(agg, 2)  # b was not staged a second time
+    edge.close()
+
+
+case_deferred_frame_keeps_the_launch_begun_for_it.props = {
+    "defer_ack_window": "15"}
+
+
+@staged
+def case_shed_frame_has_its_launch_dropped(agg, **_):
+    t = agg.engine.qos.tenant("loud", rate=1.0, overflow="shed")
+    assert t.bucket.try_take(100_000)
+    agg.gate.open.clear()
+    edge = agg.connect()
+    edge.send(frame("a") + frame("b", tenant="loud"))
+    wait_for(lambda: agg.srv.n_prelaunched == 1)
+    agg.gate.open.set()
+    assert edge.acks(2) == ["a", "b"]  # shed by policy: acked
+    assert agg.srv.n_shed_remote == 1 and agg.srv.n_absorbed == 1
+    assert agg.gate.entered == 1
+    lane_is_clean(agg, 2)
+    wait_for(lambda: agg.srv._begun == set())
+    edge.close()
+
+
+@staged
+def case_withheld_frame_has_its_launch_dropped(agg, **_):
+    """The defer window runs out on a frame whose launch was begun
+    ahead: no ack, not absorbed, the launch finished."""
+    t = agg.engine.qos.tenant("slow", rate=1.0, overflow="defer")
+    assert t.bucket.try_take(100_000)
+    agg.gate.open.clear()
+    edge = agg.connect()
+    edge.send(frame("a") + frame("b", tenant="slow"))
+    wait_for(lambda: agg.srv.n_prelaunched == 1)
+    agg.gate.open.set()
+    assert edge.acks(2, timeout=1.5) == ["a"]
+    assert agg.srv.n_withheld_acks == 1 and agg.srv.n_absorbed == 1
+    wait_for(lambda: agg.srv._begun == set())
+    lane_is_clean(agg, 2)
+    edge.close()
+
+
+@staged
+def case_engine_stop_with_a_launch_begun_ahead(agg, **_):
+    agg.gate.open.clear()
+    edge = agg.connect()
+    edge.send(frame("a") + frame("b"))
+    wait_for(lambda: agg.srv.n_prelaunched == 1)
+    stopper = threading.Thread(target=agg.ctx.stop)
+    agg.stopped = True
+    stopper.start()
+    time.sleep(0.3)
+    assert stopper.is_alive()  # the stop waits for the worker
+    agg.gate.open.set()
+    stopper.join(WAIT_S)
+    assert not stopper.is_alive()
+    # a was handed over: absorbed, flushed, acked. b was not: no ack,
+    # not absorbed — and its launch is not left open
+    assert agg.workers() == []
+    assert [r["chunk"] for r in agg.records() if r["i"] == 0] == ["a"]
+    assert edge.acks(2, timeout=1.0) == ["a"]
+    assert agg.srv.n_absorbed == 1 and agg.srv._begun == set()
+    lane_is_clean(agg, 2)
+    edge.close()
+
+
+@staged
+def case_filter_reloaded_between_begin_and_absorb(agg, **_):
+    """b's launch is begun under the old rules, the filter is swapped
+    before b's turn: the handle is discarded, b's verdict is the new
+    rules', and the old launch is finished all the same."""
+    go = threading.Event()
+    real = agg.engine.input_log_append
+
+    def held(*a, **kw):  # a waits outside the engine: a reload can land
+        assert go.wait(WAIT_S), "never let go"
+        return real(*a, **kw)
+
+    agg.engine.input_log_append = held
+    try:
+        edge = agg.connect()
+        edge.send(frame("a") + frame("b") + frame("c"))
+        wait_for(lambda: agg.srv.n_prelaunched == 1)
+        old = agg.engine.filters[0].plugin
+        txn = agg.engine.reload_txn()
+        txn.replace_filter("grep.0", match="*", tpu_batch_records="1",
+                           exclude="chunk ^b", regex="chunk .")
+        txn.commit()
+        assert agg.engine.filters[0].plugin is not old
+        go.set()
+        assert edge.acks(3) == ["a", "b", "c"]
+    finally:
+        del agg.engine.input_log_append
+    agg.ctx.flush_now()
+    wait_for(lambda: len(agg.records()) == 16)
+    # b — begun under rules that keep it — is dropped by the new ones
+    assert [r["chunk"] for r in agg.records() if r["i"] == 0] == ["a", "c"]
+    assert agg.srv.n_absorbed == 3
+    assert old.raw_timings["device_records"] == 0
+    new = agg.engine.filters[0].plugin.raw_timings
+    assert new["device_records"] == new["records"] == 24
+    # a, b begun ahead under the old rules and dropped, b again, c
+    lane_is_clean(agg, 4)
+    edge.close()
+
+
+@staged
+def case_faults_on_a_launch_begun_ahead_fall_back_to_the_host(agg, **_):
+    """``device.dispatch`` and ``device.launch_hang`` on flights that
+    were begun ahead of their frames' turn: each resolves to the host
+    twin at the frame's own finish, and the bytes are the sound run's."""
+    lane = fault.lane("grep")
+    edge = agg.connect()
+    edge.send(frame("warm"))  # compiled before a deadline is short
+    assert edge.acks(1) == ["warm"]
+    try:
+        for n, (site, spec, counted) in enumerate((
+                ("device.dispatch", "1*off->1*return(injected)",
+                 "failures"),
+                ("device.launch_hang", "1*off->1*hang(3000)", "timeouts"))):
+            lane.deadline = 0.5
+            agg.gate.open.clear()
+            failpoints.enable(site, spec)  # a's launch passes, b's not
+            edge.send(frame(f"a{n}") + frame(f"drop{n}") + frame(f"c{n}"))
+            wait_for(lambda: agg.srv.n_prelaunched >= 2 * n + 1)
+            agg.gate.open.set()
+            assert edge.acks(3) == [f"a{n}", f"drop{n}", f"c{n}"]
+            failpoints.reset()
+            st = lane.stats()
+            assert st[counted] == 1 and st["fallback_segments"] == n + 1
+    finally:
+        failpoints.reset()
+    agg.ctx.flush_now()
+    wait_for(lambda: len(agg.records()) == 8 * 5)
+    assert [r["chunk"] for r in agg.records() if r["i"] == 0] == [
+        "warm", "a0", "c0", "a1", "c1"]
+    st = lane.stats()
+    assert st["launches"] == 7
+    assert st["ok"] + st["failures"] + st["timeouts"] == 7
+    wait_for(lambda: agg.srv._begun == set())
+    edge.close()
+
+
 CASES = [v for k, v in sorted(globals().items()) if k.startswith("case_")]
 
 
@@ -714,7 +1063,8 @@ CASES = [v for k, v in sorted(globals().items()) if k.startswith("case_")]
                          ids=[c.__name__[5:] for c in CASES])
 def test_forward_pipeline(case, tmp_path, monkeypatch, caplog, no_codec):
     props = {"defer_ack_window": "0.4", **getattr(case, "props", {})}
-    agg = Aggregator(tmp_path, **props)
+    agg = Aggregator(tmp_path, staged=getattr(case, "staged", False),
+                     monkeypatch=monkeypatch, **props)
     try:
         case(agg=agg, monkeypatch=monkeypatch, caplog=caplog,
              no_codec=no_codec)

@@ -24,6 +24,7 @@ import fluentbit_tpu as flb
 from fluentbit_tpu.codec.msgpack import Unpacker, packb
 from fluentbit_tpu.core import spans
 from fluentbit_tpu.ops import fault
+from fluentbit_tpu.plugins import net_forward
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 APACHE2 = (r'^(?<host>[^ ]*) [^ ]* (?<user>[^ ]*) \[(?<time>[^\]]*)\] '
@@ -47,12 +48,19 @@ def wait_for(cond, timeout=20.0, interval=0.01):
     raise TimeoutError("condition not met")
 
 
-def frame(chunk=CHUNK, n=N_LINES) -> bytes:
+def frame(chunk=CHUNK, n=N_LINES, pad=0) -> bytes:
     """One Forward-mode frame; every fourth line fails the regex."""
+    more = {"pad": "x" * pad} if pad else {}
     entries = [[1700000000 + i,
-                {"log": OK_LINE if i % 4 else f"kernel: oom {i}"}]
+                {"log": OK_LINE if i % 4 else f"kernel: oom {i}", **more}]
                for i in range(n)]
     return packb(["app", entries, {"chunk": chunk}])
+
+
+def short_frame(chunk: str) -> bytes:
+    """24 lines, one segment of the 32 ``mesh_env`` sets, and padded
+    past what ``in_forward`` absorbs on the loop (``_INLINE_BYTES``)."""
+    return frame(chunk, n=24, pad=200)
 
 
 class Aggregator:
@@ -570,28 +578,146 @@ def test_handover_is_on_the_loops_thread_around_the_absorb(runs):
     assert reencode["end"] <= over["start"] and over["end"] <= ack["start"]
 
 
+@pytest.fixture(scope="module")
+def prelaunched_events(mesh_env, tmp_path_factory):
+    """Three frames of ONE segment each on one connection under a
+    profiler session, the first held in the filter until the second's
+    launch has been begun ahead of its turn (``n_prelaunched`` says
+    so). → the session's events and the input's count."""
+    jax = mesh_env
+    agg = Aggregator()
+    srv = agg.engine.inputs[0].plugin
+    ids = ["pre-a", "pre-b", "pre-c"]
+    try:
+        agg.send(short_frame("pre-warm"), "pre-warm")  # compiles
+        real = agg.grep.process_batch
+        held = []
+
+        def hold_the_first(chunk):
+            if not held:
+                # ... and the loop holds every frame it may decode
+                held.append(wait_for(
+                    lambda: srv.n_prelaunched >= 1 and srv.n_overlapped
+                    >= 1 + net_forward._DECODE_AHEAD))
+            return real(chunk)
+
+        agg.grep.process_batch = hold_the_first
+        trace_dir = str(tmp_path_factory.mktemp("prelaunched"))
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        try:
+            with socket.create_connection(("127.0.0.1", agg.port)) as s:
+                s.settimeout(60)
+                s.sendall(b"".join(short_frame(c) for c in ids))
+                u, acks = Unpacker(), []
+                while len(acks) < 3:
+                    u.feed(s.recv(4096))
+                    acks.extend(msg["ack"] for msg in u)
+        finally:
+            jax.profiler.stop_trace()
+            del agg.grep.process_batch
+        assert acks == ids and held == [True]
+        n_prelaunched = srv.n_prelaunched
+    finally:
+        agg.stop()
+    return {"events": read_events(trace_dir), "prelaunched": n_prelaunched}
+
+
 @pytest.mark.mesh
 def test_await_span_marks_the_frame_that_waited_for_the_worker(
-        pipelined_events, runs):
-    """``forward.await`` is opened only when the loop holds a decoded
-    frame while an earlier one is still with the worker, and carries the
-    chunk of the frame that waits."""
-    events = pipelined_events
+        prelaunched_events, runs):
+    """``forward.await`` is opened only when the loop holds as many
+    decoded frames as it may (``1 + _DECODE_AHEAD``) behind one that is
+    still with the worker, and carries the chunk of the newest, the one
+    it cannot go on from."""
+    events = prelaunched_events["events"]
     (wait,) = by_name(events, "forward.await")
-    assert wait["stats"]["chunk"] == "pipe-b"
+    waiting = ["pre-b", "pre-c"][net_forward._DECODE_AHEAD]
+    assert wait["stats"]["chunk"] == waiting
     assert wait["line"] == by_name(events, "forward.read")[0]["line"]
     absorb = {e["stats"]["chunk"]: e
               for e in by_name(events, "forward.absorb")}
     over = {e["stats"]["chunk"]: e
             for e in by_name(events, "forward.handover")}
-    assert set(over) == set(absorb) == {"pipe-a", "pipe-b", "pipe-c"}
-    # it waited for a's absorb to end, then its own hand-over began
-    assert wait["start"] <= absorb["pipe-a"]["end"] <= wait["end"]
-    assert wait["end"] <= over["pipe-b"]["start"]
+    assert set(over) == set(absorb) == {"pre-a", "pre-b", "pre-c"}
+    # it waited for a's absorb to end; its own hand-over came later
+    assert wait["start"] <= absorb["pre-a"]["end"] <= wait["end"]
+    assert wait["end"] <= over[waiting]["start"]
     for chunk in over:
         assert inside(absorb[chunk], over[chunk]), chunk
     # a frame sent alone waits for nobody
     assert not by_name(runs["events"], "forward.await")
+
+
+@pytest.mark.mesh
+def test_prelaunch_span_marks_the_frames_whose_launch_was_begun_ahead(
+        prelaunched_events, runs):
+    """``forward.prelaunch`` is written for exactly the frames whose
+    launch was begun while an earlier frame was with the worker, with
+    the frame's ``chunk``, on the input's second thread; that frame's
+    staging and ``lane.begin`` lie on that thread too, before its
+    absorb begins, and its absorb stages nothing."""
+    events = prelaunched_events["events"]
+    marks = by_name(events, "forward.prelaunch")
+    assert len(marks) == prelaunched_events["prelaunched"] >= 1
+    chunks = [e["stats"]["chunk"] for e in marks]
+    assert "pre-b" in chunks and set(chunks) <= {"pre-b", "pre-c"}
+    loop_line = by_name(events, "forward.read")[0]["line"]
+    absorb = {e["stats"]["chunk"]: e
+              for e in by_name(events, "forward.absorb")}
+    assert {e["line"] for e in marks}.isdisjoint(
+        {loop_line, absorb["pre-a"]["line"]})
+    for mark in marks:
+        chunk = mark["stats"]["chunk"]
+        for name in ("grep.stage", "lane.begin"):
+            (ahead,) = [e for e in by_name(events, name)
+                        if e["stats"]["chunk"] == chunk]
+            assert ahead["line"] == mark["line"], name
+            assert ahead["end"] <= mark["start"] \
+                and mark["end"] <= absorb[chunk]["start"], name
+        (wait,) = [e for e in by_name(events, "lane.wait")
+                   if e["stats"]["chunk"] == chunk]
+        assert inside(wait, absorb[chunk])
+    # the frame that found the worker idle staged inside its absorb
+    (stage_a,) = [e for e in by_name(events, "grep.stage")
+                  if e["stats"]["chunk"] == "pre-a"]
+    assert inside(stage_a, absorb["pre-a"])
+    assert not by_name(runs["events"], "forward.prelaunch")
+
+
+@pytest.mark.mesh
+@pytest.mark.parametrize("which", ["segments", "begun_ahead"])
+def test_launch_number_joins_a_launch_to_its_own_spans(
+        which, runs, prelaunched_events):
+    """Every span of one launch — ``lane.begin`` and ``lane.wait`` on
+    the calling threads, ``lane.launch`` and the ``grep.dispatch`` /
+    ``put`` / ``call`` / ``force`` inside it on the lane's worker —
+    carries the same ``launch`` number, another one each launch: with
+    two flights open a reader joins by it, not by overlapping windows."""
+    events = runs["events"] if which == "segments" \
+        else prelaunched_events["events"]
+    launches = by_name(events, "lane.launch")
+    numbers = [e["stats"]["launch"] for e in launches]
+    assert len(set(numbers)) == len(numbers) == 3
+    assert all(isinstance(n, int) and n > 0 for n in numbers)
+    for launch in launches:
+        n = launch["stats"]["launch"]
+        for name in ("grep.dispatch", "grep.put", "grep.call",
+                     "grep.force"):
+            own = [e for e in by_name(events, name)
+                   if e["stats"].get("launch") == n]
+            assert own and all(inside(e, launch) for e in own), name
+            assert {e["line"] for e in own} == {launch["line"]}, name
+            assert {e["stats"]["chunk"] for e in own} \
+                == {launch["stats"]["chunk"]}, name
+        for name in ("lane.begin", "lane.wait"):
+            (own,) = [e for e in by_name(events, name)
+                      if e["stats"].get("launch") == n]
+            assert own["line"] != launch["line"], name
+    for name in ("grep.stage", "forward.absorb", "engine.append"):
+        assert all("launch" not in e["stats"]
+                   for e in by_name(events, name)), name
 
 
 @pytest.fixture(scope="module")
