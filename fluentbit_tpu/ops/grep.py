@@ -287,6 +287,14 @@ class GrepProgram:
             "rules": rules,
             "k": int(self.k),
             "k_groups": k_groups,
+            # one entry a mesh handle a child has built (none before
+            # the first sharded launch): each child decides its own
+            # variant (mesh_variant)
+            "mesh_children": [
+                {"k": int(c.k), "rules": len(c.dfas),
+                 "variant": h.variant, "devices": h.n_devices}
+                for c in self._children or [self]
+                for h in list(c._mesh_cache.values())],
             "max_states": int(self.max_states),
             "assoc_eligible": self.max_states <= 64,
             "kernel": self.kernel,
@@ -437,10 +445,25 @@ class GrepProgram:
         # shard_map; a no-op single-device
         state0 = jnp.broadcast_to(t["starts"][:, None], (R, B)) + 0 * lengths
 
-        def step(state, c_t):
-            idx = state * t["Ck"][:, None] + c_t
-            ns = jnp.take_along_axis(t["trans_flat"], idx, axis=1)
-            return ns, None
+        if R == 1 and B:
+            # One rule: the scan is handed its table as the 1-D array
+            # the gather reads, made once a launch out here. Left as
+            # ``[1, N]`` XLA makes it 1-D inside the ``while``, every
+            # step, wherever the table is an argument (the mesh program:
+            # ``reduce.2 s32[N]``, 31-39 us a step on a v5e, 5.5 of the
+            # 7.7 ms of an apache2 launch a chip; PERF.md, PRs 33, 38).
+            # A program that closes over its tables compiles either
+            # form to the same code. (Not for an empty batch: a take of
+            # no indices forgets the carry's shard_map annotation.)
+            flat, ck = t["trans_flat"][0], t["Ck"][0]
+
+            def step(state, c_t):
+                return jnp.take(flat, state * ck + c_t, axis=0), None
+        else:
+            def step(state, c_t):
+                idx = state * t["Ck"][:, None] + c_t
+                ns = jnp.take_along_axis(t["trans_flat"], idx, axis=1)
+                return ns, None
 
         with jax.named_scope("grep.scan"):
             final, _ = lax.scan(step, state0, comb_t)
@@ -579,15 +602,28 @@ class GrepProgram:
         crossing ``ops.mesh.TABLE_BUDGET`` (64 MiB) or R ≥
         ``FBTPU_MESH_RULE_SHARD_R`` (default 64), and on R dividing the
         mesh evenly (no rule padding — a dead-rule pad row would cost a
-        full batch scan)."""
+        full batch scan).
+
+        On a k-split parent this is the FIRST child's answer and no
+        more: ``dispatch_mesh`` asks each child, and the children need
+        not agree (grep-tenants' 5, 38, 6 and 1 rules all take
+        ``batch`` on four devices because none divides by four; 36 or
+        40 rules at k=3, 144 MB of tables a device, would take
+        ``rules``). ``decision()["mesh_children"]`` reports what each
+        child's handle took."""
         import os as _os
 
         from .mesh import TABLE_BUDGET, replicated_table_bytes
 
         if self._children is not None:
-            # k-split programs never rule-shard (the split is gated off
-            # the rule-shard regime in __init__); each child answers
-            # for its own slice and they all land on "batch"
+            # a k-split parent has no variant of its own: dispatch_mesh
+            # lets every child decide for its own slice, and this is
+            # only the first child's answer. The split is gated off the
+            # R >= FBTPU_MESH_RULE_SHARD_R arm, not off the table arm:
+            # a child whose rule count divides the mesh and whose
+            # tables, replicated, cross TABLE_BUDGET takes "rules"
+            # beside siblings on "batch" (decision()["mesh_children"]
+            # says what each took)
             return self._children[0].mesh_variant(mesh)
         n_dev = mesh.devices.size
         R = len(self.dfas)
@@ -795,7 +831,8 @@ class GrepProgram:
         B = batch.shape[1]
         # the host's share of the copy-in (padding, the rules variant's
         # gather, the contiguous copy) and the two transfers
-        with span("grep.put"):
+        with span("grep.put", variant=h.variant,
+                  devices=h.n_devices) as put:
             if h.variant == "batch":
                 Bp = pad_to_devices(B, h.n_devices)
                 batch, lengths = _pad_rows(batch, lengths, Bp)
@@ -803,6 +840,7 @@ class GrepProgram:
                 Bp = B
                 idx = list(self.plane_of)
                 batch, lengths = batch[idx], lengths[idx]
+            put.set_metadata(bytes=batch.nbytes + lengths.nbytes)
             bd = jax.device_put(
                 np.ascontiguousarray(batch, dtype=np.uint8), h.sh_b)
             ld = jax.device_put(

@@ -61,10 +61,16 @@ class _RawDecline(Exception):
 #: the staged bytes handed to the launch (the distinct [K, Bp, L] planes
 #: + their lengths), the verdict bytes copied back (``mask[R, Bp]``: a
 #: byte a rule and row) and the gathered elements the launched program
-#: steps through (``GrepProgram.scan_elements``)
+#: steps through (``GrepProgram.scan_elements``). The last three say
+#: how the launches staged for a mesh were laid out, counted where a
+#: launch is dispatched: ``mesh_launches`` went out sharded, over
+#: ``mesh_devices`` devices in all (so the ratio is devices a launch),
+#: ``unsharded_launches`` found the lane's mesh gone and went out on
+#: one device
 _TIMING_KEYS = ("extract_s", "kernel_s", "compact_s", "records",
                 "device_records", "overflow_rows", "h2d_bytes",
-                "d2h_bytes", "scan_elements")
+                "d2h_bytes", "scan_elements", "mesh_launches",
+                "mesh_devices", "unsharded_launches")
 
 
 def _len_bucket(n: int, cap: int) -> int:
@@ -333,7 +339,8 @@ class Begun:
     def drop(self) -> None:
         """End a launch nobody will use: the flight is finished (its
         verdict, the device's or the fallback's, is thrown away) and
-        nothing is counted in any ``raw_timings``. A no-op on a handle
+        nothing more is counted in any ``raw_timings`` (on the mesh its
+        layout was, when it was dispatched). A no-op on a handle
         that was finished or dropped before."""
         mine = self._spend()
         if mine is not None:
@@ -363,7 +370,15 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
     partitioned pjit matcher instead: the batch axis is padded to the
     mesh size and sharded across devices at ONE jit-stable width, and
     the staged lengths are donated to the kernel where they can alias
-    its output.
+    its output. The mesh's verdict is ``i32[R, Bp]``, 4 B a rule and
+    row, so that the ``i32[K, Bp]`` lengths can be donated into it —
+    which aliases only at K = R (a plane a rule); a rule list on fewer
+    planes than rules (50 rules on one key) copies out four times what
+    one device would and donates nothing. Such a launch is counted
+    where it is dispatched: ``mesh_launches`` and ``mesh_devices`` (the
+    lane's mesh then; their ratio is devices a launch), or
+    ``unsharded_launches`` where the lane's mesh is gone (fewer than
+    two devices survive) and the staged planes go out on one device.
 
     With ``spans`` the one rule's ``program`` is an
     ``ops.grep.SpanProgram`` and the verdict a :class:`SpanVerdict`:
@@ -374,8 +389,9 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
 
     **In two halves.** With ``begin`` a chunk of ONE segment is staged
     and its launch begun (``lane.begin``), and a :class:`Begun` comes
-    back instead of a verdict — no ``tm`` is touched, nothing is
-    committed, so any thread may do it ahead of the chunk's turn
+    back instead of a verdict — of ``tm`` only the mesh's three layout
+    counts are touched (below), nothing is committed, so any
+    long-lived thread may do it ahead of the chunk's turn
     (``in_forward`` does, for a connection's next frame, while the
     frame before it is collected and acked: ``begin_batch``). With
     ``begun`` — that handle, given back by the chunk's own absorb — the
@@ -387,7 +403,10 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
     (``begin`` declines such a chunk: None).
 
     ``tm`` (the plugin's ``raw_timings``) is written by the finishing
-    half alone, once a chunk whichever half ran where. It takes
+    half alone, once a chunk whichever half ran where — but for
+    ``mesh_launches``, ``mesh_devices`` and ``unsharded_launches``,
+    which the dispatching half adds, as the lane counts its launches:
+    a launch that is dropped unused was laid out all the same. It takes
     ``extract_s``, ``kernel_s`` (the finishing call's wall less the
     extraction done inside it: what the chunk waited for its launch
     **in series** — the whole launch where both halves ran here, what
@@ -550,8 +569,17 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
             # over the whole execution AND preserves the staging
             # overlap (the worker forces while the caller stages
             # the next segment).
+            # The lane's mesh is read here, on the dispatching thread
+            # (a lane worker lives for one launch and may not add to
+            # ``tm``), and the layout counted with it.
+            m = lane.current_mesh()
+            if m is None:
+                tm.add("unsharded_launches", 1)
+            else:
+                tm.add("mesh_launches", 1)
+                tm.add("mesh_devices", int(m.devices.size))
+
             def launch(b=batch, ln=lengths):
-                m = lane.current_mesh()
                 if m is None:
                     # mesh shrunk below 2 devices: serve unsharded
                     return forced(b, ln)

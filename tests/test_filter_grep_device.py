@@ -260,8 +260,11 @@ def _same_verdict(kind, a, b) -> None:
         assert np.array_equal(va.ok, vb.ok) and va.ok.any()
         assert np.array_equal(va.spans, vb.spans)
         assert np.array_equal(va.lengths, vb.lengths)
-        assert all(np.array_equal(p, q)
-                   for p, q in zip(va.planes, vb.planes))
+        # the staged rows up to each value's length: past it a row holds
+        # whatever ``np.empty`` held (the kernel reads it as padding)
+        (p,), (q,) = va.planes, vb.planes               # one segment
+        live = np.arange(p.shape[1]) < np.maximum(va.lengths, 0)[:, None]
+        assert p.shape == q.shape and np.array_equal(p[live], q[live])
     else:
         assert va.dtype == vb.dtype and np.array_equal(va, vb)
         assert va.any()
@@ -275,8 +278,9 @@ def test_begin_then_finish_equals_the_one_call(kind, path):
     the one call bit for bit — verdict, offsets, count and every
     ``raw_timings`` count — for the three clients' verdicts, on one
     chip's path and the mesh's, with overflow rows and an L=512 row;
-    the begin half touches no counter, and the two flights of the lane
-    are both finished."""
+    the begin half touches no counter but the mesh's layout counts
+    (a launch is laid out where it is dispatched, used or not), and the
+    two flights of the lane are both finished."""
     from fluentbit_tpu.core.spans import ShardedTimings
     from fluentbit_tpu.plugins.filter_grep import (_TIMING_KEYS, Begun,
                                                    staged_match)
@@ -285,17 +289,21 @@ def test_begin_then_finish_equals_the_one_call(kind, path):
     data, n = _halves_data()
     tm_one, tm_two = ShardedTimings(_TIMING_KEYS), ShardedTimings(_TIMING_KEYS)
     one = staged_match(rules, program, lane, tm_one, data, n, **how)
-    begun = staged_match(rules, program, lane, None, data, n, begin=True,
+    begun = staged_match(rules, program, lane, tm_two, data, n, begin=True,
                          **how)
     assert isinstance(begun, Begun)
     assert lane.stats()["launches"] == 2 and lane.stats()["ok"] == 1
-    assert all(tm_two[k] == 0 for k in _TIMING_KEYS)
+    layout = {"mesh_launches": 1,
+              "mesh_devices": how["mesh"].devices.size} \
+        if path == "mesh" else {}
+    assert all(tm_two[k] == layout.get(k, 0) for k in _TIMING_KEYS)
     two = staged_match(rules, program, lane, tm_two, data, n, begun=begun,
                        **how)
     _same_verdict(kind, one, two)
     for key in ("device_records", "overflow_rows", "h2d_bytes",
-                "d2h_bytes", "scan_elements"):
+                "d2h_bytes", "scan_elements", *layout):
         assert tm_one[key] == tm_two[key] > 0, key
+    assert tm_two["unsharded_launches"] == 0
     assert tm_two["extract_s"] > 0 and tm_two["kernel_s"] > 0
     st = lane.stats()
     assert st["launches"] == st["ok"] == 2 and st["begun_in_flight"] == 0

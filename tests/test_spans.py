@@ -1222,10 +1222,14 @@ def test_lane_workers_make_no_timing_shards(mesh_env):
     tm = plugin.raw_timings
     assert len(tm._shards) == 1  # the one ingest thread
     assert tm["device_records"] == 1600 and tm["kernel_s"] > 0
-    # the nine keys the benchmark and the smoke read, and no other
+    # the keys the benchmark and the smoke read, and no other
     assert set(tm) == {"extract_s", "kernel_s", "compact_s", "records",
                        "device_records", "overflow_rows", "h2d_bytes",
-                       "d2h_bytes", "scan_elements"}
+                       "d2h_bytes", "scan_elements", "mesh_launches",
+                       "mesh_devices", "unsharded_launches"}
+    # the layout of each launch, counted on the dispatching thread
+    assert tm["mesh_launches"] == 200 and tm["unsharded_launches"] == 0
+    assert tm["mesh_devices"] == 200 * plugin._mesh.devices.size
     # the mesh's verdict is copied out as i32: four bytes a rule and row
     assert tm["d2h_bytes"] >= 4 * 1600 and tm["d2h_bytes"] % (4 * 200) == 0
     assert tm["scan_elements"] >= 1600 * (512 // plugin._program.k + 1)

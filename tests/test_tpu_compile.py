@@ -23,6 +23,7 @@ Rules this file keeps (each one has cost a whole tier-1 run somewhere):
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -327,6 +328,44 @@ def test_grep_mesh_program_compiles_for_four_chips(mesh4, with_counts,
     # the donated lengths shard aliases the i32 verdict shard
     assert compiled.memory_analysis().alias_size_in_bytes \
         == SEGMENT // 4 * 4
+
+
+def _while_body(hlo: str) -> str:
+    """The text of the computation the module's one ``while`` runs."""
+    name = re.search(r"while\([^\n]*body=%?([\w.\-]+)", hlo).group(1)
+    start = hlo.index(f"\n%{name} (")
+    return hlo[start:hlo.index("\n}\n", start)]
+
+
+@pytest.mark.parametrize("which", ["apache2-S10-k5", "tenants-k5x1"])
+def test_one_rule_mesh_child_lays_its_table_out_once(mesh4, which):
+    """A one-rule child's ``[1, N]`` table is an argument of the mesh
+    program, and XLA made it 1-D inside the scan's ``while`` — every
+    step, ``reduce.2 s32[N]``: 5.5 of the 7.7 ms of an apache2 launch a
+    chip, 3.2 ms of a tenants launch (PERF.md, PRs 33 and 38).
+    ``_match_impl`` hands the scan the 1-D table, made once a launch:
+    no table-sized op is left in the loop's body."""
+    mesh = mesh4("batch")
+    if which == "apache2-S10-k5":
+        child = _program(SMALL, "scan")
+    else:
+        patterns = _tenants_patterns()
+        child = GrepProgram([compile_dfa(p) for p in patterns], 512,
+                            plane_of=(0,) * len(patterns))._children[-1]
+    assert len(child.dfas) == 1 and child.k == 5
+    child.kernel_resolved, child._tbl = "scan", child._np  # names+shapes
+    fn, tsh, sh_b, sh_l, variant, _donate = child._mesh_program(
+        mesh, "auto", False)
+    assert variant == "batch"
+    tables = {k: sds(v.shape, v.dtype, tsh[k])
+              for k, v in child._np.items()}
+    hlo = fn.lower(tables, sds((1, SEGMENT, 512), jnp.uint8, sh_b),
+                   sds((1, SEGMENT), jnp.int32, sh_l)).compile().as_text()
+    n = child._np["trans_flat"].shape[1]
+    body = _while_body(hlo)
+    assert f"s32[{n}]" in hlo                 # the 1-D table exists,
+    assert " reduce(" not in body             # and is made outside
+    assert " copy(" not in body
 
 
 @pytest.mark.parametrize("sketch", ["hll-pmax", "cms-psum"])
