@@ -35,7 +35,9 @@ fallback that reports success.
     python chip_smoke.py --rules-sweep CONF MAKER 1,20,50
                                     # no phase: the compiled match
                                     # programs alone for the first R
-                                    # grep rules of CONF (rules_sweep)
+                                    # grep (or rewrite_tag) rules of
+                                    # CONF, and a frame in two groups
+                                    # against the whole (rules_sweep)
 
 Every line of stdout is one JSON object; the LAST line is
 ``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}``
@@ -482,6 +484,18 @@ def grep_phase(dev: dict, n_records: int, mesh_sample: bool) -> None:
             "the filter kept everything or nothing")
 
 
+def median_ms(fn) -> float:
+    """The median wall time of ``fn()`` (which ends in a forced
+    result), ms: 7 calls, or 3 once they have taken 2 s (assoc at
+    S=690)."""
+    times = []
+    while len(times) < 7 and (len(times) < 3 or sum(times) < 2e3):
+        t0 = time.perf_counter()
+        fn()
+        times.append((time.perf_counter() - t0) * 1e3)
+    return round(sorted(times)[len(times) // 2], 3)
+
+
 def grep_launch_probe(prog, batches=(SEGMENT,), kernels=("scan", "assoc"),
                       stage=None) -> None:
     """Where one segment's launch spends its time, per child, kernel,
@@ -502,15 +516,6 @@ def grep_launch_probe(prog, batches=(SEGMENT,), kernels=("scan", "assoc"),
     import numpy as np
 
     from fluentbit_tpu.ops.grep import GrepProgram
-
-    def median_ms(fn):
-        # 7 calls, or 3 once they have taken 2 s (assoc at S=690)
-        times = []
-        while len(times) < 7 and (len(times) < 3 or sum(times) < 2e3):
-            t0 = time.perf_counter()
-            fn()
-            times.append((time.perf_counter() - t0) * 1e3)
-        return round(sorted(times)[len(times) // 2], 3)
 
     rng = np.random.default_rng(SEED)
 
@@ -561,15 +566,18 @@ def grep_launch_probe(prog, batches=(SEGMENT,), kernels=("scan", "assoc"),
 
 def rules_sweep(conf: str, maker: str, sizes) -> None:
     """The compiled match programs alone along a rule axis: for each
-    ``R`` of ``sizes`` the first ``R`` grep rules of pipeline file
-    ``conf`` as the filter builds them (``program_for`` with the
-    filter's ``plane_of``: per-stride children under
-    ``FBTPU_MESH_RULE_SHARD_R``), probed child by child on the scan
-    kernel (``grep_launch_probe``) over ``SEGMENT`` records of the
-    benchmark's corpus maker ``maker`` staged at L=256 and L=512 (a
-    longer value is an overflow row), and the whole program's verdicts
-    held to Python's ``re`` on the same rows. How a launch's device
-    time grows with the list, without the pipeline around it."""
+    ``R`` of ``sizes`` the first ``R`` rules of pipeline file ``conf``'s
+    grep filter — or of its ``rewrite_tag`` filter, where it has no
+    grep — as the filter builds them (``program_for`` with the filter's
+    ``plane_of``: per-stride children under ``FBTPU_MESH_RULE_SHARD_R``),
+    probed child by child on the scan kernel (``grep_launch_probe``)
+    over 256, 1,024 and ``SEGMENT`` records of the benchmark's corpus
+    maker ``maker`` staged at L=256 and L=512 (a longer value is an
+    overflow row), the whole program's verdicts held to Python's ``re``
+    on the same rows, and a frame staged in two groups against the
+    frame staged whole (``two_group_probe``). How a launch's device
+    time grows with the list, the rows and the width, without the
+    pipeline around it."""
     import importlib.util
     import re
 
@@ -580,14 +588,20 @@ def rules_sweep(conf: str, maker: str, sizes) -> None:
     from fluentbit_tpu.ops.grep import program_for
     from fluentbit_tpu.plugins.filter_grep import (parse_grep_rules,
                                                    plane_index)
+    from fluentbit_tpu.plugins.filter_rewrite_tag import RewriteRule
 
-    section = next(sec for sec in load_config_file(conf).sections
-                   if sec.name == "filter"
-                   and sec.get("name", "").lower() == "grep")
-    props = Properties()
-    for k, v in section.properties:
-        props.set(k, v)
-    rules = parse_grep_rules(props)
+    filters = {sec.get("name", "").lower(): sec
+               for sec in load_config_file(conf).sections
+               if sec.name == "filter"}
+    section = filters.get("grep") or filters["rewrite_tag"]
+    if section is filters.get("grep"):
+        props = Properties()
+        for k, v in section.properties:
+            props.set(k, v)
+        rules = parse_grep_rules(props)
+    else:
+        rules = [RewriteRule(*v.split(None, 3))
+                 for k, v in section.properties if k.lower() == "rule"]
     max_len = int(section.get("tpu_max_record_len", 512))
     accessors, plane_of = plane_index(rules)
     require(len(accessors) == 1 and not accessors[0].parts,
@@ -610,7 +624,7 @@ def rules_sweep(conf: str, maker: str, sizes) -> None:
         return planes, lengths
 
     for R in sizes:
-        patterns = tuple(r.pattern for r in rules[:R])
+        patterns = tuple(r.regex.pattern for r in rules[:R])
         prog = program_for(patterns, max_len, plane_of=plane_of[:R])
         require(prog.try_ready(), f"the {R}-rule program did not attach")
         children = prog._children or [prog]
@@ -622,7 +636,9 @@ def rules_sweep(conf: str, maker: str, sizes) -> None:
                       for c in children],
             elements_256=prog.scan_elements(SEGMENT, 256),
             elements_512=prog.scan_elements(SEGMENT, 512))
-        grep_launch_probe(prog, kernels=("scan",), stage=stage)
+        grep_launch_probe(prog, batches=(256, 1024, SEGMENT),
+                          kernels=("scan",), stage=stage)
+        two_group_probe(prog, patterns, values)
         for L in (256, 512):
             planes, lengths = stage(1, SEGMENT, L)
             got = np.asarray(prog.match(planes, lengths))
@@ -635,6 +651,74 @@ def rules_sweep(conf: str, maker: str, sizes) -> None:
             say(stage="rules_sweep:verdicts", rules=R, L=L, equal=True,
                 matches=int(got.sum()),
                 rows_matched=int(got.any(axis=0).sum()))
+
+
+def two_group_probe(prog, patterns, values, batches=(1024, SEGMENT),
+                    longs=(1, 100, 256)) -> None:
+    """A frame staged in two groups against the frame staged whole, on
+    the program alone (``GrepProgram._enqueue`` over planes that are on
+    the device, every child and the merge, forced): ``B`` rows of
+    ``values`` of at most 256 B with ``n_long`` rows of 257-500 B (corpus
+    values joined) among them, as ``filter_grep.staged_match`` would
+    send them — whole at L=512; the main group at L=256 with the long
+    rows as rows without a value, and they as a 256-row group at L=512
+    with their row indices, in one module a child — and beside the two
+    the main group alone and the long group alone. Medians of 7 on the
+    host's clock; the two verdicts held to each other and to ``re``.
+    The numbers beside ``filter_grep._LONG_SHARE``."""
+    import re
+
+    import jax
+    import numpy as np
+
+    from fluentbit_tpu.plugins.filter_grep import LongGroup
+
+    def forced_ms(*args):
+        return median_ms(lambda: prog._enqueue(*args).block_until_ready())
+
+    short = [v for v in values if len(v) <= 256]
+    joined = b" ".join(values)
+    put = jax.device_put
+    for B in batches:
+        for n_long in longs:
+            rows = [short[i % len(short)] for i in range(B)]
+            at = np.linspace(0, B - 1, n_long).astype(np.int32)
+            for j, i in enumerate(at):
+                cut = 257 + (j * 61) % 244
+                rows[i] = joined[j * 97:j * 97 + cut]
+            whole = np.zeros((1, B, 512), dtype=np.uint8)
+            lengths = np.zeros((1, B), dtype=np.int32)
+            for i, v in enumerate(rows):
+                whole[0, i, :len(v)] = np.frombuffer(v, dtype=np.uint8)
+                lengths[0, i] = len(v)
+            group = LongGroup.of([(whole[0], lengths[0])], at, 512, B)
+            main_len = lengths.copy()
+            main_len[0, at] = -1
+            d_whole = put(whole), put(lengths)
+            d_main = put(np.ascontiguousarray(whole[:, :, :256])), \
+                put(main_len)
+            d_long = tuple(put(a) for a in group[:3])
+            got_whole = np.asarray(prog._enqueue(*d_whole))
+            got_two = np.asarray(prog._enqueue(*d_main, d_long))
+            want = np.array([[re.search(p, v.decode()) is not None
+                              for v in rows] for p in patterns])
+            require(np.array_equal(got_two, got_whole)
+                    and np.array_equal(got_two, want),
+                    f"two groups, whole and re differ at B={B}, "
+                    f"{n_long} long rows")
+            whole_ms = forced_ms(*d_whole)
+            two_ms = forced_ms(*d_main, d_long)
+            say(stage="rules_sweep:two_groups", rules=len(patterns),
+                rows=B, long_rows=n_long, equal=True,
+                long_matches=int(got_two[:, at].sum()),
+                elements_whole=prog.scan_elements(B, 512),
+                elements_two=prog.scan_elements(B, 256)
+                + prog.scan_elements(*group.planes.shape[1:]),
+                whole_512_ms=whole_ms, two_groups_ms=two_ms,
+                main_256_alone_ms=forced_ms(*d_main),
+                long_group_alone_ms=forced_ms(*d_long[:2]),
+                two_over_whole=round(two_ms / whole_ms, 3),
+                note="smoke observation, one run, not a benchmark")
 
 
 def grep_mesh_vs_one_program(plugin, payload: str) -> None:
@@ -898,10 +982,11 @@ def main(argv=None) -> int:
     ap.add_argument("--rules-sweep", nargs=3, default=None,
                     metavar=("CONF", "MAKER", "SIZES"),
                     help="instead of the phases: the compiled match "
-                         "programs alone for the first R grep rules of "
-                         "pipeline file CONF, R in SIZES (1,20,50), over "
-                         "records of the benchmark's corpus maker MAKER "
-                         "(rules_sweep)")
+                         "programs alone for the first R grep (or "
+                         "rewrite_tag) rules of pipeline file CONF, R in "
+                         "SIZES (1,20,50), over records of the "
+                         "benchmark's corpus maker MAKER, and a frame in "
+                         "two groups against the whole (rules_sweep)")
     args = ap.parse_args(argv)
     dev = None
     try:

@@ -365,9 +365,20 @@ class GrepProgram:
             def impl(planes, lengths):
                 return kern(tbl, *self._gather_planes(planes, lengths))
 
-            impl.__name__ = self.program_name()
+            def impl_long(planes, lengths, lplanes, llengths, rows):
+                # the frame in two groups (dispatch's ``long``): both
+                # scans in this one module, over the one table; the
+                # long rows' verdicts land in their columns (a pad
+                # index lies past the mask and is dropped)
+                return impl(planes, lengths).at[:, rows].set(
+                    impl(lplanes, llengths), mode="drop")
+
+            # one name for both: a launch is one module a child whichever
+            # ran, which is what the trace's readers sum by
+            impl.__name__ = impl_long.__name__ = self.program_name()
             self._impl = impl
-            self._jit = jax.jit(impl)
+            self._jit_long = jax.jit(impl_long)
+            self._jit = jax.jit(impl)  # last: what try_ready() reads
             self._np = None  # tables now live on device; free host copy
             # the shrink/unlock audit line: S/C before→after, chosen
             # stride, resolved kernel
@@ -549,7 +560,7 @@ class GrepProgram:
             self._materialize()
 
     def dispatch(self, planes: np.ndarray, lengths: np.ndarray,
-                 first_match: bool = False):
+                 first_match: bool = False, long=None):
         """Launch the kernel WITHOUT forcing the result (jax dispatch
         is asynchronous) — the launch half of the double-buffered
         staging pipeline (core.chunk_batch.double_buffered): the caller
@@ -559,26 +570,43 @@ class GrepProgram:
         ``planes[K, B, L]`` / ``lengths[K, B]`` are the distinct staged
         fields; they cross to the device ONCE, whatever the number of
         rules or per-k children that read them. → ``mask[R, B]`` bool,
-        or with ``first_match`` the ``[B]`` i32 first-match vector."""
+        or with ``first_match`` the ``[B]`` i32 first-match vector.
+
+        ``long``: the frame's few long rows as a narrow group of their
+        own — ``(planes[K, Bl, Ll], lengths[K, Bl], rows[Bl])``, the
+        same layout at a wider ``Ll``, ``rows`` each one's row among the
+        ``B`` (a pad row's lies past them) — so that ``L`` is the width
+        the rest needs and not the longest row's: the scan takes
+        ``⌈L/k⌉ + 1`` dependent steps over every row it is given. Each
+        child then runs ONE module that scans both groups over the one
+        table and writes the long rows' verdicts over their columns of
+        the mask (whatever the main group said of them: the caller
+        stages them there as rows without a value), so what comes back
+        has the shape and the meaning it has without."""
         # the copy-in and the enqueue apart: two spans inside the
         # caller's grep.dispatch, once a launch whatever the children
         with span("grep.put"):
             planes, lengths = jnp.asarray(planes), jnp.asarray(lengths)
+            if long is not None:
+                long = tuple(jnp.asarray(a) for a in long)
         n = len(self._children) if self._children is not None else 1
         with span("grep.call", children=n):
-            mask = self._enqueue(planes, lengths)
+            mask = self._enqueue(planes, lengths, long)
             return first_match_of(mask) if first_match else mask
 
-    def _enqueue(self, planes, lengths):
+    def _enqueue(self, planes, lengths, long=None):
         """The jitted calls over planes that are on the device."""
         if self._children is not None:
             # per-k child programs: every child launches (async) before
             # the merge touches any result, so the k-groups overlap the
             # same way double-buffered segments do
             return self._merge_rule_axis(
-                [c._enqueue(planes, lengths) for c in self._children])
+                [c._enqueue(planes, lengths, long)
+                 for c in self._children])
         self._ensure_materialized()
-        return self._jit(planes, lengths)
+        if long is None:
+            return self._jit(planes, lengths)
+        return self._jit_long(planes, lengths, *long)
 
     def match(self, planes: np.ndarray, lengths: np.ndarray,
               first_match: bool = False) -> np.ndarray:

@@ -46,6 +46,23 @@ LEGACY, AND, OR = "legacy", "AND", "OR"
 
 _LEN_BUCKETS = (64, 128, 256, 512, 1024, 2048, 4096)
 
+#: rows of a frame's long group (``staged_match``): ``bucket_size``'s
+#: smallest rung, one shape whether a frame has 1 long row or 256
+_LONG_ROWS = 256
+#: a frame is staged in two groups only where the long group is at most
+#: this share of its padded rows (``Bp >= 4,096``). The device alone,
+#: ``chip_smoke.py --rules-sweep`` on a v5e (PERF.md, PR 39), two groups
+#: (``Bp`` rows at L=256 + 256 at L=512, one module a child) over the
+#: whole frame at L=512: at 4,096 rows 0.48 (R=50), 0.57 (R=20), 0.61
+#: (R=8), 0.64-0.76 (R=1), the same for 1, 100 or 256 long rows; at
+#: 1,024 rows 0.76-0.86 — a step's cost does not fall as fast as its
+#: rows (a 256-row scan costs 0.25-0.3 of a 4,096-row one at R <= 8) —
+#: which saves 0.2 ms (R=1) to 1.7 ms (R=8) of device time a frame there
+#: where a second group costs the host ~3 ms (three more arrays to stage
+#: and put, a two-scan program to enqueue: spans of a traced run): the
+#: rule asks for the rows at which the device's saving is the larger
+_LONG_SHARE = 16
+
 
 class _RawDecline(Exception):
     """Internal: a staging stage inside the pipelined raw path cannot
@@ -66,11 +83,15 @@ class _RawDecline(Exception):
 #: launch is dispatched: ``mesh_launches`` went out sharded, over
 #: ``mesh_devices`` devices in all (so the ratio is devices a launch),
 #: ``unsharded_launches`` found the lane's mesh gone and went out on
-#: one device
+#: one device. ``split_launches`` counts the segments launched in two
+#: groups (the few long rows apart, the rest at the width they need)
+#: and ``long_rows`` the rows sent in the long group; the three before
+#: them count both groups
 _TIMING_KEYS = ("extract_s", "kernel_s", "compact_s", "records",
                 "device_records", "overflow_rows", "h2d_bytes",
                 "d2h_bytes", "scan_elements", "mesh_launches",
-                "mesh_devices", "unsharded_launches")
+                "mesh_devices", "unsharded_launches", "split_launches",
+                "long_rows")
 
 
 def _len_bucket(n: int, cap: int) -> int:
@@ -248,6 +269,77 @@ def host_spans(regex, n_groups: int, plane: np.ndarray,
     return ok, spans
 
 
+class LongGroup(NamedTuple):
+    """A segment's few long rows, staged apart (``staged_match``): the
+    layout of the segment's own planes again, ``_LONG_ROWS`` rows at the
+    width the longest needs — ``planes[K, 256, L]`` u8 and
+    ``lengths[K, 256]`` i32 (every key of the row, the short ones too,
+    with its true length; a pad row -1) — and ``rows[256]`` i32, each
+    one's row in the segment (a pad row's lies past the mask and is
+    dropped); ``n`` rows are real. The first three are what
+    ``GrepProgram.dispatch`` takes as ``long``."""
+
+    planes: np.ndarray
+    lengths: np.ndarray
+    rows: np.ndarray
+    n: int
+
+    @classmethod
+    def of(cls, staged, rows: np.ndarray, L: int, Bp: int) -> "LongGroup":
+        """Rows ``rows`` of ``staged`` — a ``(values u8[cnt, >=L],
+        lengths i32[cnt])`` a key — at width ``L``, for a main group of
+        ``Bp`` rows (where a pad row's index points)."""
+        n, K = len(rows), len(staged)
+        group = cls(np.zeros((K, _LONG_ROWS, L), dtype=np.uint8),
+                    np.full((K, _LONG_ROWS), -1, dtype=np.int32),
+                    np.full((_LONG_ROWS,), Bp, dtype=np.int32), n)
+        for k, (values, lengths) in enumerate(staged):
+            group.planes[k, :n] = values[rows, :L]
+            group.lengths[k, :n] = lengths[rows]
+        group.rows[:n] = rows
+        return group
+
+
+def long_split(longest: np.ndarray, n_rows: int, max_len: int):
+    """The widths a segment stages at, and the split rule, from what the
+    stage sees alone: ``longest[cnt]`` (a row's longest staged value
+    over the keys; negative where none was staged), ``n_rows`` (the rows
+    it pads for) and the cap ``max_len``. → ``(L, split)``: ``L`` the
+    bucket of the longest value — the width the whole segment needs —
+    and ``split`` either None or ``(L_lo, rows, Bp)``: the bucket of the
+    value that only ``_LONG_ROWS`` rows exceed, i.e. the smallest one
+    that all but at most ``_LONG_ROWS`` rows fit, those rows' indices,
+    and the padded rows of the main group. None where that bucket is
+    ``L`` itself, or where the long group would be more than one
+    ``_LONG_SHARE``-th of the padded rows (the sweep beside
+    ``_LONG_SHARE``).
+
+    Both values come from ONE selection pass where a plain maximum was
+    taken before: numpy drops the GIL in every pass over more than a few
+    hundred elements, and with four or five busy threads beside it a
+    frame pays for each pass by waiting to take it back (six passes more
+    read as 0.7 ms a frame of staging on the chip's host where they cost
+    0.07 alone; PERF.md, PR 39)."""
+    from ..ops.batch import bucket_size
+
+    cnt = len(longest)
+    if cnt <= _LONG_ROWS \
+            or bucket_size(n_rows) < _LONG_SHARE * _LONG_ROWS:
+        # (no width pads a segment to more rows than bucket_size's rung)
+        top = int(longest.max()) if cnt else 0
+        return _len_bucket(max(top, 1), max_len), None
+    kth = cnt - 1 - _LONG_ROWS  # in order: the longest of the rest
+    part = np.partition(longest, (kth, cnt - 1))
+    L = _len_bucket(max(int(part[-1]), 1), max_len)
+    lo = _len_bucket(max(int(part[kth]), 1), max_len)
+    if lo == L:
+        return L, None
+    Bp = bucket_size(n_rows, max_len=lo)
+    if Bp < _LONG_SHARE * _LONG_ROWS:
+        return L, None
+    return L, (lo, np.flatnonzero(longest > lo), Bp)
+
+
 def decoded_match(rules, program, lane, events: list,
                   max_len: int) -> np.ndarray:
     """The decoded path's launch: stage each distinct field of
@@ -366,6 +458,25 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
     host fallback); rows longer than ``max_len`` (-2) are decided on
     the host after the launch.
 
+    **A segment's few long rows go as a group of their own.** The scan
+    takes ``⌈L/k⌉ + 1`` dependent steps over every row it is given, so
+    one 400-byte line among 4,096 of 200 cost the whole segment twice
+    the steps. Where all but at most ``_LONG_ROWS`` (256) rows fit a
+    narrower bucket and the segment pads to at least ``_LONG_SHARE``
+    (16) times as many rows (:func:`long_split`: what the stage sees,
+    nothing else), the planes are staged at that bucket, the long rows
+    again as a :class:`LongGroup` at the bucket the longest needs, and
+    the ONE launch scans both (``GrepProgram.dispatch``'s ``long``): on
+    the device the main group holds the long rows as rows without a
+    value and their verdicts are written over theirs, so the verdict,
+    its shape, the copy-out and everything after it are what they are
+    for a segment staged whole; the fallback is ``host_mask`` over both
+    groups, joined the same way. ``split_launches`` and ``long_rows``
+    count how often and how many; ``h2d_bytes`` and ``scan_elements``
+    count both groups; ``grep.stage`` and ``grep.dispatch`` carry ``L``
+    and, of such a segment, ``L_long`` and ``long``. A span program
+    (``spans``) takes its plane whole, and so does the mesh.
+
     With ``mesh`` set, each segment launches through the explicitly
     partitioned pjit matcher instead: the batch axis is padded to the
     mesh size and sharded across devices at ONE jit-stable width, and
@@ -461,7 +572,9 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
     if begin and multi:
         return None  # the segments overlap each other: double_buffered
     extract_s = [0.0]
-    sent = [0, 0]  # h2d bytes, gathered elements: counted at the finish
+    # h2d bytes, gathered elements, segments launched in two groups,
+    # rows in the long groups: counted at the finish
+    sent = [0, 0, 0, 0]
     lens_parts: list = []
     cnts: list = []
     plane_parts: list = []  # spans: the staged rows the offsets cut
@@ -502,9 +615,8 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
                 for k in range(K):
                     stage_key(part, keys[k], batch[k], lengths[k], cnt)
                 extract_s[0] += _time.perf_counter() - t0
-                return batch, lengths, cnt
+                return batch, lengths, cnt, lengths[:, :cnt], None
             staged = []
-            max_staged = 1
             for k in range(K):
                 # stage straight into a caller-owned [cnt, max_len]
                 # matrix: no arena round-trip, ONE copy per key (the
@@ -513,50 +625,82 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
                 wlen = np.full((cnt,), -1, dtype=np.int32)
                 stage_key(part, keys[k], wide, wlen, cnt)
                 staged.append((wide, wlen))
-                mx = int(wlen[:cnt].max()) if cnt else 0
-                max_staged = max(max_staged, mx)
+            longest = staged[0][1] if K == 1 else np.maximum.reduce(
+                [wlen for _wide, wlen in staged])
             # scan-length bucketing: the DFA scan is sequential in
             # L, so clamp to the longest staged value (rounded to a
-            # small bucket set for jit shape stability)
-            L = _len_bucket(max_staged, max_len)
-            # segment-uniform batch shape: one compile covers every
-            # full segment of the chunk stream
-            Bp = bucket_size(seg if multi else cnt, max_len=L)
-            batch = np.zeros((K, Bp, L), dtype=np.uint8)
+            # small bucket set for jit shape stability) — and where a
+            # few long rows alone ask for that width, they go as a
+            # narrow group of their own (LongGroup) and the rest at the
+            # width it needs. Segment-uniform batch shape: one compile
+            # covers every full segment of the chunk stream. The span
+            # program takes one plane whole (its verdict carries the
+            # staged rows)
+            n_rows = seg if multi else cnt
+            L, split = long_split(longest, n_rows, max_len)
+            if spans:
+                split = None
+            if split is None:
+                width, Bp = L, bucket_size(n_rows, max_len=L)
+            else:
+                width, long_idx, Bp = split
+            batch = np.zeros((K, Bp, width), dtype=np.uint8)
             lengths = np.full((K, Bp), -1, dtype=np.int32)
             for k, (b, ln) in enumerate(staged):
-                batch[k, :cnt] = b[:cnt, :L]
+                batch[k, :cnt] = b[:cnt, :width]
                 lengths[k, :cnt] = ln[:cnt]
+            # the true lengths: what finish() reads (overflow rows)
+            host_lens, long = lengths[:, :cnt], None
+            if split is not None:
+                long = LongGroup.of(staged, long_idx, L, Bp)
+                # on the device the main group holds them as rows
+                # without a value: they scan as empty, and the long
+                # group's verdict is written over theirs
+                lengths = lengths.copy()
+                lengths[:, long_idx] = -1
             extract_s[0] += _time.perf_counter() - t0
-            return batch, lengths, cnt
+            return batch, lengths, cnt, host_lens, long
 
         for si, (s, e) in enumerate(bounds):
-            with span("grep.stage", seg=si):
+            with span("grep.stage", seg=si) as sp:
                 item = stage(s, e)
+                sp.set_metadata(**widths(item[0], item[4]))
             yield item + (si,)
 
-    def launch_ids(b) -> dict:
-        return {"rules": len(rules), "planes": K, "L": b.shape[2]}
+    def widths(b, long) -> dict:
+        ids = {"L": b.shape[2]}
+        if long is not None:
+            ids.update(L_long=long.planes.shape[2], long=long.n)
+        return ids
 
-    def forced(b, ln):
+    def launch_ids(b, long=None) -> dict:
+        return {"rules": len(rules), "planes": K, **widths(b, long)}
+
+    def forced(b, ln, long=None):
         # enqueue + argument copy-in, then the wait for the
         # execution and the copy-out
-        with span("grep.dispatch", **launch_ids(b)):
+        with span("grep.dispatch", **launch_ids(b, long)):
             out = program.dispatch(b, ln) if spans else \
-                program.dispatch(b, ln, first_match=first_match)
+                program.dispatch(b, ln, first_match=first_match,
+                                 long=long and long[:3])
         with span("grep.force"):
             if spans:
                 return tuple(np.asarray(o) for o in out)
             return np.asarray(out)
 
     def dispatch(item):
-        batch, lengths, cnt, si = item
-        lens_parts.append(lengths[:, :cnt])
+        batch, lengths, cnt, host_lens, long, si = item
+        lens_parts.append(host_lens)
         cnts.append(cnt)
         if spans:
             plane_parts.append(batch[0, :cnt])
         sent[0] += batch.nbytes + lengths.nbytes
         sent[1] += program.scan_elements(batch.shape[1], batch.shape[2])
+        if long is not None:
+            sent[0] += sum(a.nbytes for a in long[:3])
+            sent[1] += program.scan_elements(*long.planes.shape[1:])
+            sent[2] += 1
+            sent[3] += long.n
         if mesh is not None:
             # sharded launch through the device fault domain: the
             # launch closure re-stages (fresh device_put + donation)
@@ -590,14 +734,17 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
                 with span("grep.force"):
                     return np.asarray(out)  # the mask as i32, as copied
         else:
-            def launch(b=batch, ln=lengths):
-                return forced(b, ln)
+            def launch(b=batch, ln=lengths, lg=long):
+                return forced(b, ln, lg)
 
-        def fallback(b=batch, ln=lengths, c=cnt):
+        def fallback(b=batch, ln=lengths, c=cnt, lg=long):
             if spans:
                 return host_spans(rules[0].regex, len(program.names),
                                   b[0], ln[0], c)
             mask = host_mask(rules, plane_of, b, ln, c)
+            if lg is not None:  # both groups, joined as on the device
+                mask[:, lg.rows[:lg.n]] = host_mask(
+                    rules, plane_of, lg.planes, lg.lengths, lg.n)
             return first_of_mask(mask) if first_match else mask
 
         with bind(seg=si):
@@ -626,6 +773,9 @@ def staged_match(rules, program, lane, tm, data, n_records, *,
                max(wall - (extract_s[0] - staged_before), 0.0))
         tm.add("h2d_bytes", sent[0])
         tm.add("scan_elements", sent[1])
+        if not spans:
+            tm.add("split_launches", sent[2])
+            tm.add("long_rows", sent[3])
         offsets = offs_box[0]
         lengths = np.concatenate(lens_parts, axis=1)
         overflow_rows = np.unique(np.nonzero(lengths == -2)[1])

@@ -61,8 +61,8 @@ def _to_text(v) -> Optional[str]:
 
 #: ``raw_timings`` keys of the batched path. ``extract_s``,
 #: ``kernel_s``, ``h2d_bytes``, ``d2h_bytes``, ``scan_elements``,
-#: ``device_records`` and ``overflow_rows``
-#: are the staged launch's (``filter_grep.staged_match``: the device
+#: ``device_records``, ``overflow_rows``, ``split_launches`` and
+#: ``long_rows`` are the staged launch's (``filter_grep.staged_match``: the device
 #: lane only); ``records`` counts every record ``process_batch``
 #: served; ``emit_s`` is the time from the verdict to the last emitter
 #: append (grouping, ``native.compact``, ``add_record`` and the
@@ -70,6 +70,7 @@ def _to_text(v) -> Optional[str]:
 #: ``emit_backpressure`` those the emitter refused (originals kept)
 _TIMING_KEYS = ("extract_s", "kernel_s", "h2d_bytes", "d2h_bytes",
                 "scan_elements", "device_records", "overflow_rows",
+                "split_launches", "long_rows",
                 "records", "emit_s", "emits", "emit_backpressure")
 
 

@@ -347,3 +347,254 @@ def test_a_handle_made_for_other_bytes_or_rules_is_dropped():
         st = lane.stats()
         assert st["launches"] == before + 1 == st["ok"]  # staged anew
     assert tm["device_records"] == 3 * n
+
+
+# ------------------- a frame's few long rows as a group of their own
+
+_SPLIT_RULES = [("exclude", "log 404$"), ("regex", "log ^(GET|POST)"),
+                ("exclude", "log /path/7")]
+_TWO_KEY_RULES = _SPLIT_RULES + [("exclude", "stream stderr$")]
+
+#: ``rows`` records a frame with ``long`` of them 300-500 B (the verdict
+#: of each hangs on its last bytes), ``overflow`` past
+#: ``tpu_max_record_len`` among the long ones, ``mids`` of 70-120 B;
+#: ``split``: how many of its segments go out in two groups, and at
+#: which widths
+_SPLIT_CASES = {
+    "no_long_row": dict(rows=4096, long=0, split=0, widths=(64,)),
+    "one_long_row": dict(rows=4096, long=1, split=1, widths=(64, 512)),
+    "a_full_group": dict(rows=4096, long=256, split=1, widths=(64, 512)),
+    "one_row_too_many": dict(rows=4096, long=257, split=0, widths=(512,)),
+    "a_frame_of_1024_rows": dict(rows=1024, long=3, split=0,
+                                 widths=(512,)),
+    "overflow_rows_among_them": dict(rows=4096, long=9, overflow=4,
+                                     split=1, widths=(64, 512)),
+    "the_rest_at_the_width_it_needs": dict(rows=4096, long=5, mids=300,
+                                           split=1, widths=(128, 512)),
+    "long_in_one_key_only": dict(rows=4096, long=6, overflow=2,
+                                 two_keys=True, split=1,
+                                 widths=(64, 512)),
+    "two_segments": dict(rows=4096 + 600, long=12, split=2,
+                         widths=(64, 512)),
+}
+
+
+def _split_frame(rows, long=0, overflow=0, mids=0, two_keys=False, **_):
+    """→ ``(data, bodies)``: the long, overflow and mid rows spread over
+    the frame (and over both segments of a frame of two)."""
+    rng = random.Random(rows + long)
+    bodies = []
+    for i in range(rows):
+        method = rng.choice(["GET", "POST", "PUT", "DELETE"])
+        code = rng.choice(["200", "404", "500"])
+        body = {"log": f"{method} /path/{i % 97} HTTP/1.1 {code}"}
+        if two_keys:
+            body["stream"] = rng.choice(["stdout", "stderr"])
+        if rng.random() < 0.05:
+            body.pop("log")  # a row without a value
+        bodies.append(body)
+    special = rng.sample(range(rows), long + overflow + mids)
+    if rows > 4096:  # some in the second segment too
+        special[0], special[1] = 4100, rows - 1
+    for j, i in enumerate(special):
+        code = rng.choice(["200", "404"])
+        if j < long:
+            fill = "p" * rng.randrange(280, 470)
+            key = "stream" if two_keys and j % 2 else "log"
+        elif j < long + overflow:
+            fill, key = "p" * rng.randrange(600, 900), "log"
+        else:
+            fill, key = "p" * rng.randrange(50, 90), "log"
+        bodies[i][key] = f"GET /{fill} {code}" if key == "log" \
+            else f"{fill} std{'err' if code == '404' else 'out'}"
+        if two_keys and j == 2:
+            # long in one key, past the limit in the other
+            bodies[i]["stream"] = "s" * 700 + " stderr"
+    return b"".join(encode_event(b, float(i))
+                    for i, b in enumerate(bodies)), bodies
+
+
+def _split_setup(kind, two_keys=False):
+    from fluentbit_tpu.ops import fault
+
+    f = make_filter((_TWO_KEY_RULES if two_keys else _SPLIT_RULES)
+                    + [("tpu_batch_records", "1")])
+    if f._program is None or not f._program.try_ready():
+        pytest.skip("device program unavailable")
+    how = {"max_len": 512, "min_records": 1,
+           "first_match": kind == "first_match"}
+    return f.rules, f._program, fault.DeviceLane(f"t-split-{kind}"), how
+
+
+def _host_chain(kind, rules, bodies):
+    """The per-record host chain's verdict, a record at a time."""
+    import numpy as np
+
+    from fluentbit_tpu.plugins.filter_grep import first_of_mask
+
+    mask = np.array([[rule.match(b) for b in bodies] for rule in rules])
+    return mask if kind == "mask" else first_of_mask(mask)
+
+
+def _both_groups(program, case, K):
+    """``(h2d_bytes, scan_elements)`` of the case's launches, from
+    their shapes: the planes, lengths and (of a long group) row indices
+    of both groups."""
+    from fluentbit_tpu.ops.batch import bucket_size
+
+    segments = 2 if case["rows"] > 4096 else 1
+    Bp = bucket_size(min(case["rows"], 4096))
+    L = case["widths"][0]
+    h2d, elements = K * Bp * (L + 4), program.scan_elements(Bp, L)
+    if case["split"]:
+        L_long = case["widths"][1]
+        h2d += K * 256 * (L_long + 4) + 256 * 4
+        elements += program.scan_elements(256, L_long)
+    return segments * h2d, segments * elements
+
+
+@pytest.mark.parametrize("kind", ["mask", "first_match"])
+@pytest.mark.parametrize("name", list(_SPLIT_CASES))
+def test_a_frame_in_two_groups_is_the_whole_frame_and_the_host_chain(
+        name, kind, monkeypatch):
+    """``staged_match`` sends a frame's few long rows as a 256-row group
+    of their own and the rest at the width it needs — where they fit
+    one group and the frame pads to 4,096 rows — and the verdict is the
+    whole frame's and the per-record host chain's, bit for bit; the
+    counters count both groups, and a frame is one launch either way."""
+    import numpy as np
+
+    from fluentbit_tpu.core.spans import ShardedTimings
+    from fluentbit_tpu.plugins import filter_grep
+    from fluentbit_tpu.plugins.filter_grep import _TIMING_KEYS, staged_match
+
+    case = _SPLIT_CASES[name]
+    rules, program, lane, how = _split_setup(kind, case.get("two_keys"))
+    data, bodies = _split_frame(**case)
+    n, K = len(bodies), program.n_planes
+    segments = 2 if n > 4096 else 1
+    tm = ShardedTimings(_TIMING_KEYS)
+    got, offsets, n_got = staged_match(rules, program, lane, tm, data, n,
+                                       **how)
+    assert n_got == n and len(offsets) == n + 1
+    st = lane.stats()
+    assert st["launches"] == st["ok"] == segments
+    assert st["fallback_segments"] == 0
+    assert tm["split_launches"] == case["split"]
+    assert tm["long_rows"] == (case["long"] if case["split"] else 0)
+    # (of two keys, one row is long in one and past the limit in the
+    # other: in the long group, and the host's for that key's rule)
+    assert tm["overflow_rows"] == case.get("overflow", 0) \
+        + (1 if case.get("two_keys") else 0)
+    assert (tm["h2d_bytes"], tm["scan_elements"]) == _both_groups(
+        program, case, K)
+    assert tm["device_records"] == n
+
+    # the whole frame, as before this rule: one width, the longest row's
+    monkeypatch.setattr(filter_grep, "_LONG_SHARE", 1 << 30)
+    tm_whole = ShardedTimings(_TIMING_KEYS)
+    whole, _offs, _n = staged_match(rules, program, lane, tm_whole, data,
+                                    n, **how)
+    assert tm_whole["split_launches"] == tm_whole["long_rows"] == 0
+    assert whole.dtype == got.dtype and np.array_equal(whole, got)
+    assert tm_whole["scan_elements"] >= tm["scan_elements"]
+    want = _host_chain(kind, rules, bodies)
+    assert np.array_equal(got, want)
+    # the cases decide something: long rows on both sides of a verdict
+    long_rows = [i for i, b in enumerate(bodies)
+                 if 256 < len(b.get("log", "")) <= 512]
+    if kind == "mask" and len(long_rows) >= 9:
+        assert got[0, long_rows].any() and not got[0, long_rows].all()
+
+
+@pytest.mark.parametrize("kind", ["mask", "first_match"])
+def test_a_failed_launch_of_two_groups_falls_back_bit_exact(kind):
+    """The lane's fallback over a frame in two groups is ``host_mask``
+    over both, joined as on the device: exactly one of the two decides
+    a frame, and they agree."""
+    import numpy as np
+
+    from fluentbit_tpu import failpoints
+    from fluentbit_tpu.core.spans import ShardedTimings
+    from fluentbit_tpu.plugins.filter_grep import _TIMING_KEYS, staged_match
+
+    case = _SPLIT_CASES["overflow_rows_among_them"]
+    rules, program, lane, how = _split_setup(kind)
+    data, bodies = _split_frame(**case)
+    n = len(bodies)
+    tm = ShardedTimings(_TIMING_KEYS)
+    dev, _offs, _n = staged_match(rules, program, lane, tm, data, n, **how)
+    failpoints.enable("device.dispatch", "1*return(split)")
+    try:
+        host, _offs, _n = staged_match(rules, program, lane, tm, data, n,
+                                       **how)
+    finally:
+        failpoints.reset()
+    st = lane.stats()
+    assert st["launches"] == 2 and st["ok"] == 1
+    assert st["fallback_segments"] == 1
+    assert tm["split_launches"] == 2 and tm["long_rows"] == 2 * case["long"]
+    assert host.dtype == dev.dtype and np.array_equal(host, dev)
+    assert np.array_equal(host, _host_chain(kind, rules, bodies))
+
+
+@pytest.mark.parametrize("kind", ["mask", "first_match"])
+def test_a_frame_in_two_groups_begun_ahead_and_a_handle_dropped(kind):
+    """``begin=True`` / ``begun=`` on a frame that goes out in two
+    groups: one flight, the finishing half counts both groups once; a
+    handle that is dropped counts in the lane and nowhere else."""
+    import numpy as np
+
+    from fluentbit_tpu.core.spans import ShardedTimings
+    from fluentbit_tpu.plugins.filter_grep import (_TIMING_KEYS, Begun,
+                                                   staged_match)
+
+    case = _SPLIT_CASES["the_rest_at_the_width_it_needs"]
+    rules, program, lane, how = _split_setup(kind)
+    data, bodies = _split_frame(**case)
+    n = len(bodies)
+    tm_one, tm_two = ShardedTimings(_TIMING_KEYS), ShardedTimings(_TIMING_KEYS)
+    one = staged_match(rules, program, lane, tm_one, data, n, **how)
+    begun = staged_match(rules, program, lane, tm_two, data, n, begin=True,
+                         **how)
+    assert isinstance(begun, Begun)
+    assert all(tm_two[k] == 0 for k in _TIMING_KEYS)
+    two = staged_match(rules, program, lane, tm_two, data, n, begun=begun,
+                       **how)
+    _same_verdict(kind, one, two)
+    assert np.array_equal(two[0], _host_chain(kind, rules, bodies))
+    for key in ("split_launches", "long_rows", "h2d_bytes", "d2h_bytes",
+                "scan_elements", "device_records"):
+        assert tm_one[key] == tm_two[key] > 0, key
+    assert tm_two["split_launches"] == 1 and tm_two["long_rows"] == 5
+    dropped = staged_match(rules, program, lane, tm_two, data, n,
+                           begin=True, **how)
+    dropped.drop()
+    st = lane.stats()
+    assert st["launches"] == st["ok"] == 3 and st["begun_in_flight"] == 0
+    assert tm_two["split_launches"] == 1 and tm_two["device_records"] == n
+
+
+def test_the_mesh_keeps_its_one_width():
+    """Staged for the mesh a frame goes out at ``tpu_max_record_len``
+    whatever its rows, as before: each mesh child places the planes
+    itself, and a second group there is another contract."""
+    import numpy as np
+
+    from fluentbit_tpu.core.spans import ShardedTimings
+    from fluentbit_tpu.plugins.filter_grep import _TIMING_KEYS, staged_match
+
+    case = _SPLIT_CASES["the_rest_at_the_width_it_needs"]
+    rules, program, lane, how = _split_setup("mask")
+    mesh = lane.current_mesh()
+    if mesh is None:
+        pytest.skip("need a multi-device mesh")
+    data, bodies = _split_frame(**case)
+    tm = ShardedTimings(_TIMING_KEYS)
+    got, _offs, n = staged_match(rules, program, lane, tm, data,
+                                 len(bodies), mesh=mesh, **how)
+    assert tm["mesh_launches"] == 1
+    assert tm["split_launches"] == tm["long_rows"] == 0
+    assert tm["h2d_bytes"] == 4096 * (512 + 4)
+    assert tm["scan_elements"] == program.scan_elements(4096, 512)
+    assert np.array_equal(got, _host_chain("mask", rules, bodies))

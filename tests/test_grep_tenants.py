@@ -90,6 +90,32 @@ def gate_open(monkeypatch):
     monkeypatch.setenv("FBTPU_MESH", "off")
 
 
+@pytest.fixture
+def seen(monkeypatch):
+    """``filter_grep``'s spans as ``(name, ids)``, in the order they
+    were opened, ``set_metadata``'s ids among them."""
+    from fluentbit_tpu.plugins import filter_grep
+
+    seen = []
+
+    class Recorded:
+        def __init__(self, name, **ids):
+            self.ids = ids
+            seen.append((name, ids))
+
+        def set_metadata(self, **ids):
+            self.ids.update(ids)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(filter_grep, "span", Recorded)
+    return seen
+
+
 def pick(records, n: int) -> list:
     """``n`` row indices: every line over 256 B first, then the rest in
     order."""
@@ -269,31 +295,57 @@ def test_process_batch_equals_the_host_chain(rows_n, corpus, gate_open):
 
 
 def test_dispatch_and_verdict_spans_say_what_was_launched(corpus, gate_open,
-                                                          monkeypatch):
-    from fluentbit_tpu.plugins import filter_grep
-
-    seen = []
-
-    class Recorded:
-        def __init__(self, name, **ids):
-            seen.append((name, ids))
-
-        def __enter__(self):
-            return self
-
-        def __exit__(self, *exc):
-            return False
-
-    monkeypatch.setattr(filter_grep, "span", Recorded)
+                                                          seen):
     records = corpus[0]
     rows = pick(records, 64)
     data = b"".join(encode_event(records[i], float(i)) for i in rows)
     make_filter().process_batch(RawChunk(data, "kube.tenants", 64))
     got = dict(seen)
+    # 64 rows: too few to send the long ones apart, one width
+    assert got["grep.stage"] == {"seg": 0, "L": 512}
     assert got["grep.dispatch"] == {"rules": 50, "planes": 1, "L": 512}
     assert got["grep.verdict"] == {"rules": 50}
     names = [n for n, _ids in seen]
     assert names.index("grep.force") < names.index("grep.verdict")
+
+
+def test_a_frame_of_4096_lines_sends_its_long_lines_apart(maker, gate_open,
+                                                          seen):
+    """A whole frame of the cell: about a hundred of its 4,096 lines are
+    257-500 B, and they no longer set the width of all of them — the
+    frame stages at L=256 and they at L=512 as a 256-row group of their
+    own, in one launch (20.2 M gathered elements where the whole frame
+    at L=512 was 35.6 M); the bytes are the host chain's."""
+    from fluentbit_tpu.ops import fault
+
+    records, labels = maker.make(4096, 3400000103, {})
+    data = b"".join(encode_event(r, float(i))
+                    for i, r in enumerate(records))
+    long_lines = sum(256 < len(r["log"]) <= 512 for r in records)
+    assert 90 < long_lines < 110
+    dev = make_filter()
+    before = fault.lane("grep").stats()
+    n_keep, out = dev.process_batch(RawChunk(data, "kube.tenants", 4096))
+    after = fault.lane("grep").stats()
+    assert after["launches"] - before["launches"] == 1 \
+        == after["ok"] - before["ok"]
+    host = make_filter([("tpu.enable", "off")])
+    _res, kept = host.filter(decode_events(data), "kube.tenants", None)
+    assert bytes(out) == b"".join(e.raw for e in kept)
+    assert n_keep == len(kept) == sum(lb & 1 for lb in labels)
+
+    tm, prog = dev.raw_timings, dev._program
+    assert tm["split_launches"] == 1 and tm["long_rows"] == long_lines
+    assert tm["overflow_rows"] == 4
+    assert tm["h2d_bytes"] == 4096 * (256 + 4) + 256 * (512 + 4 + 4)
+    assert tm["d2h_bytes"] == 50 * 4096                   # as it was
+    assert prog.scan_elements(4096, 512) == 35631104      # the whole frame
+    assert tm["scan_elements"] == 20224768 \
+        == prog.scan_elements(4096, 256) + prog.scan_elements(256, 512)
+    got = dict(seen)
+    widths = {"L": 256, "L_long": 512, "long": long_lines}
+    assert got["grep.stage"] == {"seg": 0, **widths}
+    assert got["grep.dispatch"] == {"rules": 50, "planes": 1, **widths}
 
 
 def test_one_fused_program_counts_every_rule_at_the_least_stride(program):

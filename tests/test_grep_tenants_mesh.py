@@ -186,6 +186,7 @@ def test_process_batch_on_the_mesh_equals_the_host_chain(corpus, lane4):
     assert tm["h2d_bytes"] == Bp * (512 + 4)
     assert tm["d2h_bytes"] == 4 * 50 * Bp     # the mesh's verdict is i32
     assert tm["scan_elements"] == dev._program.scan_elements(Bp, 512)
+    assert tm["split_launches"] == tm["long_rows"] == 0   # one chip's
     took = dev._program.decision()["mesh_children"]
     assert len(took) == 4 and all(
         t["variant"] == "batch" and t["devices"] == CHIPS for t in took)

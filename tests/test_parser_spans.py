@@ -419,7 +419,11 @@ def test_parser_spans_and_their_ids(gate_open, monkeypatch):
 
     class Recorded:
         def __init__(self, name, **ids):
+            self.ids = ids
             seen.append((name, ids))
+
+        def set_metadata(self, **ids):
+            self.ids.update(ids)
 
         def __enter__(self):
             return self
@@ -441,6 +445,8 @@ def test_parser_spans_and_their_ids(gate_open, monkeypatch):
         assert name in names, names
     build = dict(seen)["parser.build"]
     assert build["rows"] == 100 and 0 < build["parsed"] < 100
+    # the span program takes its one plane whole: one width, no group
+    assert dict(seen)["grep.stage"] == {"seg": 0, "L": 128}
     assert set(plugin.raw_timings) == set(PARSER_KEYS)
 
 

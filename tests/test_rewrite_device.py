@@ -167,13 +167,23 @@ def test_device_verdict_served_every_record(routed):
     assert routed["host"]["program"] is None
 
 
-def test_one_plane_staged_for_the_eight_rules(routed):
+def test_one_plane_staged_for_the_eight_rules(routed, corpus):
     """Eight rules on ``log`` cost one plane of host→device bytes a
-    line (L + 4), not eight."""
+    line (L + 4), not eight — and the few 512-bucket lines of a frame
+    do not set its width: they go as a 256-row group of their own at
+    L=512 (with their row indices), the frame at L=256."""
     prog, tm = routed["device"]["program"], routed["device"]["timings"]
     assert prog.n_planes == 1 and prog.plane_of == (0,) * 8
     assert sorted(c.k for c in prog._children) == [4, 5, 6]
-    assert tm["h2d_bytes"] == N_LINES * (512 + 4)
+    frames = N_LINES // FRAME
+    long_lines = sum(256 < len(r["log"]) <= 512 for r in corpus["records"])
+    assert 0 < long_lines <= 256
+    assert tm["split_launches"] == frames
+    assert tm["long_rows"] == long_lines
+    assert tm["h2d_bytes"] == N_LINES * (256 + 4) \
+        + frames * 256 * (512 + 4 + 4)
+    assert tm["scan_elements"] == frames * (
+        prog.scan_elements(FRAME, 256) + prog.scan_elements(256, 512))
 
 
 def test_overflow_rows_counted(routed, corpus):

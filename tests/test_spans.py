@@ -1226,7 +1226,10 @@ def test_lane_workers_make_no_timing_shards(mesh_env):
     assert set(tm) == {"extract_s", "kernel_s", "compact_s", "records",
                        "device_records", "overflow_rows", "h2d_bytes",
                        "d2h_bytes", "scan_elements", "mesh_launches",
-                       "mesh_devices", "unsharded_launches"}
+                       "mesh_devices", "unsharded_launches",
+                       "split_launches", "long_rows"}
+    # the mesh stages a frame at its one width: never in two groups
+    assert tm["split_launches"] == tm["long_rows"] == 0
     # the layout of each launch, counted on the dispatching thread
     assert tm["mesh_launches"] == 200 and tm["unsharded_launches"] == 0
     assert tm["mesh_devices"] == 200 * plugin._mesh.devices.size
@@ -1300,6 +1303,7 @@ def test_every_timing_key_this_pr_adds_feeds_a_metric(key):
 #: the helper it shares with grep, and its own three)
 REWRITE_KEYS = ("extract_s", "kernel_s", "h2d_bytes", "d2h_bytes",
                 "scan_elements", "device_records", "overflow_rows",
+                "split_launches", "long_rows",
                 "records", "emit_s", "emits", "emit_backpressure")
 
 

@@ -368,6 +368,65 @@ def test_one_rule_mesh_child_lays_its_table_out_once(mesh4, which):
     assert " copy(" not in body
 
 
+@pytest.mark.parametrize("which", ["whole", "two-groups", "mesh"])
+def test_a_frame_in_two_groups_is_one_module_over_one_table(
+        one_chip, mesh4, which):
+    """``GrepProgram.dispatch(..., long=...)`` (PR 39): a child scans a
+    frame's main group and its 256 long rows in ONE module — two
+    ``while``s over one copy of the table (a second program a child
+    would fold the table's constant twice, 4.6 s a time for the tenants'
+    k=3 child, and would read as two launches to whoever sums a launch's
+    modules by name) — and scatters the long rows' verdicts into the
+    mask, which keeps its shape. A frame without long rows runs the
+    program it ran before, text for text, and the mesh program has no
+    second group."""
+    prog = GrepProgram([compile_dfa(p) for p in CONFIG3], 512,
+                       plane_of=(0,) * len(CONFIG3))
+    child = next(c for c in prog._children if c.k == 5)
+    child._materialize()  # tables on the test's CPU backend, closed over
+    table = "s32[%d,%d]" % child._tbl["trans_flat"].shape
+    main = (sds((1, SEGMENT, 256), jnp.uint8, one_chip),
+            sds((1, SEGMENT), jnp.int32, one_chip))
+    if which == "whole":
+        def parents(planes, lengths):  # _materialize's impl before PR 39
+            return child._match_impl(
+                child._tbl, *child._gather_planes(planes, lengths))
+
+        parents.__name__ = child.program_name()
+        lowered = child._jit.lower(*main)
+        assert lowered.as_text() == jax.jit(parents).lower(*main).as_text()
+        hlo = lowered.compile().as_text()
+        assert len(re.findall(r" while\(", hlo)) == 1
+        assert " scatter(" not in hlo
+    elif which == "two-groups":
+        long = (sds((1, 256, 512), jnp.uint8, one_chip),
+                sds((1, 256), jnp.int32, one_chip),
+                sds((256,), jnp.int32, one_chip))
+        compiled = child._jit_long.lower(*main, *long).compile()
+        hlo = compiled.as_text()
+        assert hlo.count("HloModule ") == 1
+        assert child.program_name() in hlo.split("\n", 1)[0]
+        assert len(re.findall(r" while\(", hlo)) == 2
+        assert len(re.findall(re.escape(table) + r"\S* constant\(",
+                              hlo)) == 1
+        assert " scatter(" in hlo
+        (out,) = jax.tree_util.tree_leaves(compiled.out_info)
+        assert out.shape == (2, SEGMENT) and out.dtype == jnp.bool_
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    else:
+        mesh = mesh4("batch")
+        fn, tsh, sh_b, sh_l, variant, _donate = child._mesh_program(
+            mesh, "auto", False)
+        tables = {k: sds(v.shape, v.dtype, tsh[k])
+                  for k, v in child._tbl.items()}
+        hlo = fn.lower(tables, sds((1, SEGMENT, 512), jnp.uint8, sh_b),
+                       sds((1, SEGMENT), jnp.int32, sh_l)).compile(
+                           ).as_text()
+        assert variant == "batch"
+        assert len(re.findall(r" while\(", hlo)) == 1
+        assert " scatter(" not in hlo
+
+
 @pytest.mark.parametrize("sketch", ["hll-pmax", "cms-psum"])
 def test_sharded_sketch_compiles_for_four_chips(mesh4, sketch):
     mesh = mesh4("flux")
