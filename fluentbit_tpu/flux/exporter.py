@@ -8,6 +8,8 @@ the metrics pipeline like every other ``fluentbit_*`` family):
 - ``fluentbit_flux_batches_total{name}``        absorbed chunks/appends
 - ``fluentbit_flux_late_records_total{name}``   event-time late drops
 - ``fluentbit_flux_window_emits_total{name}``   closed-window emissions
+- ``fluentbit_flux_window_closes_total{name}``  window boundaries passed
+  (a close whose window holds no group emits nothing and still counts)
 - ``fluentbit_flux_groups{name}``               open-pane group count
 - ``fluentbit_flux_cardinality{name,group,field}``  HLL estimates
 - ``fluentbit_flux_topk_estimate{name,group,value}`` CMS hot keys
@@ -22,7 +24,7 @@ from __future__ import annotations
 from typing import Optional
 
 from ..core.metrics import MetricsRegistry
-from .state import FluxState
+from .state import FluxState, render_key_part
 
 __all__ = ["FluxExporter"]
 
@@ -32,12 +34,13 @@ def _group_label(key: tuple) -> str:
     distinct keys must render distinct labels or two groups' series
     silently overwrite each other on refresh — so '/' inside a part is
     escaped and a missing (None) part renders differently from an
-    empty string."""
+    empty string. An integer part renders as its digits (the one pair
+    that shares a label is an integer and its own decimal string)."""
     if not key:
         return ""
     return "/".join(
         "\\N" if part is None
-        else part.decode("utf-8", "replace")
+        else str(render_key_part(part))
         .replace("\\", "\\\\").replace("/", "\\/")
         for part in key
     )
@@ -70,6 +73,9 @@ class FluxExporter:
         self.m_emits = m.counter(
             "fluentbit", "flux", "window_emits_total",
             "Closed-window emissions", ("name",))
+        self.m_closes = m.counter(
+            "fluentbit", "flux", "window_closes_total",
+            "Window boundaries passed", ("name",))
         self.m_groups = m.gauge(
             "fluentbit", "flux", "groups",
             "Open-pane group count", ("name",))
@@ -84,6 +90,7 @@ class FluxExporter:
         self._c_batches = 0
         self._c_late = 0
         self._c_emits = 0
+        self._c_closes = 0
 
     def refresh(self, force: bool = True) -> bool:
         """Publish the current state; ``force=False`` applies the
@@ -98,6 +105,7 @@ class FluxExporter:
         self._bump(self.m_batches, "_c_batches", st.batches_total)
         self._bump(self.m_late, "_c_late", st.late_records_total)
         self._bump(self.m_emits, "_c_emits", st.window_emits_total)
+        self._bump(self.m_closes, "_c_closes", st.window_closes_total)
         groups = st.live_groups()
         self.m_groups.set(float(len(groups)), (self.name,))
         # wholesale refresh of THIS state's series only: stale groups
@@ -105,7 +113,9 @@ class FluxExporter:
         # not (the families are shared engine-registry metrics)
         self.m_cardinality.remove_matching("name", self.name)
         self.m_topk.remove_matching("name", self.name)
-        for key, g in groups:
+        # (a count-only state has no series a group: no walk of what
+        # may be thousands of groups under the ingest lock)
+        for key, g in groups if st.spec.distinct else ():
             label = _group_label(key)
             for field, hll in g.hlls.items():
                 self.m_cardinality.set(

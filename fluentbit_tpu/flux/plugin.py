@@ -5,9 +5,12 @@ The flux plane's ingest hook: one configured instance maintains one
 window aggregates) and rides the PR-2 ``process_batch`` fast path —
 per tagged append, the needed columns are extracted straight from chunk
 bytes by the native stagers (``stage_field`` / ``stage_field_f64`` /
-``map_mask``) and absorbed in ONE batched commit; records pass through
-untouched.  The per-record ``filter()`` twin runs the identical math on
-decoded events, so a decline anywhere on the raw chain stays bit-exact.
+``stage_field_i64``) and absorbed in ONE batched commit; records pass
+through untouched.  A GROUP BY field is typed row by row — a string or
+an integer (FLUX.md "Typed group keys"); a chunk holding any other kind
+of key declines before the commit to the per-record twin.  The
+per-record ``filter()`` twin runs the identical math on decoded events,
+so a decline anywhere on the raw chain stays bit-exact.
 
 Batch-exactness contract (machine-checked, ``analysis.batch``): every
 decline (``return None``) is dominated by ZERO committed effects — all
@@ -40,7 +43,8 @@ from ..core.config import ConfigMapEntry
 from ..core.plugin import FilterPlugin, FilterResult, registry
 from ..core.spans import ShardedTimings
 from .exporter import FluxExporter
-from .state import FluxSpec, FluxState, WindowSpec
+from .state import (KEY_NULL, KEY_OBJ, KEY_STR, TIMING_KEYS, FluxSpec,
+                    FluxState, KeyCol, WindowSpec, render_key_part)
 
 log = logging.getLogger("flb.flux")
 
@@ -54,7 +58,8 @@ class FluxFilter(FilterPlugin):
     stateful_batch = True
     config_map = [
         ConfigMapEntry("group_by", "str", multiple=True,
-                       desc="tenant/group label fields (string-typed)"),
+                       desc="tenant/group label fields (strings or "
+                            "integers, row by row)"),
         ConfigMapEntry("distinct_field", "str", multiple=True,
                        desc="HLL cardinality columns"),
         ConfigMapEntry("aggregate_field", "str", multiple=True,
@@ -114,9 +119,9 @@ class FluxFilter(FilterPlugin):
             ))
             if self.snapshot_path:
                 self.state.load(self.snapshot_path)
-        # seconds of absorb_batch/absorb_events outside the device
-        # launch (the lane's stats hold the launch's own seconds)
-        self.raw_timings = ShardedTimings(("absorb_s",))
+        # absorbs and window closes, accounted (state.TIMING_KEYS; the
+        # lane's stats hold the launch's own seconds)
+        self.raw_timings = ShardedTimings(TIMING_KEYS)
         self.state.timings = self.raw_timings
         metrics = engine.metrics if engine is not None else None
         if metrics is None:
@@ -137,7 +142,8 @@ class FluxFilter(FilterPlugin):
         # path instead.
         self._batch_ok = (
             _native.available() and not self.state.spec.event_time
-            and (not self.state.spec.numeric
+            and (not (self.state.spec.numeric
+                      or self.state.spec.group_by)
                  or _native.has_flux_stagers())
         )
         if self._preset_state is None and engine is not None \
@@ -190,10 +196,13 @@ class FluxFilter(FilterPlugin):
     def _tick_locked(self):
         """→ snapshot dict to write after the lock is released, or
         None."""
-        closed = self.state.tick()
-        if closed and self.tag and self._emitter is not None:
-            self._emit_rows(closed, "window")
-        self.exporter.refresh(force=bool(closed))
+        def hand_on(groups) -> int:
+            if self.tag and self._emitter is not None:
+                return self._emit_rows(groups, "window")
+            return 0
+
+        closed = self.state.close_window(None, hand_on)
+        self.exporter.refresh(force=closed)
         if not self.snapshot_path:
             return None
         import time as _time
@@ -204,16 +213,20 @@ class FluxFilter(FilterPlugin):
             return None
         return self.state.snapshot()
 
-    def _emit_rows(self, closed, what: str) -> None:
+    def _emit_rows(self, closed, what: str) -> int:
+        """The closed groups as rows under ``tag``, all of one emission
+        under one record time → the number of rows."""
         rows = self._render_rows(closed)
+        ts = now_event_time()
         buf = bytearray()
         for r in rows:
-            buf += encode_event(r, now_event_time())
+            buf += encode_event(r, ts)
         try:
             self._emitter.add_record(self.tag, bytes(buf), len(rows))
         except Exception:
             log.exception("flux %s emit failed; rows dropped "
                           "(state already rolled over)", what)
+        return len(rows)
 
     def _render_rows(self, closed) -> List[dict]:
         spec = self.state.spec
@@ -221,8 +234,7 @@ class FluxFilter(FilterPlugin):
         for key, g in closed:
             row: dict = {"flux": spec.name}
             for fname, part in zip(spec.group_by, key):
-                row[fname] = None if part is None \
-                    else part.decode("utf-8", "replace")
+                row[fname] = render_key_part(part)
             row["count"] = g.count
             for f in spec.numeric:
                 st = g.cols[f]
@@ -291,6 +303,12 @@ class FluxFilter(FilterPlugin):
             if n2 is None or n2 != n:
                 return None
             strcols[f] = (b, ln)
+        keycols = {}
+        for f in spec.group_by:
+            kc = self._stage_key(native, data, f, n, *strcols[f])
+            if kc is None:
+                return None
+            keycols[f] = kc
         numcols = {}
         for f in spec.numeric:
             got = native.stage_field_f64(data, f.encode("utf-8"),
@@ -303,7 +321,7 @@ class FluxFilter(FilterPlugin):
             n = n2
             numcols[f] = (vals, kinds)
         # ---- the single commit: nothing below declines ----
-        self.state.absorb_batch(n, strcols, numcols)
+        self.state.absorb_batch(n, strcols, numcols, keycols)
         try:
             # a raise past the commit would be an implicit decline and
             # the decoded-tail rerun would absorb the chunk AGAIN —
@@ -312,6 +330,34 @@ class FluxFilter(FilterPlugin):
         except Exception:
             log.exception("flux metrics refresh failed; export deferred")
         return (n, data, n)
+
+    @staticmethod
+    def _stage_key(native, data, field: str, n: int, b, ln):
+        """One GROUP BY field of a staged chunk as a :class:`KeyCol`,
+        or None: the chunk declines. The string stager has run (``b``,
+        ``ln``); a column it filled is a column of strings. Rows it
+        left are missing, integers, or something else: the typed
+        stager tells them apart, int64 holds every integer it accepts,
+        and a row of any other kind — a float, a bool, a uint64 past
+        2^63-1, a nested value — declines, for the per-record twin
+        keys those as the exact path does (FLUX.md "Typed group
+        keys"). An oversize string (``ln`` -2) is missing, as ever."""
+        if not (ln == -1).any():
+            return KeyCol.of_strings(b, ln)
+        got = native.stage_field_i64(data, field.encode("utf-8"),
+                                     n_hint=n)
+        if got is None or got[2] != n:
+            return None
+        ints, kinds, _n = got
+        if (kinds == KEY_OBJ).any():
+            return None
+        is_str = ln >= 0
+        # (a string the string stager left is an oversize one: missing)
+        kinds[kinds == KEY_STR] = KEY_NULL
+        kinds[is_str] = KEY_STR
+        if not is_str.any():
+            return KeyCol(kinds, ints)
+        return KeyCol(kinds, ints, b, ln)
 
     # ------------------------------------------------- per-record twin
 

@@ -30,7 +30,7 @@ import logging
 import os
 from typing import List, Optional
 
-from .state import FluxSpec, FluxState, WindowSpec
+from .state import FluxSpec, FluxState, WindowSpec, render_key_part
 
 log = logging.getLogger("flb.flux")
 
@@ -107,8 +107,7 @@ class FluxBinding:
         for key, g in closed:
             row: dict = {}
             for gname, part in zip(q.group_by, key):
-                row[gname] = None if part is None \
-                    else part.decode("utf-8", "replace")
+                row[gname] = render_key_part(part)
             for k in q.keys:
                 if k.func:
                     row[k.out_name] = self._agg_result(g, k)
@@ -135,11 +134,25 @@ class FluxBinding:
             return st.max_value()
         return None
 
-    def rows_on_tick(self, now: float) -> List[dict]:
-        return self._rows(self.state.tick(now))
+    def close(self, now: float, emit) -> bool:
+        """The task's window tick: where a window closes, its rows to
+        ``emit(rows)``, the whole close accounted
+        (``FluxState.close_window``). → whether one closed."""
+        def hand_on(closed) -> int:
+            rows = self._rows(closed)
+            emit(rows)
+            return len(rows)
 
-    def rows_on_drain(self) -> List[dict]:
-        return self._rows(self.state.drain())
+        return self.state.close_window(now, hand_on)
+
+    def drain(self, emit) -> None:
+        """Shutdown: what the ring and the open pane hold, to
+        ``emit(rows)``."""
+        rows = self._rows(self.state.drain())
+        if rows:
+            emit(rows)
+            if self.state.timings is not None:
+                self.state.timings.add("emitted_rows", len(rows))
 
 
 def sql_mesh_enabled() -> bool:
@@ -179,13 +192,7 @@ def attach_flux(engine, task) -> bool:
     with engine._ingest_lock:
         engine.filters = engine.filters + [ins]
     task.flux = FluxBinding(query, state)
-    log.info(
-        "stream task %s resolved against flux state (%s); NOTE: "
-        "GROUP BY / COUNT(DISTINCT) fields must be string-typed at "
-        "runtime (non-string values land in the null group — FLUX.md "
-        "eligibility rules; pin the exact path with WITH (flux='off') "
-        "if %s carries numeric labels)",
-        query.stream_name or query.source,
-        "mesh" if state.spec.mesh else "single",
-        ", ".join(query.group_by) or "the query")
+    log.info("stream task %s resolved against flux state (%s)",
+             query.stream_name or query.source,
+             "mesh" if state.spec.mesh else "single")
     return True

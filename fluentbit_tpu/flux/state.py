@@ -2,8 +2,9 @@
 
 One :class:`FluxState` is the analytics state behind one flux consumer
 (a configured ``filter_flux`` instance, or one sketch-eligible
-stream-processor query).  Per group key (the tenant/tag labels) it
-maintains:
+stream-processor query).  Per group key (the tenant/tag labels, or
+integer ids: a GROUP BY field is typed row by row, :class:`KeyCol`,
+FLUX.md "Typed group keys") it maintains:
 
 - **HLL cardinality** per distinct-column (``ops.sketch.HyperLogLog``,
   registers device-resident once the backend attaches; cross-chip merge
@@ -56,7 +57,8 @@ from ..ops.batch import assemble, bucket_size
 from ..ops.sketch import CountMin, HyperLogLog
 from . import kernels
 
-__all__ = ["WindowSpec", "FluxSpec", "FluxState", "SNAPSHOT_VERSION"]
+__all__ = ["WindowSpec", "FluxSpec", "FluxState", "KeyCol",
+           "SNAPSHOT_VERSION", "TIMING_KEYS", "render_key_part"]
 
 SNAPSHOT_VERSION = 1
 
@@ -68,6 +70,109 @@ _VALUE_SEP = b"\x1f"
 #: cap on distinct group keys tracked for top-k candidates (LRU-ish;
 #: the CMS itself is fixed-size — only the nomination sets need a bound)
 _MAX_CANDIDATE_GROUPS = 4096
+
+#: what a flux filter's ``raw_timings`` holds: ``absorb_s`` host seconds
+#: of the absorbs outside the lane's launch, ``group_s`` the part of it
+#: in ``_group_rows``; ``fused_absorbs`` / ``host_absorbs`` absorbs whose
+#: counts came from the fused device program / from the host twin;
+#: ``close_s`` seconds of the closing ticks (pane roll, merge, rows,
+#: emit), ``closes`` their number, ``closed_groups`` the groups they
+#: merged, ``emitted_rows`` the rows they and the drain handed on
+TIMING_KEYS = ("absorb_s", "group_s", "fused_absorbs", "host_absorbs",
+               "close_s", "closes", "closed_groups", "emitted_rows")
+
+#: kinds of one row's GROUP BY key (:class:`KeyCol`; the codes
+#: ``native.stage_field_i64`` writes)
+KEY_NULL, KEY_STR, KEY_INT, KEY_OBJ = 0, 1, 2, 3
+
+
+def _is_i64(v) -> bool:
+    return type(v) is int and -(1 << 63) <= v < (1 << 63)
+
+
+class _Opaque:
+    """A GROUP BY value that cannot sit in a key tuple as itself: a
+    nested value (unhashable) or a msgpack ``bin`` (its ``bytes`` would
+    collide with a string key's). Equal where the values are equal."""
+
+    __slots__ = ("value",)
+
+    def __init__(self, value):
+        self.value = value
+
+    def __eq__(self, other):
+        return isinstance(other, _Opaque) and type(self.value) is \
+            type(other.value) and self.value == other.value
+
+    def __hash__(self):
+        return hash(repr(self.value))
+
+    def __repr__(self):
+        return f"_Opaque({self.value!r})"
+
+
+def render_key_part(part):
+    """One part of a group key tuple as a window row carries it: the
+    value in its own Python type, as the exact path's rows do."""
+    if isinstance(part, bytes):
+        return part.decode("utf-8", "replace")
+    if isinstance(part, _Opaque):
+        return part.value
+    return part
+
+
+class KeyCol:
+    """One GROUP BY field of one chunk, typed row by row.
+
+    kind   u8 [n]: 0 missing, 1 string, 2 integer, 3 object
+    ints   i64 [n] or None: the integer of a kind-2 row, the index into
+           ``objs`` of a kind-3 row, 0 elsewhere
+    sbatch u8 [n, L], slen i32 [n], or None: the bytes and length of a
+           kind-1 row (``slen`` < 0 elsewhere)
+    objs   the distinct other values of a decoded chunk (floats, bools,
+           integers past int64, nested values): the per-record twin
+           only; a staged chunk holding one declines before any commit
+    """
+
+    __slots__ = ("kind", "ints", "sbatch", "slen", "objs")
+
+    def __init__(self, kind, ints=None, sbatch=None, slen=None,
+                 objs=None):
+        self.kind = kind
+        self.ints = ints
+        self.sbatch = sbatch
+        self.slen = slen
+        self.objs = objs
+
+    @classmethod
+    def of_strings(cls, sbatch: np.ndarray, slen: np.ndarray) -> "KeyCol":
+        """A column of strings and missing values, as staged."""
+        return cls((slen >= 0).astype(np.uint8), None, sbatch, slen)
+
+    def take(self, rows: np.ndarray) -> "KeyCol":
+        return KeyCol(
+            self.kind[rows],
+            None if self.ints is None else self.ints[rows],
+            None if self.sbatch is None else self.sbatch[rows],
+            None if self.slen is None else self.slen[rows],
+            self.objs)
+
+    def values(self, rows: np.ndarray) -> list:
+        """The key parts of ``rows``: None, bytes, int, or the object."""
+        kinds = self.kind[rows].tolist()
+        ints = None if self.ints is None else self.ints[rows].tolist()
+        out: list = []
+        for j, k in enumerate(kinds):
+            if k == KEY_INT:
+                out.append(ints[j])
+            elif k == KEY_STR:
+                r = rows[j]
+                out.append(self.sbatch[r, :self.slen[r]].tobytes())
+            elif k == KEY_OBJ:
+                out.append(self.objs[ints[j]])
+            else:
+                out.append(None)
+        return out
 
 
 class WindowSpec:
@@ -166,14 +271,23 @@ class FluxSpec:
         }
 
     @property
-    def string_fields(self) -> Tuple[str, ...]:
-        """Columns staged as string bytes, in staging order."""
-        out: List[str] = list(self.group_by)
+    def sketch_fields(self) -> Tuple[str, ...]:
+        """Columns the sketches hash as string bytes (distinct and
+        top-k), in staging order."""
+        out: List[str] = []
         for f in self.distinct:
             if f not in out:
                 out.append(f)
         if self.topk_field and self.topk_field not in out:
             out.append(self.topk_field)
+        return tuple(out)
+
+    @property
+    def string_fields(self) -> Tuple[str, ...]:
+        """Columns staged as string bytes, in staging order: the GROUP
+        BY fields (their string rows) and the sketch columns."""
+        out: List[str] = list(self.group_by)
+        out += [f for f in self.sketch_fields if f not in out]
         return tuple(out)
 
 
@@ -244,6 +358,15 @@ class _FluxGroup:
                 else np.asarray(h.registers))
 
 
+def _platform_of(arr) -> Optional[str]:
+    """The platform an array of a launch's result lives on (None for a
+    numpy array: the host twin's)."""
+    devices = getattr(arr, "devices", None)
+    if devices is None:
+        return None
+    return next(iter(devices())).platform
+
+
 def _seq_sum(start: float, values: np.ndarray) -> float:
     """``((start + v0) + v1) + ...`` with C-double sequential adds —
     np.bincount accumulates its weights in input order, which is
@@ -290,12 +413,16 @@ class FluxState:
         #: state nobody times
         self.timings = None
         self._launch_s = 0.0  # lane.run seconds of the absorb under way
+        #: the platform the last fused absorb's counts came back from
+        self.counts_platform: Optional[str] = None
+        self.window_closes_total = 0
 
     # ------------------------------------------------------------ absorb
 
     def absorb_batch(self, n: int,
                      strcols: Dict[str, Tuple[np.ndarray, np.ndarray]],
                      numcols: Dict[str, Tuple[np.ndarray, np.ndarray]],
+                     keycols: Optional[Dict[str, KeyCol]] = None,
                      ) -> int:
         """Absorb one staged chunk (processing-time mode).
 
@@ -303,6 +430,8 @@ class FluxState:
                    < 0 = missing/non-string/oversize
         numcols  : field → (values f64 [n], kinds u8 [n]); kind 0 =
                    missing/non-numeric, 1 = integer, 2 = float
+        keycols  : GROUP BY field → :class:`KeyCol`; a field left out
+                   is keyed by its ``strcols`` entry (strings only)
 
         EVERY record counts — the codec coerces non-map bodies to empty
         dicts at decode (codec.events._to_event), so the Python
@@ -316,10 +445,18 @@ class FluxState:
         if n <= 0:
             return 0
         t0 = time.perf_counter()
-        self._absorb_rows(self._groups, n, strcols, numcols)
+        self._absorb_rows(self._groups, n, strcols, numcols,
+                          self._keycols(strcols, keycols))
         self._note_absorb(t0)
         self.records_total += n
         return n
+
+    def _keycols(self, strcols, keycols) -> Dict[str, KeyCol]:
+        out = dict(keycols or ())
+        for f in self.spec.group_by:
+            if f not in out:
+                out[f] = KeyCol.of_strings(*strcols[f])
+        return out
 
     def absorb_events(self, events: list) -> int:
         """Per-record twin of :meth:`absorb_batch` — converts decoded
@@ -336,18 +473,22 @@ class FluxState:
                   for ev in events]
         strcols = {
             f: self._str_column(bodies, f)
-            for f in self.spec.string_fields
+            for f in self.spec.sketch_fields
         }
         numcols = {
             f: self._num_column(bodies, f) for f in self.spec.numeric
+        }
+        keycols = {
+            f: self._key_column(bodies, f) for f in self.spec.group_by
         }
         self.batches_total += 1
         if self.spec.event_time:
             ts = np.asarray([ev.ts_float for ev in events],
                             dtype=np.float64)
-            absorbed = self._absorb_event_time(ts, strcols, numcols)
+            absorbed = self._absorb_event_time(ts, strcols, numcols,
+                                               keycols)
         else:
-            self._absorb_rows(self._groups, n, strcols, numcols)
+            self._absorb_rows(self._groups, n, strcols, numcols, keycols)
             absorbed = n
         self._note_absorb(t0)
         self.records_total += absorbed
@@ -376,6 +517,69 @@ class FluxState:
         ln[ln == -2] = -1  # collapse oversize into plain missing
         return batch.batch, ln
 
+    def _key_column(self, bodies: List[dict], field: str) -> KeyCol:
+        """One GROUP BY field of decoded records, keyed as the exact
+        path's ``_Agg`` dict keys it: a string by its bytes, an integer
+        int64 holds by its value, and every other value — a float, a
+        bool, a longer integer, a nested value — as an object, equal
+        where Python says so (``1 == 1.0 == True`` is ONE group, named
+        by whichever came first); None and a missing key are the null
+        group. A string longer than ``max_len`` is missing (FLUX.md)."""
+        n = len(bodies)
+        kind = np.zeros((n,), dtype=np.uint8)
+        ints = np.zeros((n,), dtype=np.int64)
+        svals: List[Optional[bytes]] = [None] * n
+        any_str = False
+        others: List[Tuple[int, Any]] = []   # rows of non-string values
+        any_obj = False
+        for i, b in enumerate(bodies):
+            v = b.get(field)
+            if v is None:
+                continue
+            if isinstance(v, str):
+                vb = v.encode("utf-8")
+                if len(vb) <= self.spec.max_len:
+                    svals[i] = vb
+                    kind[i] = KEY_STR
+                    any_str = True
+                continue
+            if _is_i64(v):
+                kind[i] = KEY_INT
+                ints[i] = v
+            else:
+                any_obj = True
+            others.append((i, v))
+        objs: Optional[list] = None
+        if any_obj:
+            # Python equality across the non-string values, first seen
+            # names the group: what a dict keyed on the values does
+            objs = []
+            first: Dict[Any, Tuple[int, int]] = {}
+            for i, v in others:
+                part = v
+                if isinstance(v, (bytes, bytearray)):
+                    part = _Opaque(v)
+                else:
+                    try:
+                        hash(v)
+                    except TypeError:
+                        part = _Opaque(v)
+                seen = first.get(part)
+                if seen is None:
+                    if _is_i64(part):
+                        seen = (KEY_INT, part)
+                    else:
+                        seen = (KEY_OBJ, len(objs))
+                        objs.append(part)
+                    first[part] = seen
+                kind[i], ints[i] = seen
+        sbatch = slen = None
+        if any_str:
+            batch = assemble(svals, self.spec.max_len)
+            sbatch, slen = batch.batch, batch.lengths.copy()
+            slen[slen == -2] = -1
+        return KeyCol(kind, ints if others else None, sbatch, slen, objs)
+
     def _num_column(self, bodies: List[dict], field: str):
         vals = np.zeros((len(bodies),), dtype=np.float64)
         kinds = np.zeros((len(bodies),), dtype=np.uint8)
@@ -389,24 +593,35 @@ class FluxState:
 
     # -- grouping ------------------------------------------------------
 
-    def _group_rows(self, n_rows: int, strcols
+    def _group_rows(self, n_rows: int, keycols: Dict[str, KeyCol]
                     ) -> Tuple[np.ndarray, List[tuple]]:
-        """Segment ids (first-seen order) + group key tuples."""
+        """Segment ids (first-seen order) + group key tuples: ONE
+        ``np.unique`` over a fixed-width key — a kind byte a field,
+        eight bytes where the column holds integers, and the string
+        plane with its length only where it holds strings."""
         gb = self.spec.group_by
         if not gb:
             return np.zeros((n_rows,), dtype=np.int64), [()]
         mats = []
         for f in gb:
-            b, ln = strcols[f]
-            L = b.shape[1]
-            ln2 = np.where(ln < 0, np.int32(-1), ln)
-            bz = np.ascontiguousarray(b, dtype=np.uint8).copy()
-            # zero pad bytes so the void view compares by value; the
-            # length column disambiguates embedded-NUL prefixes
-            mask = np.arange(L)[None, :] >= np.clip(ln2, 0, None)[:, None]
-            bz[mask] = 0
-            mats.append(bz)
-            mats.append(ln2.astype("<i4").view(np.uint8).reshape(-1, 4))
+            kc = keycols[f]
+            mats.append(kc.kind.reshape(-1, 1))
+            if kc.ints is not None:
+                mats.append(np.ascontiguousarray(
+                    kc.ints, dtype="<i8").view(np.uint8).reshape(-1, 8))
+            if kc.sbatch is not None:
+                b, L = kc.sbatch, kc.sbatch.shape[1]
+                ln2 = np.where(kc.kind == KEY_STR, kc.slen,
+                               np.int32(-1)).astype(np.int32)
+                bz = np.ascontiguousarray(b, dtype=np.uint8).copy()
+                # zero pad bytes so the void view compares by value;
+                # the length column disambiguates embedded-NUL prefixes
+                mask = np.arange(L)[None, :] >= \
+                    np.clip(ln2, 0, None)[:, None]
+                bz[mask] = 0
+                mats.append(bz)
+                mats.append(ln2.astype("<i4").view(np.uint8)
+                            .reshape(-1, 4))
         keyed = np.ascontiguousarray(np.concatenate(mats, axis=1))
         void = keyed.view(f"V{keyed.shape[1]}").reshape(-1)
         _, first_idx, inv = np.unique(void, return_index=True,
@@ -415,15 +630,8 @@ class FluxState:
         remap = np.empty(order.size, dtype=np.int64)
         remap[order] = np.arange(order.size)
         seg = remap[np.asarray(inv).reshape(-1)]
-        keys: List[tuple] = []
-        for j in order:
-            row = int(first_idx[j])
-            key = []
-            for f in gb:
-                b, ln = strcols[f]
-                lni = int(ln[row])
-                key.append(b[row, :lni].tobytes() if lni >= 0 else None)
-            keys.append(tuple(key))
+        rows = first_idx[order]
+        keys = list(zip(*(keycols[f].values(rows) for f in gb)))
         return seg, keys
 
     # -- the shared core ----------------------------------------------
@@ -436,8 +644,11 @@ class FluxState:
     _FUSED_MAX_GROUPS = 512
 
     def _absorb_rows(self, pane: Dict[tuple, _FluxGroup], n_rows: int,
-                     strcols, numcols) -> None:
-        seg, keys = self._group_rows(n_rows, strcols)
+                     strcols, numcols, keycols) -> None:
+        tm = self.timings
+        with (tm.timed("group_s", "flux.group", rows=n_rows)
+              if tm is not None else span("flux.group", rows=n_rows)):
+            seg, keys = self._group_rows(n_rows, keycols)
         n_groups = len(keys)
         single = n_groups == 1
         order = bounds = None
@@ -500,6 +711,7 @@ class FluxState:
             counts = self._fused_absorb(groups, seg, strcols, comp,
                                         comp_len, gslice)
         else:
+            self._note_counts(fused=False)
             if single:
                 counts = np.asarray([n_rows], dtype=np.int32)
             else:
@@ -537,6 +749,10 @@ class FluxState:
         mesh_on = self._mesh is not None
         n_groups = len(groups)
         fields = list(spec.distinct)
+        # a state without a register stack pays four bytes a slot of
+        # the count table: ONE table shape for every chunk, so a group
+        # count that crosses a power of two compiles nothing mid-run
+        n_seg = n_groups if fields else self._FUSED_MAX_GROUPS
         regs0 = [[g.hlls[f].registers for g in groups]
                  for f in fields]
         table0 = self.cms.table if comp is not None else None
@@ -578,12 +794,12 @@ class FluxState:
                     got = kernels.sharded_fused_absorb(
                         m, seg32, valid, fcols, regs0, comp, comp_len,
                         table0, hll_p=spec.hll_p, cms=self.cms,
-                        n_seg=n_groups)
+                        n_seg=n_seg)
                 else:  # mesh shrunk below 2 devices (or none): plain jit
                     got = kernels.fused_absorb(
                         seg32, valid, fcols, regs0, comp, comp_len,
                         table0, hll_p=spec.hll_p, cms=self.cms,
-                        n_seg=n_groups)
+                        n_seg=n_seg)
             counts, regs_out, table_out = got
             with span("flux.force"):
                 return (_wait(counts),
@@ -613,13 +829,21 @@ class FluxState:
         t0 = time.perf_counter()
         counts, regs_out, table_out = lane.run(launch, fallback)
         self._launch_s += time.perf_counter() - t0
+        # the fallback resolves to (counts, None, None): the host twin
+        self._note_counts(fused=regs_out is not None)
         if regs_out is not None:
+            self.counts_platform = _platform_of(counts)
             for fi, f in enumerate(fields):
                 for gid, g in enumerate(groups):
                     g.hlls[f].registers = regs_out[fi][gid]
         if table_out is not None:
             self.cms.table = table_out
         return np.asarray(counts)
+
+    def _note_counts(self, fused: bool) -> None:
+        if self.timings is not None:
+            self.timings.add("fused_absorbs" if fused
+                             else "host_absorbs", 1)
 
     @staticmethod
     def _update_col(st: _ColStat, vals: np.ndarray,
@@ -756,13 +980,19 @@ class FluxState:
         if not key:
             return b""
         return _FIELD_SEP.join(
-            b"\x00" if part is None else part for part in key
+            b"\x00" if part is None
+            else part if isinstance(part, bytes)
+            # an integer is not its decimal string: \x01 marks it (and
+            # \x02 any other value), where no label's bytes begin
+            else b"\x01%d" % part if type(part) is int
+            else b"\x02" + repr(part).encode("utf-8", "replace")
+            for part in key
         ) + _VALUE_SEP
 
     # -- event-time (per-record path only) ----------------------------
 
     def _absorb_event_time(self, ts: np.ndarray, strcols,
-                           numcols) -> int:
+                           numcols, keycols) -> int:
         size = self.spec.window.size
         wid = np.floor(ts / size).astype(np.int64)
         wm = self._watermark
@@ -782,7 +1012,8 @@ class FluxState:
                 pane = self._event_windows[w] = {}
             sc = {f: (b[rows], ln[rows]) for f, (b, ln) in strcols.items()}
             nc = {f: (v[rows], k[rows]) for f, (v, k) in numcols.items()}
-            self._absorb_rows(pane, int(rows.size), sc, nc)
+            kc = {f: c.take(rows) for f, c in keycols.items()}
+            self._absorb_rows(pane, int(rows.size), sc, nc, kc)
             absorbed += int(rows.size)
         new_wm = float(ts.max())
         if wm is None or new_wm > wm:
@@ -801,8 +1032,41 @@ class FluxState:
                 self._pending_closed.append(
                     ((w + 1) * size, list(pane.items())))
                 self.window_emits_total += 1
+                self.window_closes_total += 1
 
     # ------------------------------------------------------------ window
+
+    def due(self, now: Optional[float] = None) -> bool:
+        """Whether :meth:`tick` would close a window now."""
+        w = self.spec.window
+        if self.spec.event_time:
+            return bool(self._pending_closed)
+        if w.kind is None:
+            return False
+        now = self._now() if now is None else now
+        return now - self._window_start >= w.advance
+
+    def close_window(self, now: Optional[float], hand_on) -> bool:
+        """One tick, and where it closes a window the whole of the close
+        under the span ``flux.close`` and on the timings: the pane roll
+        and merge (:meth:`tick`), then ``hand_on(closed) -> rows``, the
+        caller's rendering and emit of the closed groups (not called
+        for a window that holds none). → whether a window closed."""
+        if not self.due(now):
+            return False
+        t0 = time.perf_counter()
+        with span("flux.close", window=self.spec.window.kind) as sp:
+            closed = self.tick(now)
+            rows = hand_on(closed) if closed else 0
+            sp.set_metadata(groups=len(closed), rows=rows,
+                            panes=len(self._panes))
+        tm = self.timings
+        if tm is not None:
+            tm.add("close_s", time.perf_counter() - t0)
+            tm.add("closes", 1)
+            tm.add("closed_groups", len(closed))
+            tm.add("emitted_rows", rows)
+        return True
 
     def tick(self, now: Optional[float] = None
              ) -> List[Tuple[tuple, _FluxGroup]]:
@@ -824,6 +1088,7 @@ class FluxState:
                 return []
             self._window_start += w.size * (
                 (now - self._window_start) // w.size)
+            self.window_closes_total += 1
             closed = list(self._groups.items())
             self._groups = {}
             if closed:
@@ -834,6 +1099,7 @@ class FluxState:
             return []
         self._window_start += w.advance * (
             (now - self._window_start) // w.advance)
+        self.window_closes_total += 1
         self._panes.append(self._groups)
         self._groups = {}
         self._panes = self._panes[-w.n_panes:]
@@ -946,6 +1212,7 @@ class FluxState:
                            self._candidates.items()},
             "counters": (self.records_total, self.late_records_total,
                          self.window_emits_total, self.batches_total),
+            "window_closes": self.window_closes_total,
         }
         return snap
 
@@ -1026,6 +1293,7 @@ class FluxState:
         self.late_records_total = late
         self.window_emits_total = emits
         self.batches_total = batches
+        self.window_closes_total = snap.get("window_closes", emits)
 
     def persist(self, path: str) -> None:
         """Atomic snapshot write: tmp + fsync + rename — a crash at the

@@ -103,6 +103,16 @@ def _load():
                 ctypes.POINTER(ctypes.c_uint8),
                 ctypes.c_longlong, ctypes.POINTER(ctypes.c_longlong),
             ]
+        i64_fn = getattr(lib, "fbtpu_stage_field_i64", None)
+        if i64_fn is not None:
+            i64_fn.restype = ctypes.c_longlong
+            i64_fn.argtypes = [
+                ctypes.c_char_p, ctypes.c_longlong,
+                ctypes.c_char_p, ctypes.c_longlong,
+                ctypes.POINTER(ctypes.c_int64),
+                ctypes.POINTER(ctypes.c_uint8),
+                ctypes.c_longlong,
+            ]
         hll_fn = getattr(lib, "fbtpu_hll_update", None)
         if hll_fn is not None:
             hll_fn.restype = None
@@ -771,13 +781,45 @@ def stage_field_f64(
     return values[:n], kinds[:n], n
 
 
+def stage_field_i64(
+    buf, key: bytes, n_hint: Optional[int] = None,
+) -> Optional[Tuple[np.ndarray, np.ndarray, int]]:
+    """Stage one top-level field as a typed GROUP BY key straight from
+    chunk bytes: (values[B] i64, kinds[B] u8, n_records). kinds, the
+    ``KEY_*`` codes of ``flux/state.py``: 0 = missing or nil, 1 = a
+    string (``stage_field_into`` carries the bytes), 2 = an integer
+    int64 holds exactly (its value in ``values``), 3 = anything else
+    (float, bool, uint64 past 2^63-1, bin, nested): what the batched
+    flux path declines on. Freshly allocated arrays."""
+    lib = _load()
+    if lib is None or getattr(lib, "fbtpu_stage_field_i64", None) is None:
+        return None
+    est = n_hint if n_hint is not None else count_records(buf)
+    if est is None:
+        return None
+    values = np.zeros((max(est, 1),), dtype=np.int64)
+    kinds = np.zeros((max(est, 1),), dtype=np.uint8)
+    p, blen, _keep = _buf_arg(buf)
+    n = lib.fbtpu_stage_field_i64(
+        p, blen, key, len(key),
+        values.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+        kinds.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+        est,
+    )
+    if n < 0:
+        return None
+    n = int(n)
+    return values[:n], kinds[:n], n
+
+
 def has_flux_stagers() -> bool:
     """True when the loaded .so exports the flux entry points (a stale
     prebuilt library may predate them — callers should then skip the
     batched flux path once instead of probing per chunk)."""
     lib = _load()
     return lib is not None and \
-        getattr(lib, "fbtpu_stage_field_f64", None) is not None
+        getattr(lib, "fbtpu_stage_field_f64", None) is not None and \
+        getattr(lib, "fbtpu_stage_field_i64", None) is not None
 
 
 def hll_update(registers: np.ndarray, batch: np.ndarray,

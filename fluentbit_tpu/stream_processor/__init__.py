@@ -674,9 +674,8 @@ class SPTask:
         ``size/advance`` panes (a true sliding window over panes)."""
         q = self.query
         if self.flux is not None:
-            rows = self.flux.rows_on_tick(self._now())
-            if rows:
-                self.emit(self.out_tag, rows)
+            self.flux.close(self._now(),
+                            lambda rows: self.emit(self.out_tag, rows))
             return
         if q.window is None or not q.has_aggregates:
             return
@@ -714,9 +713,7 @@ class SPTask:
     def drain(self) -> None:
         """Shutdown: emit whatever the open window accumulated."""
         if self.flux is not None:
-            rows = self.flux.rows_on_drain()
-            if rows:
-                self.emit(self.out_tag, rows)
+            self.flux.drain(lambda rows: self.emit(self.out_tag, rows))
             return
         if self.query.window is not None and self.query.has_aggregates:
             for pane in self._panes:
@@ -755,12 +752,16 @@ class StreamProcessor:
     def _emit(self, src_task: SPTask, tag: str, bodies: List[dict]) -> None:
         from ..codec.events import decode_events, encode_event, now_event_time
 
+        # the clock is read once an emission (upstream's
+        # package_results does): the rows of one window close carry ONE
+        # record time, and a reader tells the closes apart by it
+        now = now_event_time()
         buf = bytearray()
         for b in bodies:
             if isinstance(b, tuple):  # snapshot flush: (orig_ts, body)
                 ts, body = b
             else:
-                ts, body = now_event_time(), b
+                ts, body = now, b
             buf += encode_event(body, ts)
         data = bytes(buf)
         if self._emitter is None:

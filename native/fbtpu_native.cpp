@@ -565,6 +565,114 @@ long long fbtpu_stage_field_f64(const uint8_t *buf, long long buflen,
 }
 
 // ---------------------------------------------------------------------
+// Typed group-key staging (fbtpu-flux): each record's top-level field
+// `key` as a GROUP BY key → out[i] int64 + kinds[i], flux/state.py's
+// KEY_* codes: 0 missing (no such key, a non-map body, or nil: the
+// exact path's `_get_key` gives None for all three), 1 a string (the
+// string stager carries its bytes), 2 an integer that int64 holds
+// exactly, 3 anything else — a float, a
+// bool, a uint64 past INT64_MAX, bin, ext, a nested value — which the
+// batched path cannot key exactly and declines on. Duplicate map keys
+// keep the LAST occurrence, as the dict decode does.
+// ---------------------------------------------------------------------
+
+static inline int read_key_i64(const uint8_t *p, const uint8_t *end,
+                               int64_t *out) {
+    if (p >= end) return 3;
+    uint8_t b = *p++;
+    if (b <= 0x7f) { *out = (int64_t)b; return 2; }           // pos fixint
+    if (b >= 0xe0) { *out = (int64_t)(int8_t)b; return 2; }   // neg fixint
+    if ((b & 0xe0) == 0xa0 || b == 0xd9 || b == 0xda || b == 0xdb)
+        return 1;                                             // str
+    uint64_t v = 0;
+    switch (b) {
+    case 0xc0: return 0;                                      // nil
+    case 0xcc: if (p + 1 > end) return 3;
+        *out = (int64_t)p[0]; return 2;
+    case 0xcd: if (p + 2 > end) return 3;
+        *out = (int64_t)(((uint32_t)p[0] << 8) | p[1]); return 2;
+    case 0xce: if (p + 4 > end) return 3;
+        *out = (int64_t)(((uint32_t)p[0] << 24) | ((uint32_t)p[1] << 16)
+                         | ((uint32_t)p[2] << 8) | p[3]);
+        return 2;
+    case 0xcf:
+        if (p + 8 > end) return 3;
+        for (int i = 0; i < 8; i++) v = (v << 8) | p[i];
+        if (v > (uint64_t)INT64_MAX) return 3;    // no int64 holds it
+        *out = (int64_t)v;
+        return 2;
+    case 0xd0: if (p + 1 > end) return 3;
+        *out = (int64_t)(int8_t)p[0]; return 2;
+    case 0xd1: if (p + 2 > end) return 3;
+        *out = (int64_t)(int16_t)(((uint16_t)p[0] << 8) | p[1]); return 2;
+    case 0xd2: if (p + 4 > end) return 3;
+        *out = (int64_t)(int32_t)(((uint32_t)p[0] << 24)
+                                  | ((uint32_t)p[1] << 16)
+                                  | ((uint32_t)p[2] << 8) | p[3]);
+        return 2;
+    case 0xd3:
+        if (p + 8 > end) return 3;
+        for (int i = 0; i < 8; i++) v = (v << 8) | p[i];
+        *out = (int64_t)v;
+        return 2;
+    }
+    return 3;
+}
+
+long long fbtpu_stage_field_i64(const uint8_t *buf, long long buflen,
+                                const uint8_t *key, long long keylen,
+                                int64_t *out, uint8_t *kinds,
+                                long long max_records) {
+    const uint8_t *p = buf, *end = buf + buflen;
+    long long rec = 0;
+    while (p < end) {
+        if (rec >= max_records) return -2;
+        const uint8_t *rec_start = p;
+        out[rec] = 0;
+        kinds[rec] = 0;
+        uint32_t outer;
+        const uint8_t *q = read_array_hdr(rec_start, end, &outer);
+        const uint8_t *rec_end = nullptr;
+        if (q && outer >= 2) {
+            const uint8_t *body = skip_obj(q, end, 0);
+            if (body) {
+                uint32_t pairs;
+                const uint8_t *kv = read_map_hdr(body, end, &pairs);
+                if (kv) {
+                    for (uint32_t i = 0; i < pairs && kv; i++) {
+                        uint32_t klen;
+                        const uint8_t *kstr = read_str_hdr(kv, end, &klen);
+                        const uint8_t *val;
+                        bool match = false;
+                        if (kstr) {
+                            val = kstr + klen;
+                            if (val > end) { kv = nullptr; break; }
+                            match = ((long long)klen == keylen &&
+                                     memcmp(kstr, key, klen) == 0);
+                        } else {
+                            val = skip_obj(kv, end, 0);
+                            if (!val) { kv = nullptr; break; }
+                        }
+                        if (match) {
+                            int64_t v = 0;
+                            int kind = read_key_i64(val, end, &v);
+                            out[rec] = kind == 2 ? v : 0;
+                            kinds[rec] = (uint8_t)kind;
+                        }
+                        kv = skip_obj(val, end, 0);
+                    }
+                    if (kv && outer == 2) rec_end = kv;
+                }
+            }
+        }
+        p = rec_end ? rec_end : skip_obj(rec_start, end, 0);
+        if (!p) return -1;
+        rec++;
+    }
+    return rec;
+}
+
+// ---------------------------------------------------------------------
 // Host-pinned sketch updates (fbtpu-flux): the bit-identical C twins of
 // the device HLL/count-min kernels (fluentbit_tpu/ops/sketch.py), used
 // while the backend is still attaching (or pinned to CPU). Hash is

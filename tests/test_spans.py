@@ -1272,7 +1272,11 @@ def test_log_to_metrics_and_flux_have_raw_timings():
             assert tm[key] > 0, (plugin.mode, key)
     tm = flux.raw_timings
     assert tm is flux.state.timings
-    assert set(tm) == {"absorb_s"} and tm["absorb_s"] > 0
+    from fluentbit_tpu.flux.state import TIMING_KEYS
+
+    assert set(tm) == set(TIMING_KEYS) and tm["absorb_s"] > 0
+    assert 0 < tm["group_s"] <= tm["absorb_s"]
+    assert tm["host_absorbs"] == 1 and tm["fused_absorbs"] == 0
     assert len(tm._shards) == 1
 
 

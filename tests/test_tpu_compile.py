@@ -306,6 +306,22 @@ def test_donating_fused_absorb_compiles_for_one_chip(one_chip):
     assert compiled.memory_analysis().alias_size_in_bytes == stack_bytes
 
 
+def test_count_only_fused_absorb_compiles_for_one_chip(one_chip):
+    """The absorb of a state with no sketch (``nexmark-q5``: COUNT(*)
+    GROUP BY an integer key): segment ids and validity in, the one
+    512-slot count table out, nothing donated."""
+    from fluentbit_tpu.flux.state import FluxState
+
+    slots = FluxState._FUSED_MAX_GROUPS
+    fn = kernels.build_fused_absorb(None, kernels._pad_segments(slots), 0,
+                                    HLL_P, None, donate=True)
+    compiled = fn.lower(sds((PUSH,), jnp.int32, one_chip),
+                        sds((PUSH,), jnp.int32, one_chip)).compile()
+    (counts,) = compiled.out_info
+    assert counts.shape == (slots,) and counts.dtype == jnp.int32
+    assert compiled.memory_analysis().alias_size_in_bytes == 0
+
+
 # -- four chips: the mesh programs and their collectives ------------------
 
 @pytest.mark.parametrize("with_counts,collective", [(True, True),
