@@ -36,8 +36,12 @@ fallback that reports success.
                                     # no phase: the compiled match
                                     # programs alone for the first R
                                     # grep (or rewrite_tag) rules of
-                                    # CONF, and a frame in two groups
-                                    # against the whole (rules_sweep)
+                                    # CONF, child by child, and a frame
+                                    # in two groups against the whole
+                                    # (rules_sweep); with
+                                    # --child-budgets 1024,48,32,16 the
+                                    # children's probe alone at each
+                                    # table budget (MiB)
 
 Every line of stdout is one JSON object; the LAST line is
 ``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}``
@@ -564,12 +568,13 @@ def grep_launch_probe(prog, batches=(SEGMENT,), kernels=("scan", "assoc"),
                         f"{[K, B, L]} (S={child.max_states}, k={child.k})")
 
 
-def rules_sweep(conf: str, maker: str, sizes) -> None:
+def rules_sweep(conf: str, maker: str, sizes, budgets_mib=()) -> None:
     """The compiled match programs alone along a rule axis: for each
     ``R`` of ``sizes`` the first ``R`` rules of pipeline file ``conf``'s
     grep filter — or of its ``rewrite_tag`` filter, where it has no
     grep — as the filter builds them (``program_for`` with the filter's
-    ``plane_of``: per-stride children under ``FBTPU_MESH_RULE_SHARD_R``),
+    ``plane_of``: scan children — one stride and at most 16 MiB of
+    tables a child — under ``FBTPU_MESH_RULE_SHARD_R``),
     probed child by child on the scan kernel (``grep_launch_probe``)
     over 256, 1,024 and ``SEGMENT`` records of the benchmark's corpus
     maker ``maker`` staged at L=256 and L=512 (a longer value is an
@@ -577,7 +582,12 @@ def rules_sweep(conf: str, maker: str, sizes) -> None:
     on the same rows, and a frame staged in two groups against the
     frame staged whole (``two_group_probe``). How a launch's device
     time grows with the list, the rows and the width, without the
-    pipeline around it."""
+    pipeline around it. Each child's module alone over a frame staged
+    as a catch-up cell stages it says what a gathered element costs
+    from that child's tables (``child_probe``); with ``budgets_mib``
+    that probe alone, for the same rules laid out under each child
+    table budget in turn (``GrepProgram(child_budget=)``; the numbers
+    beside ``ops.grep._CHILD_TABLE_BUDGET``)."""
     import importlib.util
     import re
 
@@ -585,7 +595,7 @@ def rules_sweep(conf: str, maker: str, sizes) -> None:
 
     from fluentbit_tpu.config_format import load_config_file
     from fluentbit_tpu.core.plugin import Properties
-    from fluentbit_tpu.ops.grep import program_for
+    from fluentbit_tpu.ops.grep import GrepProgram, program_for
     from fluentbit_tpu.plugins.filter_grep import (parse_grep_rules,
                                                    plane_index)
     from fluentbit_tpu.plugins.filter_rewrite_tag import RewriteRule
@@ -626,16 +636,20 @@ def rules_sweep(conf: str, maker: str, sizes) -> None:
     for R in sizes:
         patterns = tuple(r.regex.pattern for r in rules[:R])
         prog = program_for(patterns, max_len, plane_of=plane_of[:R])
+        for mib in budgets_mib:
+            laid = GrepProgram(prog.dfas, max_len, plane_of=plane_of[:R],
+                               child_budget=mib << 20)
+            require(laid.try_ready(), f"{R} rules at {mib} MiB a child "
+                    "did not attach")
+            child_probe(laid, patterns, values, budget_mib=mib)
+        if budgets_mib:
+            continue
         require(prog.try_ready(), f"the {R}-rule program did not attach")
-        children = prog._children or [prog]
         say(stage="rules_sweep:program", rules=R,
-            children=[{"k": c.k, "rules": len(c.dfas),
-                       "max_states": c.max_states,
-                       "table_mb": round(
-                           c._tbl["trans_flat"].nbytes / 1e6, 1)}
-                      for c in children],
+            children=prog.decision()["children"],
             elements_256=prog.scan_elements(SEGMENT, 256),
             elements_512=prog.scan_elements(SEGMENT, 512))
+        child_probe(prog, patterns, values)
         grep_launch_probe(prog, batches=(256, 1024, SEGMENT),
                           kernels=("scan",), stage=stage)
         two_group_probe(prog, patterns, values)
@@ -653,6 +667,86 @@ def rules_sweep(conf: str, maker: str, sizes) -> None:
                 rows_matched=int(got.any(axis=0).sum()))
 
 
+def two_group_frame(values, B: int, n_long: int):
+    """``B`` rows of ``values`` of at most 256 B with ``n_long`` rows of
+    257-500 B (corpus values joined) spread among them, as
+    ``filter_grep.staged_match`` would send them: whole at L=512, and
+    the main group at L=256 with the long rows as rows without a value
+    beside those as a 256-row ``LongGroup`` at L=512. → ``(rows, at,
+    (planes, lengths) whole, (planes, lengths) main, (planes, lengths,
+    row indices) long)``, the arrays on the device."""
+    import jax
+    import numpy as np
+
+    from fluentbit_tpu.plugins.filter_grep import LongGroup
+
+    short = [v for v in values if len(v) <= 256]
+    joined = b" ".join(values)
+    put = jax.device_put
+    rows = [short[i % len(short)] for i in range(B)]
+    at = np.linspace(0, B - 1, n_long).astype(np.int32)
+    for j, i in enumerate(at):
+        cut = 257 + (j * 61) % 244
+        rows[i] = joined[j * 97:j * 97 + cut]
+    whole = np.zeros((1, B, 512), dtype=np.uint8)
+    lengths = np.zeros((1, B), dtype=np.int32)
+    for i, v in enumerate(rows):
+        whole[0, i, :len(v)] = np.frombuffer(v, dtype=np.uint8)
+        lengths[0, i] = len(v)
+    group = LongGroup.of([(whole[0], lengths[0])], at, 512, B)
+    main_len = lengths.copy()
+    main_len[0, at] = -1
+    return (rows, at, (put(whole), put(lengths)),
+            (put(np.ascontiguousarray(whole[:, :, :256])), put(main_len)),
+            tuple(put(a) for a in group[:3]))
+
+
+def child_probe(prog, patterns, values, budget_mib=None) -> None:
+    """What a gathered element costs from each child's tables: every
+    child's module alone (``_jit_long``, forced; median of 7 on the
+    host's clock) over one frame staged as a catch-up cell stages it —
+    ``SEGMENT`` rows at L=256 and its 100 lines of 257-500 B as a
+    256-row group at L=512 — with the child's name, stride, rules and
+    laid-out table bytes (``decision()["children"]``), and then the
+    whole launch, children back to back and the merge, its verdicts
+    held to ``re``."""
+    import re
+
+    import numpy as np
+
+    rows, _at, _whole, d_main, d_long = two_group_frame(
+        values, SEGMENT, 100)
+    B_long = d_long[0].shape[1]
+    total = 0.0
+    for child, said in zip(prog._children or [prog],
+                           prog.decision()["children"]):
+        elements = (child.scan_elements(SEGMENT, 256)
+                    + child.scan_elements(B_long, 512))
+        child._jit_long(*d_main, *d_long).block_until_ready()  # compiles
+        ms = median_ms(lambda: child._jit_long(
+            *d_main, *d_long).block_until_ready())
+        total += ms
+        say(stage="rules_sweep:child", budget_mib=budget_mib,
+            list_rules=len(patterns), **said, elements=elements,
+            launch_ms=ms, ns_per_element=round(1e6 * ms / elements, 2))
+    got = np.asarray(prog._enqueue(*d_main, d_long))
+    want = np.array([[re.search(p, v.decode()) is not None for v in rows]
+                     for p in patterns])
+    require(np.array_equal(got, want),
+            f"{len(patterns)} rules at {budget_mib} MiB a child: the "
+            f"program and re differ on {int((got != want).sum())} verdicts")
+    elements = (prog.scan_elements(SEGMENT, 256)
+                + prog.scan_elements(B_long, 512))
+    ms = median_ms(lambda: prog._enqueue(
+        *d_main, d_long).block_until_ready())
+    say(stage="rules_sweep:launch", budget_mib=budget_mib,
+        list_rules=len(patterns), children=len(prog._children or [prog]),
+        table_bytes=prog.table_bytes, elements=elements, equal=True,
+        children_alone_ms=round(total, 3), launch_ms=ms,
+        ns_per_element=round(1e6 * ms / elements, 2),
+        note="smoke observation, one run, not a benchmark")
+
+
 def two_group_probe(prog, patterns, values, batches=(1024, SEGMENT),
                     longs=(1, 100, 256)) -> None:
     """A frame staged in two groups against the frame staged whole, on
@@ -668,36 +762,15 @@ def two_group_probe(prog, patterns, values, batches=(1024, SEGMENT),
     The numbers beside ``filter_grep._LONG_SHARE``."""
     import re
 
-    import jax
     import numpy as np
-
-    from fluentbit_tpu.plugins.filter_grep import LongGroup
 
     def forced_ms(*args):
         return median_ms(lambda: prog._enqueue(*args).block_until_ready())
 
-    short = [v for v in values if len(v) <= 256]
-    joined = b" ".join(values)
-    put = jax.device_put
     for B in batches:
         for n_long in longs:
-            rows = [short[i % len(short)] for i in range(B)]
-            at = np.linspace(0, B - 1, n_long).astype(np.int32)
-            for j, i in enumerate(at):
-                cut = 257 + (j * 61) % 244
-                rows[i] = joined[j * 97:j * 97 + cut]
-            whole = np.zeros((1, B, 512), dtype=np.uint8)
-            lengths = np.zeros((1, B), dtype=np.int32)
-            for i, v in enumerate(rows):
-                whole[0, i, :len(v)] = np.frombuffer(v, dtype=np.uint8)
-                lengths[0, i] = len(v)
-            group = LongGroup.of([(whole[0], lengths[0])], at, 512, B)
-            main_len = lengths.copy()
-            main_len[0, at] = -1
-            d_whole = put(whole), put(lengths)
-            d_main = put(np.ascontiguousarray(whole[:, :, :256])), \
-                put(main_len)
-            d_long = tuple(put(a) for a in group[:3])
+            rows, at, d_whole, d_main, d_long = two_group_frame(
+                values, B, n_long)
             got_whole = np.asarray(prog._enqueue(*d_whole))
             got_two = np.asarray(prog._enqueue(*d_main, d_long))
             want = np.array([[re.search(p, v.decode()) is not None
@@ -713,7 +786,7 @@ def two_group_probe(prog, patterns, values, batches=(1024, SEGMENT),
                 long_matches=int(got_two[:, at].sum()),
                 elements_whole=prog.scan_elements(B, 512),
                 elements_two=prog.scan_elements(B, 256)
-                + prog.scan_elements(*group.planes.shape[1:]),
+                + prog.scan_elements(*d_long[0].shape[1:]),
                 whole_512_ms=whole_ms, two_groups_ms=two_ms,
                 main_256_alone_ms=forced_ms(*d_main),
                 long_group_alone_ms=forced_ms(*d_long[:2]),
@@ -987,6 +1060,10 @@ def main(argv=None) -> int:
                          "SIZES (1,20,50), over records of the "
                          "benchmark's corpus maker MAKER, and a frame in "
                          "two groups against the whole (rules_sweep)")
+    ap.add_argument("--child-budgets", default="", metavar="MIB",
+                    help="with --rules-sweep: only the children's probe, "
+                         "the rules laid out under each of these child "
+                         "table budgets (MiB, 1024,48,32,16) in turn")
     args = ap.parse_args(argv)
     dev = None
     try:
@@ -994,7 +1071,9 @@ def main(argv=None) -> int:
             conf, maker, sizes = args.rules_sweep
             dev = attach(1)
             rules_sweep(os.path.abspath(conf), os.path.abspath(maker),
-                        [int(n) for n in sizes.split(",")])
+                        [int(n) for n in sizes.split(",")],
+                        [int(n) for n in args.child_budgets.split(",")
+                         if n])
         else:
             dev = run(args.chips)
     except BaseException as e:  # noqa: BLE001 - every failure is a verdict

@@ -27,9 +27,8 @@ DFA (fluentbit_tpu.regex.dfa) runs over a ``[B, L] uint8`` batch as a
   benchmark's rules, 255 at worst), so the super-symbol prepass
   computes ``class(b) = class_base + Σ_n (b >= run_start[n]) *
   run_delta[n]`` in the same elementwise pass that pads and combines k
-  classes — an element gather costs 8-11 ns an element on a v5e,
-  whatever the table's size (PERF.md, PR 28), a compare-add next to
-  nothing.
+  classes — an element gather costs 8-12 ns an element on a v5e
+  (PERF.md, PRs 28 and 41), a compare-add next to nothing.
 - Padding positions map to the EOL symbol class, which is absorbing after
   the first step — fixed shapes stay exact, no masking in the inner loop.
 - matched == (final_state == ACC): single comparison at scan end, no
@@ -42,8 +41,15 @@ DFA (fluentbit_tpu.regex.dfa) runs over a ``[B, L] uint8`` batch as a
   v5e at ``[1, 4096, 256]`` the benchmark's S=10 rule took 21.1 ms on
   assoc and 2.3 on scan, its S=690 rule 2,707 and 3.3, and scan won at
   every shape down to 256 rows (PERF.md, PR 33); on a host CPU assoc
-  measured 300× slower. Rules whose strides differ split into per-k
-  child programs below the rule-shard regime (``R < 64``).
+  measured 300× slower.
+- Scan children: below the rule-shard regime (``R < 64``) the rules run
+  as child programs, one stride a child and no child's tables — laid
+  out ``[R_c, widest]`` — over ``_CHILD_TABLE_BUDGET`` unless it is one
+  rule (``partition_children``): the scan's gather costs by the table it
+  reads, 8-12 ns an element from children of up to 50 MB and 17-25 from
+  one of 144 MB (PERF.md, PRs 34 and 41), and sorted by size a child
+  pads little. A list whose strides agree and whose tables fit is one
+  program, as it always was.
 
 This module works on any JAX backend (tests force a CPU mesh); on TPU the
 gathers vectorize across the batch dimension.
@@ -51,6 +57,7 @@ gathers vectorize across the batch dimension.
 
 from __future__ import annotations
 
+import collections
 import functools
 import logging
 import threading
@@ -74,6 +81,11 @@ from ..regex.dfa import ACC, DFA, EOL
 
 # table budget for k-byte super-stepping (bytes); C^k columns * S rows * 4
 _TABLE_BUDGET = 4 * 1024 * 1024
+# budget of one scan child's tables as laid out (bytes): ``R_c`` rules
+# padded to the child's widest and their class runs, the whole pytree as
+# ``ops.mesh.replicated_table_bytes`` weighs it. A gathered element costs
+# by the table it is gathered from (v5e; PERF.md, PR 41)
+_CHILD_TABLE_BUDGET = 16 * 1024 * 1024
 
 
 def choose_k(n_states: int, n_classes: int, budget: int = _TABLE_BUDGET) -> int:
@@ -115,6 +127,45 @@ def scan_steps(L: int, k: int) -> int:
     return -(-L // k) + 1
 
 
+def laid_out_bytes(R: int, max_flat: int, n_runs: int) -> int:
+    """What ``R`` rules take as ONE scan child, from shapes alone: every
+    table padded to the widest (``trans_flat[R, max_flat]``), the two
+    ``[R, n_runs]`` class-run arrays and the five ``[R]`` vectors, i32
+    all — to the byte what ``ops.mesh.replicated_table_bytes`` reads off
+    the child's built pytree."""
+    return 4 * R * (max_flat + 2 * max(n_runs, 1) + 5)
+
+
+def partition_children(k_by_rule: Sequence[int], flat: Sequence[int],
+                       n_runs: Sequence[int],
+                       budget: int) -> List[np.ndarray]:
+    """The rules in scan children: stride by stride (ascending), a
+    stride's rules ordered by table size (``flat[r]`` = ``S · C^k``
+    entries, the widest first; file order among equals) and cut greedily
+    wherever one rule more would carry the child's laid-out tables
+    (``laid_out_bytes``; ``n_runs[r]`` the rule's class runs) over
+    ``budget`` — sorted, a child pads little. A rule alone is always a
+    child. → each child's rule indices, the children in that order,
+    a child's rules in file order (so a stride that stays one child is
+    laid out as it always was)."""
+    groups: List[np.ndarray] = []
+    for k in sorted(set(k_by_rule)):
+        rules = sorted((r for r, kk in enumerate(k_by_rule) if kk == k),
+                       key=lambda r: -flat[r])
+        child: List[int] = []
+        runs = 0
+        for r in rules:
+            if child and laid_out_bytes(
+                    len(child) + 1, flat[child[0]],
+                    max(runs, n_runs[r])) > budget:
+                groups.append(np.sort(np.asarray(child, dtype=np.int64)))
+                child, runs = [], 0
+            child.append(r)
+            runs = max(runs, n_runs[r])
+        groups.append(np.sort(np.asarray(child, dtype=np.int64)))
+    return groups
+
+
 class GrepProgram:
     """R compiled DFAs fused into one device program.
 
@@ -126,7 +177,8 @@ class GrepProgram:
 
     def __init__(self, dfas: Sequence[DFA], max_len: int = 512,
                  kernel: str = "auto", segment: int = 32,
-                 plane_of: Optional[Sequence[int]] = None):
+                 plane_of: Optional[Sequence[int]] = None,
+                 child_budget: int = _CHILD_TABLE_BUDGET):
         if not HAVE_JAX:
             raise RuntimeError("jax is unavailable")
         self.dfas = list(dfas)
@@ -157,44 +209,54 @@ class GrepProgram:
 
         # fbtpu-shrink: per-DFA stride selection. choose_k re-resolves
         # here against the MINIMIZED (S, C) — the whole point of the
-        # compile-path reduction is that these numbers shrank. When the
-        # rules disagree on k, the program splits into per-k child
-        # programs (each a plain homogeneous GrepProgram) instead of
-        # pinning the whole fleet to min(k): a literal rule's k=6 no
-        # longer rides at a rich parser's k=3. The split is gated off
-        # the rule-shard regime (large R wants ONE fused table set to
-        # shard over the rule axis — ops/mesh.py).
+        # compile-path reduction is that these numbers shrank. The rules
+        # then go into scan children (each a plain homogeneous
+        # GrepProgram) by what the code can observe of them, their
+        # tables: one stride a child — a literal rule's k=6 does not
+        # ride at a rich parser's k=3 — and no child's laid-out tables
+        # over ``child_budget`` where it holds more than one rule
+        # (partition_children). A list that comes out as one child is this
+        # program itself. The split is gated off the rule-shard regime
+        # (large R wants ONE fused table set to shard over the rule
+        # axis — ops/mesh.py).
         self.k_by_rule = [choose_k(d.n_states, d.n_classes)
                           for d in self.dfas]
         # byte classing as breakpoints (class_runs); decision() reads
-        # the per-rule counts on a mixed-k parent too
+        # the per-rule counts on a split parent too
         runs = [class_runs(d.class_map) for d in self.dfas]
         self._class_runs = [int(st.size) for _, st, _ in runs]
         self._children: Optional[List["GrepProgram"]] = None
         self._inv_perm: Optional[np.ndarray] = None
-        self._child_idxs: Optional[List[np.ndarray]] = None
-        distinct_ks = sorted(set(self.k_by_rule))
+        #: what sets this child's module name apart from an earlier
+        #: child's of the same stride (the parent sets it)
+        self.name_tag = ""
         import os as _os
         min_shard_r = int(_os.environ.get("FBTPU_MESH_RULE_SHARD_R", "64"))
-        if len(distinct_ks) > 1 and R < min_shard_r:
-            self._child_idxs = [
-                np.asarray([i for i, kk in enumerate(self.k_by_rule)
-                            if kk == k], dtype=np.int64)
-                for k in distinct_ks
-            ]
+        groups = partition_children(
+            self.k_by_rule,
+            [d.n_states * d.n_classes ** k
+             for d, k in zip(self.dfas, self.k_by_rule)],
+            self._class_runs, child_budget) if 1 < R < min_shard_r else []
+        if len(groups) > 1:
             self._children = [
                 GrepProgram([self.dfas[int(i)] for i in idxs], max_len,
                             kernel=self.kernel, segment=segment,
                             plane_of=[self.plane_of[int(i)]
-                                      for i in idxs])
-                for idxs in self._child_idxs
+                                      for i in idxs],
+                            child_budget=child_budget)
+                for idxs in groups
             ]
+            seen: collections.Counter = collections.Counter()
             for c in self._children:
                 c.n_planes = self.n_planes
-            perm = np.concatenate(self._child_idxs)
-            self._inv_perm = np.argsort(perm)
-            self.k = distinct_ks[0]
+                # the first child of a stride keeps the name a stride's
+                # one child has; the trace's readers sum by module name
+                c.name_tag = f"_c{seen[c.k]}" if seen[c.k] else ""
+                seen[c.k] += 1
+            self._inv_perm = np.argsort(np.concatenate(groups))
+            self.k = min(self.k_by_rule)
             self.max_states = max(d.n_states for d in self.dfas)
+            self.table_bytes = sum(c.table_bytes for c in self._children)
             self._merge_jit = None
             self._np = None
             self._jit = None
@@ -235,6 +297,11 @@ class GrepProgram:
             "starts": np.asarray([d.start for d in self.dfas],
                                  dtype=np.int32),
         }
+        from .mesh import replicated_table_bytes
+
+        #: the tables as laid out, bytes (what ``child_budget`` bounds
+        #: and ``mesh_variant`` weighs)
+        self.table_bytes = replicated_table_bytes(self._np)
         self.max_states = max(d.n_states for d in self.dfas)
         self._jit = None
         self._mat_lock = threading.Lock()
@@ -275,18 +342,20 @@ class GrepProgram:
                 "k": self.k_by_rule[r],
                 "class_runs": self._class_runs[r],
             })
-        if self._children is not None:
-            resolved = {c.kernel_resolved for c in self._children}
-            kernel_resolved = (resolved.pop() if len(resolved) == 1
-                               else "mixed")
-            k_groups = [int(c.k) for c in self._children]
-        else:
-            kernel_resolved = self.kernel_resolved
-            k_groups = [int(self.k)]
+        children = self._children or [self]
+        resolved = {c.kernel_resolved for c in children}
+        kernel_resolved = resolved.pop() if len(resolved) == 1 else "mixed"
         return {
             "rules": rules,
             "k": int(self.k),
-            "k_groups": k_groups,
+            "k_groups": [int(c.k) for c in children],
+            # the scan children in launch order, ``table_bytes`` the
+            # tables as laid out (each within the child budget unless
+            # the child is one rule)
+            "children": [
+                {"name": c.program_name(), "k": int(c.k),
+                 "rules": len(c.dfas), "table_bytes": int(c.table_bytes)}
+                for c in children],
             # one entry a mesh handle a child has built (none before
             # the first sharded launch): each child decides its own
             # variant (mesh_variant)
@@ -303,18 +372,22 @@ class GrepProgram:
 
     def program_name(self, suffix: str = "") -> str:
         """The jitted function's name — ``jit_<name>`` is the module
-        name a profiler trace shows on ``XLA Modules`` (known once the
-        kernel is resolved)."""
-        return (f"grep_{self.kernel_resolved}_S{self.max_states}"
-                f"_k{self.k}{suffix}")
+        name a profiler trace shows on ``XLA Modules``. One name a
+        child, whatever it shares with a sibling (``name_tag``:
+        ``…_k3``, ``…_k3_c1``, ``…_k3_c2``): the trace's readers take a
+        launch as the sum over the names."""
+        return (f"grep_{self.kernel_resolved or self._resolve_kernel()}"
+                f"_S{self.max_states}_k{self.k}{self.name_tag}{suffix}")
 
     def scan_elements(self, B: int, L: int) -> int:
         """Gathered elements one launch over ``[K, B, L]`` planes steps
         through on the scan kernel: every rule of every child reads one
         table entry a row and super-step, ``Σ R_c · B · scan_steps(L,
-        k_c)`` — what a launch's device time counts in (8-11 ns an
-        element on a v5e from a child's table of up to 58 MB, 16-25
-        from one of 144 MB and more; PERF.md, PRs 28, 33 and 34). From
+        k_c)`` — what a launch's device time counts in (8-12 ns an
+        element on a v5e from a child's tables of up to 50 MB, which
+        ``_CHILD_TABLE_BUDGET`` keeps every child under; 17-25 from
+        one of 144 MB; PERF.md, PRs 28, 33, 34 and 41). The same sum
+        however the rules of a stride are cut into children. From
         shapes alone; the assoc kernel gathers ``S``× more and is not
         counted here."""
         return sum(len(c.dfas) * B * scan_steps(L, c.k)
@@ -569,7 +642,7 @@ class GrepProgram:
 
         ``planes[K, B, L]`` / ``lengths[K, B]`` are the distinct staged
         fields; they cross to the device ONCE, whatever the number of
-        rules or per-k children that read them. → ``mask[R, B]`` bool,
+        rules or scan children that read them. → ``mask[R, B]`` bool,
         or with ``first_match`` the ``[B]`` i32 first-match vector.
 
         ``long``: the frame's few long rows as a narrow group of their
@@ -597,8 +670,8 @@ class GrepProgram:
     def _enqueue(self, planes, lengths, long=None):
         """The jitted calls over planes that are on the device."""
         if self._children is not None:
-            # per-k child programs: every child launches (async) before
-            # the merge touches any result, so the k-groups overlap the
+            # child programs: every child launches (async) before the
+            # merge touches any result, so the children overlap the
             # same way double-buffered segments do
             return self._merge_rule_axis(
                 [c._enqueue(planes, lengths, long)
@@ -632,38 +705,37 @@ class GrepProgram:
         mesh evenly (no rule padding — a dead-rule pad row would cost a
         full batch scan).
 
-        On a k-split parent this is the FIRST child's answer and no
+        On a split parent this is the FIRST child's answer and no
         more: ``dispatch_mesh`` asks each child, and the children need
-        not agree (grep-tenants' 5, 38, 6 and 1 rules all take
-        ``batch`` on four devices because none divides by four; 36 or
-        40 rules at k=3, 144 MB of tables a device, would take
-        ``rules``). ``decision()["mesh_children"]`` reports what each
-        child's handle took."""
+        not agree. Since a child of more than one rule lays out at most
+        ``_CHILD_TABLE_BUDGET`` (16 MiB) of tables — the same bytes
+        weighed here — four replicas never cross ``TABLE_BUDGET``:
+        grep-tenants' nine children (5; 4, 5, 6, 11, 12; 4, 2; 1 rules)
+        all take ``batch`` on four devices, the three that divide by
+        four among them, where 36 of its k=3 rules in ONE child, 137 MB
+        a device, would take ``rules``. ``decision()["mesh_children"]``
+        reports what each child's handle took."""
         import os as _os
 
-        from .mesh import TABLE_BUDGET, replicated_table_bytes
+        from .mesh import TABLE_BUDGET
 
         if self._children is not None:
-            # a k-split parent has no variant of its own: dispatch_mesh
+            # a split parent has no variant of its own: dispatch_mesh
             # lets every child decide for its own slice, and this is
             # only the first child's answer. The split is gated off the
             # R >= FBTPU_MESH_RULE_SHARD_R arm, not off the table arm:
             # a child whose rule count divides the mesh and whose
-            # tables, replicated, cross TABLE_BUDGET takes "rules"
-            # beside siblings on "batch" (decision()["mesh_children"]
+            # tables, replicated, cross TABLE_BUDGET would take "rules"
+            # beside siblings on "batch" — which on up to four devices
+            # the child budget rules out (decision()["mesh_children"]
             # says what each took)
             return self._children[0].mesh_variant(mesh)
         n_dev = mesh.devices.size
         R = len(self.dfas)
         if R < 2 or R % n_dev != 0:
             return "batch"
-        tbl = getattr(self, "_tbl", None)
-        if tbl is None:
-            table_bytes = replicated_table_bytes(self._np)
-        else:
-            table_bytes = replicated_table_bytes(tbl)
         min_r = int(_os.environ.get("FBTPU_MESH_RULE_SHARD_R", "64"))
-        if table_bytes * n_dev > TABLE_BUDGET or R >= min_r:
+        if self.table_bytes * n_dev > TABLE_BUDGET or R >= min_r:
             return "rules"
         return "batch"
 
@@ -820,7 +892,7 @@ class GrepProgram:
         from .mesh import pad_to_devices
 
         if self._children is not None:
-            # per-k children: launch them all first (async), then merge
+            # scan children: launch them all first (async), then merge
             # on the rule axis. Each child takes the host planes whole
             # and places them itself (a donated buffer cannot be shared
             # between programs). Children may pad B differently (the

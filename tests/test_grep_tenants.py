@@ -1,8 +1,12 @@
 """The grep-tenants configuration at a small size on the CPU: 50
 ``Exclude`` rules on ``log`` (``benchmark/configs/grep-tenants.conf``:
 ``BASELINE.json`` config 5's filter_grep at the top of its rule sweep)
-as the ``GrepProgram`` the filter builds — four per-stride scan children
-at R < 64 — against Python's ``re``; at the sweep's other sizes (1, 20);
+as the ``GrepProgram`` the filter builds — nine scan children at R < 64,
+one stride a child and no child's laid-out tables over 16 MiB — against
+Python's ``re``; at the sweep's other sizes (1, 20); the partition
+itself (``partition_children``: the budget kept, every rule in one child,
+the verdict in file order and bit-equal whatever the budget, a name a
+child, the other configurations' programs laid out as they were);
 ``process_batch`` on the configuration's own pipeline
 file against the host chain's bytes; what the program decides
 (``decision()``), what the staged launch counts (``d2h_bytes``,
@@ -27,7 +31,9 @@ from fluentbit_tpu.config_format import load_config_file
 from fluentbit_tpu.core.chunk_batch import RawChunk
 from fluentbit_tpu.core.plugin import registry
 from fluentbit_tpu.ops import device
+from fluentbit_tpu.ops import grep as grep_ops
 from fluentbit_tpu.ops.grep import GrepProgram, program_for, scan_steps
+from fluentbit_tpu.ops.mesh import replicated_table_bytes
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmark")
@@ -173,27 +179,41 @@ def test_no_two_rules_are_the_same_automaton(program):
     assert len(shapes) == 50
 
 
-#: the per-stride children the constructor builds at each size of the
-#: sweep, ``(k, rules)``: PERF.md section 4, ``grep-tenants``
-CHILDREN = {1: [(4, 1)], 20: [(2, 2), (3, 14), (4, 4)],
-            50: [(2, 5), (3, 38), (4, 6), (5, 1)]}
+#: the scan children the constructor builds at each size of the sweep,
+#: ``(k, rules)`` in launch order — one stride a child, a stride's rules
+#: by table size in children of at most 16 MiB as laid out: PERF.md
+#: section 4, ``grep-tenants``
+CHILDREN = {1: [(4, 1)],
+            20: [(2, 2), (3, 4), (3, 7), (3, 3), (4, 4)],
+            50: [(2, 5), (3, 4), (3, 5), (3, 6), (3, 11), (3, 12),
+                 (4, 4), (4, 2), (5, 1)]}
+#: the same lists with one child a stride (``child_budget`` set high):
+#: the layout up to PR 40
+PER_STRIDE = {1: [(4, 1)], 20: [(2, 2), (3, 14), (4, 4)],
+              50: [(2, 5), (3, 38), (4, 6), (5, 1)]}
+NO_BUDGET = 1 << 40
 
 
 @pytest.mark.parametrize("size", SWEEP)
 def test_program_decision_is_what_perf_md_says(size, program):
-    """R < 64: one scan child a stride among the first ``size`` rules of
-    the file, each at its own stride (PERF.md section 4)."""
+    """R < 64: the first ``size`` rules of the file in scan children,
+    each rule at its own stride, each child within the table budget
+    (PERF.md section 4); the elements a launch steps through are the
+    per-stride layout's to the element."""
     prog = program if size == 50 else GrepProgram(
         program.dfas[:size], 512, plane_of=PLANE_OF[:size])
     d = prog.decision()
     by_k = collections.Counter(r["k"] for r in d["rules"])
-    assert sorted(by_k.items()) == CHILDREN[size]
+    assert sorted(by_k.items()) == PER_STRIDE[size]
     children = prog._children or [prog]
     assert [(c.k, len(c.dfas)) for c in children] == CHILDREN[size]
     assert d["k_groups"] == [k for k, _n in CHILDREN[size]]
+    assert [(c["k"], c["rules"]) for c in d["children"]] == CHILDREN[size]
+    assert [c["table_bytes"] for c in d["children"]] \
+        == [c.table_bytes for c in children]
     assert prog.n_planes == 1 and prog.plane_of == PLANE_OF[:size]
     assert prog.scan_elements(4096, 512) == 4096 * sum(
-        n * scan_steps(512, k) for k, n in CHILDREN[size])
+        n * scan_steps(512, k) for k, n in PER_STRIDE[size])
     if size == 50:
         assert len(by_k) >= 3
         assert d["max_states"] == 80
@@ -201,6 +221,145 @@ def test_program_decision_is_what_perf_md_says(size, program):
         assert d["kernel_resolved"] == "scan"
         assert prog.scan_elements(4096, 512) == 35631104
         assert prog.scan_elements(4096, 256) == 17997824
+        # 94 MB of tables where one child a stride laid out 169.5
+        assert [round(c["table_bytes"] / 1e6, 1) for c in d["children"]] \
+            == [0.9, 15.2, 15.8, 16.1, 16.3, 9.6, 14.6, 3.2, 2.4]
+
+
+# ------------------------------------------------- the partition
+
+def rules_of_children(prog) -> list:
+    """Each child's rules as indices into the parent's list (file
+    order), by identity: a child holds the parent's DFA objects."""
+    at = {id(d): i for i, d in enumerate(prog.dfas)}
+    return [[at[id(d)] for d in c.dfas]
+            for c in prog._children or [prog]]
+
+
+def laid_out(size: int, budget: int) -> GrepProgram:
+    dfas = program_for(PATTERNS, 512, plane_of=PLANE_OF).dfas[:size]
+    return GrepProgram(dfas, 512, plane_of=PLANE_OF[:size],
+                       child_budget=budget)
+
+
+@pytest.mark.parametrize("size", SWEEP)
+@pytest.mark.parametrize("mib", [1, 8, 16, 32, 48])
+def test_every_child_is_within_the_budget_or_one_rule(size, mib):
+    """The budget bounds a child's tables as laid out — ``R_c`` times the
+    widest, and the class runs: the whole pytree as the mesh weighs it
+    — unless the child is one rule, which is always a child; and what
+    ``partition_children`` reckons from shapes is what was built."""
+    prog = laid_out(size, mib << 20)
+    children = prog._children or [prog]
+    assert sum(len(c.dfas) for c in children) == size
+    for c in children:
+        assert c._children is None
+        assert c.table_bytes == replicated_table_bytes(c._np) \
+            == grep_ops.laid_out_bytes(
+                len(c.dfas),
+                max(d.n_states * d.n_classes ** c.k for d in c.dfas),
+                max(c._class_runs))
+        assert c.table_bytes <= mib << 20 or len(c.dfas) == 1
+    for c, idxs in zip(children, rules_of_children(prog)):
+        assert {prog.k_by_rule[i] for i in idxs} == {c.k}
+    assert prog.table_bytes == sum(c.table_bytes for c in children)
+    # a smaller budget never lays out more bytes than one child a stride
+    assert prog.table_bytes <= laid_out(size, NO_BUDGET).table_bytes
+    if mib == 16:
+        assert grep_ops._CHILD_TABLE_BUDGET == 16 << 20
+        assert [(c.k, len(c.dfas)) for c in children] == CHILDREN[size]
+
+
+@pytest.mark.parametrize("mib", [1, 8, 16, 48])
+def test_each_rule_is_in_one_child_and_a_strides_children_go_by_size(mib):
+    """Every rule of the file in exactly one child; the children of a
+    stride ordered by table size, the widest rules first; a child's
+    rules in file order."""
+    prog = laid_out(50, mib << 20)
+    where = rules_of_children(prog)
+    assert sorted(i for idxs in where for i in idxs) == list(range(50))
+    assert all(idxs == sorted(idxs) for idxs in where)
+    assert (np.argsort(np.concatenate(where)) == prog._inv_perm).all()
+    size = [d.n_states * d.n_classes ** k
+            for d, k in zip(prog.dfas, prog.k_by_rule)]
+    for (a, ia), (b, ib) in zip(zip(prog._children, where),
+                                zip(prog._children[1:], where[1:])):
+        assert a.k <= b.k
+        if a.k == b.k:
+            assert min(size[i] for i in ia) >= max(size[i] for i in ib)
+
+
+@pytest.mark.parametrize("mib,n_children", [(16, 9), (8, 15), (1, 42)])
+def test_a_name_a_child_and_a_strides_first_keeps_the_strides_name(
+        mib, n_children):
+    """The trace's readers sum a launch by module name
+    (``readers/element_cost.py::launch_seconds``): two children under
+    one name would be read as one. The first child of a stride is named
+    as a stride's one child always was, the later ones ``_c1``, ``_c2``
+    … after it; the mesh's names follow."""
+    prog = laid_out(50, mib << 20)
+    names = [c.program_name() for c in prog._children]
+    assert len(names) == n_children == len(set(names))
+    assert names == [c["name"] for c in prog.decision()["children"]]
+    seen = collections.Counter()
+    for c, name in zip(prog._children, names):
+        n = seen[c.k]
+        seen[c.k] += 1
+        assert name == f"grep_scan_S{c.max_states}_k{c.k}" \
+            + (f"_c{n}" if n else "")
+        assert c.program_name("_mesh") == name + "_mesh"
+    if mib == 16:
+        assert names == [
+            "grep_scan_S80_k2", "grep_scan_S58_k3", "grep_scan_S74_k3_c1",
+            "grep_scan_S52_k3_c2", "grep_scan_S41_k3_c3",
+            "grep_scan_S37_k3_c4", "grep_scan_S32_k4",
+            "grep_scan_S14_k4_c1", "grep_scan_S10_k5"]
+
+
+def test_one_child_a_stride_is_named_as_it_was():
+    """With no budget to speak of the layout and the names are PR 40's."""
+    prog = laid_out(50, NO_BUDGET)
+    assert [(c.k, len(c.dfas)) for c in prog._children] == PER_STRIDE[50]
+    assert [c.program_name() for c in prog._children] == [
+        "grep_scan_S80_k2", "grep_scan_S74_k3", "grep_scan_S32_k4",
+        "grep_scan_S10_k5"]
+    assert round(prog._children[1].table_bytes / 1e6, 1) == 144.3
+
+
+#: the programs of the benchmark's other configurations: no child of
+#: theirs comes near the budget, so each is laid out — name, stride,
+#: rules — as on the commit before the budget (PR 40)
+OTHER_PROGRAMS = {
+    "grep-apache2": ("grep", [("grep_scan_S690_k3", 3, 1),
+                              ("grep_scan_S10_k5", 5, 1)]),
+    "rewrite-syslog": ("rewrite_tag", [("grep_scan_S12_k4", 4, 1),
+                                       ("grep_scan_S9_k5", 5, 2),
+                                       ("grep_scan_S7_k6", 6, 5)]),
+    "nexmark-q5": ("grep", [("grep_scan_S7_k6", 6, 1)]),
+}
+
+
+@pytest.mark.parametrize("conf", sorted(OTHER_PROGRAMS))
+def test_the_other_configurations_programs_are_laid_out_as_they_were(conf):
+    plugin, want = OTHER_PROGRAMS[conf]
+    path = os.path.join(BENCH, "configs", conf + ".conf")
+    section = next(s for s in load_config_file(path).sections
+                   if s.name == "filter"
+                   and s.get("name").lower() == plugin)
+    ins = registry.create_filter(plugin)
+    for k, v in section.properties:
+        if k.lower() != "name":
+            ins.set(k, v)
+    ins.configure()
+    ins.plugin.init(ins, None)
+    prog = ins.plugin._program
+    children = prog._children or [prog]
+    assert [(c.program_name(), c.k, len(c.dfas)) for c in children] == want
+    assert [(c["name"], c["k"], c["rules"])
+            for c in prog.decision()["children"]] == want
+    # rules in file order inside a child, as one child a stride had them
+    assert all(idxs == sorted(idxs) for idxs in rules_of_children(prog))
+    assert max(c.table_bytes for c in children) < 8 << 20
 
 
 @pytest.mark.parametrize("L,k,steps", [(512, 2, 257), (256, 2, 129),
@@ -255,6 +414,79 @@ def test_50_rules_on_one_plane_equal_re(L, corpus, program):
     assert got.any(axis=1).sum() >= 20     # the hot tenants' rules fire
 
 
+@pytest.mark.parametrize("mib", [1, 4])
+def test_the_verdict_is_the_same_whatever_the_budget(mib, corpus):
+    """A small list under a budget that splits its strides (20 rules:
+    1 MiB makes 17 children of them, 4 MiB twelve) against the same
+    rules one child a stride (three children) and against ``re``, rule
+    by rule in file order: the frame staged whole at L=512, and in two
+    groups — the main group at L=256 with the long rows as rows without
+    a value, those as a ``LongGroup`` at L=512 — as ``staged_match``
+    sends a 4,096-row frame."""
+    from fluentbit_tpu.plugins.filter_grep import LongGroup
+
+    split, per_stride = laid_out(20, mib << 20), laid_out(20, NO_BUDGET)
+    assert [(c.k, len(c.dfas)) for c in per_stride._children] \
+        == PER_STRIDE[20]
+    assert len(split._children) == {1: 17, 4: 12}[mib]
+    records = corpus[0]
+    rows = pick(records, 320)             # 49 of 257-500 B, two over 512
+    planes, lengths = staged(records, rows, 512)
+    want = np.array([[len(records[i]["log"]) <= 512
+                      and re.search(p, records[i]["log"]) is not None
+                      for i in rows] for p in PATTERNS[:20]])
+    assert want.any(axis=1).sum() >= 8
+    whole = split.match(planes, lengths)
+    assert whole.shape == (20, 320) and whole.dtype == bool
+    assert (whole == per_stride.match(planes, lengths)).all()
+    assert (whole == want).all(), np.argwhere(whole != want)[:5]
+
+    at = np.flatnonzero(lengths[0] > 256).astype(np.int32)
+    assert at.size == 49
+    group = LongGroup.of([(planes[0], lengths[0])], at, 512, 320)
+    main_len = lengths.copy()
+    main_len[0, at] = -1
+    main = np.ascontiguousarray(planes[:, :, :256])
+    for prog in (split, per_stride):
+        two = np.asarray(prog.dispatch(main, main_len, long=group[:3]))
+        assert (two == want).all(), np.argwhere(two != want)[:5]
+    first = np.asarray(split.dispatch(planes, lengths, first_match=True))
+    assert (first == np.where(want.any(axis=0),
+                              want.argmax(axis=0), -1)).all()
+
+
+def test_the_smokes_probe_says_what_each_child_costs(corpus, monkeypatch):
+    """``chip_smoke.py --rules-sweep … --child-budgets``: a line a
+    child — name, stride, rules, laid-out table bytes, ms a launch, ns
+    an element — and one for the whole launch, its verdicts held to
+    ``re``; here on the CPU at 512 rows (the numbers are the host's)."""
+    import chip_smoke
+
+    said = []
+    monkeypatch.setattr(chip_smoke, "say", lambda **kw: said.append(kw))
+    monkeypatch.setattr(chip_smoke, "SEGMENT", 512)
+    assert device.wait(120)
+    prog = laid_out(8, 1 << 20)
+    assert prog.try_ready()
+    values = [r["log"].encode() for r in corpus[0]]
+    chip_smoke.child_probe(prog, PATTERNS[:8], values, budget_mib=1)
+    lines = [kw for kw in said if kw["stage"] == "rules_sweep:child"]
+    assert [(ln["name"], ln["k"], ln["rules"], ln["table_bytes"])
+            for ln in lines] \
+        == [(c["name"], c["k"], c["rules"], c["table_bytes"])
+            for c in prog.decision()["children"]]
+    assert len(lines) == 7 and len({ln["name"] for ln in lines}) == 7
+    for ln, c in zip(lines, prog._children):
+        assert ln["budget_mib"] == 1 and ln["list_rules"] == 8
+        assert ln["elements"] == c.scan_elements(512, 256) \
+            + c.scan_elements(256, 512)
+        assert ln["launch_ms"] > 0 and ln["ns_per_element"] > 0
+    (launch,) = [kw for kw in said if kw["stage"] == "rules_sweep:launch"]
+    assert launch["children"] == 7 and launch["equal"] is True
+    assert launch["elements"] == sum(ln["elements"] for ln in lines)
+    assert launch["table_bytes"] == prog.table_bytes
+
+
 # --------------------------- the filter on the configuration's file
 
 def make_filter(extra=()):
@@ -273,7 +505,7 @@ def test_process_batch_equals_the_host_chain(rows_n, corpus, gate_open):
     data = b"".join(encode_event(records[i], float(i)) for i in rows)
     events = decode_events(data)
     dev = make_filter()
-    assert dev._program is not None and len(dev._program._children) == 4
+    assert dev._program is not None and len(dev._program._children) == 9
     assert dev.can_process_batch()
     n_keep, out = dev.process_batch(RawChunk(data, "kube.tenants", rows_n))
     host = make_filter([("tpu.enable", "off")])
@@ -291,7 +523,7 @@ def test_process_batch_equals_the_host_chain(rows_n, corpus, gate_open):
     assert tm["h2d_bytes"] == Bp * (512 + 4)
     assert tm["d2h_bytes"] == 50 * Bp                    # 50 B a row
     assert tm["scan_elements"] == dev._program.scan_elements(Bp, 512) \
-        == Bp * sum(n * scan_steps(512, k) for k, n in CHILDREN[50])
+        == Bp * sum(n * scan_steps(512, k) for k, n in PER_STRIDE[50])
 
 
 def test_dispatch_and_verdict_spans_say_what_was_launched(corpus, gate_open,

@@ -1,6 +1,6 @@
 """The grep-tenants-x4 configuration at a small size on the CPU: the
-50-rule program of ``benchmark/configs/grep-tenants.conf`` (four
-per-stride scan children) sharded by rows over four of conftest's eight
+50-rule program of ``benchmark/configs/grep-tenants.conf`` (nine scan
+children of at most 16 MiB of tables) sharded by rows over four of conftest's eight
 virtual devices, as ``BASELINE.json`` config 5 lays it over its chips —
 (a) a chip's share of the verdict, shard by shard, against the
 one-device kernel and the benchmark's plain reference; (b) the served
@@ -28,8 +28,8 @@ from fluentbit_tpu.ops.mesh import (TABLE_BUDGET, build_mesh,
                                     pad_to_devices, replicated_table_bytes)
 from fluentbit_tpu.plugins.filter_grep import staged_match
 
-from test_grep_tenants import (CHILDREN, CONF, corpus,  # noqa: F401
-                               make_filter, maker, pick, program,
+from test_grep_tenants import (CHILDREN, CONF, NO_BUDGET,  # noqa: F401
+                               corpus, make_filter, maker, pick, program,
                                reference, staged)
 
 pytestmark = pytest.mark.mesh
@@ -109,20 +109,23 @@ def test_row_shards_equal_one_device_and_the_reference(
 # ------------------------------------------------ (c) the variants
 
 def test_every_child_takes_batch_on_four_devices(program, mesh4):
-    """Each child of a k-split parent decides its own variant
+    """Each child of a split parent decides its own variant
     (``dispatch_mesh``); the parent's ``mesh_variant`` is its first
-    child's answer only. All four land on ``batch`` here because none of
-    5, 38, 6 and 1 divides by four — not because their tables are small:
-    the k=3 child's are 144 MB, and replicated four times they are far
-    over ``TABLE_BUDGET``."""
+    child's answer only. All nine land on ``batch``: three of them hold
+    a rule count that divides by four (4 and 12 at k=3, 4 at k=4), and
+    what keeps those off ``rules`` is the child budget — 16 MiB of
+    tables as ``replicated_table_bytes`` weighs them, so four replicas
+    do not cross ``TABLE_BUDGET`` (64 MiB)."""
     children = program._children
     assert [(c.k, len(c.dfas)) for c in children] == CHILDREN[50]
-    assert [c.mesh_variant(mesh4) for c in children] == ["batch"] * 4
+    assert [c.mesh_variant(mesh4) for c in children] == ["batch"] * 9
     assert program.mesh_variant(mesh4) == "batch"
-    k3 = children[1]
-    tables = k3._tbl if k3._np is None else k3._np
-    assert replicated_table_bytes(tables) * CHIPS > TABLE_BUDGET
-    assert all(len(c.dfas) % CHIPS for c in children)
+    divides = [c for c in children if len(c.dfas) % CHIPS == 0]
+    assert [(c.k, len(c.dfas)) for c in divides] == [(3, 4), (3, 12), (4, 4)]
+    for c in children:
+        tables = c._tbl if c._np is None else c._np
+        assert c.table_bytes == replicated_table_bytes(tables)
+        assert c.table_bytes * CHIPS <= TABLE_BUDGET
     # once a child has a handle for the mesh, decision() says what it took
     program.dispatch_mesh(mesh4, *staged([{"log": "x"}], [0], 512),
                           with_counts=False)
@@ -132,18 +135,27 @@ def test_every_child_takes_batch_on_four_devices(program, mesh4):
 
 
 @pytest.mark.parametrize("n_rules,variant", [(36, "rules"), (37, "batch")])
-def test_what_would_flip_the_k3_child(n_rules, variant, program, mesh4):
+def test_what_would_flip_a_k3_child(n_rules, variant, program, mesh4):
     """What flips a child to ``rules``: a rule count that divides the
     mesh, with tables that cross ``TABLE_BUDGET`` replicated (or R ≥
-    ``FBTPU_MESH_RULE_SHARD_R``). 36 of the k=3 child's 38 rules would
-    shard the rule axis and have the planes expanded to ``[36, B, 512]``
-    on the host every launch (ROADMAP M5/D14); the configuration's 38 do
-    not divide, nor do 37."""
-    k3 = program._children[1]
-    cut = GrepProgram(k3.dfas[:n_rules], 512, plane_of=(0,) * n_rules)
+    ``FBTPU_MESH_RULE_SHARD_R``). With no child budget to speak of, 36
+    of the 38 k=3 rules in one child would shard the rule axis and have
+    the planes expanded to ``[36, B, 512]`` on the host every launch
+    (ROADMAP M5/D14); 37 do not divide. Under the module's budget the
+    same 36 rules are children of at most 16 MiB and none can flip."""
+    k3 = [d for d, k in zip(program.dfas, program.k_by_rule) if k == 3]
+    assert len(k3) == 38
+    cut = GrepProgram(k3[:n_rules], 512, plane_of=(0,) * n_rules,
+                      child_budget=NO_BUDGET)
     assert cut._children is None and cut.k == 3
-    assert replicated_table_bytes(cut._np) * CHIPS > TABLE_BUDGET
+    assert cut.table_bytes == replicated_table_bytes(cut._np)
+    assert cut.table_bytes * CHIPS > TABLE_BUDGET
     assert cut.mesh_variant(mesh4) == variant
+    laid = GrepProgram(k3[:n_rules], 512, plane_of=(0,) * n_rules)
+    assert len(laid._children) == 5
+    assert all(c.table_bytes * CHIPS <= TABLE_BUDGET
+               and c.mesh_variant(mesh4) == "batch"
+               for c in laid._children)
 
 
 # --------------------------------------------- (b) the served path
@@ -154,7 +166,7 @@ def chunk_of(records, rows) -> bytes:
 
 def test_process_batch_on_the_mesh_equals_the_host_chain(corpus, lane4):
     """``FBTPU_MESH=1``: the filter's raw path stages at the one mesh
-    width (L=512, rows padded to the mesh) and launches the four
+    width (L=512, rows padded to the mesh) and launches the nine
     children sharded; what it re-emits is byte for byte what the host
     chain (``tpu.enable off``) keeps, mid-length and overflow rows
     among them, and the counters say how the launch was laid out."""
@@ -188,7 +200,7 @@ def test_process_batch_on_the_mesh_equals_the_host_chain(corpus, lane4):
     assert tm["scan_elements"] == dev._program.scan_elements(Bp, 512)
     assert tm["split_launches"] == tm["long_rows"] == 0   # one chip's
     took = dev._program.decision()["mesh_children"]
-    assert len(took) == 4 and all(
+    assert len(took) == 9 and all(
         t["variant"] == "batch" and t["devices"] == CHIPS for t in took)
 
 

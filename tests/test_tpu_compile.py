@@ -181,20 +181,29 @@ def _tenants_patterns():
             if k.lower() == "exclude"]
 
 
+#: grep-tenants' nine scan children, ``(k, rules)`` in launch order
+#: (``tests/test_grep_tenants.py::CHILDREN``)
+TENANTS_CHILDREN = [(2, 5), (3, 4), (3, 5), (3, 6), (3, 11), (3, 12),
+                    (4, 4), (4, 2), (5, 1)]
+
+
 @pytest.mark.parametrize("length", [256, 512])
-@pytest.mark.parametrize("k,n_rules", [(2, 5), (3, 38), (4, 6), (5, 1)],
-                         ids=["k2x5", "k3x38", "k4x6", "k5x1"])
-def test_tenants_child_compiles_for_one_chip(one_chip, k, n_rules, length):
+@pytest.mark.parametrize("at", range(len(TENANTS_CHILDREN)),
+                         ids=["c%d-k%dx%d" % (i, k, n) for i, (k, n)
+                              in enumerate(TENANTS_CHILDREN)])
+def test_tenants_child_compiles_for_one_chip(one_chip, at, length):
     """grep-tenants' program (PR 34): 50 rules on one key, under
-    ``FBTPU_MESH_RULE_SHARD_R``, so one scan child a stride; each takes
-    the one staged plane ``[1, B, L]`` and makes ``[R_c, B, L]`` of it
-    on the device, every rule classed at its child's widest rule's
+    ``FBTPU_MESH_RULE_SHARD_R``, so scan children — one stride a child
+    and at most 16 MiB of laid-out tables (PR 41); each takes the one
+    staged plane ``[1, B, L]`` and makes ``[R_c, B, L]`` of it on the
+    device, every rule classed at its child's widest rule's
     breakpoints."""
     patterns = _tenants_patterns()
     prog = GrepProgram([compile_dfa(p) for p in patterns], 512,
                        plane_of=(0,) * len(patterns))
-    child = next(c for c in prog._children if c.k == k)
-    assert len(child.dfas) == n_rules and child.n_planes == 1
+    assert [(c.k, len(c.dfas)) for c in prog._children] == TENANTS_CHILDREN
+    child = prog._children[at]
+    assert child.n_planes == 1 and child.table_bytes <= 16 << 20
 
     def step(tables, planes, lengths):
         return child._match_impl(
@@ -205,9 +214,9 @@ def test_tenants_child_compiles_for_one_chip(one_chip, k, n_rules, length):
         sds((1, SEGMENT, length), jnp.uint8, one_chip),
         sds((1, SEGMENT), jnp.int32, one_chip)).compile()
     assert compiled.output_shardings.device_set == {one_chip._device}
-    # [38, 4096, L/3 + 1] i32 super-symbols and their transpose at the
-    # widest child: well inside one chip's 16 GB
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+    # [12, 4096, L/3 + 1] i32 super-symbols and their transpose at the
+    # child of most rules: well inside one chip's 16 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 @pytest.mark.parametrize("length", [256, 512])
@@ -382,6 +391,34 @@ def test_one_rule_mesh_child_lays_its_table_out_once(mesh4, which):
     assert f"s32[{n}]" in hlo                 # the 1-D table exists,
     assert " reduce(" not in body             # and is made outside
     assert " copy(" not in body
+
+
+@pytest.mark.parametrize("at", [1, 5, 6], ids=["k3x4", "k3x12", "k4x4"])
+def test_a_tenants_child_that_divides_by_four_still_shards_rows(mesh4, at):
+    """The three tenants children whose rule count divides the mesh
+    (PR 41): within the child budget their tables, replicated four
+    times, do not cross ``ops.mesh.TABLE_BUDGET``, so each compiles for
+    the four chips as a ``batch`` program under a name of its own —
+    rows sharded, the ``[R_c, N]`` table whole on every chip."""
+    mesh = mesh4("batch")
+    patterns = _tenants_patterns()
+    child = GrepProgram([compile_dfa(p) for p in patterns], 512,
+                        plane_of=(0,) * len(patterns))._children[at]
+    assert (child.k, len(child.dfas)) == TENANTS_CHILDREN[at]
+    assert len(child.dfas) % 4 == 0
+    child.kernel_resolved, child._tbl = "scan", child._np  # names+shapes
+    fn, tsh, sh_b, sh_l, variant, _donate = child._mesh_program(
+        mesh, "auto", False)
+    assert variant == "batch"
+    tables = {k: sds(v.shape, v.dtype, tsh[k])
+              for k, v in child._np.items()}
+    compiled = fn.lower(tables, sds((1, SEGMENT, 512), jnp.uint8, sh_b),
+                        sds((1, SEGMENT), jnp.int32, sh_l)).compile()
+    hlo = compiled.as_text()
+    assert child.program_name("_mesh") in hlo.split("\n", 1)[0]
+    assert "s32[%d,%d]" % child._np["trans_flat"].shape in hlo
+    (out,) = jax.tree_util.tree_leaves(compiled.out_info)
+    assert out.shape == (len(child.dfas), SEGMENT)
 
 
 @pytest.mark.parametrize("which", ["whole", "two-groups", "mesh"])
