@@ -30,12 +30,21 @@ on a plain top-level key — byte-equal to the per-record chain:
   staged once a segment, the span program (``ops.grep.SpanProgram``)
   launched through the ``grep`` DeviceLane on one device, the named
   groups' offsets copied back — and records are built from the spans
-  without ``re``: fields cut from the staged value, then
-  ``Parser.do_fields`` (``Skip_Empty_Values``, zero fields = failure,
-  ``Types``, the ``Time_Key`` lookup and drop) and ``Reserve_Data`` /
-  ``Preserve_Key`` as the per-record path has them; an unmatched row's
-  bytes pass through untouched, a chunk with no match returns its
-  buffer. Rows the program cannot decide go to the host per row and are
+  without ``re``, in C with the GIL released (fbtpu_codec
+  ``parser_spans_build``, one call a chunk): fields cut from the staged
+  value with ``Parser.do_fields``' semantics (``Skip_Empty_Values``, zero
+  fields = failure, ``Types`` integer, the ``Time_Key`` lookup over
+  ``%d %m %b %Y %H %M %S %z %T`` and literals, and its drop) and
+  ``Reserve_Data`` / ``Preserve_Key`` as the per-record path has them; an
+  unmatched row's bytes pass through untouched, a chunk with no match
+  returns its buffer. A row C cannot prove — an integer capture of
+  another shape than ``[+-]digits``, a time that does not parse, a record
+  of another framing or body — is a leftover, built per row in Python
+  (``_span_event``, ``do_fields`` itself) and spliced in at its place. A
+  parser outside that description (a ``float``/``bool``/``hex`` type, a
+  Time_Format directive outside the set or with no year) or an extension
+  without the function takes the Python build whole; ``decision()`` says
+  which. Rows the program cannot decide go to the host per row and are
   counted (``host_rows``): a value longer than ``tpu_max_record_len``,
   a missing key or a ``bin`` value, and a value with a byte past ASCII
   (Python ``re`` reads characters where the automaton reads bytes, so
@@ -64,7 +73,8 @@ from typing import List, Optional
 import numpy as np
 
 from .. import failpoints as _fp
-from ..codec.events import LogEvent
+from ..codec.events import LogEvent, decode_events, reencode_event
+from ..codec.msgpack import EventTime
 from ..core.config import ConfigMapEntry
 from ..core.plugin import FilterPlugin, FilterResult, registry
 from ..core.record_accessor import RecordAccessor
@@ -78,15 +88,49 @@ log = logging.getLogger("flb")
 #: ``build_s`` is the time from the spans to the chunk's new bytes,
 #: ``parsed`` the records it replaced, ``host_rows`` the rows decided on
 #: the host per row (overflow rows, a missing key or a ``bin`` value, a
-#: byte past ASCII)
+#: byte past ASCII), ``native_rows`` the records of ``parsed`` that C
+#: built (``parser_spans_build``)
 _TIMING_KEYS = ("extract_s", "kernel_s", "h2d_bytes", "d2h_bytes",
                 "scan_elements", "device_records", "overflow_rows",
-                "build_s", "parsed", "host_rows")
+                "build_s", "parsed", "host_rows", "native_rows")
 
 #: ``[[EventTime, {}], {`` — the head of an event whose time is the
 #: Forward protocol's ext and whose metadata is empty, before the body
 #: map's own header
 _EVENT_HEAD = b"\x92\x92\xd7\x00"
+
+#: the Time_Format directives ``parser_spans_build`` reads, as its ops:
+#: flb_strptime's day, month, month name, year, hour, minute, second,
+#: zone, and %T = %H:%M:%S (``L`` + a byte is a literal)
+_NATIVE_DIRECTIVES = {"d": b"d", "m": b"m", "b": b"b", "B": b"b",
+                      "h": b"b", "Y": b"Y", "H": b"H", "M": b"M",
+                      "S": b"S", "z": b"z", "T": b"HL:ML:S"}
+
+
+def _time_ops(fmt: str):
+    """``Time_Format`` → the ops ``parser_spans_build`` walks (``W`` a
+    run of white space, ``L`` + a literal byte, a directive's letter),
+    or a str: why C does not serve it."""
+    if not any(x in fmt for x in ("%Y", "%y", "%s", "%D", "%x", "%C")):
+        return "Time_Format has no year (time_lookup prepends this one)"
+    ops = bytearray()
+    f = 0
+    while f < len(fmt):
+        c = fmt[f]
+        f += 1
+        if c.isspace():
+            ops += b"W"
+        elif c != "%":
+            if not c.isascii():
+                return f"Time_Format literal {c!r} is not ASCII"
+            ops += b"L" + c.encode()
+        elif fmt[f:f + 1] in _NATIVE_DIRECTIVES:
+            ops += _NATIVE_DIRECTIVES[fmt[f]]
+            f += 1
+        else:
+            return (f"Time_Format directive %{fmt[f:f + 1]} is outside "
+                    f"the C build's set")
+    return bytes(ops)
 
 
 class _KeyRule:
@@ -97,6 +141,16 @@ class _KeyRule:
     def __init__(self, key: str, regex):
         self.ra = RecordAccessor(key)
         self.regex = regex
+
+
+def _row_value(res, i: int) -> bytes:
+    """Row ``i``'s staged bytes, from the plane that holds it."""
+    ln = int(res.lengths[i])
+    for plane in res.planes:
+        if i < len(plane):
+            return plane[i, :ln].tobytes()
+        i -= len(plane)
+    raise IndexError("row past the staged planes")
 
 
 def _to_str(v) -> Optional[str]:
@@ -176,6 +230,8 @@ class ParserFilter(FilterPlugin):
         self._spans = None
         self._span_rules = None  # the one rule, as staged_match's list
         self._span_decline: Optional[str] = None
+        self._native_desc = None  # parser_spans_build's description
+        self._native_decline: Optional[str] = None
         self.raw_timings = ShardedTimings(_TIMING_KEYS)
         p0 = self.parsers[0]
         if self.ra is None and len(self.parsers) == 1 and self.key_name:
@@ -228,6 +284,8 @@ class ParserFilter(FilterPlugin):
             # the body {key: <the value>} and nothing else, from its
             # map header on
             self._single_pair = b"\x81" + packb(self.key_name)
+            self._native_desc, self._native_decline = \
+                self._native_build_desc(p0)
             device.wait()  # bounded; the host path serves until attached
             self._spans.try_ready()
         except (SpanDecline, UnsupportedRegex) as e:
@@ -240,6 +298,36 @@ class ParserFilter(FilterPlugin):
             log.debug("parser span program unavailable; host path "
                       "serves", exc_info=True)
 
+    def _native_build_desc(self, p0):
+        """The parser as ``parser_spans_build`` reads it → (description,
+        None), or (None, why the Python build serves)."""
+        from ..codec import _native_codec
+        from ..parsers import TYPE_CASTERS
+
+        mod = _native_codec.load()
+        if mod is None or not hasattr(mod, "parser_spans_build"):
+            return None, "the codec extension has no parser_spans_build"
+        names = self._spans.names
+        as_int, as_str = TYPE_CASTERS["integer"], TYPE_CASTERS["string"]
+        for k, caster in p0.types.items():
+            if caster is not as_int and caster is not as_str:
+                return None, f"Types {k}: C builds integer and string only"
+        ops, time_group = b"", -1
+        if p0.time_format and p0.time_key in names:
+            if p0.time_key in p0.types:
+                return None, f"Types casts the Time_Key {p0.time_key}"
+            ops = _time_ops(p0.time_format)
+            if isinstance(ops, str):
+                return None, ops
+            time_group = names.index(p0.time_key)
+        return (tuple(nm.encode("utf-8") for nm in names),
+                bytes(p0.types.get(nm) is as_int for nm in names),
+                time_group, p0.time_keep, ops, p0.time_offset,
+                p0.skip_empty_values, self.reserve_data or self.preserve_key,
+                self.preserve_key, self._single_pair,
+                names.index(self.key_name) if self.key_name in names
+                else -1), None
+
     def decision(self) -> dict:
         """What the batched path will do, and why not more."""
         return {
@@ -247,6 +335,9 @@ class ParserFilter(FilterPlugin):
             "spans": None if self._spans is None
             else self._spans.decision(),
             "span_decline": self._span_decline,
+            "build": "native" if self._native_desc is not None
+            else "python",
+            "build_decline": self._native_decline,
         }
 
     # -- per-record semantics --
@@ -396,83 +487,122 @@ class ParserFilter(FilterPlugin):
         return self._process_batch_host(chunk, data)
 
     def _build_from_spans(self, data, res, offsets, n):
-        """Records from spans, without ``re``: a device row's fields are
-        cut from its staged value and go through ``Parser.do_fields``;
-        a row the program did not decide (no staged value, or a byte
-        past ASCII) is decoded and parsed on the host; every other
-        record's bytes pass through, in runs."""
-        from ..codec.events import decode_events, reencode_event
-        from ..codec.msgpack import EventTime
-
+        """The chunk's new bytes from the spans: one C call where the
+        parser's description allows it, its leftovers spliced in from
+        the Python build; else the Python build row by row."""
         tm = self.raw_timings
-        p0 = self.parsers[0]
-        names = self._spans.names
-        need_orig = self.reserve_data or self.preserve_key
+        with tm.timed("build_s", "parser.build", rows=n,
+                      parsed=int(res.ok.sum())):
+            try:
+                got = None
+                if self._native_desc is not None:
+                    got = self._build_native(data, res, offsets)
+                if got is None:
+                    got = self._build_python(data, res, offsets, n)
+            except (ValueError, IndexError):
+                return None  # a record that does not decode: decline
+            host_rows, native_rows, parsed, out = got
+            tm.add("host_rows", host_rows)
+            tm.add("native_rows", native_rows)
+            tm.add("parsed", parsed)
+            if not parsed:
+                return (n, data, n)  # nothing parsed: zero-copy
+            return (n, out, n)
+
+    def _build_native(self, data, res, offsets):
+        """``parser_spans_build`` over the chunk, then each leftover row
+        from ``_span_event`` at its place → (host_rows, native_rows,
+        parsed, bytes); None where C hands the chunk back."""
+        from ..codec import _native_codec
+
+        mod = _native_codec.load()
+        try:
+            out, left, native_rows, host_rows = mod.parser_spans_build(
+                data, np.ascontiguousarray(offsets, dtype=np.int64),
+                [np.ascontiguousarray(p) for p in res.planes],
+                np.ascontiguousarray(res.lengths, dtype=np.int32),
+                np.ascontiguousarray(res.ok, dtype=bool),
+                np.ascontiguousarray(res.spans, dtype=np.int32),
+                *self._native_desc)
+        except mod.FallbackError:
+            return None
+        parsed = native_rows
+        if left:
+            pieces, at, mv = [], 0, memoryview(out)
+            for i, pos, host in left:
+                rec = data[offsets[i]: offsets[i + 1]]
+                new_ev = self._span_event(
+                    rec, None if host else _row_value(res, i),
+                    res.spans[i].tolist())
+                pieces += (mv[at:pos],
+                           rec if new_ev is None else reencode_event(new_ev))
+                parsed += new_ev is not None
+                at = pos
+            pieces.append(mv[at:])
+            out = b"".join(pieces)
+        return host_rows, native_rows, parsed, out
+
+    def _build_python(self, data, res, offsets, n):
+        """The build row by row: ``_span_event`` for every matched or
+        host row, the others' bytes passing through in runs →
+        (host_rows, 0, parsed, bytes)."""
+        host = res.lengths < 0
+        rows: list = []  # each row's staged bytes
+        at = 0
+        for plane in res.planes:
+            cnt, L = plane.shape
+            ln = res.lengths[at: at + cnt]
+            high = (plane >= 0x80) & (
+                np.arange(L, dtype=np.int32)[None, :] < ln[:, None])
+            host[at: at + cnt] |= high.any(axis=1)
+            buf = plane.tobytes()
+            rows.extend(buf[i * L: i * L + max(int(ln[i]), 0)]
+                        for i in range(cnt))
+            at += cnt
         parts: list = []
         kept_from = 0  # records [kept_from, i) pass through as one run
         parsed = 0
-        with tm.timed("build_s", "parser.build", rows=n,
-                      parsed=int(res.ok.sum())):
-            host = res.lengths < 0
-            rows: list = []  # each row's staged bytes
-            at = 0
-            for plane in res.planes:
-                cnt, L = plane.shape
-                ln = res.lengths[at: at + cnt]
-                high = (plane >= 0x80) & (
-                    np.arange(L, dtype=np.int32)[None, :] < ln[:, None])
-                host[at: at + cnt] |= high.any(axis=1)
-                buf = plane.tobytes()
-                rows.extend(buf[i * L: i * L + max(int(ln[i]), 0)]
-                            for i in range(cnt))
-                at += cnt
-            tm.add("host_rows", int(host.sum()))
-            todo = np.nonzero(res.ok | host)[0]
-            try:
-                for i, sp in zip(todo.tolist(), res.spans[todo].tolist()):
-                    rec = data[offsets[i]: offsets[i + 1]]
-                    if host[i]:
-                        ev = decode_events(rec)[0]
-                        v = self._get_value(ev.body)
-                        new_ev = self._apply(ev, v) \
-                            if v is not None else None
-                    else:
-                        value = rows[i]
-                        got = p0.do_fields({
-                            name: value[s:e].decode("ascii")
-                            for name, (s, e) in zip(names, sp) if s >= 0})
-                        if got is None:
-                            new_ev = None
-                        elif rec.startswith(_EVENT_HEAD) \
-                                and rec[12] == 0x80 and (
-                                    not need_orig or rec.startswith(
-                                        self._single_pair, 13)):
-                            # [[EventTime, {}], body], and where the
-                            # originals count the body is {key: value}:
-                            # nothing to decode
-                            new_ev = self._replace(
-                                EventTime.from_bytes(rec[4:12]), {},
-                                {self.key_name: value.decode("ascii")}
-                                if need_orig else {}, *got)
-                        else:
-                            ev = decode_events(rec)[0]
-                            new_ev = self._replace(
-                                ev.timestamp, ev.metadata, ev.body, *got)
-                    if new_ev is None:
-                        continue
-                    if kept_from < i:
-                        parts.append(data[offsets[kept_from]: offsets[i]])
-                    parts.append(reencode_event(new_ev))
-                    kept_from = i + 1
-                    parsed += 1
-            except (ValueError, IndexError):
-                return None  # a record that does not decode: decline
-            tm.add("parsed", parsed)
-            if not parts:
-                return (n, data, n)  # nothing parsed: zero-copy
-            if kept_from < n:
-                parts.append(data[offsets[kept_from]: offsets[n]])
-            return (n, b"".join(parts), n)
+        todo = np.nonzero(res.ok | host)[0]
+        for i, sp in zip(todo.tolist(), res.spans[todo].tolist()):
+            rec = data[offsets[i]: offsets[i + 1]]
+            new_ev = self._span_event(rec, None if host[i] else rows[i], sp)
+            if new_ev is None:
+                continue
+            if kept_from < i:
+                parts.append(data[offsets[kept_from]: offsets[i]])
+            parts.append(reencode_event(new_ev))
+            kept_from = i + 1
+            parsed += 1
+        if parts and kept_from < n:
+            parts.append(data[offsets[kept_from]: offsets[n]])
+        return int(host.sum()), 0, parsed, b"".join(parts)
+
+    def _span_event(self, rec, value, sp):
+        """One row's new event, or None where its bytes pass through: a
+        device row's fields are cut from its staged ``value`` and go
+        through ``Parser.do_fields``; a host row (``value`` None: no
+        staged value, or a byte past ASCII) is decoded and parsed on
+        the host."""
+        if value is None:
+            ev = decode_events(rec)[0]
+            v = self._get_value(ev.body)
+            return self._apply(ev, v) if v is not None else None
+        got = self.parsers[0].do_fields({
+            name: value[s:e].decode("ascii")
+            for name, (s, e) in zip(self._spans.names, sp) if s >= 0})
+        if got is None:
+            return None
+        need_orig = self.reserve_data or self.preserve_key
+        if rec.startswith(_EVENT_HEAD) and rec[12] == 0x80 and (
+                not need_orig or rec.startswith(self._single_pair, 13)):
+            # [[EventTime, {}], body], and where the originals count
+            # the body is {key: value}: nothing to decode
+            return self._replace(
+                EventTime.from_bytes(rec[4:12]), {},
+                {self.key_name: value.decode("ascii")}
+                if need_orig else {}, *got)
+        ev = decode_events(rec)[0]
+        return self._replace(ev.timestamp, ev.metadata, ev.body, *got)
 
     def _process_batch_host(self, chunk, data):
         """Native one-pass DFA mask over chunk bytes; the regex (with
@@ -481,7 +611,6 @@ class ParserFilter(FilterPlugin):
         bit-exact twin of the fallback engine, same contract as
         filter_grep's raw path)."""
         from .. import native
-        from ..codec.events import decode_events, reencode_event
 
         got = native.grep_match(data, self._batch_tables, n_hint=chunk.n)
         if got is None:

@@ -19,7 +19,9 @@
  * entry's bytes are canonical, and the V2 events copied from them;
  * forward_cut_entries is the same walk over an inflated blob), and
  * unpack_from's decode stays the path of every message that proof does
- * not cover. parser_json_batch transcodes a whole chunk the same way.
+ * not cover. parser_json_batch transcodes a whole chunk the same way,
+ * and parser_spans_build builds filter_parser's records from the spans
+ * the device found.
  *
  * Reference precedent: the hot decode loop is C in fluent-bit too
  * (lib/msgpack-c via flb_log_event_decoder, src/flb_log_event_decoder.c).
@@ -1442,6 +1444,500 @@ static PyObject *py_parser_json_batch(PyObject *self, PyObject *args) {
 }
 
 /* ------------------------------------------------------------------ */
+/* filter_parser's record build from the device's spans.
+ *
+ * parser_spans_build(data, offsets, planes, lengths, ok, spans, names,
+ *                    casts, time_group, time_keep, time_fmt,
+ *                    time_offset, skip_empty, need_orig, preserve,
+ *                    pair, key_group)
+ *     → (out, leftovers, native_rows, host_rows)
+ *
+ * The chunk's new bytes from the span verdict of filter_grep.staged_match,
+ * the twin of filter_parser._span_event for every row whose result it can
+ * prove, with the GIL released from the first row to the last. `names` are
+ * the groups' UTF-8 names in the spans' order, `casts` one byte a group (1:
+ * Types integer), `time_fmt` the Time_Format compiled by filter_parser to
+ * ops ('W' white space, 'L' + a literal byte, and the directives d m b Y
+ * H M S z), `pair` the body {key: ...}'s head (0x81 + the packed key) and
+ * `key_group` the group named as the key, or -1.
+ *
+ * A row passes through (its bytes copied in runs) where it does not match
+ * or leaves no field; it is built here where it is a matched ASCII device
+ * row, its integers strict ^[+-]?[0-9]+$ within msgpack's range (a capture
+ * with no digit and no '.' stays a string: int() rejects it), its time
+ * the format's shape within flb_strptime's ranges, its record
+ * [[EventTime, {}], body] with the body {key: value} where the originals
+ * count. Every other row is a leftover: an empty place in `out` at its
+ * position, (row, pos, host) in `leftovers`, for the Python build to fill.
+ * A host row is one with no staged value or a byte past ASCII. Offsets,
+ * planes or spans that do not describe the chunk raise FallbackError. */
+
+#define SB_PASS  0
+#define SB_BUILT 1
+#define SB_LEFT  2
+#define SB_MAX_GROUPS 256
+
+typedef struct {
+    Py_ssize_t n_groups;
+    const uint8_t *names[SB_MAX_GROUPS];
+    Py_ssize_t name_len[SB_MAX_GROUPS];
+    const uint8_t *casts, *fmt, *pair;
+    Py_ssize_t fmt_len, pair_len;
+    int time_group, time_keep, skip_empty, need_orig, preserve, key_group;
+    long long time_offset;
+} sb_desc;
+
+typedef struct {
+    long long year, mon, mday, hour, min, sec, off;
+    int has_off;
+} sb_tm;
+
+typedef struct {
+    Py_ssize_t row, pos;
+    int host;
+} sb_left;
+
+/* str.isspace() over ASCII: \t-\r, the four separators \x1c-\x1f, ' ' */
+static int sb_isspace(uint8_t c) {
+    return (c >= 9 && c <= 13) || (c >= 28 && c <= 32);
+}
+
+/* strptime's _digits: 1 to `max` ASCII digits at *i → their value, or -1
+ * with *i unmoved */
+static long long sb_digits(const uint8_t *s, Py_ssize_t s_len,
+                           Py_ssize_t *i, int max) {
+    Py_ssize_t j = *i;
+    long long v = 0;
+    while (j < s_len && j - *i < max && s[j] >= '0' && s[j] <= '9')
+        v = v * 10 + (s[j++] - '0');
+    if (j == *i) return -1;
+    *i = j;
+    return v;
+}
+
+static const char *const sb_months[12] = {
+    "january", "february", "march", "april", "may", "june", "july",
+    "august", "september", "october", "november", "december"};
+
+static int sb_ieq(const uint8_t *s, const char *name, Py_ssize_t n) {
+    for (Py_ssize_t k = 0; k < n; k++) {
+        uint8_t c = s[k];
+        if (c >= 'A' && c <= 'Z') c = (uint8_t)(c + 32);
+        if (c != (uint8_t)name[k]) return 0;
+    }
+    return 1;
+}
+
+/* strptime's _name over the months: the first whose three letters
+ * match, its whole name where that follows → 1..12, or -1 */
+static int sb_month(const uint8_t *s, Py_ssize_t s_len, Py_ssize_t *i) {
+    Py_ssize_t left_len = s_len - *i;
+    if (left_len < 3) return -1;
+    for (int m = 0; m < 12; m++) {
+        Py_ssize_t nl = (Py_ssize_t)strlen(sb_months[m]);
+        if (!sb_ieq(s + *i, sb_months[m], 3)) continue;
+        *i += nl <= left_len && sb_ieq(s + *i, sb_months[m], nl) ? nl : 3;
+        return m + 1;
+    }
+    return -1;
+}
+
+/* flb_strptime over the compiled ops: 0, or -1 where it returns None;
+ * trailing bytes are left, as there */
+static int sb_strptime(const uint8_t *s, Py_ssize_t s_len,
+                       const uint8_t *fmt, Py_ssize_t fmt_len, sb_tm *tm) {
+    Py_ssize_t i = 0;
+    for (Py_ssize_t f = 0; f < fmt_len; f++) {
+        long long v, h, m;
+        switch (fmt[f]) {
+        case 'W':
+            while (i < s_len && sb_isspace(s[i])) i++;
+            break;
+        case 'L':
+            if (f + 1 >= fmt_len || i >= s_len || s[i] != fmt[f + 1])
+                return -1;
+            i++;
+            f++;
+            break;
+        case 'd':
+            v = sb_digits(s, s_len, &i, 2);
+            if (v < 1 || v > 31) return -1;
+            tm->mday = v;
+            break;
+        case 'm':
+            v = sb_digits(s, s_len, &i, 2);
+            if (v < 1 || v > 12) return -1;
+            tm->mon = v;
+            break;
+        case 'b':
+            v = sb_month(s, s_len, &i);
+            if (v < 0) return -1;
+            tm->mon = v;
+            break;
+        case 'Y':
+            v = sb_digits(s, s_len, &i, 4);
+            if (v < 0) return -1;
+            tm->year = v;
+            break;
+        case 'H':
+            v = sb_digits(s, s_len, &i, 2);
+            if (v < 0 || v > 23) return -1;
+            tm->hour = v;
+            break;
+        case 'M':
+            v = sb_digits(s, s_len, &i, 2);
+            if (v < 0 || v > 59) return -1;
+            tm->min = v;
+            break;
+        case 'S':
+            v = sb_digits(s, s_len, &i, 2);
+            if (v < 0 || v > 61) return -1;
+            tm->sec = v;
+            break;
+        case 'z':
+            if (i < s_len && (s[i] == 'Z' || s[i] == 'z')) {
+                tm->off = 0;
+                tm->has_off = 1;
+                i++;
+                break;
+            }
+            if (i >= s_len || (s[i] != '+' && s[i] != '-')) return -1;
+            v = s[i++] == '-' ? -1 : 1;
+            h = sb_digits(s, s_len, &i, 2);
+            if (h < 0) return -1;
+            if (i < s_len && s[i] == ':') i++;
+            m = sb_digits(s, s_len, &i, 2);
+            tm->off = v * (h * 3600 + (m < 0 ? 0 : m) * 60);
+            tm->has_off = 1;
+            break;
+        default:
+            return -1;
+        }
+    }
+    return 0;
+}
+
+/* Tm.to_epoch through calendar.timegm (days from the civil date, as
+ * datetime.date.toordinal counts them); -1 where date() would raise */
+static int sb_epoch(const sb_tm *tm, long long dflt_off, long long *out) {
+    if (tm->year < 1 || tm->year > 9999) return -1;
+    long long y = tm->year - (tm->mon <= 2);
+    long long mp = tm->mon > 2 ? tm->mon - 3 : tm->mon + 9;
+    long long era = y / 400;  /* y >= 0 */
+    long long yoe = y - era * 400;
+    long long doe = yoe * 365 + yoe / 4 - yoe / 100 + (153 * mp + 2) / 5;
+    long long days = era * 146097 + doe - 719468 + tm->mday - 1;
+    *out = ((days * 24 + tm->hour) * 60 + tm->min) * 60 + tm->sec
+        - (tm->has_off ? tm->off : dflt_off);
+    return 0;
+}
+
+/* _cast_int's outcome where it is certain: 1 an integer msgpack packs
+ * (neg, mag), 0 the string itself, -1 the Python build decides */
+static int sb_int(const uint8_t *v, Py_ssize_t v_len, int *neg,
+                  unsigned long long *mag) {
+    int digit = 0, dot = 0;
+    for (Py_ssize_t k = 0; k < v_len; k++) {
+        digit |= v[k] >= '0' && v[k] <= '9';
+        dot |= v[k] == '.';
+    }
+    if (!digit && !dot) return 0;
+    Py_ssize_t k = v_len > 0 && (v[0] == '+' || v[0] == '-');
+    *neg = k && v[0] == '-';
+    unsigned long long m = 0;
+    if (k >= v_len) return -1;
+    for (; k < v_len; k++) {
+        if (v[k] < '0' || v[k] > '9') return -1;
+        unsigned d = (unsigned)(v[k] - '0');
+        if (m > (UINT64_MAX - d) / 10) return -1;
+        m = m * 10 + d;
+    }
+    if (*neg && m > 0x8000000000000000ULL) return -1;
+    *mag = m;
+    return 1;
+}
+
+/* pack_obj's str branch over bytes */
+static int wr_pack_str(wr *w, const uint8_t *s, Py_ssize_t n) {
+    int rc;
+    if (n < 32)
+        rc = wr_u8(w, (uint8_t)(0xA0 | n));
+    else if (n <= 0xFF)
+        rc = wr_u8(w, 0xD9) < 0 ? -1 : wr_u8(w, (uint8_t)n);
+    else if (n <= 0xFFFF)
+        rc = wr_u8(w, 0xDA) < 0 ? -1 : wr_be(w, (uint64_t)n, 2);
+    else
+        rc = wr_u8(w, 0xDB) < 0 ? -1 : wr_be(w, (uint64_t)n, 4);
+    return rc < 0 ? -1 : wr_bytes(w, s, n);
+}
+
+/* room for `extra` more bytes with plain realloc (no GIL): the writers
+ * after it then never reach wr_reserve's PyMem_Realloc */
+static int sb_room(wr *w, Py_ssize_t extra) {
+    if (extra <= w->cap - w->len) return 0;
+    Py_ssize_t ncap = w->cap ? w->cap : 4096;
+    while (ncap - w->len < extra) ncap *= 2;
+    uint8_t *nb = realloc(w->buf, (size_t)ncap);
+    if (!nb) return -1;
+    w->buf = nb;
+    w->cap = ncap;
+    return 0;
+}
+
+/* what sb_decide finds of a row and sb_write packs */
+typedef struct {
+    uint8_t present[SB_MAX_GROUPS], as_int[SB_MAX_GROUPS];
+    int neg[SB_MAX_GROUPS];
+    unsigned long long mag[SB_MAX_GROUPS];
+    Py_ssize_t nf, bound;
+    double ts;
+    int add_key;
+} sb_fields;
+
+/* one matched device row → SB_PASS, SB_BUILT (f says what to pack) or
+ * SB_LEFT, in do_fields' order: skip-empty, zero fields, Types, the
+ * Time_Key lookup; then the record's shape */
+static int sb_decide(const sb_desc *d, const uint8_t *row,
+                     Py_ssize_t row_len, const int32_t *sp,
+                     const uint8_t *rec, Py_ssize_t rec_len, sb_fields *f) {
+    f->nf = 0;
+    f->bound = 18 + d->pair_len + 5 + row_len;
+    for (Py_ssize_t g = 0; g < d->n_groups; g++) {
+        int32_t s = sp[2 * g], e = sp[2 * g + 1];
+        f->present[g] = f->as_int[g] = 0;
+        if (s < 0) continue;
+        if (e < s || e > row_len) return SB_LEFT;
+        if (e == s && d->skip_empty) continue;
+        f->present[g] = 1;
+        f->nf++;
+        f->bound += 14 + d->name_len[g] + (e - s);
+        if (d->casts[g]) {
+            int k = sb_int(row + s, e - s, &f->neg[g], &f->mag[g]);
+            if (k < 0) return SB_LEFT;
+            f->as_int[g] = (uint8_t)k;
+        }
+    }
+    if (f->nf == 0) return SB_PASS;  /* zero fields: the parse fails */
+    int tg = d->time_group;
+    f->ts = 0.0;
+    if (tg >= 0 && f->present[tg]) {
+        sb_tm tm = {1970, 1, 1, 0, 0, 0, 0, 0};
+        long long epoch;
+        int32_t s = sp[2 * tg], e = sp[2 * tg + 1];
+        if (sb_strptime(row + s, e - s, d->fmt, d->fmt_len, &tm) < 0
+                || sb_epoch(&tm, d->time_offset, &epoch) < 0)
+            return SB_LEFT;  /* Python logs it and drops the field */
+        if (!d->time_keep) {
+            f->present[tg] = 0;
+            f->nf--;
+        }
+        f->ts = (double)epoch;
+    }
+    if (rec_len < 13 || memcmp(rec, "\x92\x92\xd7\x00", 4) != 0
+            || rec[12] != 0x80)
+        return SB_LEFT;
+    if (d->need_orig && (rec_len - 13 < d->pair_len
+                         || memcmp(rec + 13, d->pair, d->pair_len) != 0))
+        return SB_LEFT;
+    f->add_key = d->preserve
+        && !(d->key_group >= 0 && f->present[d->key_group]);
+    return SB_BUILT;
+}
+
+/* [[time, {}], {the fields in group order, the kept key last}]; a time
+ * of 0 keeps the record's EventTime */
+static int sb_write(const sb_desc *d, const sb_fields *f,
+                    const uint8_t *row, Py_ssize_t row_len,
+                    const int32_t *sp, const uint8_t *rec, wr *w) {
+    if (sb_room(w, f->bound) < 0) return JT_NOMEM;
+    if (wr_u8(w, 0x92) < 0 || wr_u8(w, 0x92) < 0) return JT_NOMEM;
+    if (f->ts != 0.0 ? wr_pack_f64(w, f->ts) != 0
+            : wr_bytes(w, rec + 2, 10) < 0)
+        return JT_NOMEM;
+    if (wr_u8(w, 0x80) < 0
+            || pack_header(w, f->nf + f->add_key, 0x80, 0xDE, 0xDF, 16) < 0)
+        return JT_NOMEM;
+    for (Py_ssize_t g = 0; g < d->n_groups; g++) {
+        if (!f->present[g]) continue;
+        int32_t s = sp[2 * g], e = sp[2 * g + 1];
+        if (wr_pack_str(w, d->names[g], d->name_len[g]) < 0)
+            return JT_NOMEM;
+        if (f->as_int[g] ? wr_pack_int(w, f->neg[g], f->mag[g]) != 0
+                : wr_pack_str(w, row + s, e - s) < 0)
+            return JT_NOMEM;
+    }
+    if (f->add_key && (wr_bytes(w, d->pair + 1, d->pair_len - 1) < 0
+                       || wr_pack_str(w, row, row_len) < 0))
+        return JT_NOMEM;
+    return 0;
+}
+
+/* the chunk: every record in order, under the GIL released */
+static int sb_chunk(const sb_desc *d, const uint8_t *data,
+                    Py_ssize_t data_len, const int64_t *offs, Py_ssize_t n,
+                    const Py_buffer *planes, Py_ssize_t n_planes,
+                    const int32_t *lens, const uint8_t *ok,
+                    const int32_t *spans, wr *w, sb_left *left,
+                    Py_ssize_t *n_left, Py_ssize_t *n_native,
+                    Py_ssize_t *n_host) {
+    sb_fields f;
+    if (offs[0] < 0 || offs[n] > data_len) return JT_FALLBACK;
+    for (Py_ssize_t i = 0; i < n; i++)
+        if (offs[i] > offs[i + 1]) return JT_FALLBACK;
+    if (sb_room(w, data_len + data_len / 4 + 4096) < 0) return JT_NOMEM;
+    Py_ssize_t kept_from = 0, i = 0;
+    for (Py_ssize_t p = 0; p < n_planes; p++) {
+        const uint8_t *plane = planes[p].buf;
+        Py_ssize_t cnt = planes[p].shape[0], width = planes[p].shape[1];
+        for (Py_ssize_t r = 0; r < cnt; r++, i++) {
+            const uint8_t *row = plane + r * width;
+            const int32_t *sp = spans + i * 2 * d->n_groups;
+            Py_ssize_t row_len = lens[i];
+            int host = row_len < 0, rc = SB_LEFT;
+            if (row_len > width) return JT_FALLBACK;
+            for (Py_ssize_t k = 0; !host && k < row_len; k++)
+                host = row[k] >= 0x80;
+            if (host) {
+                ++*n_host;
+            } else if (!ok[i]) {
+                continue;
+            } else {
+                rc = sb_decide(d, row, row_len, sp, data + offs[i],
+                               offs[i + 1] - offs[i], &f);
+                if (rc == SB_PASS) continue;
+            }
+            /* the records before this one pass through as one run */
+            Py_ssize_t run = offs[i] - offs[kept_from];
+            if (sb_room(w, run) < 0
+                    || wr_bytes(w, data + offs[kept_from], run) < 0)
+                return JT_NOMEM;
+            kept_from = i + 1;
+            if (rc == SB_LEFT) {
+                left[*n_left].row = i;
+                left[*n_left].pos = w->len;
+                left[(*n_left)++].host = host;
+                continue;
+            }
+            if (sb_write(d, &f, row, row_len, sp, data + offs[i], w) < 0)
+                return JT_NOMEM;
+            ++*n_native;
+        }
+    }
+    Py_ssize_t tail = offs[n] - offs[kept_from];
+    if (sb_room(w, tail) < 0
+            || wr_bytes(w, data + offs[kept_from], tail) < 0)
+        return JT_NOMEM;
+    return 0;
+}
+
+static int sb_view(PyObject *o, Py_buffer *v, int ndim, Py_ssize_t item) {
+    if (PyObject_GetBuffer(o, v, PyBUF_ND | PyBUF_FORMAT) < 0) return -1;
+    if (v->ndim == ndim && v->itemsize == item) return 0;
+    PyBuffer_Release(v);
+    PyErr_SetString(g_fallback, "parser_spans_build: array of another shape");
+    return -1;
+}
+
+static PyObject *py_parser_spans_build(PyObject *self, PyObject *args) {
+    PyObject *data_o, *offs_o, *planes_o, *lens_o, *ok_o, *spans_o, *names;
+    PyObject *res = NULL;
+    Py_buffer data, offs, lens, ok, spans, *planes = NULL;
+    Py_ssize_t casts_len, n_planes, got_planes = 0, n = 0, rows = 0;
+    Py_ssize_t n_left = 0, n_native = 0, n_host = 0;
+    int views = 0, rc = JT_NOMEM;  /* views: those taken, in order */
+    wr w = {NULL, 0, 0, 0};
+    sb_left *left = NULL;
+    sb_desc d;
+    if (!PyArg_ParseTuple(args, "OOO!OOOO!y#ipy#Lpppy#i", &data_o, &offs_o,
+                          &PyList_Type, &planes_o, &lens_o, &ok_o,
+                          &spans_o, &PyTuple_Type, &names, &d.casts,
+                          &casts_len, &d.time_group, &d.time_keep, &d.fmt,
+                          &d.fmt_len, &d.time_offset, &d.skip_empty,
+                          &d.need_orig, &d.preserve, &d.pair, &d.pair_len,
+                          &d.key_group))
+        return NULL;
+    d.n_groups = PyTuple_GET_SIZE(names);
+    if (d.n_groups < 1 || d.n_groups > SB_MAX_GROUPS
+            || casts_len != d.n_groups || d.pair_len < 1
+            || d.time_group < -1 || d.time_group >= d.n_groups
+            || d.key_group < -1 || d.key_group >= d.n_groups) {
+        PyErr_SetString(g_fallback, "parser_spans_build: bad description");
+        return NULL;
+    }
+    for (Py_ssize_t g = 0; g < d.n_groups; g++) {
+        char *s;
+        if (PyBytes_AsStringAndSize(PyTuple_GET_ITEM(names, g), &s,
+                                    &d.name_len[g]) < 0)
+            return NULL;
+        d.names[g] = (const uint8_t *)s;
+    }
+    n_planes = PyList_GET_SIZE(planes_o);
+    planes = PyMem_Calloc((size_t)n_planes + 1, sizeof *planes);
+    if (!planes) return PyErr_NoMemory();
+    if (PyObject_GetBuffer(data_o, &data, PyBUF_SIMPLE) < 0) goto done;
+    views++;
+    if (sb_view(offs_o, &offs, 1, 8) < 0) goto done;
+    views++;
+    if (sb_view(lens_o, &lens, 1, 4) < 0) goto done;
+    views++;
+    if (sb_view(ok_o, &ok, 1, 1) < 0) goto done;
+    views++;
+    if (sb_view(spans_o, &spans, 3, 4) < 0) goto done;
+    views++;
+    n = lens.shape[0];
+    for (; got_planes < n_planes; got_planes++) {
+        if (sb_view(PyList_GET_ITEM(planes_o, got_planes),
+                    &planes[got_planes], 2, 1) < 0)
+            goto done;
+        rows += planes[got_planes].shape[0];
+    }
+    if (offs.shape[0] != n + 1 || ok.shape[0] != n || rows != n
+            || spans.shape[0] != n || spans.shape[1] != d.n_groups
+            || spans.shape[2] != 2) {
+        PyErr_SetString(g_fallback, "parser_spans_build: the arrays "
+                                    "do not describe one chunk");
+        goto done;
+    }
+    /* from here to the result: the views and malloc'ed memory alone */
+    Py_BEGIN_ALLOW_THREADS
+    left = malloc(sizeof *left * (size_t)(n + 1));
+    if (left)
+        rc = sb_chunk(&d, data.buf, data.len, offs.buf, n, planes,
+                      n_planes, lens.buf, ok.buf, spans.buf, &w, left,
+                      &n_left, &n_native, &n_host);
+    Py_END_ALLOW_THREADS
+    if (rc == JT_FALLBACK) {
+        PyErr_SetString(g_fallback, "parser_spans_build: offsets or "
+                                    "lengths outside the chunk");
+    } else if (rc < 0) {
+        PyErr_NoMemory();
+    } else {
+        PyObject *lst = PyList_New(n_left);
+        for (Py_ssize_t k = 0; lst && k < n_left; k++) {
+            PyObject *t = Py_BuildValue("(nnO)", left[k].row, left[k].pos,
+                                        left[k].host ? Py_True : Py_False);
+            if (!t) Py_CLEAR(lst);
+            else PyList_SET_ITEM(lst, k, t);
+        }
+        if (lst)
+            res = Py_BuildValue("(y#Nnn)", w.buf ? (const char *)w.buf : "",
+                                w.len, lst, n_native, n_host);
+    }
+done:
+    free(left);
+    free(w.buf);
+    for (Py_ssize_t k = 0; k < got_planes; k++)
+        PyBuffer_Release(&planes[k]);
+    PyMem_Free(planes);
+    if (views > 4) PyBuffer_Release(&spans);
+    if (views > 3) PyBuffer_Release(&ok);
+    if (views > 2) PyBuffer_Release(&lens);
+    if (views > 1) PyBuffer_Release(&offs);
+    if (views > 0) PyBuffer_Release(&data);
+    return res;
+}
+
+/* ------------------------------------------------------------------ */
 /* in_forward's chunk cut — a Forward or PackedForward message straight
  * to the V2 event buffer, with no Python object for any entry.
  *
@@ -1762,6 +2258,11 @@ static PyMethodDef methods[] = {
      "parser_json_batch(buf, key) → (out, n_records, n_parsed): "
      "whole-chunk JSON field transcode (filter_parser fast path); "
      "raises FallbackError when the per-record path must run"},
+    {"parser_spans_build", py_parser_spans_build, METH_VARARGS,
+     "parser_spans_build(data, offsets, planes, lengths, ok, spans, "
+     "*description) → (out, leftovers, native_rows, host_rows): a "
+     "chunk's records built from the device's spans with the GIL "
+     "released (filter_parser); raises FallbackError"},
     {"unpack_from", py_unpack_from, METH_VARARGS,
      "unpack_from(buf, pos) → (obj, end) for the one msgpack object "
      "at pos, None while the buffer does not hold it whole; raises "

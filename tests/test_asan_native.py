@@ -14,6 +14,8 @@ import sys
 
 import pytest
 
+from test_ubsan_native import SPANS_BUILD_DRIVER
+
 pytestmark = pytest.mark.sanitizer
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -137,6 +139,7 @@ try:
     mod.forward_cut(b"\x92\xa1t" + b"\x91" * 100000 + b"\x90", 0)
 except mod.FallbackError:
     pass  # depth bound
+""" + SPANS_BUILD_DRIVER + """
 print("ASAN_DRIVER_OK")
 """
 

@@ -409,7 +409,7 @@ def test_failed_launch_gives_the_hosts_spans(gate_open):
 
 PARSER_KEYS = ("extract_s", "kernel_s", "h2d_bytes", "d2h_bytes",
                "scan_elements", "device_records", "overflow_rows",
-               "build_s", "parsed", "host_rows")
+               "build_s", "parsed", "host_rows", "native_rows")
 
 
 def test_parser_spans_and_their_ids(gate_open, monkeypatch):

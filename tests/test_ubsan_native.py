@@ -8,7 +8,9 @@ loads — with ``-fsanitize=undefined`` alone and
 ASan+UBSan combined build, as in test_asan_native.py, keeps UBSan in
 recovering mode and a report there only prints). Drives the scanner
 trio + fused filter over byte soup AND the whole-chunk JSON transcoder
-(``parser_json_batch``), which the ASan driver predates.
+(``parser_json_batch``), which the ASan driver predates, and
+filter_parser's build from spans (``parser_spans_build``), which both
+drivers run.
 
 Shares the ``sanitizer`` marker (tests/conftest.py) with the other
 lanes: ``-m sanitizer`` selects, ``-m 'not sanitizer'`` sheds.
@@ -23,6 +25,66 @@ import pytest
 pytestmark = pytest.mark.sanitizer
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+#: filter_parser's record build from spans (parser_spans_build) over whole,
+#: hostile and torn chunks: spans, lengths and offsets anywhere, the plane's
+#: bytes past ASCII. It has to build, or raise; never read out of bounds.
+#: (No percent sign: the drivers it joins are %-formatted.)
+SPANS_BUILD_DRIVER = r"""
+# --- filter_parser's record build from spans: whole, hostile, torn ---
+import numpy as np
+def spans_case(n, width=48):
+    vals = [(f"h{i}|{i * 7 - 30}|{1 + i // 40:02d}/Oct/2000:13:55:"
+             f"{i // 9:02d} -0700").encode() for i in range(n)]
+    recs = [encode_event({"log": v.decode()}, EventTime(1700000000 + i, 5))
+            for i, v in enumerate(vals)]
+    offs = np.zeros(n + 1, dtype=np.int64)
+    offs[1:] = np.cumsum([len(r) for r in recs])
+    plane = np.zeros((n, width), dtype=np.uint8)
+    lens = np.zeros(n, dtype=np.int32)
+    spans = np.full((n, 3, 2), -1, dtype=np.int32)
+    for i, v in enumerate(vals):
+        plane[i, :len(v)] = np.frombuffer(v, dtype=np.uint8)
+        lens[i] = len(v)
+        a, b = v.index(b"|"), v.rindex(b"|")
+        spans[i] = [(0, a), (a + 1, b), (b + 1, len(v))]
+    return [b"".join(recs), offs, [plane], lens, np.ones(n, dtype=bool),
+            spans, (b"a", b"n", b"time"), b"\x00\x01\x00", 2, False,
+            b"dL/bL/YL:HL:ML:SWz", 0, True, True, True, b"\x81\xa3log", -1]
+out, left, native_rows, host_rows = mod.parser_spans_build(*spans_case(64))
+assert (native_rows, host_rows, left) == (64, 0, []), (native_rows, left)
+assert len(mod.decode_events(out)) == 64
+assert mod.parser_spans_build(*spans_case(0)) == (b"", [], 0, 0)
+for _ in range(400):
+    args = spans_case(rng.randrange(1, 40))
+    n = len(args[3])
+    kind = rng.randrange(6)
+    if kind == 0:    # spans anywhere: past the row, backwards, negative
+        args[5] = np.array([rng.randrange(-3, 60) for _ in range(n * 6)],
+                           dtype=np.int32).reshape(n, 3, 2)
+    elif kind == 1:  # lengths anywhere: negative, past the row
+        args[3] = np.array([rng.randrange(-3, 52) for _ in range(n)],
+                           dtype=np.int32)
+    elif kind == 2:  # a torn chunk, a heap copy that ends at the tear
+        args[0] = bytes(args[0][: rng.randrange(0, len(args[0]) + 1)])
+    elif kind == 3:  # offsets anywhere, before the buffer and past it
+        args[1] = np.array(sorted(rng.randrange(-5, len(args[0]) + 5)
+                                  for _ in range(n + 1)), dtype=np.int64)
+    elif kind == 4:  # the plane's bytes anywhere, past ASCII too
+        args[2] = [np.frombuffer(bytes(rng.randrange(256)
+                                       for _ in range(n * 48)),
+                                 dtype=np.uint8).reshape(n, 48)]
+    else:            # the chunk's bytes mutated under its offsets
+        mut = bytearray(args[0])
+        for _ in range(rng.randrange(1, 10)):
+            mut[rng.randrange(len(mut))] = rng.randrange(256)
+        args[0] = bytes(mut)
+    args[4] = np.array([rng.random() < 0.8 for _ in range(n)])
+    try:
+        mod.parser_spans_build(*args)
+    except ValueError:
+        pass  # handed back is fine; a fault is not
+"""
 
 DRIVER = r"""
 import os, random, sys
@@ -139,6 +201,7 @@ try:
     mod.forward_cut(b"\x92\xa1t" + b"\x91" * 100000 + b"\x90", 0)
 except mod.FallbackError:
     pass  # depth bound
+""" + SPANS_BUILD_DRIVER + """
 print("UBSAN_DRIVER_OK")
 """
 
