@@ -180,7 +180,7 @@ def unwatch_gc(hook) -> None:
 
 
 class _Timed:
-    __slots__ = ("tm", "key", "sp", "t0")
+    __slots__ = ("tm", "key", "sp", "t0", "seconds")
 
     def __init__(self, tm, key: str, sp):
         self.tm, self.key, self.sp = tm, key, sp
@@ -192,7 +192,8 @@ class _Timed:
 
     def __exit__(self, *exc):
         self.sp.__exit__(*exc)
-        self.tm.add(self.key, time.perf_counter() - self.t0)
+        self.seconds = time.perf_counter() - self.t0
+        self.tm.add(self.key, self.seconds)
         return False
 
 
@@ -231,7 +232,8 @@ class ShardedTimings:
 
     def timed(self, key: str, name: str, **ids):
         """Context manager: the span ``name`` around the work, and its
-        seconds added to ``key`` — one site, both readings."""
+        seconds added to ``key`` — one site, both readings. Once it
+        has exited it holds them as ``seconds``."""
         return _Timed(self, key, span(name, **ids))
 
     def __iter__(self):

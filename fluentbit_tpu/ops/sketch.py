@@ -36,6 +36,11 @@ except Exception:  # pragma: no cover
 FNV_OFFSET = np.uint32(0x811C9DC5)
 FNV_PRIME = np.uint32(0x01000193)
 
+# CountMin.query_array hashes the values of at most this many bytes in
+# one numpy pass over a [n, longest] u8 matrix (1 MiB at 4,096 values);
+# a longer value is hashed by itself (_cols_cpu)
+QUERY_VECTOR_WIDTH = 256
+
 
 def _fnv1a_scan(batch, lengths):
     """FNV-1a 32-bit over valid bytes of each row: [B, L] u8 → [B] u32.
@@ -360,16 +365,53 @@ class CountMin:
             int(table[r, c]) for r, c in enumerate(self._cols_cpu(value))
         ))
 
-    def query_many(self, values) -> list:
-        """Point estimates for many values with ONE device→host table
-        copy (per-value query() would sync the device each time)."""
+    def query_many(self, values: list) -> list:
+        """Point estimates for many values, as Python ints, with ONE
+        device→host table copy (per-value query() would sync the
+        device each time)."""
+        return self.query_array(values)[0].tolist()
+
+    def query_array(self, values: list) -> Tuple[np.ndarray, int]:
+        """Point estimates for many values as one array, and how many
+        of them were hashed one at a time: one table copy, one numpy
+        hash pass over the values of at most ``QUERY_VECTOR_WIDTH``
+        bytes (bit-identical to ``_cols_cpu``), ``_cols_cpu`` for each
+        longer one, one gather and a row-min."""
+        n = len(values)
         table = np.asarray(self.table)
-        out = []
-        for v in values:
-            out.append(int(min(
-                int(table[r, c]) for r, c in enumerate(self._cols_cpu(v))
-            )))
-        return out
+        if not n:
+            return np.zeros((0,), dtype=table.dtype), 0
+        lengths = np.fromiter(map(len, values), dtype=np.int64, count=n)
+        long_rows = np.flatnonzero(lengths > QUERY_VECTOR_WIDTH)
+        short = values
+        if long_rows.size:
+            short = [b"" if len(v) > QUERY_VECTOR_WIDTH else v
+                     for v in values]
+            lengths[long_rows] = 0
+        h1 = _hash32_many(short, lengths)
+        h2 = _mix(h1) | np.uint32(1)
+        cols = (h1[None, :] + self._row_ids[:, None] * h2[None, :]) \
+            % np.uint32(self.width)  # [d, n]
+        for i in long_rows:
+            cols[:, i] = self._cols_cpu(values[i])
+        rows = np.arange(self.depth)[:, None]
+        return table[rows, cols].min(axis=0), int(long_rows.size)
+
+
+def _hash32_many(values: list, lengths: np.ndarray) -> np.ndarray:
+    """``_hash32_cpu`` of many values at once → [n] u32: FNV-1a over the
+    byte position, the values zero-padded to the longest, each step
+    masked by the lengths (a zero byte inside a value is data) — the
+    device kernel's scan, in numpy's wrapping uint32 arithmetic."""
+    n = len(values)
+    width = int(lengths.max())
+    padded = np.frombuffer(b"".join(v.ljust(width, b"\0") for v in values),
+                           dtype=np.uint8).reshape(n, width)
+    by_pos = padded.T.astype(np.uint32, order="C")  # [width, n]
+    h = np.full((n,), FNV_OFFSET, dtype=np.uint32)
+    for pos in range(width):
+        h = np.where(pos < lengths, (h ^ by_pos[pos]) * FNV_PRIME, h)
+    return _mix(h)
 
 
 def _hash32_cpu(value: bytes) -> np.uint32:

@@ -26,6 +26,8 @@ from __future__ import annotations
 import logging
 from typing import Any, Dict, List, Optional
 
+import numpy as np
+
 from ..codec.chunk import EVENT_TYPE_METRICS
 from ..codec.msgpack import packb
 from ..core.config import ConfigMapEntry
@@ -47,6 +49,21 @@ def _stringify(v) -> str:
     if isinstance(v, float) and v.is_integer():
         return str(int(v))
     return str(v)
+
+
+def top_k(ests: np.ndarray, values: list, k: int) -> list:
+    """``sorted(zip(ests, values), reverse=True)[:k]`` — the k highest
+    (estimate, value) pairs, ties broken by the value's bytes, highest
+    first — with Python ints, sorting only the pairs that can be among
+    them: those whose estimate reaches the k-th highest."""
+    n = len(values)
+    if 0 < k < n:
+        kth = ests[np.argpartition(ests, n - k)[n - k]]
+        rows = np.flatnonzero(ests >= kth).tolist()
+    else:
+        rows = range(n)
+    return sorted(((int(ests[i]), values[i]) for i in rows),
+                  reverse=True)[:k]
 
 
 @registry.register
@@ -138,9 +155,13 @@ class LogToMetricsFilter(FilterPlugin):
         # sketch back (a device→host copy and the candidate sort). Each
         # key is a counter because a per-layer metric of the benchmark
         # reads it over the whole window; what has no metric (the
-        # snapshot's emit) is a span only
+        # snapshot's emit) is a span only. The frequency instance adds
+        # its query_s to cms_query_s too (the count-min's part, apart
+        # from the HLL's), with the candidates it estimated and how
+        # many of them were hashed one at a time (query_scalar_values)
         self.raw_timings = ShardedTimings(
-            ("select_s", "stage_s", "update_s", "query_s"))
+            ("select_s", "stage_s", "update_s", "query_s", "cms_query_s",
+             "query_values", "query_scalar_values"))
 
         # the cmt context emitted through the pipeline
         self.cmt = MetricsRegistry()
@@ -407,7 +428,7 @@ class LogToMetricsFilter(FilterPlugin):
             self.hll.update(b.batch, b.lengths)
             for i in b.overflow:  # oversized values resolve on CPU
                 self.hll.add_cpu(vals[i])
-        with tm.timed("query_s", "l2m.query"):
+        with tm.timed("query_s", "l2m.query", mode="hll"):
             labels = self._labels(selected[0].body) \
                 if self.label_keys else ()
             # the estimate reads the registers back: device→host copy
@@ -434,14 +455,14 @@ class LogToMetricsFilter(FilterPlugin):
             drop = len(self._freq_candidates) - 4096
             for k in list(self._freq_candidates)[:drop]:
                 del self._freq_candidates[k]
-        with tm.timed("query_s", "l2m.query"):
+        with tm.timed("query_s", "l2m.query", mode="cms",
+                      values=len(self._freq_candidates)) as q:
+            cands = list(self._freq_candidates)
             base = self._labels(selected[0].body) \
                 if self.label_keys else ()
             # one device→host table copy for the whole candidate set
-            ests = self.cms.query_many(list(self._freq_candidates))
-            top = sorted(
-                zip(ests, self._freq_candidates), reverse=True,
-            )[: self.frequency_top_k]
+            ests, n_scalar = self.cms.query_array(cands)
+            top = top_k(ests, cands, self.frequency_top_k)
             # the gauge reports the CURRENT top-k only: stale series
             # from values that dropped out must not linger in the
             # exposition
@@ -450,3 +471,6 @@ class LogToMetricsFilter(FilterPlugin):
                 self.metric.set(
                     est, base + (v.decode("utf-8", "replace"),)
                 )
+        tm.add("cms_query_s", q.seconds)
+        tm.add("query_values", len(cands))
+        tm.add("query_scalar_values", n_scalar)

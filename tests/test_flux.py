@@ -158,6 +158,44 @@ def test_topk_oversize_group_prefix_does_not_crash():
     assert [v for _, v in st.topk((b"ok",))] == [b"u2"]
 
 
+@pytest.mark.parametrize("geometry", [(4, 16384), (2, 64)])
+def test_topk_order_matches_per_value_sort(geometry):
+    """A group's top-k is what the per-value row-min and the sort by
+    (-estimate, value) gave: eight values tied across the k-th place go
+    by their bytes, a value past the read-back's vector width among
+    them."""
+    depth, width = geometry
+    st, _ = clocked_state(group_by=("tenant",), topk_field="user",
+                          topk=5, cms_depth=depth, cms_width=width,
+                          max_len=512)
+    rng = np.random.default_rng(width)
+    users = (["h0"] * 100 + ["h1"] * 90
+             + [f"tie{j}" for j in range(8)] * 40 + ["L" * 300] * 40
+             + [f"u{min(z, 500)}" for z in rng.zipf(1.5, 150)])
+    rng.shuffle(users)
+    bodies = [{"tenant": "a", "user": u} for u in users]
+    bodies += [{"tenant": "b", "user": f"u{min(z, 500)}"}
+               for z in rng.zipf(1.5, 300)]
+    rng.shuffle(bodies)
+    for i in range(0, len(bodies), 50):  # every row nominated
+        absorb_py(st, bodies[i:i + 50])
+    table = np.asarray(st.cms.table)
+    ties = 0
+    for key in ((b"a",), (b"b",)):
+        cand = list(st._candidates[key])
+        prefix = st._group_prefix(key)
+        ests = [min(int(table[r, c]) for r, c in
+                    enumerate(st.cms._cols_cpu(prefix + v)))
+                for v in cand]
+        ranked = sorted(zip(ests, cand), key=lambda t: (-t[0], t[1]))
+        ties += ranked[4][0] == ranked[5][0]
+        got = st.topk(key)
+        assert got == ranked[:5]
+        assert all(type(e) is int for e, _ in got)
+    assert b"L" * 300 in st._candidates[(b"a",)]
+    assert ties
+
+
 def test_event_time_requires_tumbling_window():
     from fluentbit_tpu.flux.state import FluxSpec as FS
 

@@ -5,6 +5,7 @@ device-sketch accuracy/merge tests.
 """
 
 import json
+import types
 
 import numpy as np
 import pytest
@@ -252,3 +253,123 @@ def test_flush_interval_timer_emits_pending():
             last = obj
     m = find_metric(last, "log_metric_n")
     assert m is not None and m["values"][0]["value"] == 3
+
+
+class _Emitter:
+    """The hidden emitter input's stand-in: what log_to_metrics adds."""
+
+    def __init__(self):
+        self.events = []
+
+    def add_event(self, tag, data, event_type, n_records=1):
+        self.events.append((tag, event_type, data))
+        return n_records
+
+
+class _Engine:
+    def __init__(self):
+        self.emitter = _Emitter()
+
+    def hidden_input(self, name, owner=None, **props):
+        return types.SimpleNamespace(plugin=self.emitter)
+
+
+class _TopKBefore:
+    """The frequency snapshot as it was built before the read-back was
+    vectorised: the same candidate set (recency by delete-and-reinsert,
+    the 4,096 most recent kept), each candidate's row-min by
+    ``_cols_cpu`` over one table copy, every (estimate, value) pair
+    sorted."""
+
+    def __init__(self, k):
+        from fluentbit_tpu.core.metrics import MetricsRegistry
+
+        self.k = k
+        self.cands = {}
+        self.reg = MetricsRegistry()
+        self.gauge = self.reg.gauge("log_metric", "", "m", "d", ("value",))
+        self.kth_ties = 0
+        self.estimated = 0
+
+    def snapshot(self, cms, vals):
+        from fluentbit_tpu.codec.msgpack import packb
+
+        for v in vals:
+            self.cands.pop(v, None)
+            self.cands[v] = None
+        for v in list(self.cands)[:max(0, len(self.cands) - 4096)]:
+            del self.cands[v]
+        self.estimated += len(self.cands)
+        table = np.asarray(cms.table)
+        ests = [min(int(table[r, c]) for r, c in enumerate(cms._cols_cpu(v)))
+                for v in self.cands]
+        ranked = sorted(zip(ests, self.cands), reverse=True)
+        if len(ranked) > self.k and ranked[self.k - 1][0] == ranked[self.k][0]:
+            self.kth_ties += 1
+        self.gauge.clear()
+        for est, v in ranked[:self.k]:
+            self.gauge.set(est, (v.decode("utf-8", "replace"),))
+        return packb(self.reg.to_msgpack_obj())
+
+
+def _zipf_frames(seed, frames=12):
+    """Frames of paths: Zipf(1.2) over 10,000 paths, twelve paths seen
+    six times a frame (tied near the tenth place), 450 paths seen once
+    (5,400 in all: past the 4,096 candidates) and two of 300 bytes
+    (past the staging width and the read-back's vector width)."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for f in range(frames):
+        paths = [f"/api/v1/item/{min(z, 10_000)}"
+                 for z in rng.zipf(1.2, 300)]
+        paths += [f"/tie/{j}" for j in range(12)] * 6
+        paths += [f"/u/{f}/{i}" for i in range(450)]
+        paths += [f"/long/{j}/" + "x" * 290 for j in range(2)]
+        rng.shuffle(paths)
+        out.append(paths)
+    return out
+
+
+@pytest.mark.parametrize("geometry", [(4, 16384), (2, 256)])
+def test_frequency_snapshots_byte_identical_to_full_sort(geometry,
+                                                         monkeypatch):
+    """Every metrics snapshot the frequency mode emits is the one the
+    per-value read-back and the full sort gave, byte for byte: through
+    the candidate cap's evictions and with the tenth place tied."""
+    from fluentbit_tpu.codec.chunk import EVENT_TYPE_METRICS
+    from fluentbit_tpu.codec.events import LogEvent
+    from fluentbit_tpu.core import metrics
+    from fluentbit_tpu.core.plugin import registry
+
+    monkeypatch.setattr(metrics, "time",
+                        types.SimpleNamespace(time=lambda: 1.7e9))
+    depth, width = geometry
+    ins = registry.create_filter("log_to_metrics")
+    for key, v in {"metric_mode": "frequency", "value_field": "path",
+                   "metric_name": "m", "metric_description": "d",
+                   "tag": "metrics", "sketch_depth": str(depth),
+                   "sketch_width": str(width)}.items():
+        ins.set(key, v)
+    ins.configure()
+    engine = _Engine()
+    ins.plugin.init(ins, engine)
+    flt = ins.plugin
+    before = _TopKBefore(flt.frequency_top_k)
+
+    expect, seen = [], set()
+    for paths in _zipf_frames(sum(geometry)):
+        events = [LogEvent(1.0, {"path": p}) for p in paths]
+        flt.filter(events, "logs", None)
+        vals = [p.encode() for p in paths]
+        seen.update(vals)
+        expect.append(("metrics", EVENT_TYPE_METRICS,
+                       before.snapshot(flt.cms, vals)))
+
+    assert engine.emitter.events == expect
+    assert len(seen) > 4096
+    assert list(flt._freq_candidates) == list(before.cands)
+    assert before.kth_ties > 0
+    tm = flt.raw_timings
+    assert tm["query_values"] == before.estimated
+    assert tm["query_scalar_values"] == 2 * len(expect)
+    assert 0 < tm["cms_query_s"] <= tm["query_s"]

@@ -191,3 +191,52 @@ def test_native_twins_present():
     assert native.hll_update(regs, staged.batch, staged.lengths, 8)
     table = np.zeros((2, 64), dtype=np.int64)
     assert native.cms_update(table, staged.batch, staged.lengths)
+
+
+def _readback_values(kind):
+    """Values for the count-min read-back: paths shaped like the sketch
+    corpus, edge bytes, and every length from 1 to 600 — those past
+    ``QUERY_VECTOR_WIDTH`` take the one-at-a-time hash."""
+    rng = random.Random(kind)
+    if kind == "none":
+        return []
+    if kind == "one":
+        return [b"/api/v1/item/7"]
+    if kind == "edge_bytes":
+        return [b"", b"\x00", b"a", b"\x00\x00", b"\xff\xfe\x80",
+                b"caf\xc3\xa9", bytes(range(256))]
+    if kind == "lengths_1_600":
+        return [bytes(rng.randrange(256) for _ in range(n))
+                for n in range(1, 601)]
+    assert kind == "corpus_4096"
+    return [f"/api/v1/item/{i}".encode()
+            for i in rng.sample(range(10_000), 4096)]
+
+
+@pytest.mark.parametrize("geometry", [(4, 16384), (2, 512)])
+@pytest.mark.parametrize("table_kind", ["numpy", "jnp"])
+@pytest.mark.parametrize("kind", ["none", "one", "edge_bytes",
+                                  "lengths_1_600", "corpus_4096"])
+def test_cms_query_many_matches_per_value(kind, table_kind, geometry):
+    """The vectorised read-back gives every value the row-min of the
+    columns ``_cols_cpu`` computes for it (the device kernel's hash),
+    as a Python int, and counts the values it hashed one at a time."""
+    from fluentbit_tpu.ops.sketch import QUERY_VECTOR_WIDTH
+
+    depth, width = geometry
+    cms = CountMin(depth, width)
+    rng = np.random.default_rng(depth * width)
+    table = rng.integers(0, 1 << 20, size=(depth, width)).astype(cms._dtype)
+    cms.table = table if table_kind == "numpy" else jax.numpy.asarray(table)
+    vals = _readback_values(kind)
+
+    expect = [min(int(table[r, c]) for r, c in enumerate(cms._cols_cpu(v)))
+              for v in vals]
+    got = cms.query_many(vals)
+    assert got == expect
+    assert all(type(e) is int for e in got)
+    ests, n_scalar = cms.query_array(vals)
+    assert ests.tolist() == expect
+    assert n_scalar == sum(len(v) > QUERY_VECTOR_WIDTH for v in vals)
+    if kind == "lengths_1_600":
+        assert n_scalar == 600 - QUERY_VECTOR_WIDTH

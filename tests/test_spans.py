@@ -36,6 +36,8 @@ N_LINES = 96
 CHUNK = "frame-0001"
 #: ``log_to_metrics``' ``raw_timings``: seconds by phase of an append
 L2M_KEYS = ("select_s", "stage_s", "update_s", "query_s")
+#: the count-min read-back's own keys: only the frequency instance adds to them
+L2M_CMS_KEYS = ("cms_query_s", "query_values", "query_scalar_values")
 
 
 def wait_for(cond, timeout=20.0, interval=0.01):
@@ -1267,9 +1269,13 @@ def test_log_to_metrics_and_flux_have_raw_timings():
     card, freq, flux = (f.plugin for f in e.filters)
     for plugin in (card, freq):
         tm = plugin.raw_timings
-        assert set(tm) == set(L2M_KEYS)
+        assert set(tm) == set(L2M_KEYS + L2M_CMS_KEYS)
         for key in L2M_KEYS:
             assert tm[key] > 0, (plugin.mode, key)
+    assert [card.raw_timings[k] for k in L2M_CMS_KEYS] == [0, 0, 0]
+    tm = freq.raw_timings
+    assert 0 < tm["cms_query_s"] <= tm["query_s"]
+    assert tm["query_values"] == 7 and tm["query_scalar_values"] == 0
     tm = flux.raw_timings
     assert tm is flux.state.timings
     from fluentbit_tpu.flux.state import TIMING_KEYS
@@ -1280,7 +1286,9 @@ def test_log_to_metrics_and_flux_have_raw_timings():
     assert len(tm._shards) == 1
 
 
-@pytest.mark.parametrize("key", L2M_KEYS + ("absorb_s", "compact_s"))
+@pytest.mark.parametrize("key", L2M_KEYS + ("absorb_s", "compact_s",
+                                             "cms_query_s",
+                                             "query_scalar_values"))
 def test_every_timing_key_this_pr_adds_feeds_a_metric(key):
     """An always-on counter that nothing reads is only a cost: each
     seconds key of ``log_to_metrics`` and ``flux`` (and grep's
