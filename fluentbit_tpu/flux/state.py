@@ -90,10 +90,15 @@ _MAX_CANDIDATE_GROUPS = 4096
 #: inputs and its fold into the groups, or the host twin: span
 #: ``flux.values``, a part of ``absorb_s``), ``device_value_absorbs`` /
 #: ``host_value_absorbs`` absorbs of a state with numeric columns whose
-#: sum, min and max came from the fused device program / the host twin
+#: sum, min and max came from the fused device program / the host twin;
+#: ``group_key_bytes`` the width of the key ``_group_rows`` uniqued,
+#: summed over the ``grouped`` absorbs that grouped; ``topk_s`` host
+#: seconds of the top-k composites and their nomination (span
+#: ``flux.topk``, a part of ``absorb_s``)
 TIMING_KEYS = ("absorb_s", "group_s", "fused_absorbs", "host_absorbs",
                "close_s", "closes", "closed_groups", "emitted_rows",
-               "value_s", "device_value_absorbs", "host_value_absorbs")
+               "value_s", "device_value_absorbs", "host_value_absorbs",
+               "group_key_bytes", "grouped", "topk_s")
 
 #: the magnitude up to which every integer is a double
 _EXACT_DOUBLE = 1 << 53
@@ -628,35 +633,55 @@ class FluxState:
     def _group_rows(self, n_rows: int, keycols: Dict[str, KeyCol]
                     ) -> Tuple[np.ndarray, List[tuple]]:
         """Segment ids (first-seen order) + group key tuples: ONE
-        ``np.unique`` over a fixed-width key — a kind byte a field,
-        eight bytes where the column holds integers, and the string
-        plane with its length only where it holds strings."""
+        ``np.unique`` over a fixed-width key of the bytes that can tell
+        this batch's rows apart — a field's kind byte, eight bytes where
+        the column holds integers, and where it holds strings the plane
+        as far as the batch's longest key and the length; a column the
+        same in every row is left out. A key of at most eight bytes is
+        uniqued as one ``u64``."""
         gb = self.spec.group_by
         if not gb:
             return np.zeros((n_rows,), dtype=np.int64), [()]
+
+        def varies(col):
+            return bool((col != col[0]).any())
+
         mats = []
         for f in gb:
             kc = keycols[f]
-            mats.append(kc.kind.reshape(-1, 1))
-            if kc.ints is not None:
+            if varies(kc.kind):
+                mats.append(kc.kind.reshape(-1, 1))
+            if kc.ints is not None and varies(kc.ints):
                 mats.append(np.ascontiguousarray(
                     kc.ints, dtype="<i8").view(np.uint8).reshape(-1, 8))
             if kc.sbatch is not None:
-                b, L = kc.sbatch, kc.sbatch.shape[1]
                 ln2 = np.where(kc.kind == KEY_STR, kc.slen,
                                np.int32(-1)).astype(np.int32)
-                bz = np.ascontiguousarray(b, dtype=np.uint8).copy()
-                # zero pad bytes so the void view compares by value;
-                # the length column disambiguates embedded-NUL prefixes
-                mask = np.arange(L)[None, :] >= \
-                    np.clip(ln2, 0, None)[:, None]
-                bz[mask] = 0
+                used = np.clip(ln2, 0, None)
+                w = int(used.max())
+                bz = np.array(kc.sbatch[:, :w], dtype=np.uint8)
+                # zero pad bytes so the key compares by value; past the
+                # longest key every row's bytes would be pad, zero; the
+                # length column disambiguates embedded-NUL prefixes
+                bz[np.arange(w)[None, :] >= used[:, None]] = 0
                 mats.append(bz)
-                mats.append(ln2.astype("<i4").view(np.uint8)
-                            .reshape(-1, 4))
-        keyed = np.ascontiguousarray(np.concatenate(mats, axis=1))
-        void = keyed.view(f"V{keyed.shape[1]}").reshape(-1)
-        _, first_idx, inv = np.unique(void, return_index=True,
+                if varies(ln2):
+                    mats.append(ln2.astype("<i4").view(np.uint8)
+                                .reshape(-1, 4))
+        width = sum(m.shape[1] for m in mats)
+        keyed = np.zeros((n_rows, max(width, 8)), dtype=np.uint8)
+        at = 0
+        for m in mats:
+            keyed[:, at:at + m.shape[1]] = m
+            at += m.shape[1]
+        if self.timings is not None:
+            self.timings.add("group_key_bytes", keyed.shape[1])
+            self.timings.add("grouped", 1)
+        # a u64 sorts otherwise than its bytes, which nothing reads:
+        # the segments are renumbered first-seen below
+        flat = keyed.view("<u8" if width <= 8 else f"V{width}") \
+            .reshape(-1)
+        _, first_idx, inv = np.unique(flat, return_index=True,
                                       return_inverse=True)
         order = np.argsort(first_idx, kind="stable")
         remap = np.empty(order.size, dtype=np.int64)
@@ -677,9 +702,7 @@ class FluxState:
 
     def _absorb_rows(self, pane: Dict[tuple, _FluxGroup], n_rows: int,
                      strcols, numcols, keycols) -> None:
-        tm = self.timings
-        with (tm.timed("group_s", "flux.group", rows=n_rows)
-              if tm is not None else span("flux.group", rows=n_rows)):
+        with self._timed("group_s", "flux.group", rows=n_rows):
             seg, keys = self._group_rows(n_rows, keycols)
         n_groups = len(keys)
         single = n_groups == 1
@@ -694,10 +717,15 @@ class FluxState:
             bounds = np.searchsorted(seg[order],
                                      np.arange(n_groups + 1))
 
+        def grows(gid):
+            if single:
+                return np.arange(n_rows)
+            return order[bounds[gid]:bounds[gid + 1]]
+
         def gslice(gid, b, ln):
             if single:
                 return b, ln
-            gidx = order[bounds[gid]:bounds[gid + 1]]
+            gidx = grows(gid)
             return b[gidx], ln[gidx]
 
         groups: List[_FluxGroup] = []
@@ -706,40 +734,24 @@ class FluxState:
             if g is None:
                 g = pane[key] = _FluxGroup(self.spec)
             groups.append(g)
-        # top-k composites: host-built per group (prefix + value) in
-        # group order, absorbed below as ONE concatenated batch; the
+        # top-k composites (prefix + value): every group's rows in
+        # group order in ONE plane, absorbed below as one batch; the
         # candidate nomination reads the host rows and is independent
         # of how (or whether) the sketch update launches
         comp = comp_len = None
         if self.spec.topk_field:
             tb, tl = strcols[self.spec.topk_field]
-            parts = []
-            for gid, key in enumerate(keys):
-                gb, gl = gslice(gid, tb, tl)
-                built = self._topk_composites(key, gb, gl)
-                if built is None:
-                    continue
-                c, cl, plen = built
-                self._topk_nominate(key, c, cl, plen)
-                parts.append((c, cl))
-            if parts:
-                W = self.spec.max_len
-                comp = np.concatenate([c for c, _ in parts])
-                comp_len = np.concatenate([cl for _, cl in parts])
-                Bc = bucket_size(comp.shape[0], max_len=W)
-                if Bc > comp.shape[0]:
-                    comp = np.concatenate(
-                        [comp, np.zeros((Bc - comp.shape[0], W),
-                                        dtype=np.uint8)])
-                    comp_len = np.concatenate(
-                        [comp_len, np.full((Bc - comp_len.shape[0],),
-                                           -1, dtype=np.int32)])
+            vw = max(int(tl.max()), 0)
+            with self._timed("topk_s", "flux.topk", rows=n_rows, width=vw):
+                comp, comp_len = self._topk_composites(keys, tb, tl, vw,
+                                                       grows)
         fuse = (self._mesh is not None or self._use_device()) \
             and n_groups <= self._FUSED_MAX_GROUPS
         numeric = self.spec.numeric
         values = stacks = None
         if fuse and numeric:
-            with self._values_span(n_rows, "stage"):
+            with self._timed("value_s", "flux.values", rows=n_rows,
+                             phase="stage"):
                 values = self._value_inputs(numcols, groups, n_rows)
         if fuse:
             # ONE device launch for the whole absorb — counts + every
@@ -768,7 +780,8 @@ class FluxState:
             g.count += c
         if not numeric:
             return
-        with self._values_span(n_rows, "fold"):
+        with self._timed("value_s", "flux.values", rows=n_rows,
+                         phase="fold"):
             if stacks is not None:
                 self._fold_values(groups, stacks)
             else:
@@ -786,12 +799,13 @@ class FluxState:
             self.timings.add("device_value_absorbs" if stacks is not None
                              else "host_value_absorbs", 1)
 
-    def _values_span(self, n_rows: int, phase: str):
+    def _timed(self, key: str, name: str, **ids):
+        """The span ``name`` around one piece of an absorb, and its
+        seconds added to ``key`` where a plugin times this state."""
         tm = self.timings
         if tm is None:
-            return span("flux.values", rows=n_rows, phase=phase)
-        return tm.timed("value_s", "flux.values", rows=n_rows,
-                        phase=phase)
+            return span(name, **ids)
+        return tm.timed(key, name, **ids)
 
     def _value_inputs(self, numcols, groups: List[_FluxGroup],
                       n_rows: int) -> Optional[list]:
@@ -1041,42 +1055,56 @@ class FluxState:
             lane = self._lane = fault.lane("flux")
         return lane
 
-    def _topk_composites(self, key: tuple, batch: np.ndarray,
-                         lengths: np.ndarray):
-        """Build one group's top-k composite rows (``prefix + value``)
-        host-side — ``(comp, comp_len, plen)`` over the group's VALID
-        rows, or None when the group contributes nothing.  The sketch
-        update itself happens once for the whole batch (fused launch or
-        host twin) on the concatenation of every group's rows."""
-        prefix = self._group_prefix(key)
+    def _topk_composites(self, keys: List[tuple], batch: np.ndarray,
+                         lengths: np.ndarray, vw: int, rows_of):
+        """Every group's top-k composite rows (``prefix + value``),
+        built host-side straight into ONE bucketed ``[Bc, W]`` plane: a
+        group's VALID rows one after the other, in group order, and its
+        candidates nominated from them. A row is written only as far as
+        its prefix and ``vw`` (the batch's longest value) reach; past
+        that the plane is the zeros it was made with. → ``(comp,
+        comp_len)``, or ``(None, None)`` where no group contributes a
+        row. The sketch update itself happens once for the whole plane
+        (fused launch or host twin)."""
         W = self.spec.max_len
-        valid = np.nonzero(lengths >= 0)[0]
-        if valid.size == 0:
-            return None
-        plen = len(prefix)
-        if plen > W:
-            # the group prefix alone exceeds the composite width: no
-            # value can fit, and the broadcast below would raise AFTER
-            # earlier groups committed (a partial absorb = the
-            # batch-exactness violation). Skip identically on both
-            # paths — this group simply has no top-k.
-            return None
-        comp = np.zeros((valid.size, W), dtype=np.uint8)
-        comp_len = np.full((valid.size,), -1, dtype=np.int32)
-        if plen:
-            comp[:, :plen] = np.frombuffer(prefix, dtype=np.uint8)
-        vl = lengths[valid]
-        fits = plen + vl <= W
-        span = min(W - plen, batch.shape[1])
-        comp[:, plen:plen + span] = batch[valid, :span]
-        # oversize composites are excluded on BOTH paths (comp_len -1)
-        comp_len[fits] = (plen + vl[fits]).astype(np.int32)
-        # zero pad bytes past each composite's length (the batch slice
-        # above copied arena garbage); candidate extraction below walks
-        # by length so only the staged device batch needs the zeroing
-        pad = np.arange(W)[None, :] >= np.clip(comp_len, 0, None)[:, None]
-        comp[pad] = 0
-        return comp, comp_len, plen
+        parts = []
+        for gid, key in enumerate(keys):
+            rows = rows_of(gid)
+            valid = rows[lengths[rows] >= 0]
+            if valid.size == 0:
+                continue
+            prefix = self._group_prefix(key)
+            if len(prefix) > W:
+                # the group prefix alone exceeds the composite width:
+                # no value can fit. Skip identically on both paths —
+                # this group simply has no top-k.
+                continue
+            parts.append((key, prefix, valid))
+        n = sum(valid.size for _, _, valid in parts)
+        if n == 0:
+            return None, None
+        comp = np.zeros((bucket_size(n, max_len=W), W), dtype=np.uint8)
+        comp_len = np.full((comp.shape[0],), -1, dtype=np.int32)
+        r0 = 0
+        for key, prefix, valid in parts:
+            r1 = r0 + valid.size
+            plen = len(prefix)
+            cols = min(W - plen, vw)
+            c, cl = comp[r0:r1, :plen + cols], comp_len[r0:r1]
+            if plen:
+                c[:, :plen] = np.frombuffer(prefix, dtype=np.uint8)
+            c[:, plen:] = batch[valid, :cols]
+            vl = lengths[valid]
+            # oversize composites are excluded on BOTH paths (-1)
+            fits = plen + vl <= W
+            cl[fits] = (plen + vl[fits]).astype(np.int32)
+            # zero pad bytes past each composite's length (the copy
+            # above brought arena garbage): the device reads the plane
+            c[np.arange(plen + cols)[None, :] >=
+              np.clip(cl, 0, None)[:, None]] = 0
+            self._topk_nominate(key, comp[r0:r1], cl, plen)
+            r0 = r1
+        return comp, comp_len
 
     def _topk_nominate(self, key: tuple, comp: np.ndarray,
                        comp_len: np.ndarray, plen: int) -> None:
